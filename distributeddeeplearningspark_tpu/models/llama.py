@@ -189,24 +189,6 @@ def _remat_policy(name: str | None):
     return policies[name]
 
 
-_BARRIER_DIFFERENTIABLE: bool | None = None
-
-
-def _barrier_differentiable() -> bool:
-    """jax < 0.5 has no differentiation rule for optimization_barrier, so the
-    scan_param_barrier memory optimization (a numerics no-op by contract)
-    must quietly disable itself there instead of killing the backward pass.
-    Probed once with a scalar trace, cached for the process."""
-    global _BARRIER_DIFFERENTIABLE
-    if _BARRIER_DIFFERENTIABLE is None:
-        try:
-            jax.grad(jax.lax.optimization_barrier)(0.0)
-            _BARRIER_DIFFERENTIABLE = True
-        except Exception:
-            _BARRIER_DIFFERENTIABLE = False
-    return _BARRIER_DIFFERENTIABLE
-
-
 def rotary_embedding(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     """Apply RoPE to [B,S,H,D] in f32, half-split (rotate-half) convention."""
     d = x.shape[-1]
@@ -506,8 +488,7 @@ class LlamaForCausalLM(nn.Module):
         segment_ids = batch.get("segment_ids")
 
         layer_cls = DecoderLayer
-        if cfg.scan_layers and cfg.scan_param_barrier \
-                and _barrier_differentiable():
+        if cfg.scan_layers and cfg.scan_param_barrier:
             # barrier each SLICED layer's params (see the config field's
             # rationale). MUST wrap inside the remat region (i.e. before
             # nn.remat): outside it, the barrier's outputs become per-layer

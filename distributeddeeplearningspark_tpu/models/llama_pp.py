@@ -65,21 +65,13 @@ def build_stage_modules(cfg: LlamaConfig, stage_len: int):
     both pipeline implementations run, factored so the MPMD per-gang stage
     program (train/pipeline_trainer.py) computes bit-for-bit the same math
     as this module's single-program GPipe ring."""
-    from distributeddeeplearningspark_tpu.models.llama import (
-        _barrier_differentiable,
-    )
-
     layer_cls = DecoderLayer
-    if cfg.scan_param_barrier and _barrier_differentiable():
+    if cfg.scan_param_barrier:
         # same whole-stack relayout hazard as the non-PP scan (see
         # LlamaConfig.scan_param_barrier): each stage's [L/P, ...] stacked
         # weights would otherwise grow hoisted fwd+bwd layout copies.
         # Ordering as in llama.py: inside the remat region, or the barrier
-        # outputs become per-layer saved residuals — and like llama.py's
-        # own scan, the wrap must auto-disable on jax builds whose
-        # optimization_barrier has no autodiff rule, or every backward
-        # through a pipeline stage dies (llama.py got this guard in the
-        # jax-skew fix round; this path had been left behind).
+        # outputs become per-layer saved residuals.
         layer_cls = nn.map_variables(
             layer_cls, "params",
             trans_in_fn=lambda tree: jax.tree.map(

@@ -126,7 +126,14 @@ class MoEMLP(nn.Module):
             dropped = dropped + jnp.sum(
                 ((onehot > 0) & ~keep).astype(jnp.float32))
             gate = jnp.sum(probs * onehot, axis=-1)           # [B, S]
-            kept_gate = gate * keep.any(axis=-1)
+            # = gate * keep.any(-1), bit for bit (keep ⊆ onehot, one 1 a
+            # token) — but spelled as a float sum: a BOOL reduction over
+            # the expert axis is miscomputed by XLA:TPU when that axis is
+            # sharded (jax 0.9.0 / libtpu 0.0.34, four v5e chips, PR 21:
+            # each device OR-ed only its own experts' columns, kept_gate
+            # was off by up to 0.98 and the layer's output by its own
+            # magnitude; repro: __graft_entry__._dryrun_llama_moe(4))
+            kept_gate = jnp.sum(probs * keep, axis=-1)
             dispatch = dispatch + slot.astype(self.dtype)
             combine = combine + slot * kept_gate[:, :, None, None]
             gate_sum = gate_sum + kept_gate

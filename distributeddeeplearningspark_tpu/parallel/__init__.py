@@ -30,7 +30,6 @@ from distributeddeeplearningspark_tpu.parallel.plan import (
     FSDP_PLAN,
     Plan,
     PlanError,
-    PlanTensorAxisWarning,
     PlanValidationError,
     compile_step_with_plan,
     plan_for_rules,
@@ -67,7 +66,6 @@ __all__ = [
     "Plan",
     "PlanError",
     "PlanValidationError",
-    "PlanTensorAxisWarning",
     "compile_step_with_plan",
     "plan_for_rules",
     "stage_plan",

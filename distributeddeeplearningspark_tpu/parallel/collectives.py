@@ -66,14 +66,11 @@ def collective_probes_enabled() -> bool:
 
 
 def _is_tracing() -> bool:
-    """True whenever ANY trace is being built — checked globally, not by
-    sniffing the operands: a concrete constant captured inside a jit trace
-    would pass a per-leaf Tracer check and emit one bogus trace-time event
-    that looks like an execution-time wait."""
-    clean = getattr(jax.core, "trace_state_clean", None)
-    if clean is not None:
-        return not clean()
-    return False  # no API to ask — treat as eager (old jax)
+    """True whenever ANY trace is being built (jit, grad, shard_map, ...) —
+    checked globally, not by sniffing the operands: a concrete constant
+    captured inside a jit trace would pass a per-leaf Tracer check and emit
+    one bogus trace-time event that looks like an execution-time wait."""
+    return not jax.core.trace_ctx.is_top_level()
 
 
 def _probed(op: str, fn: Callable) -> Callable:
@@ -137,7 +134,7 @@ def barrier_probe(mesh, *, tag: str = "barrier") -> float:
     if fn is None:
         from jax.sharding import PartitionSpec as P
 
-        body = shard_map(lambda x: lax.psum(x, names), mesh=mesh,
+        body = jax.shard_map(lambda x: lax.psum(x, names), mesh=mesh,
                          in_specs=P(), out_specs=P())
         fn = jax.jit(body)
         jax.block_until_ready(fn(jnp.zeros((), jnp.float32)))  # compile
@@ -147,27 +144,6 @@ def barrier_probe(mesh, *, tag: str = "barrier") -> float:
     wait = time.perf_counter() - t0
     telemetry.emit("collective", op=tag, axis=",".join(names), wait_s=wait)
     return wait
-
-
-def axis_size(axis_name: AxisNames) -> int:
-    """``lax.axis_size`` for jax versions that predate it (the classic
-    ``psum(1, axis)`` constant-folds to the static mesh axis size)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
-def shard_map(f, **kwargs):
-    """``jax.shard_map`` across the 0.4→0.5 API move: older jax keeps it in
-    ``jax.experimental.shard_map`` and spells ``check_vma`` as ``check_rep``.
-    Every shard_map in this package goes through here."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    if "check_vma" in kwargs:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _sm(f, **kwargs)
 
 
 def all_reduce_sum(tree: Any, axis: AxisNames = BATCH_AXES) -> Any:

@@ -39,7 +39,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from distributeddeeplearningspark_tpu.parallel.mesh import AXIS_PIPE, BATCH_AXES
-from distributeddeeplearningspark_tpu.parallel.collectives import shard_map
 
 StageFn = Callable[[Any, jax.Array], jax.Array]
 
@@ -125,7 +124,7 @@ def pipeline(
     x_mb = x.reshape((num_microbatches, b // num_microbatches) + x.shape[1:])
 
     act_spec = P(None, BATCH_AXES)  # [M, mb, ...]: rows sharded, rest replicated
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _pipeline_local, stage_fn=stage_fn, num_stages=p,
             num_microbatches=num_microbatches,

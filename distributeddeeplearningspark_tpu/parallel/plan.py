@@ -38,10 +38,10 @@ bytes-accessed instead of folklore, and the winner serializes
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import json
 import os
-import warnings
 from typing import Any, Callable, Mapping
 
 from distributeddeeplearningspark_tpu.parallel.mesh import BATCH_AXES
@@ -52,9 +52,6 @@ from distributeddeeplearningspark_tpu.parallel.sharding import (
     path_str,
 )
 
-#: escape hatch for the tensor-axis refusal below (any value but ""/"0").
-TENSOR_ESCAPE_ENV = "DLS_PLAN_ALLOW_TENSOR"
-
 #: current on-disk plan format (Plan.save / Plan.load).
 PLAN_FORMAT = 1
 
@@ -64,30 +61,7 @@ class PlanError(ValueError):
 
 
 class PlanValidationError(PlanError):
-    """A plan cannot compile on this mesh (axis mismatch, bad style, or a
-    strict-mode refusal such as the tensor-axis skew guard)."""
-
-
-class PlanTensorAxisWarning(UserWarning):
-    """This jax build miscomputes on meshes with a ``tensor`` axis > 1
-    (~1.2% wrong losses — ROADMAP 'this round's jax skew', pinned repros
-    ``test_pp_composes_with_tp_and_dp`` and the ``dryrun_multichip(8)``
-    [data×fsdp×seq×tensor] fingerprint). Non-strict validation warns;
-    strict validation (the plan sweep) refuses so the bug cannot silently
-    poison a ranking. ``DLS_PLAN_ALLOW_TENSOR=1`` overrides both."""
-
-
-def tensor_axis_allowed() -> bool:
-    return os.environ.get(TENSOR_ESCAPE_ENV, "") not in ("", "0")
-
-
-_TENSOR_MSG = (
-    "mesh has tensor={n} > 1: this jax build's partitioner computes ~1.2% "
-    "wrong losses on tensor-sharded param layouts (ROADMAP 'jax skew' — "
-    "pinned repros: test_pp_composes_with_tp_and_dp, dryrun_multichip(8) "
-    "[data x fsdp x seq x tensor] fingerprint). {action} Set "
-    + TENSOR_ESCAPE_ENV + "=1 to override after re-probing on a newer jax."
-)
+    """A plan cannot compile on this mesh (axis mismatch, bad style)."""
 
 
 def _spec_entries(spec) -> list:
@@ -204,15 +178,10 @@ class Plan:
 
     # -- validation ----------------------------------------------------------
 
-    def validate(self, mesh, *, strict: bool = False) -> None:
-        """Centralized spec validation for this plan on ``mesh``.
-
-        Checks every mesh axis the plan mentions exists, the style is
-        known, and applies the tensor-axis skew guard: a ``tensor`` axis
-        > 1 on this jax build WARNS (:class:`PlanTensorAxisWarning`) on
-        the ordinary compile path and REFUSES under ``strict=True`` (the
-        plan sweep) — unless ``DLS_PLAN_ALLOW_TENSOR=1``.
-        """
+    def validate(self, mesh) -> None:
+        """Centralized spec validation for this plan on ``mesh``: every mesh
+        axis the plan mentions exists, the style is known, and ZeRO axes
+        are replica axes."""
         if self.style not in ("jit", "shard_map"):
             raise PlanValidationError(
                 f"plan {self.name!r}: style must be 'jit'|'shard_map', got "
@@ -240,16 +209,6 @@ class Plan:
                 f"replica (batch) axes — ZeRO shards optimizer state across "
                 f"the axes that replicate it, i.e. a subset of batch_axes "
                 f"{self.batch_axes}")
-        tensor_n = dict(mesh.shape).get("tensor", 1)
-        if tensor_n > 1 and not tensor_axis_allowed():
-            if strict:
-                raise PlanValidationError(_TENSOR_MSG.format(
-                    n=tensor_n,
-                    action="Refusing (strict validation: a sweep ranking "
-                           "must not be poisoned by wrong-math probes)."))
-            warnings.warn(_TENSOR_MSG.format(
-                n=tensor_n, action="Proceeding with a warning."),
-                PlanTensorAxisWarning, stacklevel=2)
 
     # -- shardings -----------------------------------------------------------
 
@@ -382,7 +341,6 @@ def compile_step_with_plan(
     name: str | None = None,
     instrument: bool = True,
     expected_signatures: int = 1,
-    strict: bool = False,
 ):
     """Compile ``step_fn`` under ``plan`` — the one jit call every
     strategy shares.
@@ -394,7 +352,7 @@ def compile_step_with_plan(
     ``style="jit"`` compiles via jit-with-explicit-shardings (batch
     shardings inherited from the arrays — ``put_global`` stays the single
     source of truth for the input layout); ``style="shard_map"`` wraps
-    the body in :func:`~.collectives.shard_map` over the plan's batch
+    the body in :func:`jax.shard_map` over the plan's batch
     axes so map-style code using the explicit Horovod verbs compiles
     through the same path.
 
@@ -408,7 +366,7 @@ def compile_step_with_plan(
 
     if kind not in ("train", "eval", "predict"):
         raise PlanError(f"kind must be 'train'|'eval'|'predict', got {kind!r}")
-    plan.validate(mesh, strict=strict)
+    plan.validate(mesh)
     if state_shardings is None:
         if state_abstract is None:
             raise PlanError(
@@ -417,15 +375,12 @@ def compile_step_with_plan(
         state_shardings = plan.state_shardings(state_abstract, mesh)
     rep = NamedSharding(mesh, P())
     donate = (0,) if (kind == "train" and plan.donate_state) else ()
+    step_fn = traced_on(step_fn, mesh)
 
     if plan.style == "shard_map":
-        from distributeddeeplearningspark_tpu.parallel.collectives import (
-            shard_map,
-        )
-
         row = P(plan.batch_axes)
         out_specs = (P(), P()) if kind == "train" else P()
-        body = shard_map(step_fn, mesh=mesh, in_specs=(P(), row),
+        body = jax.shard_map(step_fn, mesh=mesh, in_specs=(P(), row),
                          out_specs=out_specs, check_vma=False)
         jitted = jax.jit(body, donate_argnums=donate)
     else:
@@ -439,6 +394,27 @@ def compile_step_with_plan(
     return anatomy_lib.instrument(
         jitted, name=name or f"plan:{plan.name}",
         expected_signatures=expected_signatures, plan=plan)
+
+
+def traced_on(fn: Callable, mesh) -> Callable:
+    """``fn`` with ``mesh`` as the ops' default mesh while its Python body
+    runs, i.e. while jit traces it — so a kernel that must lay itself out by
+    hand (a Mosaic kernel cannot be auto-partitioned) and the ring/ulysses
+    paths find the mesh the program is being compiled for, with or without
+    a Session. Both places that trace a model for a mesh use it: this
+    module's compile path and ``train/step.init_state``."""
+    from distributeddeeplearningspark_tpu.ops import ring_attention
+
+    @functools.wraps(fn)
+    def traced(*args):
+        prev = ring_attention._default_mesh
+        ring_attention.set_default_mesh(mesh)
+        try:
+            return fn(*args)
+        finally:
+            ring_attention.set_default_mesh(prev)
+
+    return traced
 
 
 # -- canned plans -------------------------------------------------------------

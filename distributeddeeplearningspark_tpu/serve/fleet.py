@@ -225,18 +225,17 @@ def replica_main() -> int:
     from multiprocessing.connection import Listener
 
     from distributeddeeplearningspark_tpu.utils.env import (
-        apply_env_platform_config,
+        configure_compile_cache,
     )
 
     logging.basicConfig(level=logging.INFO, format="%(message)s")
-    apply_env_platform_config()
     spec = json.loads(os.environ[ENV_SPEC])
     if spec.get("pin_cores"):
         # one replica ↔ one core, the CPU stand-in for one-replica-per-chip:
         # without it XLA's per-process threadpool spans every host core, so
         # replica 0 alone saturates the box and 1→2 scaling measures thread
         # contention, not replica capacity. Affinity must land BEFORE jax
-        # initializes its threadpool (first jax import below).
+        # initializes its threadpool (the first backend use below).
         try:
             cores = sorted(os.sched_getaffinity(0))
             mine = cores[int(os.environ.get("DLS_PROCESS_ID", "0"))
@@ -244,6 +243,7 @@ def replica_main() -> int:
             os.sched_setaffinity(0, {mine})
         except (AttributeError, OSError):
             pass  # non-Linux: serve unpinned rather than not at all
+    configure_compile_cache()
     port = int(os.environ[ENV_PORT])
     authkey = bytes.fromhex(os.environ[ENV_AUTHKEY])
     replica_id = int(os.environ.get("DLS_PROCESS_ID", "0"))

@@ -135,7 +135,7 @@ Worker-side events additionally carry ``host`` (the process index from the
 ``DLS_*`` env contract via :func:`~..utils.env.process_identity`, plus
 ``hosts`` when the gang has more than one) so the cross-host aggregator in
 :mod:`.fleet` can attribute a multi-host run's streams without parsing file
-names. Non-host processes (the supervisor, ``tpu_watch``) write with
+names. Non-host processes (the supervisor) write with
 ``host=None`` and stay out of the fleet table.
 
 Writers are append-only and line-buffered; a SIGKILL can at worst tear the
@@ -283,7 +283,7 @@ class EventWriter:
         self.path = self._seg_path(0)
         # host identity stamped on every event (fleet aggregation key).
         # Default: the DLS_* env contract. host=None opts a non-host process
-        # (supervisor, tpu_watch, bench) out of the fleet table; an explicit
+        # (the supervisor) out of the fleet table; an explicit
         # host should come with the gang size (``hosts``), which otherwise
         # falls back to the env contract's count.
         from distributeddeeplearningspark_tpu.utils.env import (

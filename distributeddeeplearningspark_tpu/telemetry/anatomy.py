@@ -74,15 +74,18 @@ _SIG_LEAVES_SHOWN = 4  # leaves spelled out in the human-readable signature
 MAX_LEDGER_EVENTS_REPORTED = 50
 
 
-def resolve_peak_flops() -> tuple[float | None, str]:
+def resolve_peak_flops() -> tuple[float, str]:
     """(peak FLOPs/s per chip, source label) for the MFU denominator.
 
     Resolution order: ``DLS_PEAK_FLOPS`` env → the bf16 spec table in
-    :mod:`..metrics` by device kind → a labeled nominal figure on CPU →
-    ``(None, "unknown-device")``.
+    :mod:`..metrics` by device kind → a labeled nominal figure on the host
+    CPU. An accelerator whose ``device_kind`` is not in the table raises
+    (:func:`..metrics.spec_peak_flops`) — a default there would put a made-up
+    denominator under a device metric.
     """
     from distributeddeeplearningspark_tpu.metrics import (
         env_peak_flops_override,
+        spec_peak_flops,
     )
 
     v = env_peak_flops_override()
@@ -90,19 +93,14 @@ def resolve_peak_flops() -> tuple[float | None, str]:
         return v, PEAK_FLOPS_ENV
     import jax
 
-    from distributeddeeplearningspark_tpu.metrics import PEAK_FLOPS
-
     d = jax.devices()[0]
-    kind = getattr(d, "device_kind", "")
-    peak = PEAK_FLOPS.get(kind)
+    peak = spec_peak_flops(d)
     if peak:
-        return peak, f"spec table ({kind})"
-    if d.platform == "cpu":
-        cores = os.cpu_count() or 1
-        return (cores * CPU_NOMINAL_PEAK_PER_CORE,
-                f"nominal-cpu ({cores} cores; set {PEAK_FLOPS_ENV} to "
-                f"calibrate)")
-    return None, f"unknown-device ({kind or d.platform})"
+        return peak, f"spec table ({d.device_kind})"
+    cores = os.cpu_count() or 1
+    return (cores * CPU_NOMINAL_PEAK_PER_CORE,
+            f"nominal-cpu ({cores} cores; set {PEAK_FLOPS_ENV} to "
+            f"calibrate)")
 
 
 def _leaf_sig(x: Any) -> tuple[tuple[int, ...], str]:
@@ -146,10 +144,13 @@ class InstrumentedFunction:
     the event ``recompile=True`` — the ``dlstatus --anatomy`` verdict and
     ``bench.py``'s ``recompile_count`` read that flag.
 
-    Backends (or call shapes) where AOT lowering or dispatch fails degrade
-    to calling the wrapped jit directly, with compiles still *detected*
-    (jit-cache growth) and timed, minus the cost analysis — the ledger is
-    then best-effort rather than absent (``aot: false`` on its events).
+    A failure of ``lower().compile()`` (a Mosaic rejection, a compile-time
+    out-of-memory) propagates to the caller: retrying it through plain jit
+    would pay it twice and report it from the wrong place. Only the typed
+    AOT *dispatch* mismatch ("compiled for different types/shardings")
+    degrades to calling the wrapped jit directly, with compiles still
+    detected (jit-cache growth) and timed, minus the cost analysis
+    (``aot: false`` on the ledger's events from then on).
     """
 
     def __init__(self, jitted: Callable, *, name: str,
@@ -187,6 +188,13 @@ class InstrumentedFunction:
 
     def lower(self, *args, **kwargs):
         return self._jitted.lower(*args, **kwargs)
+
+    def executables(self) -> list[tuple[tuple, Any]]:
+        """(dispatch key, compiled executable) pairs, oldest first. The key
+        is ``(treedef, (shape, dtype) per leaf, sharding per leaf)`` of the
+        call that compiled it; the executable answers ``as_text()``."""
+        with self._lock:
+            return list(self._compiled.items())
 
     def _cache_size(self) -> int:
         """Compiled-executable count (AOT dict and/or inner jit cache)."""
@@ -243,8 +251,6 @@ class InstrumentedFunction:
         if compiled is not None:
             try:
                 cost = compiled.cost_analysis()
-                if isinstance(cost, list):  # older jax: per-device list
-                    cost = cost[0] if cost else {}
                 flops = float(cost.get("flops", 0.0)) or None
                 bytes_accessed = float(cost.get("bytes accessed", 0.0)) or None
             except Exception:  # cost analysis unsupported on some backends
@@ -297,16 +303,7 @@ class InstrumentedFunction:
                 "compile", fn=self.name,
                 **({"plan": self.plan_name} if self.plan_name else {})):
             t0 = self._clock()
-            try:
-                compiled = self._jitted.lower(*args).compile()
-            except Exception as e:  # noqa: BLE001 — AOT unsupported here:
-                # degrade to plain jit dispatch, permanently for this
-                # wrapper (re-probing every call would re-pay the failure)
-                logger.warning("%s: AOT lower/compile unavailable (%s: %s) "
-                               "— compile ledger degrades to jit-cache "
-                               "detection", self.name, type(e).__name__, e)
-                self._aot = False
-                return None
+            compiled = self._jitted.lower(*args).compile()
             compile_s = self._clock() - t0
         self._record_compile(sig, sig_hash, nleaves, compile_s,
                              compiled=compiled)
@@ -344,8 +341,6 @@ class InstrumentedFunction:
             return self._fallback_call(args, kwargs)
         if compiled is None:
             compiled = self._compile(key, args)
-            if compiled is None:  # degraded mid-flight
-                return self._fallback_call(args, kwargs)
         t0 = self._clock()
         try:
             out = compiled(*args)

@@ -22,9 +22,8 @@ host whose file is torn mid-line simply contributes fewer events.
 
 Host identity: the ``host`` field stamped by the writer (the DLS_* process
 index); streams from before that field exist fall back to the ``p<k>``
-process-name convention. Non-host processes (``supervisor``, ``tpu_watch``,
-``bench``) are excluded from the table — their events describe the gang,
-they are not members of it.
+process-name convention. Non-host processes (``supervisor``) are excluded
+from the table — their events describe the gang, they are not members of it.
 """
 
 from __future__ import annotations

@@ -178,9 +178,6 @@ class LlamaStageProgram:
         import optax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from distributeddeeplearningspark_tpu.parallel.collectives import (
-            shard_map,
-        )
         from distributeddeeplearningspark_tpu.parallel.mesh import BATCH_AXES
 
         mesh, row = self.mesh, self._row_spec
@@ -203,9 +200,9 @@ class LlamaStageProgram:
 
         if self.mode == "exact":
             def sm(f, in_specs, out_specs):
-                return jax.jit(shard_map(f, mesh=mesh, in_specs=in_specs,
-                                         out_specs=out_specs,
-                                         check_vma=False))
+                return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                                             out_specs=out_specs,
+                                             check_vma=False))
 
             self._fwd = sm(self._stage_apply, (P(), row), row)
 
@@ -1101,11 +1098,11 @@ def synthetic_batch_fn(spec: dict):
 
 def stage_main() -> int:
     from distributeddeeplearningspark_tpu.utils.env import (
-        apply_env_platform_config,
+        configure_compile_cache,
     )
 
     logging.basicConfig(level=logging.INFO, format="%(message)s")
-    apply_env_platform_config()
+    configure_compile_cache()
     spec = json.loads(os.environ[mpmd.ENV_SPEC])
     stage = int(os.environ[mpmd.ENV_STAGE])
     num_stages = int(os.environ[mpmd.ENV_NUM_STAGES])

@@ -348,6 +348,9 @@ def init_state(
         return TrainState.create(params=params, opt_state=opt_state, mutable=mutable,
                                  rng=state_rng, embed_state=embed_state)
 
+    from distributeddeeplearningspark_tpu.parallel.plan import traced_on
+
+    init_fn = traced_on(init_fn, mesh)  # model.init traces the ops too
     abstract = jax.eval_shape(init_fn, init_rng)
     if plan is not None:
         shardings = plan.state_shardings(abstract, mesh)

@@ -170,10 +170,6 @@ class Trainer:
         # stop_gradient'ed out of autodiff — pass the SAME predicate used
         # to mask the optimizer (step.py `trainable` docstring)
         self.trainable = trainable
-        if context_parallel:
-            from distributeddeeplearningspark_tpu.ops import ring_attention
-
-            ring_attention.set_default_mesh(self.mesh)
 
         self.state: TrainState | None = None
         self.state_shardings = None

@@ -8,9 +8,12 @@ releases the GIL around every call, so these kernels parallelize for real
 under the prefetch thread).
 
 Loading strategy: use a prebuilt ``_dls_native*.so`` next to this package if
-present, else build one on first import with the system ``g++`` (cached under
-``~/.cache/dls_tpu``). Every entry point has a numpy fallback with identical
-semantics — :func:`available` says which path is live, and the test suite
+present (none is committed; ``*.so`` is git-ignored), else build one from
+``csrc/`` on first use with the system ``g++`` (cached under
+``~/.cache/dls_tpu``, so a sealed copy with an empty home builds once per
+machine). Every entry point has a numpy fallback with identical semantics; a
+failed build or load logs an ERROR, :func:`available` says which path is
+live (``chip_smoke.py`` carries it in its result line), and the test suite
 pins native == numpy bit-for-bit where exactness is defined.
 """
 
@@ -67,7 +70,13 @@ def _build(srcs: list[str]) -> str | None:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, out)
     except (OSError, subprocess.SubprocessError) as e:
-        logger.warning("native build failed (%s); using numpy fallbacks", e)
+        detail = getattr(e, "stderr", b"") or b""
+        logger.error(
+            "native host kernels did NOT build from %s (%s)%s — falling "
+            "back to the numpy implementations; decode/augment will be "
+            "several times slower (available() reports False)",
+            _CSRC_DIR, e,
+            ": " + detail.decode(errors="replace")[-400:] if detail else "")
         try:
             os.unlink(tmp)
         except OSError:
@@ -141,8 +150,10 @@ def _load() -> ctypes.CDLL | None:
             _LIB = _bind(ctypes.CDLL(path))
             logger.info("native kernels loaded (%d threads): %s",
                         _LIB.dls_num_threads(), path)
-    except Exception as e:  # any load failure → clean numpy fallback
-        logger.warning("native kernels unavailable (%s); using numpy", e)
+    except Exception as e:  # any load failure → numpy fallback, said loudly
+        logger.error("native host kernels unavailable (%s: %s) — falling "
+                     "back to the numpy implementations (available() "
+                     "reports False)", type(e).__name__, e)
         _LIB = None
     return _LIB
 

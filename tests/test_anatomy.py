@@ -67,6 +67,42 @@ def test_compile_event_schema_and_phase_span(workdir):
     assert fn._cache_size() == 1
 
 
+def test_compile_errors_propagate_and_are_paid_once(workdir):
+    """A failure of lower().compile() (a Mosaic rejection, a compile-time
+    OOM) surfaces from the ledger as itself: no retry through plain jit, no
+    'AOT unavailable' downgrade."""
+    calls = {"lower": 0, "jit": 0}
+
+    class Rejecting:
+        def lower(self, *a):
+            calls["lower"] += 1
+            raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+        def __call__(self, *a, **kw):
+            calls["jit"] += 1
+            raise AssertionError("the failed compile was retried through jit")
+
+    fn = anatomy.instrument(Rejecting(), name="rejected")
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        fn(jnp.ones((4,), jnp.float32))
+    assert calls == {"lower": 1, "jit": 0}
+    assert fn.records == [] and fn.executables() == []
+    assert fn.compile_summary()["aot"] is True
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        fn.prepare(jnp.ones((4,), jnp.float32))
+
+
+def test_executables_expose_key_and_program_text(workdir):
+    fn = anatomy.instrument(jax.jit(lambda x: x * 2 + 1), name="double")
+    x = jnp.ones((4, 4), jnp.float32)
+    fn(x)
+    ((treedef, sigs, shardings), compiled), = fn.executables()
+    assert sigs == (((4, 4), "float32"),)
+    assert shardings == (x.sharding,)
+    assert jax.tree_util.tree_unflatten(treedef, ["leaf"]) == ("leaf",)
+    assert "multiply" in compiled.as_text()
+
+
 def test_second_shape_flags_exactly_one_recompile(workdir):
     """A shape-stable step (expected_signatures=1) forced through a second
     shape flags EXACTLY one recompile — the acceptance drill."""
@@ -201,6 +237,23 @@ def test_resolve_peak_flops_order(monkeypatch):
     monkeypatch.setenv(anatomy.PEAK_FLOPS_ENV, "not-a-number")
     peak2, _ = anatomy.resolve_peak_flops()
     assert peak2 == peak  # malformed override ignored, not fatal
+
+
+def test_unknown_accelerator_is_an_error_not_a_default():
+    """An accelerator whose device_kind has no peaks entry raises; the host
+    CPU simply has no spec peak (so no utilization is computed from one)."""
+    import types
+
+    from distributeddeeplearningspark_tpu import metrics
+
+    v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert metrics.spec_peak_flops(v5e) == 197e12
+    assert metrics.spec_peak_flops(jax.devices()[0]) is None
+    unknown = types.SimpleNamespace(platform="tpu", device_kind="TPU v9 mega")
+    with pytest.raises(ValueError, match="TPU v9 mega"):
+        metrics.spec_peak_flops(unknown)
+    with pytest.raises(ValueError, match="TPU v9 mega"):
+        metrics.device_peak_flops(unknown)
 
 
 # -- memory watermarks --------------------------------------------------------

@@ -182,6 +182,48 @@ def test_pick_impl_routes_bert_and_gqa_on_tpu(monkeypatch):
     assert _pick_impl(q2, kv2, None, None) == "xla"
 
 
+def test_flash_runs_per_shard_on_a_multi_device_mesh(monkeypatch):
+    """GSPMD cannot partition a Mosaic kernel (first met on the four-chip
+    host), so under a multi-device mesh the router wraps the kernel in a
+    shard_map: batch over (data, fsdp), heads over tensor. Same numbers as
+    the XLA path, fwd and bwd, with GQA + padding mask + segments + causal;
+    shapes that do not split evenly route to XLA under 'auto' and are an
+    error under an explicit impl='flash'."""
+    from distributeddeeplearningspark_tpu.ops import attention, ring_attention
+    from distributeddeeplearningspark_tpu.parallel.mesh import MeshSpec
+
+    mesh = MeshSpec(data=2, fsdp=2, tensor=2).build()
+    monkeypatch.setattr(ring_attention, "_default_mesh", mesh)
+    q, k, v = _qkv(b=8, s=128, h=4, hkv=2, d=32)
+    mask = padding_mask(_pad_mask(8, 128, 100))
+    segs = _seg_ids(8, 128, [[0, 40]] * 8)
+
+    def run(impl):
+        def loss(q, k, v):
+            o = attention.dot_product_attention(
+                q, k, v, mask=mask, segment_ids=segs, causal=True, impl=impl)
+            return jnp.sum(o ** 2), o
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    (_, o), g = run("flash")(q, k, v)
+    (_, o_ref), g_ref = run("xla")(q, k, v)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), atol=2e-5)
+    for got, want in zip(g, g_ref):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-4)
+    assert o.sharding.spec == jax.sharding.PartitionSpec(
+        ("data", "fsdp"), None, "tensor")
+    with pytest.raises(ValueError, match="impl='xla'"):
+        attention.dot_product_attention(q[:3], k[:3], v[:3], impl="flash")
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    q512 = jnp.zeros((8, 512, 4, 32))
+    assert _pick_impl(q512, q512, None, None) == "flash"
+    assert _pick_impl(q512[:3], q512[:3], None, None) == "xla"   # rows % 4
+    assert _pick_impl(q512, q512[:, :, :1], None, None) == "xla"  # kv heads % 2
+
+
 def test_flash_uneven_blocks_rejected():
     q, k, v = _qkv(s=96)
     with pytest.raises(ValueError, match="divide"):

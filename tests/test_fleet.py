@@ -9,7 +9,6 @@ whose worker is plain python (no jax) so it stays in the fast tier.
 
 import json
 import os
-import subprocess
 import sys
 
 import pytest
@@ -55,7 +54,7 @@ def test_writer_tags_events_with_host_from_env(tmp_path, monkeypatch):
 
 
 def test_writer_host_none_opts_out(tmp_path):
-    """Non-host processes (supervisor, tpu_watch) carry no host field and
+    """Non-host processes (the supervisor) carry no host field and
     stay out of the fleet table."""
     w = telemetry.EventWriter(tmp_path, process="supervisor",
                               clock=FakeClock(), host=None)
@@ -452,84 +451,6 @@ def test_supervisor_hang_without_telemetry_stays_bare():
     assert sup._localize_hang() is None
 
 
-# -- satellite: bench + tpu_watch availability audit trail -------------------
-
-
-def test_bench_probe_timeout_emits_recovery_event(tmp_path, monkeypatch):
-    import bench
-
-    monkeypatch.setenv("DLS_TELEMETRY_DIR", str(tmp_path))
-
-    def fake_run(*a, **kw):
-        raise subprocess.TimeoutExpired(cmd="probe", timeout=kw["timeout"])
-
-    monkeypatch.setattr(bench.subprocess, "run", fake_run)
-    ok, errors = bench.probe_backend(attempts=2, timeout_s=0.1, backoff_s=0.0)
-    # ONE probe-timeout, not two: a full-deadline hang caches the
-    # unavailable verdict for the remaining attempts (ISSUE 4 satellite —
-    # BENCH_r05 burned 3×150 s re-learning the same hang)
-    assert not ok and "hung" in errors[0] and "cached" in errors[1]
-    events = telemetry.read_events(str(tmp_path))
-    kinds = [(e["kind"], e.get("event")) for e in events]
-    assert kinds == [("recovery", "probe-timeout"),
-                     ("recovery", "backend-unavailable")]
-    assert all(e["process"] == "bench" and "host" not in e for e in events)
-    assert events[-1]["errors"]
-
-
-def test_bench_single_attempt_poll_emits_no_terminal_verdict(tmp_path,
-                                                             monkeypatch):
-    """tpu_watch polls with attempts=1 every interval; the per-attempt
-    event is the record — a duplicate backend-unavailable per poll would
-    flood a long outage's recovery timeline."""
-    import bench
-
-    monkeypatch.setenv("DLS_TELEMETRY_DIR", str(tmp_path))
-
-    def fake_run(*a, **kw):
-        raise subprocess.TimeoutExpired(cmd="probe", timeout=kw["timeout"])
-
-    monkeypatch.setattr(bench.subprocess, "run", fake_run)
-    ok, _ = bench.probe_backend(attempts=1, timeout_s=0.1, backoff_s=0.0)
-    assert not ok
-    events = telemetry.read_events(str(tmp_path))
-    assert [e.get("event") for e in events] == ["probe-timeout"]
-
-
-def test_bench_probe_no_workdir_no_telemetry(tmp_path, monkeypatch):
-    import bench
-
-    monkeypatch.delenv("DLS_TELEMETRY_DIR", raising=False)
-    bench.telemetry_recovery("probe-timeout", attempt=1)
-    assert telemetry.read_events(str(tmp_path)) == []
-
-
-def test_tpu_watch_mirrors_probe_observations(tmp_path):
-    import importlib.util
-
-    path = os.path.join(os.path.dirname(__file__), "..", "tools",
-                        "tpu_watch.py")
-    spec = importlib.util.spec_from_file_location("tpu_watch_fleet", path)
-    watch = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(watch)
-
-    tele = watch.WatchTelemetry(str(tmp_path))
-    tele.observe(1, False, pending=9, errors=["probe 1/1: hung past 120s"])
-    tele.observe(2, False, pending=9, errors=["probe 1/1: hung past 120s"])
-    tele.observe(3, True, pending=9)
-    tele.observe(4, True, pending=4)
-    tele.close()
-    events = telemetry.read_events(str(tmp_path))
-    hbs = [e for e in events if e["kind"] == "heartbeat"]
-    recs = [e for e in events if e["kind"] == "recovery"]
-    assert len(hbs) == 4  # one per probe
-    assert [e["event"] for e in recs] == ["tpu-down", "tpu-up"]  # transitions
-    assert recs[0]["errors"]
-    assert all(e["process"] == "tpu-watch" for e in events)
-    # and dlstatus can read the watch workdir like any run
-    assert status.main([str(tmp_path)]) == 0
-
-
 # -- satellite: collective probes --------------------------------------------
 
 
@@ -551,6 +472,36 @@ def test_barrier_probe_emits_collective_event(tmp_path):
     assert rows[0]["collectives"] == 2
 
 
+def test_is_tracing_sees_every_kind_of_trace():
+    """True under jit, grad and shard_map, false eagerly — asked of jax's
+    trace state, not guessed from the operands."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from distributeddeeplearningspark_tpu.parallel import collectives
+    from distributeddeeplearningspark_tpu.parallel.mesh import MeshSpec
+
+    seen = {}
+
+    def probe(tag):
+        def f(x):
+            seen[tag] = collectives._is_tracing()
+            return x * 2.0
+        return f
+
+    x = jnp.ones((8,), jnp.float32)
+    assert collectives._is_tracing() is False
+    probe("eager")(x)
+    jax.jit(probe("jit"))(x)
+    jax.grad(lambda v: probe("grad")(v).sum())(x)
+    jax.shard_map(probe("shard_map"), mesh=MeshSpec().build(),
+                  in_specs=P("data"), out_specs=P("data"))(x)
+    assert seen == {"eager": False, "jit": True, "grad": True,
+                    "shard_map": True}
+    assert collectives._is_tracing() is False
+
+
 def test_probed_collectives_transparent_under_tracing(tmp_path):
     """The opt-in wrappers must not change traced semantics or emit from
     inside a trace — XLA owns scheduling there."""
@@ -565,7 +516,7 @@ def test_probed_collectives_transparent_under_tracing(tmp_path):
     telemetry.configure(tmp_path)
     collectives.enable_collective_probes(True)
     try:
-        f = jax.jit(collectives.shard_map(
+        f = jax.jit(jax.shard_map(
             lambda x: collectives.all_reduce_sum(x, ("data",)),
             mesh=mesh, in_specs=P("data"), out_specs=P()))
         out = f(jnp.ones((8,), jnp.float32))

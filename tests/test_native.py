@@ -21,6 +21,29 @@ def _rand_u8(shape, seed=0):
     return np.random.default_rng(seed).integers(0, 256, shape).astype(np.uint8)
 
 
+def test_failed_build_says_so_loudly(monkeypatch, tmp_path, caplog):
+    """A sealed copy whose csrc/ does not compile falls back to numpy — at
+    ERROR level with the compiler's words, not in a warning nobody reads."""
+    import logging
+    import subprocess
+
+    def no_compiler(cmd, **kw):
+        raise subprocess.CalledProcessError(
+            1, cmd, stderr=b"dls_native.cc:1: fatal error: no such header")
+
+    monkeypatch.setenv("XDG_CACHE_HOME", str(tmp_path))  # an empty home cache
+    monkeypatch.setattr(subprocess, "run", no_compiler)
+    with caplog.at_level(logging.ERROR,
+                         logger="distributeddeeplearningspark_tpu.native"):
+        assert native._build(native._SRC) is None
+    (rec,) = caplog.records
+    assert rec.levelno == logging.ERROR
+    assert "did NOT build" in rec.getMessage()
+    assert "no such header" in rec.getMessage()
+    assert "numpy" in rec.getMessage()
+    assert list((tmp_path / "dls_tpu").glob("*.so")) == []
+
+
 def test_crop_flip_normalize_parity():
     imgs = _rand_u8((4, 12, 16, 3))
     ys = np.array([0, 1, 2, 3], np.int32)

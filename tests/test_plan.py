@@ -31,7 +31,6 @@ from distributeddeeplearningspark_tpu.parallel.plan import (
     DP,
     Plan,
     PlanError,
-    PlanTensorAxisWarning,
     PlanValidationError,
     compile_step_with_plan,
     plan_for_rules,
@@ -82,31 +81,55 @@ def test_validate_rejects_unknown_axes():
     DP.validate(mesh)  # sane plan passes
 
 
-def test_tensor_axis_guard_warns_and_strict_refuses(monkeypatch):
-    mesh = MeshSpec(data=-1, tensor=2).build()
-    monkeypatch.delenv(plan_lib.TENSOR_ESCAPE_ENV, raising=False)
-    with pytest.warns(PlanTensorAxisWarning, match="1.2%"):
-        DP.validate(mesh)
-    with pytest.raises(PlanValidationError, match="DLS_PLAN_ALLOW_TENSOR"):
-        DP.validate(mesh, strict=True)
-    # the escape hatch silences both (re-probed-on-a-newer-jax override)
-    monkeypatch.setenv(plan_lib.TENSOR_ESCAPE_ENV, "1")
+def test_tensor_sharded_step_matches_replicated():
+    """What replaced the tensor-axis refusal (PlanTensorAxisWarning, which
+    pinned a jax 0.4.37 partitioner miscompute): on this jax the pinned
+    repro layout computes the replicated reference's loss and post-SGD
+    params, and validates without a warning. The same fingerprint passed
+    on four real v5e chips (dryrun_multichip(4), CHANGES.md PR 21)."""
+    import __graft_entry__ as graft
+
+    cfg = LlamaConfig.tiny(lora_rank=4)
+    model = LlamaForCausalLM(cfg)
+    rules = llama_rules(cfg, fsdp_min_size=1)
+    batch = stack_examples([
+        {"input_ids": np.full((32,), i % cfg.vocab_size, np.int32),
+         "loss_mask": np.ones((32,), np.float32)} for i in range(16)])
+    mesh = MeshSpec(data=2, fsdp=2, tensor=2).build()
     with warnings.catch_warnings():
-        warnings.simplefilter("error", PlanTensorAxisWarning)
+        warnings.simplefilter("error")
         DP.validate(mesh)
-        DP.validate(mesh, strict=True)
-
-
-def test_tensor_mesh_refuses_whole_sweep(monkeypatch):
-    monkeypatch.delenv(plan_lib.TENSOR_ESCAPE_ENV, raising=False)
-    sweep = _load_plan_sweep()
-    mesh = MeshSpec(data=-1, tensor=2).build()
-    cfg = LlamaConfig.tiny()
-    with pytest.raises(PlanValidationError, match="Refusing to sweep"):
-        sweep.run_sweep(mesh, cfg, _llama_batch(cfg), steps=1)
+    got, loss_g, _ = graft._sgd_step_fingerprint(model, mesh, rules, False,
+                                                 batch)
+    want, loss_w, _ = graft._sgd_step_fingerprint(
+        model, MeshSpec(data=1).build(jax.devices()[:1]), rules, False, batch)
+    graft._assert_params_match(jax, got, loss_g, want, loss_w,
+                               layout="data=2 x fsdp=2 x tensor=2")
 
 
 # -- serialization / identity -------------------------------------------------
+
+
+def test_compile_path_names_its_mesh_while_tracing():
+    """Ops that lay themselves out by hand (the shard_map'd flash kernel,
+    ring/ulysses) resolve the mesh a step is being compiled for — Session or
+    not — because the compile path sets it for exactly the trace."""
+    from distributeddeeplearningspark_tpu.ops import ring_attention
+
+    mesh = MeshSpec(data=-1).build()
+    seen = []
+
+    def step(state, batch):
+        seen.append(ring_attention.resolve_mesh())
+        return state, {"loss": jnp.sum(batch["x"])}
+
+    state = {"w": jnp.ones((4,), jnp.float32)}
+    fn = compile_step_with_plan(step, DP, mesh, state_abstract=state,
+                                kind="train", instrument=False)
+    assert ring_attention.resolve_mesh() is None
+    fn(state, put_global({"x": np.ones((8, 2), np.float32)}, mesh))
+    assert seen == [mesh]
+    assert ring_attention.resolve_mesh() is None
 
 
 def test_plan_roundtrip_and_signature(tmp_path):
