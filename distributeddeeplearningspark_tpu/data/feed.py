@@ -30,6 +30,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributeddeeplearningspark_tpu.parallel.mesh import BATCH_AXES, num_data_shards
 from distributeddeeplearningspark_tpu.rdd import PartitionedDataset
+from distributeddeeplearningspark_tpu.telemetry import spans
 
 
 def process_shard_range(num_shards: int) -> tuple[int, int] | None:
@@ -67,6 +68,13 @@ def stack_examples(examples: list[dict[str, Any]]) -> dict[str, np.ndarray]:
             f"dict in a stream must carry the same fields") from e
 
 
+def _stack(examples: list[dict[str, Any]]) -> dict[str, np.ndarray]:
+    """``stack_examples`` as the feed calls it: one ``dls.feed/stack``
+    section of the probe whose thread pulls these batches, if any."""
+    with spans.span("dls.feed/stack", spans.bound_sink()):
+        return stack_examples(examples)
+
+
 def _round_robin(iters: list[Iterator]) -> Iterator:
     """Deal elements from iterators in turn; drained ones drop out so uneven
     partitions lose no data (matches Spark consuming every partition fully)."""
@@ -95,7 +103,7 @@ def _pad_to_shards(
     """
     n = len(rest)
     target = -(-n // num_shards) * num_shards
-    batch = stack_examples(rest + [rest[0]] * (target - n))
+    batch = _stack(rest + [rest[0]] * (target - n))
     if "eval_mask" in batch:
         raise ValueError(
             "'eval_mask' is reserved for remainder padding — rename the "
@@ -206,9 +214,9 @@ def host_batches(
                     # shards (GSPMD needs equal shard sizes)
                     keep = len(rest) - len(rest) % num_shards
                     if keep:
-                        yield stack_examples(rest[:keep])
+                        yield _stack(rest[:keep])
                 return
-            yield checked(stack_examples(
+            yield checked(_stack(
                 [e for chunk in shard_chunks[lo:hi] for e in chunk]
             ))
     else:
@@ -231,12 +239,12 @@ def host_batches(
                                      for k, v in batch.items()}
                         yield batch
                     elif shard_range is None:
-                        yield stack_examples(chunk)
+                        yield _stack(chunk)
                 return
             if shard_range is not None:
                 assert per_shard is not None
                 chunk = chunk[lo * per_shard:hi * per_shard]
-            out = checked(stack_examples(chunk))
+            out = checked(_stack(chunk))
             # release the example refs BEFORE the next islice refill: a
             # worker-pool dataset's examples are views into the shared-
             # memory ring (data/workers.py), and holding a full batch of

@@ -29,54 +29,80 @@ import jax
 from jax.sharding import Mesh
 
 from distributeddeeplearningspark_tpu.data.feed import put_global
+from distributeddeeplearningspark_tpu.telemetry import spans
 
 _SENTINEL = object()
 
 
+def _timed(it, name: str, sink) -> Iterator:
+    """Each blocking ``next()`` of ``it`` as one ``name`` section (the pull
+    that finds the iterator exhausted is not one)."""
+    it = iter(it)  # accept plain iterables, same as a for-loop would
+    while True:
+        try:
+            with spans.span(name, sink):
+                x = next(it)
+        except StopIteration:
+            return
+        yield x
+
+
 class StarvationProbe:
-    """Thread-safe counters for "how long did training wait on input?".
+    """Thread-safe per-lap counters for "how long did training wait on
+    input, and what was the feed doing?" — the feed's sink of
+    :func:`~..telemetry.spans.span`.
 
-    Three signals, all cheap:
-
-    - ``record_wait`` — consumer-side block: the training loop asked for the
-      next batch and the prefetch ring had nothing ready. This is the
-      starvation signal proper (sums into ``input_starved_s``).
+    - ``input_wait_s`` (``dls.feed/wait``) — consumer-side block: the
+      training loop asked for the next batch and the prefetch ring had
+      nothing ready. This is the starvation signal proper (sums into
+      ``input_starved_s``); ``input_waits`` / ``input_wait_max_s`` ride along.
+    - ``input_put_s`` (``dls.feed/put``) — the loop thread inside
+      ``put(hb, mesh)``: ``device_put`` of the batch it already has.
     - ``record_depth`` — prefetch queue depth sampled at each consumer get;
       a ring that is persistently empty (min 0, mean ≈ 0) is input-bound,
       one that hovers full is compute-bound.
-    - ``record_assembly`` — producer-side cost of building one host batch
-      (decode/augment/stack), measured in the background thread; tells you
-      WHY the ring ran dry.
+    - ``input_assembly_s`` (``dls.feed/assemble``) — producer-side cost of
+      building one host batch (decode/augment/stack), measured in the
+      background thread; tells you WHY the ring ran dry. Of it,
+      ``input_stack_s`` (``dls.feed/stack``) is ``stack_examples``.
+    - ``input_blocked_s`` (``dls.feed/ring_full``) — the producer holding a
+      finished batch with no room in the ring: the feed's headroom.
+    - ``input_map_s`` (``dls.feed/map``) — thread-seconds inside
+      ``map_parallel``'s function, summed over the pool; absent until a
+      parallel map has run under this probe.
 
     ``clock`` is injectable so tests measure deterministic fake seconds.
     ``snapshot(reset=True)`` returns-and-clears, giving per-lap gauges.
     """
 
+    #: the seconds-counters every snapshot carries (``input_map_s`` apart)
+    _ALWAYS = ("input_wait_s", "input_put_s", "input_assembly_s",
+               "input_stack_s", "input_blocked_s")
+
     def __init__(self, clock=time.perf_counter):
         self.clock = clock
         self._lock = threading.Lock()
+        self._seconds: dict[str, float] = {}
         self._zero()
 
     def _zero(self) -> None:
-        self._wait_s = 0.0
+        # a probe that has seen a parallel map keeps reporting its key
+        self._seconds = dict.fromkeys((*self._ALWAYS, *self._seconds), 0.0)
         self._waits = 0
         self._wait_max = 0.0
-        self._assembly_s = 0.0
-        self._assemblies = 0
         self._depth_sum = 0
         self._depth_n = 0
         self._depth_min: int | None = None
 
-    def record_wait(self, dt: float) -> None:
+    def add(self, name: str, dt: float, inner_s: float = 0.0) -> None:
+        """One closed section (:func:`~..telemetry.spans.span`'s sink side);
+        the feed's counters are inclusive, so ``inner_s`` is not taken off."""
+        key = spans.COUNTERS[name]
         with self._lock:
-            self._wait_s += dt
-            self._waits += 1
-            self._wait_max = max(self._wait_max, dt)
-
-    def record_assembly(self, dt: float) -> None:
-        with self._lock:
-            self._assembly_s += dt
-            self._assemblies += 1
+            self._seconds[key] = self._seconds.get(key, 0.0) + dt
+            if key == "input_wait_s":
+                self._waits += 1
+                self._wait_max = max(self._wait_max, dt)
 
     def record_depth(self, depth: int) -> None:
         with self._lock:
@@ -85,19 +111,10 @@ class StarvationProbe:
             self._depth_min = (depth if self._depth_min is None
                                else min(self._depth_min, depth))
 
-    def timed(self, it, record=None) -> Iterator:
-        """Wrap an iterable so each blocking ``next()`` is timed into
-        ``record`` (default: :meth:`record_wait`)."""
-        record = record or self.record_wait
-        it = iter(it)  # accept plain iterables, same as a for-loop would
-        while True:
-            t0 = self.clock()
-            try:
-                x = next(it)
-            except StopIteration:
-                return
-            record(self.clock() - t0)
-            yield x
+    def timed(self, it, name: str = "dls.feed/wait") -> Iterator:
+        """Wrap an iterable so each blocking ``next()`` is one ``name``
+        section of this probe (default: the consumer's wait)."""
+        return _timed(it, name, self)
 
     def snapshot(self, *, reset: bool = True) -> dict[str, float]:
         """Gauges since the last snapshot, keyed for the telemetry record.
@@ -112,12 +129,8 @@ class StarvationProbe:
         epoch); the wait/assembly keys stay per-lap as before.
         """
         with self._lock:
-            out = {
-                "input_wait_s": self._wait_s,
-                "input_waits": self._waits,
-                "input_wait_max_s": self._wait_max,
-                "input_assembly_s": self._assembly_s,
-            }
+            out = {**self._seconds, "input_waits": self._waits,
+                   "input_wait_max_s": self._wait_max}
             if self._depth_n:
                 out["prefetch_depth_mean"] = self._depth_sum / self._depth_n
                 out["prefetch_depth_min"] = self._depth_min
@@ -151,15 +164,16 @@ def prefetch_to_device(
     if background:
         host_iter = _background(host_iter, maxsize=buffer_size + 1,
                                 probe=probe)
-    if probe is not None:
-        # times the blocking pull of the NEXT host batch: with background=
-        # True that's the q.get() wait (assembly ran behind), without it the
-        # synchronous assembly itself — either way, time training stood still
-        host_iter = probe.timed(host_iter)
+    # times the blocking pull of the NEXT host batch: with background=True
+    # that's the q.get() wait (assembly ran behind), without it the
+    # synchronous assembly itself — either way, time training stood still
+    host_iter = _timed(host_iter, "dls.feed/wait", probe)
 
     buf: collections.deque = collections.deque()
     for hb in host_iter:
-        buf.append(put(hb, mesh))
+        with spans.span("dls.feed/put", probe):
+            placed = put(hb, mesh)
+        buf.append(placed)
         if len(buf) >= buffer_size:
             yield buf.popleft()
     while buf:
@@ -173,12 +187,13 @@ def _background(it: Iterator, *, maxsize: int,
     err: list[BaseException] = []
 
     def worker() -> None:
+        spans.name_thread("dls-prefetch")
+        # host_batches' stack and map_parallel's calls run under this
+        # thread's pulls and have no probe argument: they find it here
+        spans.bind_sink(probe)
         try:
-            if probe is not None:
-                for x in probe.timed(it, probe.record_assembly):
-                    q.put(x)
-            else:
-                for x in it:
+            for x in _timed(it, "dls.feed/assemble", probe):
+                with spans.span("dls.feed/ring_full", probe):
                     q.put(x)
         except BaseException as e:  # propagate into consumer
             err.append(e)

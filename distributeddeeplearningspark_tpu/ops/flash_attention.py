@@ -245,6 +245,7 @@ def _flash_fwd(q, k, v, kv_mask, *, scale, causal, group, block_q, block_k,
         ],
         compiler_params=_grid_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
+        name="flash_fwd",
     )(*operands)
     return o, lse[..., 0]
 
@@ -414,6 +415,7 @@ def _flash_bwd(res, g, *, scale, causal, group, block_q, block_k, interpret):
         scratch_shapes=[vmem((block_q, d), jnp.float32)],
         compiler_params=_grid_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*operands)[0]
 
     # dK/dV: grid batch dim is B·Hkv; inner dim sweeps (group, q block) so the
@@ -464,6 +466,7 @@ def _flash_bwd(res, g, *, scale, causal, group, block_q, block_k, interpret):
         ],
         compiler_params=_grid_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(*operands_kv)
     return dq, dk, dv
 

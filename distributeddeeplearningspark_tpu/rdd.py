@@ -39,6 +39,17 @@ from typing import Any, Callable, Iterable, Iterator, Sequence
 
 import numpy as np
 
+from distributeddeeplearningspark_tpu.telemetry import spans
+
+#: numbers the ``dls-map-<i>`` pool threads over the process (every
+#: partition has a pool of its own, and the profiler keys a thread's line
+#: by its name)
+_map_thread_ids = itertools.count()
+
+
+def _name_map_thread() -> None:
+    spans.name_thread(f"dls-map-{next(_map_thread_ids)}")
+
 PartitionFn = Callable[[], Iterable[Any]]
 
 
@@ -121,10 +132,20 @@ class PartitionedDataset:
             from collections import deque
             from concurrent.futures import ThreadPoolExecutor
 
-            with ThreadPoolExecutor(workers) as ex:
+            # the probe of the feed whose thread pulls this partition (None
+            # outside a feed with telemetry): thread-seconds in ``f`` add to
+            # its ``input_map_s``
+            sink = spans.bound_sink()
+
+            def call(item: Any) -> Any:
+                with spans.span("dls.feed/map", sink):
+                    return f(item)
+
+            with ThreadPoolExecutor(
+                    workers, initializer=_name_map_thread) as ex:
                 window: deque = deque()
                 for item in it:
-                    window.append(ex.submit(f, item))
+                    window.append(ex.submit(call, item))
                     if len(window) >= 2 * workers:
                         yield window.popleft().result()
                 while window:

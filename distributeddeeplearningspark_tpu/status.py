@@ -526,6 +526,12 @@ def render_anatomy(an: dict) -> list[str]:
             f"(dispatch {st['device_dispatch_s']:.2f}s + drain "
             f"{st['device_drain_s']:.2f}s)")
         lines.append(f"  host         {st['host_s']:10.2f}s  {pct('host')}")
+        split = [(k, st.get(k, 0.0)) for k in anatomy_lib.LOOP_SPLIT_KEYS]
+        if any(v for _, v in split):
+            # the named parts of host (the loop thread's own sections)
+            lines.append("    of it: " + "  ".join(
+                f"{k.removeprefix('input_').removesuffix('_s')} {v:.2f}s"
+                for k, v in split))
         lines.append(
             f"  input-wait   {st['input_wait_s']:10.2f}s  "
             f"{pct('input_wait')}")
@@ -567,7 +573,8 @@ def render_anatomy(an: dict) -> list[str]:
             lines.append(
                 f"memory (memory_stats): in use "
                 f"{_fmt_bytes(mem.get('bytes_in_use_max'))}  peak "
-                f"{_fmt_bytes(mem.get('peak_bytes_in_use_max'))}  limit "
+                f"{_fmt_bytes(mem.get('peak_bytes_in_use_max'))}  reserved "
+                f"{_fmt_bytes(mem.get('peak_bytes_reserved_max'))}  limit "
                 f"{_fmt_bytes(mem.get('bytes_limit_min'))}  headroom "
                 f"{_fmt_bytes(mem.get('headroom_bytes'))}")
         else:

@@ -15,7 +15,10 @@ present):
 
 - ``step_metrics`` — one metrics lap: ``step``, ``steps`` (in the lap),
   ``lap_s``, ``metrics`` (the device metrics), plus the input-starvation
-  probe's snapshot (``input_wait_s``, ``prefetch_depth_min``, ...).
+  probe's snapshot (``input_wait_s``, ``input_put_s``, ``input_stack_s``,
+  ``input_blocked_s``, ``prefetch_depth_min``, ...) and the loop's named
+  sections (``emit_s``, ``callbacks_s``, ``unaccounted_s``, ...; the one
+  list of section names is :data:`.spans.COUNTERS`).
 - ``phase`` — ``name`` + ``edge`` ("begin"/"end"; end carries ``dur_s``).
   Phase names the goodput accountant treats as overhead: ``compile``,
   ``restore``, ``checkpoint``/``checkpoint-wait``/``checkpoint-verify``,
@@ -79,7 +82,8 @@ present):
   per-format rows, per-bucket skew, slowest-bucket verdict).
 - ``compile`` — one executable built by the compile ledger
   (:mod:`.anatomy`): ``fn`` (the instrumented callable), ``sig`` /
-  ``sig_hash`` (shape/dtype signature), ``compile_s``, ``flops`` /
+  ``sig_hash`` (shape/dtype signature), ``compile_s`` (= ``lower_s``,
+  tracing and lowering, + ``backend_s``, XLA or the cache load), ``flops`` /
   ``bytes_accessed`` (XLA cost analysis), ``argument_bytes`` /
   ``output_bytes`` / ``temp_bytes`` (memory analysis), and ``recompile``
   — True when the signature compiled more than once or the distinct-
@@ -90,8 +94,9 @@ present):
   verdict.
 - ``memory`` — a device-memory watermark sample (:mod:`.anatomy`), one
   per metrics lap: ``bytes_in_use_max`` / ``peak_bytes_in_use_max`` /
-  ``bytes_limit_min`` / ``headroom_bytes`` from jax device
-  ``memory_stats()`` where the backend exposes them
+  ``peak_bytes_reserved_max`` / ``bytes_limit_min`` / ``headroom_bytes``
+  (the limit less in-use peak plus reserved peak, per device) from jax
+  device ``memory_stats()`` where the backend exposes them
   (``source="memory_stats"``), or the live-buffer byte total
   (``source="live-buffers"``, CPU fallback). The Chrome exporter draws
   these as a counter track.
@@ -154,6 +159,8 @@ import os
 import threading
 import time
 from typing import Any, Iterable
+
+from distributeddeeplearningspark_tpu.telemetry import spans
 
 logger = logging.getLogger("distributeddeeplearningspark_tpu.telemetry")
 
@@ -462,11 +469,15 @@ class EventWriter:
     def phase(self, name: str, **fields: Any):
         """Span a blocking phase: begin/end records, end carries ``dur_s``.
         The begin record makes crashed runs honest — an unterminated begin
-        is accounted up to the stream's last event."""
+        is accounted up to the stream's last event. The phase is also a
+        ``dls.phase/<name>`` span in the profiler's trace (:mod:`.spans`),
+        on the clock of the device's ops. A phase costs two file writes,
+        so never one per step."""
         t0 = self._clock()
         self.emit("phase", name=name, edge="begin", **fields)
         try:
-            yield
+            with spans.span(spans.PHASE_PREFIX + name):
+                yield
         finally:
             self.emit("phase", name=name, edge="end",
                       dur_s=self._clock() - t0, **fields)
@@ -553,10 +564,11 @@ def emit_many(kind: str, records: "list[dict[str, Any]]") -> None:
 
 
 def phase(name: str, **fields: Any):
-    """Span context through the process-wide writer (no-op unconfigured)."""
+    """Span context through the process-wide writer; unconfigured, the
+    phase is still a ``dls.phase/<name>`` span in the profiler's trace."""
     if _writer is not None:
         return _writer.phase(name, **fields)
-    return contextlib.nullcontext()
+    return spans.span(spans.PHASE_PREFIX + name)
 
 
 # -- reader ------------------------------------------------------------------

@@ -22,14 +22,19 @@ with three instruments that all land on the same JSONL bus:
   wall-clock into *device* (timed dispatch on the compiled executable +
   the lap-boundary drain the host blocks on), *compile* (in-lap ledger
   compiles), *input-wait* (the starvation probe's number), and *host* (the
-  measured residual: python bookkeeping, transfers, checkpoint/eval work).
+  measured residual: python bookkeeping, transfers, checkpoint/eval work),
+  and names the parts of that residual (``input_put_s``, ``emit_s``,
+  ``callbacks_s``, ``checkpoint_s``, ``eval_s``, ``unaccounted_s``): every
+  part is a section of :func:`.spans.span`, which also writes it into the
+  profiler's trace.
   Per-lap **MFU** is computed from the ledger's analytical FLOPs over a
   per-backend peak-FLOPs table (``DLS_PEAK_FLOPS`` override; a labeled
   nominal figure on CPU so host drills still get a finite, comparable
   number). The gauges ride each ``step_metrics`` record.
 - **HBM watermarks** (:func:`memory_watermarks`): jax device memory stats
-  (``bytes_in_use`` / ``peak_bytes_in_use`` / ``bytes_limit``) where the
-  backend exposes them, live-buffer byte totals as the CPU fallback —
+  (``bytes_in_use`` / ``peak_bytes_in_use`` / ``peak_bytes_reserved`` /
+  ``bytes_limit``) where the backend exposes them, live-buffer byte totals
+  as the CPU fallback —
   emitted as ``memory`` events per metrics lap, the headroom trendline
   ``dlstatus --anatomy`` renders and the Chrome exporter draws as a
   counter track.
@@ -43,7 +48,6 @@ records into the cross-run regression sentinel.
 
 from __future__ import annotations
 
-import contextlib
 import hashlib
 import logging
 import os
@@ -52,6 +56,7 @@ import time
 from typing import Any, Callable, Iterable
 
 from distributeddeeplearningspark_tpu import telemetry as telemetry_lib
+from distributeddeeplearningspark_tpu.telemetry import spans
 
 logger = logging.getLogger("distributeddeeplearningspark_tpu.telemetry.anatomy")
 
@@ -245,7 +250,11 @@ class InstrumentedFunction:
     # -- ledger ---------------------------------------------------------------
 
     def _record_compile(self, sig: str, sig_hash: str, nleaves: int,
-                        compile_s: float, *, compiled=None) -> dict:
+                        compile_s: float, *, lower_s: float | None = None,
+                        compiled=None) -> dict:
+        """One ledger record. ``lower_s`` is the part of ``compile_s`` spent
+        tracing and lowering (``None`` on the jit fallback, which cannot
+        tell it from the backend's part)."""
         flops = bytes_accessed = None
         mem_fields: dict[str, int] = {}
         if compiled is not None:
@@ -272,6 +281,9 @@ class InstrumentedFunction:
             rec = {
                 "fn": self.name, "sig": sig, "sig_hash": sig_hash,
                 "nleaves": nleaves, "compile_s": round(compile_s, 6),
+                **({"lower_s": round(lower_s, 6),
+                    "backend_s": round(compile_s - lower_s, 6)}
+                   if lower_s is not None else {}),
                 "flops": flops, "bytes_accessed": bytes_accessed,
                 **mem_fields,
                 **({"plan": self.plan_name, "plan_sig": self.plan_sig}
@@ -291,8 +303,6 @@ class InstrumentedFunction:
                 "vs %d expected): %s", self.name, sig_hash, n, distinct,
                 self.expected_signatures, sig)
         telemetry_lib.emit("compile", **rec)
-        if self._anatomy is not None:
-            self._anatomy.note_compile(compile_s)
         return rec
 
     def _compile(self, key: Any, args: tuple):
@@ -302,11 +312,17 @@ class InstrumentedFunction:
         with telemetry_lib.phase(
                 "compile", fn=self.name,
                 **({"plan": self.plan_name} if self.plan_name else {})):
+            # the ledger keeps the two times whether or not a lap anatomy
+            # is attached, so it reads its own clock around each section
             t0 = self._clock()
-            compiled = self._jitted.lower(*args).compile()
-            compile_s = self._clock() - t0
-        self._record_compile(sig, sig_hash, nleaves, compile_s,
-                             compiled=compiled)
+            with spans.span("dls.step/lower", self._anatomy):
+                lowered = self._jitted.lower(*args)
+            t1 = self._clock()
+            with spans.span("dls.step/compile", self._anatomy):
+                compiled = lowered.compile()
+            t2 = self._clock()
+        self._record_compile(sig, sig_hash, nleaves, t2 - t0,
+                             lower_s=t1 - t0, compiled=compiled)
         with self._lock:
             self._compiled[key] = compiled
         return compiled
@@ -341,9 +357,9 @@ class InstrumentedFunction:
             return self._fallback_call(args, kwargs)
         if compiled is None:
             compiled = self._compile(key, args)
-        t0 = self._clock()
         try:
-            out = compiled(*args)
+            with spans.span("dls.step/dispatch", self._anatomy):
+                out = compiled(*args)
         except (TypeError, ValueError) as e:
             # the typed AOT mismatch errors ("compiled for different
             # types/shardings") mean our key missed a compile-relevant
@@ -355,8 +371,6 @@ class InstrumentedFunction:
                            "degrading to jit dispatch", self.name, e)
             self._aot = False
             return self._fallback_call(args, kwargs)
-        if self._anatomy is not None:
-            self._anatomy.note_dispatch(self._clock() - t0)
         return out
 
     def _fallback_call(self, args: tuple, kwargs: dict):
@@ -368,8 +382,11 @@ class InstrumentedFunction:
             pre = int(self._jitted._cache_size())
         except Exception:
             pass
+        # which section this call was (a compile or a dispatch) is known
+        # only afterwards, so the span is bare and the anatomy is told below
         t0 = self._clock()
-        out = self._jitted(*args, **kwargs)
+        with spans.span("dls.step/dispatch"):
+            out = self._jitted(*args, **kwargs)
         dt = self._clock() - t0
         grew = False
         if pre is not None:
@@ -392,8 +409,9 @@ class InstrumentedFunction:
                                **({"plan": self.plan_name}
                                   if self.plan_name else {}))
             self._record_compile(sig, sig_hash, nleaves, dt)
-        elif self._anatomy is not None:
-            self._anatomy.note_dispatch(dt)
+        if self._anatomy is not None:
+            self._anatomy.add(
+                "dls.step/compile" if grew else "dls.step/dispatch", dt)
         return out
 
     # -- summaries ------------------------------------------------------------
@@ -407,6 +425,10 @@ class InstrumentedFunction:
             "distinct_signatures": len({r["sig_hash"] for r in recs}),
             "flagged_recompiles": sum(bool(r["recompile"]) for r in recs),
             "total_compile_s": round(sum(r["compile_s"] for r in recs), 6),
+            "total_lower_s": round(
+                sum(r.get("lower_s", 0.0) for r in recs), 6),
+            "total_backend_s": round(
+                sum(r.get("backend_s", 0.0) for r in recs), 6),
             "flops_per_step": self.flops_per_step,
             "bytes_per_step": self.bytes_per_step,
             "aot": self._aot,
@@ -435,98 +457,97 @@ def instrument(jitted: Callable, *, name: str,
 
 
 class StepAnatomy:
-    """Per-lap wall-clock split: device / host / input-wait / compile.
+    """Per-lap wall-clock split of the loop thread into named sections.
 
-    The instrumented step reports each dispatch's duration
-    (:meth:`note_dispatch`) and each in-lap compile (:meth:`note_compile`);
-    the trainer wraps the lap-boundary ``device_get`` in :meth:`drain` and
-    closes the lap with :meth:`lap`. Attribution model (async dispatch):
+    The loop thread's sink of :func:`~.spans.span`: the instrumented step
+    adds each dispatch (``dls.step/dispatch``) and each in-lap compile
+    (``dls.step/lower``, ``dls.step/compile``), the trainer the lap-boundary
+    ``device_get`` (``dls.fit/sync``), its emits, callbacks, checkpoint
+    saves and evals (``dls.fit/*``), and closes the lap with :meth:`lap`.
+    A section's own time is counted, without the sections nested in it.
+    Attribution model (async dispatch):
 
     - ``device_s`` = dispatch + drain — the host time *surrendered to the
       device*: enqueue cost plus the boundary block where the host stood
       waiting for the step's results. On an async backend this is the
       honest wall-clock the device cost the loop (overlapped device work
       the host never waited on costs nothing, correctly).
-    - ``host_s`` — the measured residual of the lap's own wall: python
-      bookkeeping, host→device transfer, checkpoint/eval work inside the
-      lap.
-    - input-wait stays the starvation probe's number (it rides the same
-      ``step_metrics`` record) and is subtracted from the residual here.
-    - ``compile_in_lap_s`` — ledger compiles that landed inside the lap,
-      kept out of all three buckets (they are their own goodput category).
+    - ``compile_in_lap_s`` — ledger compiles that landed inside the lap
+      (they are their own goodput category).
+    - input wait and ``put`` stay the starvation probe's numbers (they ride
+      the same ``step_metrics`` record) and are handed to :meth:`lap`.
+    - ``host_s`` — the residual of the lap's wall after device, compile and
+      input wait, as it always was. ``emit_s``, ``callbacks_s``,
+      ``checkpoint_s``, ``eval_s`` and ``input_put_s`` are the named parts
+      of it; ``unaccounted_s`` is what no section covers (the loop's own
+      Python between them).
 
-    The four components tile the lap by construction; the CI smoke checks
-    them against the *independently measured* ``Meter`` lap time (two
-    different clock paths must agree within 5%).
+    The sections and ``unaccounted_s`` tile the lap by construction; the CI
+    smoke checks the wall against the *independently measured* ``Meter`` lap
+    time (two different clock paths must agree within 5%).
     """
 
+    #: this sink's counters, in the order ``step_metrics`` carries them
+    _KEYS = ("device_dispatch_s", "device_drain_s", "compile_in_lap_s",
+             "emit_s", "callbacks_s", "checkpoint_s", "eval_s")
+
     def __init__(self, clock=time.perf_counter):
-        self._clock = clock
+        self.clock = clock
         self._lock = threading.Lock()
-        self._lap_t0 = clock()
-        self._dispatch_s = 0.0
-        self._drain_s = 0.0
-        self._compile_s = 0.0
-        self._dispatches = 0
+        self.reset()
 
     def reset(self) -> None:
         """Restart the current lap's clock and counters — called at the
         same instant the Meter starts, so the two independently measured
         walls cover the same window (the CI smoke pins them within 5%)."""
         with self._lock:
-            self._lap_t0 = self._clock()
-            self._dispatch_s = self._drain_s = self._compile_s = 0.0
+            self._lap_t0 = self.clock()
+            self._seconds = dict.fromkeys(self._KEYS, 0.0)
             self._dispatches = 0
 
-    def note_dispatch(self, dt: float) -> None:
+    def add(self, name: str, dt: float, inner_s: float = 0.0) -> None:
+        """One closed section (:func:`~.spans.span`'s sink side): its own
+        time, without the ``inner_s`` of sections nested in it."""
+        key = spans.COUNTERS[name]
         with self._lock:
-            self._dispatch_s += dt
-            self._dispatches += 1
-
-    def note_compile(self, dt: float) -> None:
-        with self._lock:
-            self._compile_s += dt
-
-    @contextlib.contextmanager
-    def drain(self):
-        """Time the lap-boundary device sync (the metrics ``device_get``)."""
-        t0 = self._clock()
-        try:
-            yield
-        finally:
-            with self._lock:
-                self._drain_s += self._clock() - t0
+            self._seconds[key] += dt - inner_s
+            if key == "device_dispatch_s":
+                self._dispatches += 1
 
     def now(self) -> float:
         """The anatomy clock (pass to :meth:`lap` as its close timestamp
         when work — e.g. the starvation-probe snapshot — must run between
         the true lap boundary and the lap() call)."""
-        return self._clock()
+        return self.clock()
 
     def lap(self, *, steps: int, input_wait_s: float = 0.0,
+            input_put_s: float = 0.0,
             flops_per_step: float | None = None,
             num_chips: int = 1, now: float | None = None) -> dict[str, Any]:
         """Close the current lap; returns the gauge dict the trainer merges
         into the lap's ``step_metrics`` record. ``now`` pins the lap's
         close timestamp to the true sync boundary (default: the call)."""
         if now is None:
-            now = self._clock()
+            now = self.clock()
         with self._lock:
             wall = max(0.0, now - self._lap_t0)
-            dispatch, drain = self._dispatch_s, self._drain_s
-            compile_s, dispatches = self._compile_s, self._dispatches
+            sec, dispatches = self._seconds, self._dispatches
             self._lap_t0 = now
-            self._dispatch_s = self._drain_s = self._compile_s = 0.0
+            self._seconds = dict.fromkeys(self._KEYS, 0.0)
             self._dispatches = 0
-        device = dispatch + drain
-        host = max(0.0, wall - device - compile_s - float(input_wait_s or 0.0))
+        device = sec["device_dispatch_s"] + sec["device_drain_s"]
+        feed = float(input_wait_s or 0.0)
+        host = max(0.0, wall - device - sec["compile_in_lap_s"] - feed)
         rec: dict[str, Any] = {
             "anatomy_wall_s": round(wall, 6),
             "device_s": round(device, 6),
-            "device_dispatch_s": round(dispatch, 6),
-            "device_drain_s": round(drain, 6),
             "host_s": round(host, 6),
-            "compile_in_lap_s": round(compile_s, 6),
+            **{k: round(v, 6) for k, v in sec.items()},
+            # signed: a section still open at the boundary closes into the
+            # next lap, so the two laps' figures cancel
+            "unaccounted_s": round(
+                wall - sum(sec.values()) - feed - float(input_put_s or 0.0),
+                6),
             "device_dispatches": dispatches,
             "num_chips": int(num_chips),
         }
@@ -557,12 +578,19 @@ def memory_watermarks() -> dict[str, Any]:
     per-chip view); falls back to the live-buffer byte total
     (``jax.live_arrays()``) on backends without allocator stats (CPU), so
     the watermark trendline exists everywhere even if its ceiling doesn't.
+
+    The TPU allocator counts a running program's temporaries as
+    ``peak_bytes_reserved``, apart from the arrays "in use" (BERT-base at 32
+    a chip: 1.57 + 6.30 GB), so what a chip held at most is the two
+    together: ``headroom_bytes`` is the limit less the largest such sum.
     """
     import jax
 
     devs = jax.local_devices()
     in_use: list[int] = []
     peaks: list[int] = []
+    reserved: list[int] = []
+    held: list[int] = []  # per device: the most it held, temporaries too
     limits: list[int] = []
     for d in devs:
         try:
@@ -571,8 +599,12 @@ def memory_watermarks() -> dict[str, Any]:
             s = {}
         if s.get("bytes_in_use") is not None:
             in_use.append(int(s["bytes_in_use"]))
+            held.append(int(s.get("peak_bytes_in_use") or s["bytes_in_use"])
+                        + int(s.get("peak_bytes_reserved") or 0))
         if s.get("peak_bytes_in_use") is not None:
             peaks.append(int(s["peak_bytes_in_use"]))
+        if s.get("peak_bytes_reserved") is not None:
+            reserved.append(int(s["peak_bytes_reserved"]))
         if s.get("bytes_limit") is not None:
             limits.append(int(s["bytes_limit"]))
     if in_use:
@@ -581,9 +613,11 @@ def memory_watermarks() -> dict[str, Any]:
                                "bytes_in_use_max": max(in_use)}
         if peaks:
             rec["peak_bytes_in_use_max"] = max(peaks)
+        if reserved:
+            rec["peak_bytes_reserved_max"] = max(reserved)
         if limits:
             rec["bytes_limit_min"] = min(limits)
-            rec["headroom_bytes"] = min(limits) - max(peaks or in_use)
+            rec["headroom_bytes"] = min(limits) - max(held)
         return rec
     try:
         live = sum(int(getattr(a, "nbytes", 0)) for a in jax.live_arrays())
@@ -596,6 +630,12 @@ def memory_watermarks() -> dict[str, Any]:
 # -- reader (jax-free fold for dlstatus --anatomy) ----------------------------
 
 
+#: the named parts of ``host_s`` a lap carries, as ``dlstatus --anatomy``
+#: prints them on one line
+LOOP_SPLIT_KEYS = ("input_put_s", "emit_s", "callbacks_s", "checkpoint_s",
+                   "eval_s", "unaccounted_s")
+
+
 def _steps_fold(laps: list[dict]) -> dict[str, Any]:
     out = {"laps": len(laps),
            "steps": sum(int(e.get("steps", 0) or 0) for e in laps)}
@@ -603,7 +643,9 @@ def _steps_fold(laps: list[dict]) -> dict[str, Any]:
                      ("device_dispatch_s", "device_dispatch_s"),
                      ("device_drain_s", "device_drain_s"),
                      ("host_s", "host_s"), ("compile_s", "compile_in_lap_s"),
-                     ("input_wait_s", "input_wait_s")):
+                     ("input_wait_s", "input_wait_s"),
+                     # the named parts of host_s (absent from older streams)
+                     *((k, k) for k in LOOP_SPLIT_KEYS)):
         out[key] = round(sum(float(e.get(src, 0.0) or 0.0) for e in laps), 6)
     wall = out["wall_s"]
     covered = (out["device_s"] + out["host_s"] + out["compile_s"]
@@ -660,9 +702,19 @@ def _memory_fold(mems: list[dict]) -> dict[str, Any] | None:
                                "bytes_in_use_max": in_use}
         if peaks:
             out["peak_bytes_in_use_max"] = max(peaks)
+        reserved = [int(e["peak_bytes_reserved_max"]) for e in stats
+                    if e.get("peak_bytes_reserved_max") is not None]
+        if reserved:
+            out["peak_bytes_reserved_max"] = max(reserved)
         if limits:
             out["bytes_limit_min"] = min(limits)
-            out["headroom_bytes"] = min(limits) - max(peaks or [in_use])
+            # each process worked its own headroom out per device, reserved
+            # bytes included; a stream from before that key has peaks only
+            headrooms = [int(e["headroom_bytes"]) for e in stats
+                         if e.get("headroom_bytes") is not None]
+            out["headroom_bytes"] = (
+                min(headrooms) if headrooms
+                else min(limits) - max(peaks or [in_use]))
         return out
     live = max(int(e.get("live_bytes", 0) or 0) for e in rows)
     return {"source": "live-buffers", "live_bytes": live}

@@ -8,7 +8,6 @@ feeding prefetched sharded batches, periodic metrics, and checkpoint hooks.
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import logging
 import os
@@ -30,6 +29,7 @@ from distributeddeeplearningspark_tpu.data.prefetch import (
 from distributeddeeplearningspark_tpu import faults
 from distributeddeeplearningspark_tpu import telemetry as telemetry_lib
 from distributeddeeplearningspark_tpu.telemetry import anatomy as anatomy_lib
+from distributeddeeplearningspark_tpu.telemetry import spans
 from distributeddeeplearningspark_tpu.metrics import (
     Meter,
     MetricLogger,
@@ -668,16 +668,17 @@ class Trainer:
         tele = self._telemetry()
         probe = StarvationProbe() if tele is not None else None
         # per-lap device/host/input anatomy (docs/OBSERVABILITY.md "Device
-        # anatomy"): the instrumented step reports each dispatch's and
-        # compile's duration into it, the lap-boundary device_get drains
-        # into it, and the closed lap's split rides the step_metrics record
+        # anatomy"): the instrumented step adds each dispatch and compile
+        # to it, every `spans.span(name, anat)` below adds its section, and
+        # the closed lap's split rides the step_metrics record. Telemetry
+        # off: no accumulator, and each span is a bare TraceAnnotation.
         anat = anatomy_lib.StepAnatomy() if tele is not None else None
         if isinstance(self._train_step, anatomy_lib.InstrumentedFunction):
             self._train_step.attach_anatomy(anat)
 
         def tele_phase(name: str):
             return (tele.phase(name) if tele is not None
-                    else contextlib.nullcontext())
+                    else spans.span(spans.PHASE_PREFIX + name))
 
         mlog = MetricLogger(log_every=log_every, tensorboard_dir=tensorboard_dir,
                             telemetry=tele)
@@ -771,8 +772,10 @@ class Trainer:
                     else:
                         faults.hang()
                 profiler.observe(step_i)
-                with profiling.step_annotation(step_i) if profile is not None \
-                        else contextlib.nullcontext():
+                # the step marker is always written: the profiler may be the
+                # caller's (utils/profiling.trace, a benchmark), not
+                # `profile=`'s
+                with profiling.step_annotation(step_i):
                     # compiles (the first dispatch AND any mid-run shape
                     # change) are spanned, timed, and cost-analyzed by the
                     # instrumented step itself (telemetry/anatomy.py), so
@@ -795,42 +798,42 @@ class Trainer:
                         meter.set_flops(self._train_step.flops_per_step)
                     # device_get blocks until this step's metrics exist, so the
                     # lap boundary is a true device-sync point — timing is honest.
-                    with (anat.drain() if anat is not None
-                          else contextlib.nullcontext()):
+                    with spans.span("dls.fit/sync", anat):
                         fetched = jax.device_get(metrics)
                     last_metrics = meter.lap(step_i - lap_start, fetched)
                     lap_start = step_i
                     # close the anatomy lap at the SAME sync point the
                     # meter lapped at — the log rendering below belongs to
                     # the next lap on both clocks, or the two walls drift
-                    snap: dict = {}
-                    anat_rec: dict = {}
                     lap_s, lap_n = meter.last_lap or (0.0, 0)
-                    if tele is not None:
-                        lap_close = anat.now() if anat is not None else None
+                    lap_close = anat.now() if anat is not None else None
+                    # opened after the boundary and closed after lap(): the
+                    # emits are the NEXT lap's on the anatomy's clock too
+                    with spans.span("dls.fit/emit", anat):
                         snap = probe.snapshot() if probe is not None else {}
+                        anat_rec: dict = {}
                         if anat is not None:
                             anat_rec = anat.lap(
                                 steps=lap_n,
-                                input_wait_s=float(
-                                    snap.get("input_wait_s", 0.0) or 0.0),
+                                input_wait_s=snap.get("input_wait_s", 0.0),
+                                input_put_s=snap.get("input_put_s", 0.0),
                                 flops_per_step=getattr(
                                     self._train_step, "flops_per_step",
                                     None),
                                 num_chips=self.mesh.devices.size,
                                 now=lap_close,
                             )
-                    mlog.log(step_i, {**last_metrics, **meter.summary()})
-                    _touch_heartbeat()
-                    if tele is not None:
-                        tele.step_metrics(
-                            step_i, steps=lap_n, lap_s=lap_s,
-                            metrics=last_metrics, **snap, **anat_rec)
-                        tele.emit("memory",
-                                  **anatomy_lib.memory_watermarks())
-                        tele.heartbeat(step=step_i)
-                        if comms_probe:
-                            collectives.barrier_probe(self.mesh)
+                        mlog.log(step_i, {**last_metrics, **meter.summary()})
+                        _touch_heartbeat()
+                        if tele is not None:
+                            tele.step_metrics(
+                                step_i, steps=lap_n, lap_s=lap_s,
+                                metrics=last_metrics, **snap, **anat_rec)
+                            tele.emit("memory",
+                                      **anatomy_lib.memory_watermarks())
+                            tele.heartbeat(step=step_i)
+                            if comms_probe:
+                                collectives.barrier_probe(self.mesh)
                     if on_nonfinite == "raise":
                         sanitize.assert_all_finite(last_metrics, step=step_i)
                     elif on_nonfinite == "skip":
@@ -906,8 +909,12 @@ class Trainer:
                             continue
                 if sanitize_every and step_i % sanitize_every == 0:
                     sanitize.assert_replicas_in_sync(self.state.params)
-                for cb in callbacks:
-                    cb(step_i, last_metrics)
+                if callbacks:
+                    # the callers' time (a benchmark's window, its
+                    # start_trace / stop_trace), not the program's
+                    with spans.span("dls.fit/callbacks", anat):
+                        for cb in callbacks:
+                            cb(step_i, last_metrics)
                 doomed_now: int | None = None
                 if preempt is not None and step_i >= preempt.step:
                     doomed_now = faults.fault_host()
@@ -926,12 +933,14 @@ class Trainer:
                         batch_size=batch_size, doomed=doomed_now)
                     break
                 if checkpoint_every and self.checkpointer and step_i % checkpoint_every == 0:
-                    self.checkpointer.save(
-                        step_i, self.state,
-                        data_state={"examples_seen":
-                                    (step_i + rolled_back_batches) * batch_size,
-                                    "batch_size": batch_size},
-                    )
+                    with spans.span("dls.fit/checkpoint", anat):
+                        self.checkpointer.save(
+                            step_i, self.state,
+                            data_state={"examples_seen":
+                                        (step_i + rolled_back_batches)
+                                        * batch_size,
+                                        "batch_size": batch_size},
+                        )
                     if (fault is not None and fault.kind == "truncate_ckpt"
                             and step_i >= fault.step):
                         # kill-mid-finalize drill: make the save durable +
@@ -941,7 +950,7 @@ class Trainer:
                             self.checkpointer.directory)
                         faults.crash()
                 if eval_every and eval_dataset is not None and step_i % eval_every == 0:
-                    with tele_phase("eval"):
+                    with spans.span("dls.fit/eval", anat), tele_phase("eval"):
                         emetrics = self.evaluate(eval_dataset, batch_size=batch_size)
                     mlog.log(step_i, {f"eval_{k}": v for k, v in emetrics.items()})
         finally:

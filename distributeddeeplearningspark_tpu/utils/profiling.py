@@ -8,8 +8,10 @@ native story is:
 - ``jax.profiler`` traces (host Python + device HLO timeline) written in
   TensorBoard 'profile' plugin format — ``ProfileSpec`` captures a window of
   steps mid-training from the Trainer without stopping the job;
-- ``annotate(name)`` TraceAnnotations to label host phases (input pipeline,
-  checkpoint, eval) so they're attributable in the trace viewer;
+- the program's own host spans (``dls.feed/*``, ``dls.step/*``,
+  ``dls.fit/*``, ``dls.phase/*``: :mod:`..telemetry.spans`) and the ``train``
+  step marker (:func:`step_annotation`) are written into whatever trace is
+  running, so an idle gap of the device is attributable in the trace viewer;
 - XLA HLO dumps (``enable_xla_dump``) for compiler-level inspection of what
   GSPMD did to the step function — set BEFORE the first compile.
 """
@@ -139,11 +141,6 @@ class StepProfiler:
                     "profiler: device-time budget parse still running after "
                     "%.0fs — abandoning (trace remains at %s)",
                     timeout_s, self.spec.dir)
-
-
-def annotate(name: str):
-    """Label a host-side phase in the trace (input prep, checkpoint, eval)."""
-    return jax.profiler.TraceAnnotation(name)
 
 
 def step_annotation(step: int):

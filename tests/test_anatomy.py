@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from distributeddeeplearningspark_tpu import status, telemetry
-from distributeddeeplearningspark_tpu.telemetry import anatomy
+from distributeddeeplearningspark_tpu.telemetry import anatomy, spans
 
 
 def _load_perf_guard():
@@ -201,10 +201,10 @@ def test_step_anatomy_split_and_mfu_arithmetic(monkeypatch):
     clock = FakeClock()
     anat = anatomy.StepAnatomy(clock=clock)
     anat.reset()
-    anat.note_compile(1.0)
-    anat.note_dispatch(4.0)
+    anat.add("dls.step/compile", 1.0)
+    anat.add("dls.step/dispatch", 4.0)
     clock.t = 8.0
-    with anat.drain():
+    with spans.span("dls.fit/sync", anat):
         clock.t = 10.0
     rec = anat.lap(steps=10, input_wait_s=0.5, flops_per_step=2e9,
                    num_chips=4)
@@ -266,6 +266,51 @@ def test_memory_watermarks_cpu_fallback():
     assert rec["source"] == "live-buffers"
     assert rec["devices"] >= 1
     assert rec["live_bytes"] >= keep.nbytes
+
+
+def test_memory_watermarks_count_reserved_bytes_and_the_hbm_rule_sees_them(
+        monkeypatch):
+    """The TPU allocator counts a running program's temporaries as
+    ``peak_bytes_reserved`` (BERT-base at 32 a chip: 1.57 + 6.30 GB of 16):
+    headroom is the limit less the sum, per device, and the ``hbm`` rule
+    reads that figure, not the one five times too roomy."""
+    import types
+
+    from distributeddeeplearningspark_tpu.telemetry import health
+
+    gb = 10 ** 9
+    stats = [
+        {"bytes_in_use": 1 * gb, "peak_bytes_in_use": 2 * gb,
+         "peak_bytes_reserved": 13 * gb, "bytes_limit": 16 * gb},
+        {"bytes_in_use": 3 * gb, "peak_bytes_in_use": 4 * gb,
+         "peak_bytes_reserved": 6 * gb, "bytes_limit": 16 * gb},
+    ]
+    monkeypatch.setattr(jax, "local_devices", lambda: [
+        types.SimpleNamespace(memory_stats=lambda s=s: s) for s in stats])
+    rec = anatomy.memory_watermarks()
+    assert rec["source"] == "memory_stats" and rec["devices"] == 2
+    assert rec["bytes_in_use_max"] == 3 * gb
+    assert rec["peak_bytes_in_use_max"] == 4 * gb
+    assert rec["peak_bytes_reserved_max"] == 13 * gb
+    # device 0 held 2 + 13 of its 16 GB: 1 GB of headroom, not 16 - 4
+    assert rec["headroom_bytes"] == 1 * gb
+    events = [{"ts": 1.0, "kind": "memory", "process": "p0", **rec}]
+    mem = anatomy.anatomy_report(events)["memory"]
+    assert mem["peak_bytes_reserved_max"] == 13 * gb
+    assert mem["headroom_bytes"] == 1 * gb
+    (alert,) = health._rule_hbm({"anatomy": {"memory": mem}})
+    assert alert["severity"] == "WARN" and alert["rule"] == "hbm"
+    # a backend that reports no reserved bytes: in-use peaks alone, as before
+    for s in stats:
+        del s["peak_bytes_reserved"]
+    rec = anatomy.memory_watermarks()
+    assert "peak_bytes_reserved_max" not in rec
+    assert rec["headroom_bytes"] == 12 * gb
+    # and a stream from before ``headroom_bytes`` counted them still folds
+    old = {"ts": 1.0, "kind": "memory", "process": "p0",
+           "source": "memory_stats", "bytes_in_use_max": 100,
+           "peak_bytes_in_use_max": 150, "bytes_limit_min": 1000}
+    assert anatomy.anatomy_report([old])["memory"]["headroom_bytes"] == 850
 
 
 def test_memory_fold_prefers_stats_and_computes_headroom():
@@ -331,6 +376,34 @@ def test_anatomy_report_fold_totals_and_verdicts():
     assert rep["per_process"]["p0"]["laps"] == 2
     # an empty stream has no report at all
     assert anatomy.anatomy_report([{"ts": 0, "kind": "heartbeat"}]) is None
+
+
+def test_anatomy_report_folds_the_loops_named_sections(tmp_path, capsys):
+    """The new keys fold beside host_s, and ``dlstatus --anatomy`` prints the
+    loop's split on one line; a stream without them prints no such line."""
+    split = {"input_put_s": 0.25, "emit_s": 0.5, "callbacks_s": 0.125,
+             "checkpoint_s": 1.0, "eval_s": 0.0, "unaccounted_s": 0.625}
+    laps = [{**_lap_event("p0", 10.0), **split},
+            {**_lap_event("p0", 20.0), **split}]
+    st = anatomy.anatomy_report(laps)["steps"]
+    assert {k: st[k] for k in split} == {k: 2 * v for k, v in split.items()}
+    assert tuple(split) == anatomy.LOOP_SPLIT_KEYS
+    assert st["host_s"] == 5.0 and st["coverage"] == pytest.approx(1.0)
+    w = telemetry.EventWriter(tmp_path, process="p0", clock=FakeClock(),
+                              host=0)
+    for e in laps:
+        w.emit("step_metrics", **{k: v for k, v in e.items()
+                                  if k not in ("ts", "kind", "process")})
+    w.close()
+    assert status.main([str(tmp_path), "--anatomy"]) == 0
+    out = capsys.readouterr().out
+    (line,) = [ln for ln in out.splitlines() if "of it:" in ln]
+    assert line.split("of it: ")[1] == (
+        "put 0.50s  emit 1.00s  callbacks 0.25s  checkpoint 2.00s  "
+        "eval 0.00s  unaccounted 1.25s")
+    lines = status.render_anatomy(anatomy.anatomy_report(
+        [_lap_event("p0", 10.0)]))
+    assert not any("of it:" in ln for ln in lines)
 
 
 def test_anatomy_report_cross_process_duplicates_are_not_flagged():

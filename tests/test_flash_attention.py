@@ -314,3 +314,32 @@ class TestSegmentIds:
         q, k, v = _qkv()
         with pytest.raises(ValueError, match="segment_ids"):
             flash_attention(q, k, v, segment_ids=jnp.zeros((2, 64), jnp.int32))
+
+
+def test_the_three_kernels_carry_stable_names():
+    """A trace tells a kernel by its name: the gradient of a flash-routed
+    attention (``impl="flash"``, key-padding mask, as BERT calls it) holds
+    three ``pallas_call`` equations called flash_fwd, flash_bwd_dq and
+    flash_bwd_dkv (unnamed, all three take the enclosing function's name)."""
+    from distributeddeeplearningspark_tpu.ops.attention import (
+        dot_product_attention,
+    )
+
+    q, k, v = _qkv(b=1, s=64, h=2, d=16)
+    mask = padding_mask(_pad_mask(1, 64, 48))
+
+    def loss(q, k, v):
+        return jnp.sum(dot_product_attention(
+            q, k, v, mask=mask, impl="flash") ** 2)
+
+    names = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.append(eqn.params["name"])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v).jaxpr)
+    assert sorted(names) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]

@@ -10,6 +10,7 @@ import pytest
 
 from distributeddeeplearningspark_tpu import PartitionedDataset, Session, Trainer
 from distributeddeeplearningspark_tpu.models import LeNet5
+from distributeddeeplearningspark_tpu.telemetry import spans
 from distributeddeeplearningspark_tpu.train import losses
 from distributeddeeplearningspark_tpu.utils import profiling
 
@@ -17,7 +18,7 @@ from distributeddeeplearningspark_tpu.utils import profiling
 def test_trace_context_manager_writes_xplane(tmp_path):
     d = str(tmp_path / "prof")
     with profiling.trace(d):
-        with profiling.annotate("compute"):
+        with spans.span("dls.fit/emit"):
             jax.block_until_ready(jnp.dot(jnp.ones((64, 64)), jnp.ones((64, 64))))
     assert profiling.trace_files(d), "no .xplane.pb produced by trace capture"
 
