@@ -17,6 +17,7 @@ runs a pre-built vocab file can be loaded.
 from __future__ import annotations
 
 import collections
+import itertools
 import os
 import re
 from typing import Iterable, Iterator, Sequence
@@ -29,6 +30,12 @@ PAD, UNK, CLS, SEP, MASK = "[PAD]", "[UNK]", "[CLS]", "[SEP]", "[MASK]"
 SPECIAL_TOKENS = (PAD, UNK, CLS, SEP, MASK)
 
 _WORD_RE = re.compile(r"[a-z0-9]+|[^\sa-z0-9]")
+
+#: most words a tokenizer keeps the pieces of (a word that finds no room is
+#: tokenized again on every occurrence): a dump with endless rare words
+#: cannot grow the memo without limit; some 60 MB of str keys and id lists
+#: when full
+_WORD_MEMO_WORDS = 1 << 18
 
 
 class WordPieceTokenizer:
@@ -47,6 +54,18 @@ class WordPieceTokenizer:
         self.mask_id = self.vocab[MASK]
         #: ids never selected for masking
         self.special_ids = frozenset(self.vocab[t] for t in SPECIAL_TOKENS)
+        #: the same as a lookup table over the vocabulary's ids, and the ids
+        #: a random replacement may be drawn from (loaded vocabs, e.g. the
+        #: stock BERT vocab.txt, don't keep specials in a contiguous prefix):
+        #: what :func:`mask_tokens` needs of the tokenizer, built once here
+        self.special_mask = np.zeros(max(self.inv) + 1, bool)
+        self.special_mask[list(self.special_ids)] = True
+        self.replacement_ids = np.setdiff1d(
+            np.arange(len(self.vocab), dtype=np.int32),
+            np.fromiter(self.special_ids, np.int32))
+        # whitespace-delimited word -> ids of its pieces, filled by encode()
+        self._word_pieces: dict[str, list[int]] = {}
+        self._words = self._memo_misses = 0
 
     @property
     def vocab_size(self) -> int:
@@ -71,11 +90,40 @@ class WordPieceTokenizer:
             start = end
         return ids
 
-    def encode(self, text: str) -> list[int]:
-        ids: list[int] = []
-        for word in _WORD_RE.findall(text.lower()):
-            ids.extend(self.tokenize_word(word))
+    def encode(self, text: str) -> np.ndarray:
+        """Ids of a document as one int32 array.
+
+        The text is split at whitespace (no match of ``_WORD_RE`` crosses
+        it) and each distinct word, punctuation attached, goes through
+        ``_WORD_RE`` and :meth:`tokenize_word` once, while the memo has
+        room; after that the document costs a split and a dict lookup a word.
+        """
+        words = text.lower().split()
+        pieces = list(map(self._word_pieces.get, words))
+        self._words += len(words)
+        if None in pieces:
+            for i, word in enumerate(words):
+                if pieces[i] is None:
+                    pieces[i] = self._pieces_of_new_word(word)
+        return np.fromiter(itertools.chain.from_iterable(pieces), np.int32)
+
+    def _pieces_of_new_word(self, word: str) -> list[int]:
+        """What :meth:`encode` does with a word its memo did not hold."""
+        memo = self._word_pieces
+        ids = memo.get(word)  # met earlier in the same text
+        if ids is None:
+            ids = [piece for part in _WORD_RE.findall(word)
+                   for piece in self.tokenize_word(part)]
+            self._memo_misses += 1
+            if len(memo) < _WORD_MEMO_WORDS:
+                memo[word] = ids
         return ids
+
+    def stats(self) -> dict[str, int]:
+        """How often the word memo of :meth:`encode` engaged so far."""
+        return {"words": self._words,
+                "memo_hits": self._words - self._memo_misses,
+                "memo_words": len(self._word_pieces)}
 
     def decode(self, ids: Sequence[int]) -> str:
         pieces = [self.inv.get(int(i), UNK) for i in ids]
@@ -129,34 +177,64 @@ def segments_from_docs(
     docs: Iterable[str], tokenizer: WordPieceTokenizer, seq_len: int
 ) -> Iterator[np.ndarray]:
     """Pack tokenized documents into fixed [CLS] ... [SEP] windows."""
-    for ids, _ in packed_segments_from_docs(docs, tokenizer, seq_len):
+    for ids, _ in _packed_windows((tokenizer.encode(doc) for doc in docs),
+                                  tokenizer, seq_len, segments=False):
         yield ids
 
 
 def _pack_token_windows(
-    doc_tokens: Iterable[list[int]], window: int
-) -> Iterator[tuple[list[int], list[int], bool]]:
+    doc_tokens: Iterable, window: int, *, segments: bool
+) -> Iterator[tuple[np.ndarray, np.ndarray | None, bool]]:
     """Lockstep token/segment-id packer shared by the MLM and causal-LM
-    pipelines: concatenate per-document token lists, tag every position
-    with a running document counter, and cut ``window``-sized chunks →
-    ``(chunk, seg_ids, is_partial)``. The final partial chunk (corpus
-    tail) is yielded unpadded with ``is_partial=True`` — framing (CLS/SEP
-    vs EOS, pad conventions) belongs to the caller. ONE copy of the
-    buffer-slicing invariant lives here.
+    pipelines: concatenate per-document token arrays, tag every position
+    with a running document counter (only if ``segments``; else None),
+    and cut ``window``-sized chunks → ``(chunk, seg_ids, is_partial)``, all
+    int32. The final partial chunk (corpus tail) is yielded unpadded with
+    ``is_partial=True`` — framing (CLS/SEP vs EOS, pad conventions) belongs
+    to the caller. ONE copy of the buffer-slicing invariant lives here.
+    Chunks are views of the packer's buffer: a caller copies them into its
+    frame.
     """
-    buf: list[int] = []
-    seg: list[int] = []
-    doc_id = 0
-    for toks in doc_tokens:
-        buf.extend(toks)
-        seg.extend([doc_id] * len(toks))
-        doc_id += 1
-        while len(buf) >= window:
-            chunk, buf = buf[:window], buf[window:]
-            cseg, seg = seg[:window], seg[window:]
-            yield chunk, cseg, False
-    if buf:
-        yield buf, seg, True
+    pending: list[np.ndarray] = []
+    pending_seg: list[np.ndarray] = []
+    held = 0
+    for doc_id, toks in enumerate(doc_tokens):
+        toks = np.asarray(toks, np.int32)
+        pending.append(toks)
+        if segments:
+            pending_seg.append(np.full(len(toks), doc_id, np.int32))
+        held += len(toks)
+        if held < window:
+            continue
+        buf = np.concatenate(pending)
+        seg = np.concatenate(pending_seg) if segments else None
+        full = held - held % window
+        for off in range(0, full, window):
+            yield (buf[off:off + window],
+                   seg[off:off + window] if segments else None, False)
+        pending, held = [buf[full:]], held - full
+        if segments:
+            pending_seg = [seg[full:]]
+    if held:
+        yield (np.concatenate(pending),
+               np.concatenate(pending_seg) if segments else None, True)
+
+
+def _padded(values: np.ndarray, length: int, fill) -> np.ndarray:
+    """``values`` followed by ``fill`` up to ``length``, in a new array."""
+    out = np.full(length, fill, values.dtype)
+    out[:len(values)] = values
+    return out
+
+
+def _framed(chunk: np.ndarray, tokenizer: WordPieceTokenizer, seq_len: int
+            ) -> np.ndarray:
+    """``[CLS] chunk [SEP] [PAD]...`` as one [seq_len] int32 array."""
+    ids = np.full(seq_len, tokenizer.pad_id, np.int32)
+    ids[0] = tokenizer.cls_id
+    ids[1:len(chunk) + 1] = chunk
+    ids[len(chunk) + 1] = tokenizer.sep_id
+    return ids
 
 
 def packed_segments_from_docs(
@@ -180,17 +258,28 @@ def packed_segments_from_tokens(
     doc_tokens: Iterable, tokenizer: WordPieceTokenizer, seq_len: int
 ) -> Iterator[tuple[np.ndarray, np.ndarray]]:
     """:func:`packed_segments_from_docs` over PRE-tokenized documents — the
-    split that lets the tokenize stage (the expensive per-doc map) run in
-    the :mod:`.workers` process pool while the stateful cross-document
-    packing stays on the consumer. Accepts lists or int arrays per doc."""
-    for chunk, cseg, partial in _pack_token_windows(doc_tokens, seq_len - 2):
-        ids = [tokenizer.cls_id, *chunk, tokenizer.sep_id]
-        sids = [cseg[0], *cseg, cseg[-1]]
-        if partial:
-            pad = seq_len - len(ids)
-            ids += [tokenizer.pad_id] * pad
-            sids += [-1] * pad
-        yield np.array(ids, np.int32), np.array(sids, np.int32)
+    split that lets the tokenize stage (the per-doc map) run in the
+    :mod:`.workers` process pool while the stateful cross-document packing
+    stays on the consumer. Since the tokenizer remembers its words and every
+    stage handles a document as one array, tokenizing costs well under a
+    microsecond a token in process: the pool pays where the per-doc map
+    also reads and cleans a real dump (``wikipedia_dump``:
+    ``clean_wikitext``), not for synthetic text. Accepts lists or int arrays
+    per doc."""
+    return _packed_windows(doc_tokens, tokenizer, seq_len, segments=True)
+
+
+def _packed_windows(
+    doc_tokens: Iterable, tokenizer: WordPieceTokenizer, seq_len: int, *,
+    segments: bool
+) -> Iterator[tuple[np.ndarray, np.ndarray | None]]:
+    """Framed packed windows → (ids, segment ids or None if not asked for)."""
+    for chunk, cseg, _ in _pack_token_windows(doc_tokens, seq_len - 2,
+                                              segments=segments):
+        # [CLS] joins the window's first document, [SEP] its last
+        sids = _padded(np.concatenate((cseg[:1], cseg, cseg[-1:])), seq_len,
+                       -1) if segments else None
+        yield _framed(chunk, tokenizer, seq_len), sids
 
 
 def padded_segments_from_docs(
@@ -214,14 +303,9 @@ def _padded_from_tokens(
     — the tokenize/frame split that lets the worker pool own the encode."""
     budget = seq_len - 2
     for toks in doc_tokens:
-        toks = list(toks)
-        if not toks:
-            continue
+        toks = np.asarray(toks, np.int32)
         for off in range(0, len(toks), budget):
-            chunk = toks[off:off + budget]
-            ids = [tokenizer.cls_id, *chunk, tokenizer.sep_id]
-            ids += [tokenizer.pad_id] * (seq_len - len(ids))
-            yield np.array(ids, np.int32)
+            yield _framed(toks[off:off + budget], tokenizer, seq_len)
 
 
 def _tokens_dataset(docs: PartitionedDataset, tok_fn, num_workers: int | None,
@@ -246,7 +330,7 @@ def mask_tokens(
 ) -> dict[str, np.ndarray]:
     """BERT's 80/10/10 MLM corruption → fixed-shape example dict."""
     ids = np.asarray(ids, np.int32)
-    maskable = ~np.isin(ids, list(tokenizer.special_ids))
+    maskable = ~tokenizer.special_mask[ids]
     sel = (rng.random(ids.shape) < mask_prob) & maskable
     if not sel.any() and maskable.any():  # guarantee ≥1 target per segment
         sel[rng.choice(np.flatnonzero(maskable))] = True
@@ -256,13 +340,8 @@ def mask_tokens(
     corrupted[sel & (r < 0.8)] = tokenizer.mask_id
     rand_sel = sel & (r >= 0.8) & (r < 0.9)
     if rand_sel.any():
-        # draw replacements from NON-special ids (loaded vocabs — e.g. the
-        # stock BERT vocab.txt — don't keep specials in a contiguous prefix)
-        candidates = np.setdiff1d(
-            np.arange(tokenizer.vocab_size, dtype=np.int32),
-            np.fromiter(tokenizer.special_ids, np.int32),
-        )
-        corrupted[rand_sel] = rng.choice(candidates, rand_sel.sum())
+        corrupted[rand_sel] = rng.choice(tokenizer.replacement_ids,
+                                         rand_sel.sum())
     # remaining 10%: keep original token
 
     return {
@@ -326,10 +405,15 @@ def mlm_dataset(
     RoBERTa FULL-SENTENCES convention (documents share the window).
     ``pack=False``: one padded document per window — the reference-era
     shape, kept for the padding-waste A/B (see ``token_stats``).
-    ``num_workers`` (default ``DLS_DATA_WORKERS``): tokenize — the per-doc
-    hot loop — across worker processes (:mod:`.workers`); the stateful
+    ``num_workers`` (default ``DLS_DATA_WORKERS``): run the per-doc map
+    (tokenize) across worker processes (:mod:`.workers`); the stateful
     window packing and the per-partition-seeded masking stay on the
-    consumer, so the example stream is byte-identical for any count.
+    consumer, so the example stream is byte-identical for any count. One
+    thread tokenizes, packs and masks in about a microsecond a token (the
+    tokenizer remembers its words; PERF.md §5), so the pool pays only
+    where ``docs`` itself is dear per document: a real dump read and
+    cleaned by ``wikipedia_dump`` (``clean_wikitext``), words the memo has
+    not met. Each worker process fills a memo of its own.
     """
 
     if not pack and segment_ids:
@@ -348,9 +432,8 @@ def mlm_dataset(
                 (ids, None)
                 for ids in _padded_from_tokens(toks, tokenizer, seq_len))
         else:
-            gen = packed_segments_from_tokens(toks, tokenizer, seq_len)
-            if not segment_ids:
-                gen = ((ids, None) for ids, _ in gen)
+            gen = _packed_windows(toks, tokenizer, seq_len,
+                                  segments=segment_ids)
         for seg, sids in gen:
             ex = mask_tokens(seg, tokenizer, rng, mask_prob=mask_prob)
             if sids is not None:
@@ -443,30 +526,28 @@ def lm_dataset(
     consumes ``batch["segment_ids"]`` through the flash kernel / ring
     (GPT-style packing without it is also standard; measure both).
     ``num_workers``: tokenize across worker processes, packing stays on
-    the consumer — byte-identical stream for any count (see
+    the consumer — byte-identical stream for any count. It pays where the
+    documents are dear to read and clean, not for the tokenizing (see
     :func:`mlm_dataset`).
     """
+    eos = np.full(1 if eos_between_docs else 0, tokenizer.sep_id, np.int32)
     token_ds = _tokens_dataset(
         docs,
-        lambda doc: np.asarray(
-            tokenizer.encode(doc)
-            + ([tokenizer.sep_id] if eos_between_docs else []), np.int32),
+        lambda doc: np.concatenate(
+            (np.asarray(tokenizer.encode(doc), np.int32), eos)),
         num_workers, label="lm_tokenize")
 
     def per_partition(pidx: int, stream: Iterable[np.ndarray]) -> Iterator[dict]:
         del pidx
-        for chunk, cseg, partial in _pack_token_windows(stream, seq_len):
+        for chunk, cseg, partial in _pack_token_windows(
+                stream, seq_len, segments=segment_ids):
             if partial and len(chunk) <= 1:
                 continue  # a lone token has no next-token target
-            mask = np.zeros(seq_len, np.float32)
-            mask[: len(chunk)] = 1.0
-            ids = chunk + [tokenizer.pad_id] * (seq_len - len(chunk))
-            ex = {"input_ids": np.array(ids, np.int32),
-                  "loss_mask": (np.ones(seq_len, np.float32)
-                                if not partial else mask)}
+            ex = {"input_ids": _padded(chunk, seq_len, tokenizer.pad_id),
+                  "loss_mask": _padded(np.ones(len(chunk), np.float32),
+                                       seq_len, 0.0)}
             if segment_ids:
-                sids = cseg + [-1] * (seq_len - len(cseg))
-                ex["segment_ids"] = np.array(sids, np.int32)
+                ex["segment_ids"] = _padded(cseg, seq_len, -1)
             yield ex
 
     return token_ds.map_partitions_with_index(per_partition)
@@ -487,20 +568,26 @@ def synthetic_wikipedia(
         "science", "theory", "system", "language", "island", "mountain",
     ]
 
+    # fixed bigram table (shared across partitions: same "language"):
+    # word index -> the indices of its four successors
+    trng = np.random.default_rng(20260729)
+    index = {w: i for i, w in enumerate(base)}
+    nxt = [[index[s] for s in trng.choice(base, 4, replace=True)]
+           for _ in base]
+
     def make_partition(pidx: int):
         def gen() -> Iterator[str]:
             rng = np.random.default_rng(seed * 1000 + pidx)
-            n = num_docs // num_partitions
-            # fixed bigram table (shared across partitions: same "language")
-            trng = np.random.default_rng(20260729)
-            nxt = {w: trng.choice(base, 4, replace=True) for w in base}
-            for _ in range(n):
-                w = base[int(rng.integers(len(base)))]
-                words = [w]
-                for _ in range(int(rng.integers(60, 120))):
-                    w = nxt[w][int(rng.integers(4))]
-                    words.append(w)
-                yield " ".join(words)
+            for _ in range(num_docs // num_partitions):
+                w = int(rng.integers(len(base)))
+                walk = [w]
+                # a document's transitions in one draw: the stream of the
+                # scalar draws (tests/test_text_stream.py pins the documents)
+                for step in rng.integers(
+                        4, size=int(rng.integers(60, 120))).tolist():
+                    w = nxt[w][step]
+                    walk.append(w)
+                yield " ".join(map(base.__getitem__, walk))
 
         return gen
 

@@ -22,7 +22,7 @@ class TestTokenizer:
     def test_roundtrip_known_words(self):
         tok = build_tokenizer()
         ids = tok.encode("the history of the city")
-        assert ids and all(i not in (tok.unk_id,) for i in ids)
+        assert len(ids) and all(i not in (tok.unk_id,) for i in ids)
         assert tok.decode(ids) == "the history of the city"
 
     def test_char_fallback_no_unk(self):
