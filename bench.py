@@ -358,8 +358,7 @@ def _bert_packing_economics(raw_tok_per_sec: float) -> dict:
 
 
 def _llama_09b_cfg(*, seq: int = 2048, fused_head: bool = False,
-                   moe_experts: int = 0, moe_group: int = 0,
-                   base_quant: str | None = None):
+                   moe_experts: int = 0, base_quant: str | None = None):
     """THE 0.9b bench config — one definition shared by bench_llama and
     bench_memval, so the memory validation can never drift from the shape
     the series actually runs (a review caught exactly that: memval carrying
@@ -375,13 +374,11 @@ def _llama_09b_cfg(*, seq: int = 2048, fused_head: bool = False,
         # param bytes read per step AND resident. Series condition
         # change vs r2's f32-storage numbers; recorded in the record.
         param_dtype="bfloat16",
-        # MoE cost experiment (VERDICT r3 weak-#4/next-#5): E experts,
-        # GShard dense dispatch — relative step time vs E=0 (dense)
-        # prices the [B,S,E,C] dispatch/combine tensors; the
-        # moe_dropped_frac metric rides the step output
+        # MoE cost experiment: E routed experts (models/moe.py, sorted
+        # grouped products, nothing dropped) — relative step time vs E=0
+        # (dense) prices the routing, the sort and the gathers
         moe_experts=moe_experts,
         moe_top_k=min(2, moe_experts) if moe_experts else 2,
-        moe_group_size=moe_group,
         base_quant=base_quant,
         # keep matmul outputs across the remat boundary: measured 429→391
         # ms (19.1k→21.0k tok/s) on this shape at b=4; b≥6 OOMs 16G HBM
@@ -400,7 +397,7 @@ def _llama_09b_cfg(*, seq: int = 2048, fused_head: bool = False,
 def bench_llama(iters: int, batch_size: int | None = None, seq: int = 2048,
                 fused_head: bool = False, variant: str = "0.9b",
                 segment_ids: bool = False, moe_experts: int = 0,
-                moe_group: int = 0, base_quant: str | None = None) -> dict:
+                base_quant: str | None = None) -> dict:
     """Llama LoRA fine-tune tokens/sec/chip (BASELINE.json config 5 shape).
 
     ``variant="0.9b"`` (default): single-chip-sized geometry (~0.9B params,
@@ -458,14 +455,12 @@ def bench_llama(iters: int, batch_size: int | None = None, seq: int = 2048,
             lora_rank=8, dtype="float32", remat=False,
             moe_experts=moe_experts,
             moe_top_k=min(2, moe_experts) if moe_experts else 2,
-            moe_group_size=moe_group,
             base_quant=base_quant,
             fused_head_loss=fused_head)
     else:
         batch_size = 4 if batch_size is None else batch_size
         cfg = _llama_09b_cfg(seq=seq, fused_head=fused_head,
-                             moe_experts=moe_experts, moe_group=moe_group,
-                             base_quant=base_quant)
+                             moe_experts=moe_experts, base_quant=base_quant)
     # the config builders may force fused CE on (7b always; 0.9b at s≥16384)
     # — the loss choice below must follow the config, not the CLI flag
     fused_head = cfg.fused_head_loss
@@ -562,10 +557,7 @@ def bench_llama(iters: int, batch_size: int | None = None, seq: int = 2048,
         moe_fields = {
             "moe_experts": moe_experts,
             "moe_top_k": cfg.moe_top_k,
-            "moe_group_size": cfg.moe_group_size,
-            "moe_capacity_factor": cfg.moe_capacity_factor,
             "moe_aux": round(float(m["moe_aux"]), 5),
-            "moe_dropped_frac": round(float(m["moe_dropped_frac"]), 5),
         }
     peak = device_peak_flops()
     # Add the flash kernel's invisible attention matmul FLOPs (16 layers,
@@ -1638,15 +1630,9 @@ def build_parser() -> argparse.ArgumentParser:
                          "(segment ids streamed into the flash kernel) — "
                          "prices cross-document isolation vs plain packing")
     ap.add_argument("--moe-experts", type=int, default=0,
-                    help="llama only: swap the FFN for a GShard top-2 MoE "
-                         "with E experts (0 = dense) — relative step-time "
-                         "prices the dense-dispatch cost (r3 weak-#4)")
-    ap.add_argument("--moe-group", type=int, default=0,
-                    help="llama+--moe-experts: routing-group size (0 = per-"
-                         "sequence). Dispatch cost per token is linear in "
-                         "the group, so g<S prices the GShard grouping "
-                         "lever; must divide B*S. Rejected without "
-                         "--moe-experts (would silently bench dense)")
+                    help="llama only: swap the FFN for top-2 routed "
+                         "experts, E of them (0 = dense) — relative "
+                         "step-time prices routing, sort and gathers")
     ap.add_argument("--base-quant", default=None, choices=["int8"],
                     help="llama only: QLoRA-style int8 frozen-base storage "
                          "(per-out-channel absmax scales; base HBM bytes "
@@ -1668,25 +1654,20 @@ def main(argv=None) -> int:
     parser = build_parser()
     args = parser.parse_args(argv)
     if args.base_quant and args.model not in ("llama", "all"):
-        # mirror --moe-group: a silently ignored flag would let a bf16 run
-        # masquerade as the int8 number
+        # a silently ignored flag would let a bf16 run masquerade as the
+        # int8 number
         parser.error("--base-quant only applies to the llama bench")
     if args.decode and args.model != "llama":
         parser.error("--decode only applies to the llama bench")
     if args.decode and (args.seq or args.variant != "0.9b"
                         or args.fused_head_loss or args.segment_ids
-                        or args.moe_experts or args.moe_group):
-        # no silently-ignored flags (the --base-quant/--moe-group guard
-        # pattern): the decode bench pins the 0.9b dense geometry at
+                        or args.moe_experts):
+        # no silently-ignored flags (the --base-quant guard pattern): the
+        # decode bench pins the 0.9b dense geometry at
         # prompt=128/new=128 — a requested shape that was dropped would
         # masquerade as a measured series number
         parser.error("--decode supports only --batch/--iters/--base-quant; "
                      "it pins the 0.9b dense prompt=128/new=128 shape")
-    if args.moe_group and not args.moe_experts:
-        # mirror the config-5 driver's guard: with moe_experts=0 no MoE
-        # layer is built, so the flag would silently bench plain dense
-        parser.error("--moe-group only applies to the MoE router; add "
-                     "--moe-experts or drop it")
 
     extra: dict = {"errors": []}
     host_only = args.model == "input"
@@ -1744,7 +1725,6 @@ def main(argv=None) -> int:
             fused_head=args.fused_head_loss,
             segment_ids=args.segment_ids,
             moe_experts=args.moe_experts,
-            moe_group=args.moe_group,
             base_quant=args.base_quant,
             variant=args.variant,
             **({"batch_size": args.batch} if args.batch else {}),

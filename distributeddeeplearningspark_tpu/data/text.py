@@ -553,6 +553,74 @@ def lm_dataset(
     return token_ds.map_partitions_with_index(per_partition)
 
 
+def packed_token_windows(
+    token_docs: PartitionedDataset,
+    *,
+    seq_len: int,
+    eos_id: int | None = None,
+    num_partitions: int = 1,
+    key: str = "tokens",
+) -> PartitionedDataset:
+    """PRE-tokenized documents -> full causal-LM windows, no tokenizer: what
+    LM pre-training reads (token arrays in record shards, ``array_records``).
+
+    ``token_docs`` yields int arrays, or dicts holding one under ``key``; its
+    partitions chained in order are ONE document stream. Every document is
+    followed by ``eos_id`` (if given), the stream is packed back to back by
+    the one packer (:func:`_pack_token_windows`) and cut into windows of
+    exactly ``seq_len``: ``{"input_ids": [seq_len] int32}``, no padding, no
+    loss mask, no segment ids; the stream's tail that does not fill a window
+    is left out. The windows are a function of the document stream alone:
+    output partition ``p`` of ``num_partitions`` holds the contiguous run
+    ``[W*p/P, W*(p+1)/P)`` of the ``W`` windows, so the partitions chained in
+    order are byte for byte the same for any ``num_partitions``. The price
+    is one pass over the documents' lengths the first time a partition is
+    read, and that a partition walks past the documents before its own.
+    """
+    if seq_len < 2 or num_partitions < 1:
+        raise ValueError(f"seq_len {seq_len}, num_partitions {num_partitions}")
+    extra = 0 if eos_id is None else 1
+    eos = np.full(extra, eos_id or 0, np.int32)
+
+    def docs() -> Iterator[np.ndarray]:
+        for i in range(token_docs.num_partitions):
+            for ex in token_docs.iter_partition(i):
+                yield ex[key] if isinstance(ex, dict) else ex
+
+    starts: list[np.ndarray] = []  # filled once: where each document begins
+
+    def doc_starts() -> np.ndarray:
+        if not starts:
+            lens = np.fromiter((len(d) + extra for d in docs()), np.int64)
+            starts.append(np.concatenate(([0], np.cumsum(lens))))
+        return starts[0]
+
+    def partition(p: int):
+        def gen() -> Iterator[dict]:
+            begin = doc_starts()
+            windows = int(begin[-1]) // seq_len
+            w0, w1 = windows * p // num_partitions, \
+                windows * (p + 1) // num_partitions
+            if w1 == w0:
+                return
+            first = int(np.searchsorted(begin, w0 * seq_len, "right")) - 1
+            skip = w0 * seq_len - int(begin[first])
+
+            def tail_docs() -> Iterator[np.ndarray]:
+                for n, d in enumerate(itertools.islice(docs(), first, None)):
+                    d = np.concatenate((np.asarray(d, np.int32), eos))
+                    yield d[skip:] if n == 0 else d
+
+            packed = _pack_token_windows(tail_docs(), seq_len, segments=False)
+            for chunk, _, partial in itertools.islice(packed, w1 - w0):
+                assert not partial
+                yield {"input_ids": chunk.copy()}
+
+        return gen
+
+    return PartitionedDataset([partition(p) for p in range(num_partitions)])
+
+
 def synthetic_wikipedia(
     num_docs: int = 512, *, num_partitions: int = 4, seed: int = 0
 ) -> PartitionedDataset:

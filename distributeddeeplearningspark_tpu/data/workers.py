@@ -213,25 +213,44 @@ def _release(free_q, alloc_id, counter, nbytes) -> None:
 
 class _ReleaseToken:
     """One per shm-transported example; frees its ring allocation (and the
-    parent-side outstanding-bytes counter) when the last view dies — or
-    explicitly, in copy mode. Finalizers run in the parent, so the counter
-    is an accurate live view of how many ring bytes the consumer holds."""
+    parent-side outstanding-bytes counter) when the last of its ``holders``
+    dies, or explicitly, in copy mode. Finalizers run in the parent, so the
+    counter is an accurate live view of how many ring bytes the consumer
+    holds.
 
-    __slots__ = ("_fin", "__weakref__")
+    THE CONTRACT OF A POOLED ARRAY (stated here and nowhere else): an
+    example's arrays are views of the worker's ring, and the slot stays
+    allocated for as long as ANY array derived from them is alive: slices,
+    reshapes, ``np.asarray(x, same_dtype)``, views as another type. That
+    holds because the holder is the ``np.frombuffer`` array that owns the
+    mapping, which numpy keeps as the ``base`` of everything derived from it.
+    (Until PR 26 the token hung on an ndarray-subclass instance;
+    ``np.asarray`` strips a subclass and rebases the result on the buffer
+    array UNDER it, so a packer that wrote ``toks = np.asarray(toks)`` let
+    the slot go while it still held the view, and the worker wrote the next
+    document over it.) A consumer need not copy to be safe; it copies only
+    to let the slot go sooner.
+    """
+
+    __slots__ = ("_fin", "_left", "__weakref__")
 
     def __init__(self, free_q, alloc_id: int, counter: list, nbytes: int):
+        self._left = 0
         self._fin = weakref.finalize(
             self, _release, free_q, alloc_id, counter, nbytes)
 
+    def hold(self, owner: np.ndarray) -> None:
+        """Keep the slot until ``owner`` (and so every view of it) is gone."""
+        self._left += 1
+        weakref.finalize(owner, self._drop)
+
+    def _drop(self) -> None:
+        self._left -= 1
+        if not self._left:
+            self._fin()
+
     def release(self) -> None:
         self._fin()
-
-
-class _ShmArray(np.ndarray):
-    """ndarray view into a pool ring; carries the release token so the slot
-    frees itself when the (last) view is garbage-collected."""
-
-    _dls_token: Any = None
 
 
 class _Arena:
@@ -626,14 +645,12 @@ class WorkerPool:
         for key, dstr, shape, off in metas:
             dt = np.dtype(dstr)
             count = int(np.prod(shape, dtype=np.int64)) if shape else 1
-            view = np.frombuffer(buf, dtype=dt, count=count,
-                                 offset=off).reshape(shape)
+            owner = np.frombuffer(buf, dtype=dt, count=count, offset=off)
             if copy:
-                ex[key] = view.copy()
+                ex[key] = owner.reshape(shape).copy()
             else:
-                arr = view.view(_ShmArray)
-                arr._dls_token = token
-                ex[key] = arr
+                token.hold(owner)  # the contract: see _ReleaseToken
+                ex[key] = owner.reshape(shape)
         if copy:
             token.release()
         if len(ex) == 1 and _VALUE_KEY in ex:

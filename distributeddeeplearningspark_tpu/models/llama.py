@@ -84,20 +84,15 @@ class LlamaConfig:
     # ``losses.causal_lm_fused``. Ignored in decode mode (generation needs
     # real logits).
     fused_head_loss: bool = False
-    # Mixture-of-Experts FFN (models/moe.py; 0 = dense SwiGLU). When >0
-    # every layer's MLP becomes a top-k-routed expert bank whose stacked
-    # kernels shard over the `expert` mesh axis; the model returns
-    # {"logits", "moe_aux"} in training so the load-balance loss reaches
-    # the optimizer (losses.causal_lm/_fused add it).
+    # Mixture-of-Experts FFN (models/moe.py RoutedExperts; 0 = dense
+    # SwiGLU). When >0 every layer's MLP becomes a top-k-routed expert bank
+    # (no capacity, nothing dropped) whose stacked kernels shard over the
+    # `expert` mesh axis; the model returns {"logits", "moe_aux"} in
+    # training so the load-balance loss reaches the optimizer
+    # (losses.causal_lm/_fused add it).
     moe_experts: int = 0
     moe_top_k: int = 2
-    moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
-    # Routing-group size (0 = per-sequence groups). Dispatch/combine cost
-    # per token is linear in the group size, so shrinking it below S cuts
-    # the GShard dense-dispatch overhead (the r4 1.33×-dense floor) at the
-    # price of per-group capacity enforcement; must divide B·S.
-    moe_group_size: int = 0
     # QLoRA-style int8 base storage ("int8" | None). One step below bf16:
     # every frozen projection/FFN base kernel is stored int8 with a per-
     # output-channel f32 scale (absmax), dequantized INTO the matmul (the
@@ -397,8 +392,7 @@ class LlamaMLP(nn.Module):
 
 class DecoderLayer(nn.Module):
     """Pre-norm block; returns (x, aux) — the (carry, out) pair nn.scan
-    wants; ``aux`` is the layer's ``(moe_lb_loss, moe_dropped_frac)`` pair
-    (both 0 when dense)."""
+    wants; ``aux`` is the layer's router balance loss (0 when dense)."""
 
     cfg: LlamaConfig
 
@@ -411,18 +405,18 @@ class DecoderLayer(nn.Module):
                                                       segment_ids)
         h = RMSNorm(cfg.rms_eps, cfg.dtype, name="mlp_norm")(x)
         if cfg.moe_experts:
-            from distributeddeeplearningspark_tpu.models.moe import MoEMLP
+            from distributeddeeplearningspark_tpu.models.moe import (
+                RoutedExperts,
+            )
 
-            y, aux = MoEMLP(
+            y, stats = RoutedExperts(
                 cfg.hidden_size, cfg.intermediate_size, cfg.moe_experts,
-                top_k=cfg.moe_top_k,
-                capacity_factor=cfg.moe_capacity_factor,
-                group_size=cfg.moe_group_size,
-                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                cfg.moe_top_k, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                 name="moe")(h)
+            aux = stats["aux"]
         else:
             y = LlamaMLP(cfg, name="mlp")(h)
-            aux = (jnp.float32(0.0), jnp.float32(0.0))
+            aux = jnp.float32(0.0)
         return x + y, aux
 
 
@@ -515,20 +509,16 @@ class LlamaForCausalLM(nn.Module):
                 in_axes=nn.broadcast,           # mask is shared, not scanned
                 length=cfg.num_layers,
             )(cfg, name="layers")
-            x, (aux, dropped) = stacked(x, mask, segment_ids)
+            x, aux = stacked(x, mask, segment_ids)
             moe_aux = jnp.sum(aux) if cfg.moe_experts else None
-            moe_dropped = jnp.mean(dropped) if cfg.moe_experts else None
         else:
-            auxes, droppeds = [], []
+            auxes = []
             for i in range(cfg.num_layers):
-                x, (aux, drp) = layer_cls(cfg, name=f"layers_{i}")(
+                x, aux = layer_cls(cfg, name=f"layers_{i}")(
                     x, mask, segment_ids)
                 auxes.append(aux)
-                droppeds.append(drp)
             moe_aux = (jnp.sum(jnp.stack(auxes))
                        if cfg.moe_experts else None)
-            moe_dropped = (jnp.mean(jnp.stack(droppeds))
-                           if cfg.moe_experts else None)
 
         x = RMSNorm(cfg.rms_eps, cfg.dtype, name="final_norm")(x)
         head = _LMHead(cfg.vocab_size, cfg.dtype, cfg.param_dtype,
@@ -539,15 +529,13 @@ class LlamaForCausalLM(nn.Module):
             out = {"hidden": x, "lm_head": head(x, return_kernel=True)}
             if moe_aux is not None and train:
                 out["moe_aux"] = cfg.moe_aux_weight * moe_aux
-                out["moe_dropped_frac"] = moe_dropped
             return out
         logits = head(x).astype(jnp.float32)
         if moe_aux is not None and train and not cfg.decode:
             # train only: predict/eval consumers (Trainer.predict row
             # indexing, argmax output_fns) expect a bare logits array
             return {"logits": logits,
-                    "moe_aux": cfg.moe_aux_weight * moe_aux,
-                    "moe_dropped_frac": moe_dropped}
+                    "moe_aux": cfg.moe_aux_weight * moe_aux}
         return logits
 
 

@@ -1,177 +1,251 @@
-"""Mixture-of-Experts FFN with expert parallelism — the EP mesh axis's
-model-parallel workload.
+"""Routed experts: SwiGLU experts behind a softmax-then-top-k router, with no
+capacity and nothing dropped, computed for the experts a rank HOLDS.
 
-SURVEY.md §2 lists EP as "not built unless reference shows it"; the
-reference stayed unreadable, so this is a beyond-contract addition giving
-the reserved ``expert`` mesh axis a real MoE consumer (the DLRM embedding
-tables were its only user). TPU-first choices:
+One layer serves every caller: a model told which contiguous range of the
+experts it holds (:mod:`.sparse_decoder`: one expert-parallel rank's share,
+run without its exchange), and a model that holds them all on a mesh whose
+``expert`` axis splits them (:mod:`.llama`): there the same function runs in
+a ``shard_map``, every rank computes its own experts' part for the tokens it
+sees (tokens are replicated over ``expert``, so no token travels), and a
+``psum`` over ``expert`` adds the parts up. The all-to-all that would let a
+rank see only ITS tokens' share is not built (ROADMAP queue 2, A.1).
 
-- **Dense one-hot dispatch** (GShard, arXiv:2006.16668): routing becomes
-  einsums against a [G, S, E, C] dispatch tensor — static shapes, MXU
-  matmuls, no gather/scatter. Under GSPMD the stacked expert parameters
-  shard over ``expert`` (dim 0 of every [E, ...] kernel) and the dispatch
-  einsum's contraction lowers to the all-to-all the reference would have
-  hand-written.
-- **Per-sequence routing groups** (G = batch) by default: capacity is
-  bounded per group, so the dispatch tensor is O(S · E · C) per sequence,
-  not O(T²). With C = capacity_factor·g·k/E the dispatch/combine einsums
-  still cost ~capacity_factor·k·g·H FLOPs *per token* — linear in the
-  group size g, which defaults to the whole sequence. ``group_size``
-  shrinks g below S (the GShard/GLaM grouping knob): r4 CPU table showed
-  even E=1 top-1 paying 1.33× dense step time at g=S=256, which is
-  exactly this term; smaller groups trade a little routing freedom
-  (capacity is enforced per group, so load imbalance *within* a group
-  drops tokens a global router would have kept) for dispatch cost.
-  The "tighter constraint" reading holds when ``cf·g·k/E ≥ 1`` — below
-  that, the ≥1 capacity floor (needed so tiny shapes route at all) gives
-  every group a full slot per expert and tiny groups can aggregate MORE
-  capacity than one per-sequence group; per-group ``int()`` truncation
-  also shifts aggregate capacity slightly vs g=S (ADVICE r4). Real
-  configs sit far above the boundary (g=256, E=8, k=2, cf=1.25 →
-  cf·g·k/E = 80), so the floor is a test-shape affordance, not a
-  production regime.
-- **Top-k routing with capacity dropping** (Switch/GShard): tokens beyond
-  an expert's capacity fall through (the residual connection carries
-  them); an auxiliary load-balance loss (Switch Transformer eq. 4 —
-  E · Σ_e f_e · p̄_e) keeps the router from collapsing onto one expert.
-- Router math in f32 regardless of activation dtype (standard for
-  stability); expert FFNs are SwiGLU, matching the dense LlamaMLP.
+The ``T * k`` assignments are sorted by expert (those of experts held
+elsewhere last), the tokens' rows are gathered in that order, and three
+grouped matrix products (``jax.lax.ragged_dot``: on the TPU a grouped-matmul
+kernel of XLA's own that walks only the tiles of rows the group sizes cover)
+run over a buffer of ``T * k`` rows, the worst case, of which
+``T * k * held / num_experts`` are used on average. However unbalanced the
+routing, every assignment to a held expert is computed. Router math is
+float32 at ``HIGHEST`` precision whatever the activations' dtype.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+from jax.sharding import PartitionSpec as P
+
+from distributeddeeplearningspark_tpu.parallel.mesh import (
+    AXIS_EXPERT,
+    AXIS_SEQ,
+    AXIS_TENSOR,
+    BATCH_AXES,
+)
+
+#: the mesh axes that split TOKENS (batch rows and sequence positions)
+TOKEN_AXES = (*BATCH_AXES, AXIS_SEQ)
 
 
-class MoEMLP(nn.Module):
-    """Drop-in for a SwiGLU FFN:
-    ``[B, S, H] → ([B, S, H], (aux_loss, dropped_frac))``."""
+def _zero_past(a, used):
+    """Rows ``used`` and beyond of ``a`` set to zero."""
+    live = jnp.arange(a.shape[0], dtype=jnp.int32)[:, None] < used
+    return jnp.where(live, a, 0)
+
+
+@jax.custom_vjp
+def _rows_to_experts(x, order, inverse, used):
+    """x [T, H] -> [T*k, H]: row a is the token of the a-th assignment in
+    expert order (``order`` holds token-major assignment ids, k a token).
+    Only the first ``used`` rows belong to an expert that is held; the
+    grouped products leave the rest of their outputs unwritten, so the
+    backward pass zeroes the cotangent there (uninitialised memory is not
+    zero, and ``nan * 0`` is not either). Backward is the gather by
+    ``inverse`` summed over a token's k assignments, not the scatter-add
+    autodiff would write."""
+    k = order.shape[0] // x.shape[0]
+    return x[order // k]
+
+
+def _rows_to_experts_fwd(x, order, inverse, used):
+    return _rows_to_experts(x, order, inverse, used), (inverse, used,
+                                                       x.shape[0])
+
+
+def _rows_to_experts_bwd(res, g):
+    inverse, used, tokens = res
+    g = _zero_past(g, used)
+    return (g[inverse].reshape(tokens, -1, g.shape[-1]).sum(axis=1)
+            .astype(g.dtype), None, None, None)
+
+
+_rows_to_experts.defvjp(_rows_to_experts_fwd, _rows_to_experts_bwd)
+
+
+@jax.custom_vjp
+def _rows_to_tokens(y, order, inverse, used):
+    """The inverse permutation: y [T*k, H] in expert order -> token-major,
+    with zeros for the rows past ``used`` (see :func:`_rows_to_experts`)."""
+    return _zero_past(y, used)[inverse]
+
+
+def _rows_to_tokens_fwd(y, order, inverse, used):
+    return _rows_to_tokens(y, order, inverse, used), order
+
+
+def _rows_to_tokens_bwd(order, g):
+    return g[order], None, None, None
+
+
+_rows_to_tokens.defvjp(_rows_to_tokens_fwd, _rows_to_tokens_bwd)
+
+
+def _held_experts(xf, router, w_gate, w_up, w_down, first, *, k: int,
+                  norm_topk: bool, dtype, per_rank=lambda a: a):
+    """The part of the layer that the ``n`` experts ``first .. first + n``
+    add (``w_*`` are their kernels; ``first`` may be traced): ``xf [T, H] ->
+    (y [T, H] float32, assignments of every expert [E] int32, the router's
+    probabilities summed over the tokens [E])``. ``per_rank`` marks the
+    tokens' rows as differing from rank to rank where the ranks' work on them
+    begins (inside a ``shard_map``; the routing before it is every rank's
+    alike)."""
+    tokens, h = xf.shape
+    e, n = router.shape[1], w_gate.shape[0]
+    # float32 in earnest: on the TPU a float32 product is one bf16 pass
+    # unless asked otherwise, and a router rounded to 8 bits picks other
+    # experts than the model's
+    logits = jnp.dot(xf.astype(jnp.float32), router,
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate, expert = jax.lax.top_k(probs, k)                         # [T, k]
+    if norm_topk:
+        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    local = expert - first
+    is_held = (local >= 0) & (local < n)
+    # sort key: the held expert's index here, experts held elsewhere last
+    key = jnp.where(is_held, local, n).reshape(-1)                 # [T*k]
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=jnp.int32))
+    counts = jnp.sum(jax.nn.one_hot(expert.reshape(-1), e, dtype=jnp.int32),
+                     axis=0)
+    rows = jax.lax.dynamic_slice_in_dim(counts, first, n)  # the groups' sizes
+    used = jnp.sum(rows)
+
+    xs = _rows_to_experts(per_rank(xf.astype(dtype)), order, inverse, used)
+    cast = lambda w: w.astype(dtype)
+    up = jax.lax.ragged_dot(xs, cast(w_up), rows)
+    act = nn.silu(jax.lax.ragged_dot(xs, cast(w_gate), rows)) * up
+    ys = jax.lax.ragged_dot(act, cast(w_down), rows)               # [T*k, H]
+    yt = _rows_to_tokens(ys, order, inverse, used)
+    y = jnp.einsum("tkh,tk->th", yt.reshape(tokens, k, h),
+                   jnp.where(is_held, gate, 0.0).astype(yt.dtype),
+                   preferred_element_type=jnp.float32)
+    return y, counts, jnp.sum(probs, axis=0)
+
+
+def _split_over_the_mesh(fn, mesh, first: int):
+    """``fn`` (:func:`_held_experts` but for ``first``) for ``x [B, S, H]``
+    on a mesh: batch rows over (data, fsdp), positions over ``seq``, the
+    experts' kernels over ``expert`` and their hidden width over ``tensor``.
+    A rank computes the part of ITS experts and columns for ITS tokens; the
+    parts add up over (expert, tensor), the statistics over the tokens'
+    axes."""
+    def local(x, router, w_gate, w_up, w_down):
+        b, s, h = x.shape
+        mine = first + jax.lax.axis_index(AXIS_EXPERT) * w_gate.shape[0]
+        # (the transpose of "differs from rank to rank" is the sum of the
+        # ranks' cotangents, which is what a token's gradient is)
+        y, counts, probs = fn(
+            x.reshape(-1, h), router, w_gate, w_up, w_down, mine,
+            per_rank=lambda a: jax.lax.pcast(
+                a, (AXIS_EXPERT, AXIS_TENSOR), to="varying"))
+        return (jax.lax.psum(y, (AXIS_EXPERT, AXIS_TENSOR)).reshape(b, s, h),
+                jax.lax.psum(counts, TOKEN_AXES),
+                jax.lax.psum(probs, TOKEN_AXES))
+
+    tokens = P(BATCH_AXES, AXIS_SEQ, None)
+    wide = P(AXIS_EXPERT, None, AXIS_TENSOR)
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(tokens, P(), wide, wide, P(AXIS_EXPERT, AXIS_TENSOR, None)),
+        out_specs=(tokens, P(), P()))
+
+
+class RoutedExperts(nn.Module):
+    """``[B, S, H] -> ([B, S, H], stats)``.
+
+    ``g = softmax(x Wr)`` over all ``num_experts`` in float32; a token's
+    ``top_k`` largest, renormalised to sum 1 (``norm_topk``); ``y = sum over
+    the token's experts that are held of g_e * down_e(silu(gate_e x) *
+    up_e x)``. ``held = (first, count)`` is a contiguous range of experts: an
+    expert-parallel rank's share. The router keeps its full width and its k a
+    token whatever is held; what the absent experts would have added is left
+    out (their ranks add it, and the shares of all ranks sum to the whole
+    layer: ``tests/test_sparse_decoder.py``). ``held=None`` holds them all.
+    On a mesh of more than one device what is held is split once more over
+    the mesh's ``expert`` axis (module docstring).
+
+    ``stats``: ``aux`` (Switch's balance loss over ALL experts from the top-k
+    assignments, ``E * sum_e f_e * P_e`` with ``f_e`` the share of the
+    ``T * k`` assignments and ``P_e`` the mean probability: 1 when uniform),
+    ``load_max_over_mean`` (rows of the fullest held expert over the mean of
+    the held) and ``rows_held_share`` (assignments that land on held experts
+    over all).
+    """
 
     hidden_size: int
     intermediate_size: int
     num_experts: int
-    top_k: int = 2
-    capacity_factor: float = 1.25
-    group_size: int = 0  # 0 = one group per sequence (g = S)
+    top_k: int
+    held: tuple[int, int] | None = None
+    norm_topk: bool = True
     dtype: Any = jnp.bfloat16
-    # STORAGE dtype of the expert kernels. f32 default (experts normally
-    # TRAIN and want f32 masters); bf16 halves resident expert bytes when
-    # the bank is frozen or bf16-trained — at the 0.9b bench shape E=8
-    # f32 kernels alone are 17.7 GiB (> one chip), bf16 8.9 (fits).
     param_dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> tuple[jax.Array, jax.Array]:
-        h, i, e = self.hidden_size, self.intermediate_size, self.num_experts
-        if not 1 <= self.top_k <= e:
-            raise ValueError(f"top_k {self.top_k} must be in [1, {e}]")
-        bb, ss, _ = x.shape
-        if self.group_size:
-            # Regroup [B, S] tokens into [B·S/g, g]: dim 0 stays B-major so
-            # a data/fsdp-sharded batch dim regroups without resharding (as
-            # long as g divides the per-shard token count — a group that
-            # spans shard boundaries forces an all-gather).
-            if (bb * ss) % self.group_size:
+    def __call__(self, x: jax.Array) -> tuple[jax.Array, dict]:
+        from distributeddeeplearningspark_tpu.ops.ring_attention import (
+            resolve_mesh,
+        )
+
+        h, i, e, k = (self.hidden_size, self.intermediate_size,
+                      self.num_experts, self.top_k)
+        first, n = self.held or (0, e)
+        if not (1 <= k <= e and 0 <= first and first + n <= e and n >= 1):
+            raise ValueError(f"top_k {k}, held {self.held} of {e} experts")
+        router = self.param("router", nn.initializers.lecun_normal(), (h, e),
+                            jnp.float32)
+        # lecun-normal by each expert's OWN fan-in: the leading axis counts
+        # experts and is no part of any product (taken for a receptive
+        # field, as the default would, it shrinks every kernel by
+        # sqrt(held) and the layer's output by its third power)
+        init = nn.initializers.variance_scaling(
+            1.0, "fan_in", "truncated_normal", batch_axis=(0,))
+        w_gate = self.param("w_gate", init, (n, h, i), self.param_dtype)
+        w_up = self.param("w_up", init, (n, h, i), self.param_dtype)
+        w_down = self.param("w_down", init, (n, i, h), self.param_dtype)
+
+        fn = functools.partial(_held_experts, k=k, norm_topk=self.norm_topk,
+                               dtype=self.dtype)
+        kernels = (router, w_gate, w_up, w_down)
+        mesh = resolve_mesh()
+        if mesh is None or mesh.size == 1:
+            y, counts, probs = fn(x.reshape(-1, h), *kernels, first)
+        else:
+            shape = dict(mesh.shape)
+            rows = shape[BATCH_AXES[0]] * shape[BATCH_AXES[1]]
+            if (x.ndim != 3 or x.shape[0] % rows or x.shape[1] % shape[AXIS_SEQ]
+                    or n % shape[AXIS_EXPERT] or i % shape[AXIS_TENSOR]):
                 raise ValueError(
-                    f"group_size {self.group_size} must divide B*S "
-                    f"({bb}*{ss}); pick a divisor of the per-step token "
-                    "count or 0 for per-sequence groups")
-            x = x.reshape(bb * ss // self.group_size, self.group_size, h)
-        b, s, _ = x.shape
-        # per-group (= per-sequence) expert capacity, ≥1 so tiny test
-        # shapes still route. The floor means the module-docstring
-        # "small groups only drop more" trade only holds for
-        # cf·g·k/E ≥ 1 (see header); an exact ceil-split of the
-        # sequence-level cap would restore universality but change
-        # routing vs the measured r4 group-size A/B series, so the
-        # claim is qualified instead.
-        cap = max(1, int(self.capacity_factor * s * self.top_k / e))
+                    f"routed experts on mesh {shape}: x {x.shape} must be "
+                    f"[B, S, H] with B dividing by data x fsdp and S by seq, "
+                    f"the {n} experts held by expert, their width {i} by "
+                    f"tensor")
+            y, counts, probs = _split_over_the_mesh(fn, mesh, first)(
+                x, *kernels)
 
-        router = self.param("router", nn.initializers.lecun_normal(),
-                            (h, e), jnp.float32)  # router math stays f32
-        w_gate = self.param("w_gate", nn.initializers.lecun_normal(),
-                            (e, h, i), self.param_dtype)
-        w_up = self.param("w_up", nn.initializers.lecun_normal(),
-                          (e, h, i), self.param_dtype)
-        w_down = self.param("w_down", nn.initializers.lecun_normal(),
-                            (e, i, h), self.param_dtype)
-
-        logits = jnp.einsum("bsh,he->bse", x.astype(jnp.float32), router)
-        probs = jax.nn.softmax(logits, axis=-1)               # [B, S, E] f32
-
-        # Iterative top-k assignment with per-expert cumulative positions
-        # (the GShard scheme): slot k masks out previously chosen experts,
-        # takes the argmax, and claims the next capacity positions.
-        remaining = probs
-        claimed = jnp.zeros((b, e), jnp.int32)                # tokens so far
-        dispatch = jnp.zeros((b, s, e, cap), self.dtype)
-        combine = jnp.zeros((b, s, e, cap), jnp.float32)
-        gate_sum = jnp.zeros((b, s), jnp.float32)
-        dropped = jnp.float32(0.0)  # routed-but-over-capacity assignments
-        first_mask = None
-        for _ in range(self.top_k):
-            idx = jnp.argmax(remaining, axis=-1)              # [B, S]
-            onehot = jax.nn.one_hot(idx, e, dtype=jnp.int32)  # [B, S, E]
-            if first_mask is None:
-                first_mask = onehot
-            # position of each token within its chosen expert's capacity
-            pos = (jnp.cumsum(onehot, axis=1) - 1) + claimed[:, None, :]
-            keep = (onehot > 0) & (pos < cap)                 # [B, S, E]
-            pos_oh = jax.nn.one_hot(pos, cap, dtype=jnp.float32)  # [B,S,E,C]
-            slot = jnp.where(keep[..., None], pos_oh, 0.0)
-            dropped = dropped + jnp.sum(
-                ((onehot > 0) & ~keep).astype(jnp.float32))
-            gate = jnp.sum(probs * onehot, axis=-1)           # [B, S]
-            # = gate * keep.any(-1), bit for bit (keep ⊆ onehot, one 1 a
-            # token) — but spelled as a float sum: a BOOL reduction over
-            # the expert axis is miscomputed by XLA:TPU when that axis is
-            # sharded (jax 0.9.0 / libtpu 0.0.34, four v5e chips, PR 21:
-            # each device OR-ed only its own experts' columns, kept_gate
-            # was off by up to 0.98 and the layer's output by its own
-            # magnitude; repro: __graft_entry__._dryrun_llama_moe(4))
-            kept_gate = jnp.sum(probs * keep, axis=-1)
-            dispatch = dispatch + slot.astype(self.dtype)
-            combine = combine + slot * kept_gate[:, :, None, None]
-            gate_sum = gate_sum + kept_gate
-            # NOTE (ADVICE r3): `claimed` counts every routed token,
-            # INCLUDING ones just dropped for exceeding capacity — so later
-            # top-k slots compute positions past those holes and effective
-            # capacity is slightly understated at tight capacity_factor.
-            # This is deliberate GShard parity (their cumsum also runs over
-            # the pre-drop assignment); reclaiming dropped slots would
-            # change routing vs the paper. The dropped-token fraction is
-            # measured honestly instead (`moe_dropped_frac` in the metrics).
-            claimed = claimed + jnp.sum(onehot, axis=1)
-            remaining = remaining * (1 - onehot)
-        # normalize kept gates so the output is a convex combination
-        combine = combine / jnp.maximum(gate_sum, 1e-9)[:, :, None, None]
-
-        xe = jnp.einsum("bsec,bsh->bech", dispatch, x.astype(self.dtype))
-        g1 = jnp.einsum("bech,ehi->beci", xe, w_gate.astype(self.dtype))
-        g2 = jnp.einsum("bech,ehi->beci", xe, w_up.astype(self.dtype))
-        ye = jnp.einsum("beci,eih->bech", nn.silu(g1) * g2,
-                        w_down.astype(self.dtype))
-        y = jnp.einsum("bsec,bech->bsh", combine.astype(self.dtype), ye)
-
-        # Switch load-balance loss: E · Σ_e (fraction routed to e, top-1) ·
-        # (mean router prob of e) — minimized at uniform routing (= 1.0)
-        frac = jnp.mean(first_mask.astype(jnp.float32), axis=(0, 1))  # [E]
-        mean_p = jnp.mean(probs, axis=(0, 1))                         # [E]
-        aux = e * jnp.sum(frac * mean_p)
-        # dropped-token fraction of all B·S·top_k routing assignments —
-        # the capacity-tuning honesty metric (VERDICT r3 weak-#4): reported
-        # next to moe_aux so a tight capacity_factor can't silently starve
-        # tokens of their experts
-        dropped_frac = dropped / jnp.float32(b * s * self.top_k)
-        if self.group_size:
-            y = y.reshape(bb, ss, h)
-        return y.astype(x.dtype), (aux, dropped_frac)
-
-
-# Sharding rules for the MoE params live in models/llama.py:llama_rules
-# (one source for the whole tree): stacked expert kernels shard dim-0 over
-# ``expert`` (+ the FFN dims over ``tensor``); the router replicates.
+        assignments = jnp.float32(x.size // h * k)
+        rows_f = counts[first:first + n].astype(jnp.float32)
+        stats = {
+            "aux": e * jnp.sum(counts.astype(jnp.float32) / assignments
+                               * probs * (k / assignments)),
+            "load_max_over_mean": jnp.max(rows_f)
+            / jnp.maximum(jnp.mean(rows_f), 1.0),
+            "rows_held_share": jnp.sum(rows_f) / assignments,
+        }
+        return y.reshape(x.shape).astype(x.dtype), stats

@@ -17,6 +17,10 @@ Models call :func:`dot_product_attention`; the implementation is chosen by
 - ``"auto"`` — flash on TPU when the shape qualifies (seq multiple of the
   block size, head_dim lane-friendly, mask expressible key-only), else xla.
 
+:func:`indexed_attention` is the second call site: causal attention over a
+learned per-query selection of keys (:mod:`.indexed_attention`), with the
+same choice of a kernel path and an XLA path and the same ``shard_map``.
+
 All implementations take/return ``[batch, seq, heads, head_dim]`` (BSHD
 layout — batch and sequence leading so (data, fsdp) batch sharding and
 ``seq``-axis context parallelism shard the first two dims without transposes).
@@ -26,6 +30,8 @@ only the xla fallback broadcasts KV up (an O(group) HBM copy).
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -125,6 +131,50 @@ def _flash_on_mesh(q, k, v, *, bias, mask, causal, scale, segment_ids):
         local, mesh=mesh,
         in_specs=(qkv, qkv, qkv, *([P(BATCH_AXES, None)] * len(extras))),
         out_specs=qkv, check_vma=False)(q, k, v, *extras)
+
+
+def indexed_attention(q, k, v, index_q, index_k, index_w, *, topk: int,
+                      scale: float | None = None, impl: str = "auto"
+                      ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Causal softmax attention of every query over the ``topk`` keys its
+    indexer scores highest, and the indexer's loss: ``(o [B, S, H, D],
+    kl [B, S], selected [B])``; see :mod:`.indexed_attention` for the
+    mathematics and the shapes.
+
+    ``impl``: ``"pallas"`` (the kernels), ``"xla"`` (dense ``jax.numpy``),
+    or ``"auto"``: the kernels on a TPU when the sequence divides by their
+    block, else XLA. On a mesh of more than one device the kernels run in a
+    ``shard_map`` over the batch rows, like the flash kernel and for the same
+    reason; every head stays on every device (the indexer's selection is one
+    per query, shared by all heads, so splitting heads would repeat it).
+    """
+    from distributeddeeplearningspark_tpu.ops import indexed_attention as ia
+    from distributeddeeplearningspark_tpu.ops.ring_attention import resolve_mesh
+
+    s, d = q.shape[1], q.shape[3]
+    if impl == "auto":
+        block = min(ia.DEFAULT_BLOCK, s)
+        fits = not (s % block or block % 128 or d % 8 or index_q.shape[3] % 8)
+        impl = "pallas" if on_tpu() and fits else "xla"
+    if impl == "xla":
+        return ia.indexed_attention_xla(q, k, v, index_q, index_k, index_w,
+                                        topk=topk, scale=scale)
+    if impl != "pallas":
+        raise ValueError(f"unknown indexed-attention impl {impl!r}")
+    fn = functools.partial(ia.indexed_attention, topk=topk, scale=scale)
+    mesh = resolve_mesh()
+    if mesh is None or mesh.size == 1:
+        return fn(q, k, v, index_q, index_k, index_w)
+    rows = mesh.shape[BATCH_AXES[0]] * mesh.shape[BATCH_AXES[1]]
+    if q.shape[0] % rows:
+        raise ValueError(f"indexed attention on mesh {dict(mesh.shape)}: "
+                         f"batch {q.shape[0]} must divide by data x fsdp")
+    by_row = lambda x: P(BATCH_AXES, *([None] * (x.ndim - 1)))
+    args = (q, k, v, index_q, index_k, index_w)
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=tuple(by_row(a) for a in args),
+        out_specs=(P(BATCH_AXES, None, None, None), P(BATCH_AXES, None),
+                   P(BATCH_AXES)), check_vma=False)(*args)
 
 
 def _shards_evenly(q, k, mesh) -> bool:

@@ -138,16 +138,39 @@ def causal_lm_fused(outputs: dict[str, jax.Array], batch: dict[str, Any]
     return _add_moe_aux(loss, metrics, outputs)
 
 
+def sparse_moe_lm(outputs: dict[str, jax.Array], batch: dict[str, Any]
+                  ) -> tuple[jax.Array, dict]:
+    """The three-term loss of :class:`~..models.sparse_decoder.SparseDecoderLM`:
+    next-token cross-entropy over the vocabulary the model holds (fused with
+    the head, as :func:`causal_lm_fused`), the router's balance loss and the
+    indexer's KL, both weighted by the model. ``loss`` is their sum; ``lm_loss``
+    the first alone (``perplexity`` is of that). The model's counters
+    (``sparse_decoder.COUNTERS``) ride along into the step's metrics. The indexer's input is
+    detached inside the model, so the KL trains the indexer and nothing else,
+    and the cross-entropy nothing of the indexer."""
+    from distributeddeeplearningspark_tpu.models.sparse_decoder import COUNTERS
+    from distributeddeeplearningspark_tpu.train.fused_ce import (
+        chunked_softmax_xent,
+    )
+
+    per_tok = chunked_softmax_xent(outputs["hidden"][:, :-1],
+                                   outputs["lm_head"],
+                                   batch["input_ids"][:, 1:])
+    lm_loss, metrics = _reduce_next_token(per_tok, batch)
+    loss = lm_loss + outputs["moe_aux"] + outputs["index_kl"]
+    metrics = {**metrics, "loss": loss, "lm_loss": lm_loss,
+               "moe_aux": outputs["moe_aux"],
+               "dsa_index_kl": outputs["index_kl"],
+               **{k: outputs[k] for k in COUNTERS}}
+    return loss, metrics
+
+
 def _add_moe_aux(loss, metrics, outputs) -> tuple[jax.Array, dict]:
-    """Fold a model-reported (already-weighted) MoE load-balance loss in;
-    also surfaces the dropped-token fraction (capacity honesty, r3 weak-#4)
-    as a pure metric — it never contributes to the loss."""
+    """Fold a model-reported (already-weighted) MoE load-balance loss in."""
     if isinstance(outputs, dict) and "moe_aux" in outputs:
         aux = outputs["moe_aux"]
         loss = loss + aux
         metrics = {**metrics, "loss": loss, "moe_aux": aux}
-        if "moe_dropped_frac" in outputs:
-            metrics["moe_dropped_frac"] = outputs["moe_dropped_frac"]
     return loss, metrics
 
 

@@ -75,10 +75,6 @@ def main() -> None:
                         "channel scales): the 7B base drops ~12.6 to ~6.3 "
                         "GiB; --weights are quantized after import. "
                         "Requires --lora-rank > 0")
-    p.add_argument("--moe-group", type=int, default=0,
-                   help="routing-group size for --moe-experts (0 = per-"
-                        "sequence): dispatch cost per token is linear in "
-                        "the group size; must divide batch*seq_len")
     p.add_argument("--expert", type=int, default=1,
                    help="expert-parallel axis size (with --moe-experts)")
     p.add_argument("--corpus", default=None, help="text file (one doc per line); synthetic if unset")
@@ -107,9 +103,6 @@ def main() -> None:
         p.error("--expert > 1 without --moe-experts just replicates the "
                 "dense model over extra chips; drop --expert or add "
                 "--moe-experts")
-    elif args.moe_group:
-        p.error("--moe-group only applies to the MoE router; add "
-                "--moe-experts or drop it")
     if args.base_quant and not args.lora_rank:
         p.error("--base-quant requires --lora-rank > 0 (the quantized base "
                 "is frozen; adapters carry the training)")
@@ -167,8 +160,7 @@ def main() -> None:
                     "(the GPipe forward emits real logits)")
         cfg = dataclasses.replace(cfg, fused_head_loss=True)
     if args.moe_experts:  # incompatibilities rejected at parse time above
-        cfg = dataclasses.replace(cfg, moe_experts=args.moe_experts,
-                                  moe_group_size=args.moe_group)
+        cfg = dataclasses.replace(cfg, moe_experts=args.moe_experts)
     if args.base_quant:
         cfg = dataclasses.replace(cfg, base_quant=args.base_quant)
     model = LlamaForCausalLM(cfg)

@@ -1,10 +1,11 @@
-"""Mixture-of-Experts FFN + expert parallelism (beyond-contract EP).
+"""Routed experts (models/moe.py) and expert parallelism.
 
-The dense one-hot dispatch must be a faithful router: every kept token's
-output is a convex combination of its chosen experts' FFN outputs, capacity
-drops fall through to the residual, E=1 reduces to a plain SwiGLU, and the
-whole thing trains under a data × expert mesh with the stacked expert
-kernels genuinely sharded."""
+The layer must be a faithful router: every token's output is the convex
+combination of its chosen experts' FFN outputs, whatever the imbalance (no
+capacity, nothing dropped); E=1 reduces to a plain SwiGLU; the same layer
+split over a mesh's ``expert`` (and ``tensor``, and token) axes gives the
+one-device result, forward and backward; and Llama trains with it under a
+data × expert mesh with the stacked expert kernels genuinely sharded."""
 
 import dataclasses
 
@@ -15,7 +16,8 @@ import optax
 import pytest
 
 from distributeddeeplearningspark_tpu.models import LlamaConfig, LlamaForCausalLM
-from distributeddeeplearningspark_tpu.models.moe import MoEMLP
+from distributeddeeplearningspark_tpu.models.moe import RoutedExperts
+from distributeddeeplearningspark_tpu.ops import ring_attention
 from distributeddeeplearningspark_tpu.parallel.mesh import MeshSpec
 from distributeddeeplearningspark_tpu.train import losses, step as step_lib
 
@@ -25,27 +27,44 @@ def _x(b=2, s=8, h=16, seed=0):
     return jnp.asarray(rng.normal(0, 1, (b, s, h)).astype(np.float32))
 
 
-class TestMoEMLP:
+def _dense_experts(x, p, top_k):
+    """Every expert on every token, weighted by the renormalised top-k
+    gates: the layer's definition, in numpy."""
+    x = np.asarray(x, np.float64).reshape(-1, x.shape[-1])
+    logits = x @ np.asarray(p["router"], np.float64)
+    probs = np.exp(logits - logits.max(-1, keepdims=True))
+    probs /= probs.sum(-1, keepdims=True)
+    top = np.argsort(-probs, axis=-1, kind="stable")[:, :top_k]
+    y = np.zeros_like(x)
+    for t in range(x.shape[0]):
+        gates = probs[t, top[t]] / probs[t, top[t]].sum()
+        for g, e in zip(gates, top[t]):
+            a = x[t] @ np.asarray(p["w_gate"][e], np.float64)
+            u = x[t] @ np.asarray(p["w_up"][e], np.float64)
+            y[t] += g * ((a / (1 + np.exp(-a)) * u)
+                         @ np.asarray(p["w_down"][e], np.float64))
+    return y
+
+
+class TestRoutedExperts:
     def test_shapes_and_finite(self):
         x = _x()
-        m = MoEMLP(16, 32, num_experts=4, top_k=2, dtype=jnp.float32)
+        m = RoutedExperts(16, 32, num_experts=4, top_k=2, dtype=jnp.float32)
         v = m.init(jax.random.PRNGKey(0), x)
-        y, (aux, dropped) = m.apply(v, x)
+        y, stats = m.apply(v, x)
         assert y.shape == x.shape and y.dtype == x.dtype
         assert np.isfinite(np.asarray(y)).all()
-        assert np.isfinite(float(aux)) and float(aux) > 0
-        assert 0.0 <= float(dropped) <= 1.0
+        assert np.isfinite(float(stats["aux"])) and float(stats["aux"]) > 0
+        assert float(stats["rows_held_share"]) == 1.0   # it holds them all
+        assert float(stats["load_max_over_mean"]) >= 1.0
 
     def test_single_expert_matches_dense_swiglu(self):
-        """E=1, top_k=1, ample capacity: routing is the identity, so the
-        MoE output must equal the plain SwiGLU with the same kernels."""
+        """E=1, top_k=1: routing is the identity, so the output must equal
+        the plain SwiGLU with the same kernels."""
         x = _x(seed=1)
-        m = MoEMLP(16, 32, num_experts=1, top_k=1, capacity_factor=2.0,
-                   dtype=jnp.float32)
+        m = RoutedExperts(16, 32, num_experts=1, top_k=1, dtype=jnp.float32)
         v = m.init(jax.random.PRNGKey(1), x)
-        y, (aux, dropped) = m.apply(v, x)
-        # ample capacity, one expert: nothing can drop
-        assert float(dropped) == 0.0
+        y, stats = m.apply(v, x)
         p = v["params"]
         g = np.asarray(x) @ np.asarray(p["w_gate"][0])
         u = np.asarray(x) @ np.asarray(p["w_up"][0])
@@ -53,105 +72,88 @@ class TestMoEMLP:
         want = (silu * u) @ np.asarray(p["w_down"][0])
         np.testing.assert_allclose(np.asarray(y), want, atol=1e-4, rtol=1e-4)
         # single expert: perfectly "balanced" → aux = E · 1 · 1 = 1
-        assert abs(float(aux) - 1.0) < 1e-5
+        assert abs(float(stats["aux"]) - 1.0) < 1e-5
 
-    def test_capacity_drop_falls_through(self):
-        """capacity_factor → tiny: most tokens are dropped; dropped tokens
-        must output ZERO (the residual carries them), never garbage."""
-        x = _x(b=1, s=16, seed=2)
-        m = MoEMLP(16, 32, num_experts=2, top_k=1, capacity_factor=0.07,
-                   dtype=jnp.float32)  # cap = max(1, int(.07*16/2)) = 1
+    @pytest.mark.parametrize("top_k", [1, 2, 4])
+    def test_output_is_the_convex_combination_of_the_chosen_experts(
+            self, top_k):
+        x = _x(b=2, s=16, seed=2)
+        m = RoutedExperts(16, 32, num_experts=4, top_k=top_k,
+                          dtype=jnp.float32)
         v = m.init(jax.random.PRNGKey(2), x)
-        y, (_, dropped) = m.apply(v, x)
-        y = np.asarray(y)[0]
-        zero_rows = (np.abs(y).max(axis=-1) < 1e-7).sum()
-        assert zero_rows >= 16 - 2 * 1  # at most cap tokens per expert kept
-        # the honesty metric must agree with what actually fell through:
-        # ≥ 14 of 16 top-1 assignments dropped (r3 weak-#4)
-        assert float(dropped) >= (16 - 2) / 16
+        y, _ = m.apply(v, x)
+        np.testing.assert_allclose(
+            np.asarray(y).reshape(-1, 16),
+            _dense_experts(x, v["params"], top_k), atol=1e-5)
+
+    def test_nothing_is_dropped_at_any_imbalance(self):
+        """A router that sends EVERY token to expert 0: a capacity layer
+        would drop all but a few; here every token gets expert 0's FFN."""
+        x = jnp.abs(_x(b=1, s=32, seed=3))   # positive: column 0 then wins
+        m = RoutedExperts(16, 32, num_experts=4, top_k=1, dtype=jnp.float32)
+        p = dict(m.init(jax.random.PRNGKey(3), x)["params"])
+        p["router"] = jnp.zeros_like(p["router"]).at[:, 0].set(100.0)
+        y, stats = m.apply({"params": p}, x)
+        assert float(stats["load_max_over_mean"]) == pytest.approx(4.0)
+        np.testing.assert_allclose(
+            np.asarray(y).reshape(-1, 16), _dense_experts(x, p, 1), atol=1e-5)
+        assert (np.abs(np.asarray(y)).max(axis=-1) > 0).all()
 
     def test_top_k_bounds_checked(self):
         with pytest.raises(ValueError, match="top_k"):
-            MoEMLP(16, 32, num_experts=2, top_k=3).init(
+            RoutedExperts(16, 32, num_experts=2, top_k=3).init(
                 jax.random.PRNGKey(0), _x())
 
-    def test_group_size_equal_to_seq_is_identity(self):
-        """group_size = S regroups [B, S] into B groups of S — exactly the
-        default per-sequence grouping, so outputs must match bit-for-bit
-        (same einsums, same capacity, same drops)."""
-        x = _x(b=2, s=8, seed=3)
-        base = MoEMLP(16, 32, num_experts=4, top_k=2, dtype=jnp.float32)
-        grouped = MoEMLP(16, 32, num_experts=4, top_k=2, group_size=8,
-                         dtype=jnp.float32)
-        v = base.init(jax.random.PRNGKey(3), x)
-        y0, (a0, d0) = base.apply(v, x)
-        y1, (a1, d1) = grouped.apply(v, x)
-        np.testing.assert_array_equal(np.asarray(y0), np.asarray(y1))
-        assert float(a0) == float(a1) and float(d0) == float(d1)
+    def test_each_expert_is_initialised_by_its_own_fan_in(self):
+        x = _x(h=64)
+        m = RoutedExperts(64, 256, num_experts=16, top_k=2)
+        p = m.init(jax.random.PRNGKey(0), x)["params"]
+        assert float(p["w_gate"].std()) == pytest.approx(64 ** -0.5, rel=0.05)
+        assert float(p["w_down"].std()) == pytest.approx(256 ** -0.5, rel=0.05)
 
-    def test_group_size_invariant_when_capacity_ample(self):
-        """E=1 top-1 with ample capacity: every token goes to the only
-        expert with gate 1 and nothing drops, so the output equals the
-        dense SwiGLU no matter how tokens are grouped — the correctness
-        contract that lets group_size be a pure cost knob."""
-        x = _x(b=2, s=8, seed=4)
-        v = None
-        outs = []
-        for g in (0, 2, 4, 16):  # 16 = B·S: one global group
-            m = MoEMLP(16, 32, num_experts=1, top_k=1, capacity_factor=2.0,
-                       group_size=g, dtype=jnp.float32)
-            v = v or m.init(jax.random.PRNGKey(4), x)
-            y, (_, dropped) = m.apply(v, x)
-            assert float(dropped) == 0.0
-            outs.append(np.asarray(y))
-        for y in outs[1:]:
-            np.testing.assert_allclose(y, outs[0], atol=1e-5, rtol=1e-5)
+    @pytest.mark.parametrize("axes", [
+        dict(expert=4), dict(data=2, expert=2), dict(expert=2, tensor=2),
+        dict(data=2, seq=2, expert=2)])
+    def test_split_over_a_mesh_is_the_one_device_layer(self, eight_devices,
+                                                       axes):
+        """Output, statistics and every gradient, with the experts' kernels
+        over ``expert``, their width over ``tensor`` and the tokens over
+        data and seq: a ``psum`` of the ranks' parts is the whole layer."""
+        x = _x(b=4, s=8, seed=4)
+        m = RoutedExperts(16, 32, num_experts=4, top_k=2, dtype=jnp.float32)
+        v = m.init(jax.random.PRNGKey(4), x)
+        cot = _x(b=4, s=8, seed=5)
 
-    def test_group_size_must_divide_tokens(self):
-        with pytest.raises(ValueError, match="group_size"):
-            MoEMLP(16, 32, num_experts=2, group_size=5).init(
-                jax.random.PRNGKey(0), _x(b=2, s=8))
+        def f(params, x):
+            y, stats = m.apply({"params": params}, x)
+            return jnp.sum(y * cot) + 3.0 * stats["aux"], (y, stats)
 
-    def test_small_groups_can_only_drop_more(self):
-        """Capacity enforced per group is a tighter constraint than per
-        sequence: at tight capacity the grouped router's drop fraction
-        must be ≥ the per-sequence one. QUALIFIED claim (ADVICE r4): this
-        holds when cf·g·k/E ≥ 1; below that the ≥1 capacity floor gives
-        tiny groups a full slot per expert and the inequality can flip.
-        The shape here is checked to sit in the valid regime so the test
-        can't silently rely on the floor."""
-        x = _x(b=1, s=16, seed=5)
-        kw = dict(num_experts=2, top_k=1, capacity_factor=0.5,
-                  dtype=jnp.float32)
-        g = 4
-        assert kw["capacity_factor"] * g * kw["top_k"] / kw["num_experts"] >= 1
-        base = MoEMLP(16, 32, **kw)
-        v = base.init(jax.random.PRNGKey(5), x)
-        _, (_, d_seq) = base.apply(v, x)
-        _, (_, d_grp) = MoEMLP(16, 32, group_size=g, **kw).apply(v, x)
-        assert float(d_grp) >= float(d_seq) - 1e-9
+        want = jax.value_and_grad(f, argnums=(0, 1), has_aux=True)(
+            v["params"], x)
+        size = int(np.prod(list(axes.values())))
+        mesh = MeshSpec(**axes).build(eight_devices[:size])
+        ring_attention.set_default_mesh(mesh)
+        try:
+            with mesh:
+                got = jax.jit(jax.value_and_grad(
+                    f, argnums=(0, 1), has_aux=True))(v["params"], x)
+        finally:
+            ring_attention.set_default_mesh(None)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
 
-    def test_capacity_floor_below_regime_boundary(self):
-        """The other side of the qualified claim: with cf·g·k/E < 1 the
-        ≥1 floor is active — per-group capacity is 1 per expert and the
-        aggregate across groups EXCEEDS the per-sequence cap, so tiny
-        groups may drop fewer tokens. Pins the documented boundary so a
-        future capacity rework that changes the semantics fails loudly."""
-        # cf·g·k/E = 0.5·2·1/4 = 0.25 < 1 → floor active, cap=1/group
-        # aggregate grouped capacity: (16/2 groups)·4 experts·1 = 32 slots
-        # vs per-sequence cap max(1, int(0.5·16·1/4)) = 2 slots·... = 8
-        x = _x(b=1, s=16, seed=6)
-        kw = dict(num_experts=4, top_k=1, capacity_factor=0.5,
-                  dtype=jnp.float32)
-        base = MoEMLP(16, 32, **kw)
-        v = base.init(jax.random.PRNGKey(6), x)
-        _, (_, d_seq) = base.apply(v, x)
-        _, (_, d_grp) = MoEMLP(16, 32, group_size=2, **kw).apply(v, x)
-        # the floor regime permits d_grp < d_seq — both must stay valid
-        # fractions, and the per-sequence run at tight capacity must
-        # actually be dropping (else this test exercises nothing)
-        assert 0.0 <= float(d_grp) <= 1.0
-        assert float(d_seq) > 0.0
+    def test_a_mesh_the_experts_do_not_divide_by_is_refused(
+            self, eight_devices):
+        x = _x(b=4, s=8)
+        m = RoutedExperts(16, 32, num_experts=3, top_k=1, dtype=jnp.float32)
+        v = m.init(jax.random.PRNGKey(0), x)
+        mesh = MeshSpec(expert=2).build(eight_devices[:2])
+        ring_attention.set_default_mesh(mesh)
+        try:
+            with pytest.raises(ValueError, match="experts held by expert"):
+                m.apply(v, x)
+        finally:
+            ring_attention.set_default_mesh(None)
 
 
 class TestMoELlama:
@@ -166,14 +168,12 @@ class TestMoELlama:
         v = model.init(jax.random.PRNGKey(0), batch, train=False)
         out = model.apply(v, batch, train=True)
         assert isinstance(out, dict) and "moe_aux" in out
-        assert "moe_dropped_frac" in out
-        assert 0.0 <= float(out["moe_dropped_frac"]) <= 1.0
+        assert "moe_dropped_frac" not in out   # nothing is dropped
         assert out["logits"].shape == (2, 16, cfg.vocab_size)
         loss, metrics = losses.causal_lm(
             out, {"input_ids": batch["input_ids"],
                   "loss_mask": np.ones((2, 16), np.float32)})
         assert "moe_aux" in metrics and np.isfinite(float(loss))
-        assert "moe_dropped_frac" in metrics
 
     def test_trains_on_data_expert_mesh(self, eight_devices):
         """Full train step over data=2 × expert=4: expert kernels sharded,

@@ -22,7 +22,7 @@ CMD[bert]="python examples/train_bert.py --master local[2] --variant tiny --step
 GREP[bert]="train summary"
 CMD[dlrm]="python examples/train_dlrm.py --master local[2] --steps 30 --batch-size 64 --vocab-size 100"
 GREP[dlrm]="eval AUC"
-CMD[llama]="python examples/train_llama_lora.py --master local[2] --expert 2 --moe-experts 4 --moe-group 64 --segment-ids --steps 4"
+CMD[llama]="python examples/train_llama_lora.py --master local[2] --expert 2 --moe-experts 4 --segment-ids --steps 4"
 GREP[llama]="moe_aux"
 
 [ -f SMOKE_LOG.md ] || {
