@@ -45,14 +45,32 @@ int default_threads() {
   return nt;
 }
 
+// Output elements a thread has to have before it is worth starting: a
+// thread costs tens of microseconds to start and join, and these kernels
+// write a few hundred thousand elements in a few hundred. One 224 x 224 x 3
+// example (150,528) is below it and runs on the caller's thread. The caller
+// is one of `map_parallel`'s pool threads, a pool as wide as the host: when
+// each of its thirteen threads started thirteen more for every image, the
+// kernel spent 4.5 of the host's 13 cores starting and ending threads and
+// the pool ran at 0.42 of its rate without them (PERF.md, PR 27).
+constexpr int64_t kMinWorkPerThread = int64_t{1} << 18;
+
+// Threads for n items of `work_per_item` output elements each.
+int plan_threads(int64_t n, int64_t work_per_item) {
+  const int64_t by_work = n * work_per_item / kMinWorkPerThread;
+  return static_cast<int>(std::max<int64_t>(
+      1, std::min<int64_t>({default_threads(), n, by_work})));
+}
+
 // Parallel-for over [0, n): per-call thread spawn with dynamic (atomic)
 // work claiming. Per-call spawn keeps the kernels trivially reentrant —
 // ctypes releases the GIL, so the prefetch background thread and the main
 // thread may invoke kernels concurrently; a shared persistent pool would
 // need cross-call synchronization to be safe for that.
-void parallel_for(int64_t n, const std::function<void(int64_t)>& fn) {
+void parallel_for(int64_t n, int64_t work_per_item,
+                  const std::function<void(int64_t)>& fn) {
   if (n <= 0) return;
-  int nt = std::min<int64_t>(default_threads(), n);
+  int nt = plan_threads(n, work_per_item);
   if (nt <= 1) {
     for (int64_t i = 0; i < n; ++i) fn(i);
     return;
@@ -105,6 +123,11 @@ int dls_version() { return 1; }
 
 int dls_num_threads() { return default_threads(); }
 
+// What a call of n items of `work_per_item` output elements fans out to.
+int dls_plan_threads(int64_t n, int64_t work_per_item) {
+  return plan_threads(n, work_per_item);
+}
+
 // Batch fused augment: N images, each cropped at (ys[i], xs[i]) to (ch, cw),
 // flipped when flips[i], normalized. in: [N,H,W,C] u8 → out: [N,ch,cw,C] f32.
 void dls_crop_flip_normalize_batch(const uint8_t* in, int64_t n, int h, int w,
@@ -116,11 +139,11 @@ void dls_crop_flip_normalize_batch(const uint8_t* in, int64_t n, int h, int w,
   for (int k = 0; k < c; ++k) inv_std[k] = 1.0f / std[k];
   const int64_t in_stride = static_cast<int64_t>(h) * w * c;
   const int64_t out_stride = static_cast<int64_t>(ch) * cw * c;
-  // Parallelize over (image, row-group) so n=1 calls (the per-example
-  // transform path) still use every core, not just batch-level callers.
+  // Parallelize over (image, row-group) so a call on one large image can
+  // use every core, not just batch-level callers.
   const int kRowGroup = 32;
   const int64_t groups_per_img = (ch + kRowGroup - 1) / kRowGroup;
-  parallel_for(n * groups_per_img, [&](int64_t g) {
+  parallel_for(n * groups_per_img, int64_t{kRowGroup} * cw * c, [&](int64_t g) {
     const int64_t i = g / groups_per_img;
     const int y0 = static_cast<int>(g % groups_per_img) * kRowGroup;
     const int rows = std::min(kRowGroup, ch - y0);
@@ -157,7 +180,7 @@ void dls_resize_bilinear(const float* in, int h, int w, int c, int oh, int ow,
     x1s[x] = std::min(x0 + 1, w - 1);
     wxs[x] = static_cast<float>(std::clamp(src - static_cast<double>(x0), 0.0, 1.0));
   }
-  parallel_for(oh, [&](int64_t y) {
+  parallel_for(oh, int64_t{ow} * c, [&](int64_t y) {
     double src = (static_cast<double>(y) + 0.5) * h / oh - 0.5;
     int y0 = std::clamp(static_cast<int>(std::floor(src)), 0, h - 1);
     int y1 = std::min(y0 + 1, h - 1);
@@ -186,7 +209,7 @@ void dls_resize_bilinear(const float* in, int h, int w, int c, int oh, int ow,
 // all in one pass with no float intermediate image. Interpolating raw u8
 // then scaling is the same linear map as scaling-then-interpolating, so
 // this matches the Python crop→resize→normalize chain to fp rounding.
-// Parallel over output rows.
+// Parallel over output rows where the output is large enough.
 void dls_rrc_flip_normalize(const uint8_t* in, int h, int w, int c,
                             int y0, int x0, int ch, int cw, int flip,
                             int oh, int ow, const float* mean,
@@ -207,7 +230,7 @@ void dls_rrc_flip_normalize(const uint8_t* in, int h, int w, int c,
     // dls_resize_bilinear / vision.resize_bilinear
     wxs[x] = static_cast<float>(std::clamp(src - static_cast<double>(cx0), 0.0, 1.0));
   }
-  parallel_for(oh, [&](int64_t y) {
+  parallel_for(oh, int64_t{ow} * c, [&](int64_t y) {
     double src = (static_cast<double>(y) + 0.5) * ch / oh - 0.5;
     int cy0 = std::clamp(static_cast<int>(std::floor(src)), 0, ch - 1);
     int cy1 = std::min(cy0 + 1, ch - 1);
@@ -255,7 +278,7 @@ void dls_rrc_flip_normalize_varbatch(
   }
   // Parallel over IMAGES (a 256-image batch keeps ≤16 threads saturated);
   // column taps are computed once per image, not per row.
-  parallel_for(n, [&](int64_t i) {
+  parallel_for(n, out_stride, [&](int64_t i) {
     const uint8_t* in = static_cast<const uint8_t*>(imgs[i]);
     const int w = ws[i], ch = chs[i], cw = cws[i];
     const int y0 = ys[i], x0 = xs[i];
@@ -303,7 +326,7 @@ void dls_rrc_flip_normalize_varbatch(
 void dls_sum_into_f32(float* dst, const float* src, int64_t n) {
   constexpr int64_t kChunk = 1 << 16;
   int64_t chunks = (n + kChunk - 1) / kChunk;
-  parallel_for(chunks, [&](int64_t ci) {
+  parallel_for(chunks, kChunk, [&](int64_t ci) {
     int64_t lo = ci * kChunk, hi = std::min(n, lo + kChunk);
     for (int64_t i = lo; i < hi; ++i) dst[i] += src[i];
   });

@@ -70,12 +70,17 @@ class StarvationProbe:
     - ``input_map_s`` (``dls.feed/map``) — thread-seconds inside
       ``map_parallel``'s function, summed over the pool; absent until a
       parallel map has run under this probe.
+    - ``input_decode_s`` (``dls.feed/decode``) — seconds inside
+      ``vision.decode_jpeg`` on this feed's threads (thread-seconds where
+      the pool decodes, and then a part of ``input_map_s``); absent until a
+      JPEG has been decoded under this probe.
 
     ``clock`` is injectable so tests measure deterministic fake seconds.
     ``snapshot(reset=True)`` returns-and-clears, giving per-lap gauges.
     """
 
-    #: the seconds-counters every snapshot carries (``input_map_s`` apart)
+    #: the seconds-counters every snapshot carries (``input_map_s`` and
+    #: ``input_decode_s`` apart)
     _ALWAYS = ("input_wait_s", "input_put_s", "input_assembly_s",
                "input_stack_s", "input_blocked_s")
 
@@ -86,7 +91,7 @@ class StarvationProbe:
         self._zero()
 
     def _zero(self) -> None:
-        # a probe that has seen a parallel map keeps reporting its key
+        # a probe that has seen a parallel map, or a decode, keeps its key
         self._seconds = dict.fromkeys((*self._ALWAYS, *self._seconds), 0.0)
         self._waits = 0
         self._wait_max = 0.0

@@ -165,10 +165,11 @@ def imagenet_folder(
     (``root/n01440764/xxx.JPEG``) — VERDICT r1 missing-#3: "point at a
     directory and train" for config 2.
 
-    Decoding (our native baseline-JPEG decoder, PIL fallback — see
-    :func:`..data.vision.decode_jpeg`) happens lazily inside the partition
-    iterator, i.e. on the prefetch thread, overlapping device compute the way
-    the reference's executors decode inside Spark tasks. Labels follow sorted
+    Decoding (libjpeg-turbo through PIL; our native baseline decoder where
+    PIL cannot be imported — see :func:`..data.vision.decode_jpeg`) happens
+    lazily inside the partition iterator, i.e. on the prefetch thread,
+    overlapping device compute the way the reference's executors decode
+    inside Spark tasks. Labels follow sorted
     class-directory order (torchvision's convention) unless an explicit
     ``class_to_index`` is given; ``decode=False`` yields raw bytes under
     ``"jpeg"`` for pipelines that want decode inside a later ``.map``.

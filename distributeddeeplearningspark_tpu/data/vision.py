@@ -8,17 +8,21 @@ time is reserved for the MXU; host decode overlaps device compute via
 :mod:`.prefetch`).
 
 All transforms are numpy, per-example, composable with ``dataset.map``. JPEG
-decoding is our own native baseline decoder (csrc/dls_jpeg.cc) with a PIL
-fallback for non-baseline streams — see :func:`decode_jpeg`.
+decoding is libjpeg-turbo's through PIL wherever PIL can be imported, so a
+pixel is what PIL, torchvision and TensorFlow users get from the same file;
+our own baseline decoder (csrc/dls_jpeg.cc) decodes only where it cannot —
+see :func:`decode_jpeg`.
 """
 
 from __future__ import annotations
 
+import io
 from typing import Callable, Iterable
 
 import numpy as np
 
 from distributeddeeplearningspark_tpu.rdd import PartitionedDataset
+from distributeddeeplearningspark_tpu.telemetry import spans
 
 #: ImageNet channel statistics (the universal constants every framework bakes in).
 IMAGENET_MEAN = np.array([0.485, 0.456, 0.406], np.float32)
@@ -57,7 +61,8 @@ def _resize(image: np.ndarray, size: tuple[int, int]) -> np.ndarray:
     """Bilinear resize via the native (C++) kernel when built, numpy otherwise.
 
     Identical math either way (csrc/dls_native.cc mirrors resize_bilinear);
-    the native path parallelizes across rows and releases the GIL.
+    the native path releases the GIL and, for a large output, parallelizes
+    across rows.
     """
     from distributeddeeplearningspark_tpu.utils import native
 
@@ -122,42 +127,77 @@ def _content_seed(img: np.ndarray) -> int:
 
 
 def decode_jpeg(path_or_bytes) -> np.ndarray:
-    """JPEG → uint8 HWC.
+    """JPEG → uint8 HWC, at full resolution: ``[H, W, 3]``, or ``[H, W, 1]``
+    for a one-channel file.
 
-    Decode order (VERDICT r1 missing-#3: the old path hard-depended on the
-    absent torchvision):
+    The decoder follows what the interpreter can import, and nothing else:
 
-    1. the native baseline decoder (csrc/dls_jpeg.cc — GIL-free, our own
-       host data plane, covers the sequential-DCT files ImageNet consists of);
-    2. PIL, for non-baseline streams (progressive) or when the native
-       library didn't build.
+    1. libjpeg-turbo through PIL, whenever PIL can be imported: every coding
+       mode (baseline, progressive, CMYK → RGB), chroma interpolated as
+       libjpeg does it, the GIL released while it decodes and while it
+       packs the pixels (one call each: :func:`_pil_pixels`). The array
+       views PIL's bytes and is read-only.
+    2. the native baseline decoder (csrc/dls_jpeg.cc, also GIL-free) where
+       PIL is absent. It replicates chroma where libjpeg interpolates, so a
+       4:2:0 file's pixels differ by a level or so on average, and with
+       them the content-seeded crop and flip (:func:`_content_seed`).
+
+    A malformed stream raises ``ValueError`` on both routes. The time spent
+    here is the span ``dls.feed/decode`` (counter ``input_decode_s`` where
+    the calling thread has a feed's sink bound).
     """
     if isinstance(path_or_bytes, (bytes, bytearray)):
-        data = bytes(path_or_bytes)
+        data, where = bytes(path_or_bytes), ""
     else:
         with open(path_or_bytes, "rb") as f:
             data = f.read()
+        where = f" in {path_or_bytes}"
+    with spans.span("dls.feed/decode", spans.bound_sink()):
+        try:
+            from PIL import Image
+        except ImportError:
+            return _decode_jpeg_native(data, where)
+        try:
+            img = Image.open(io.BytesIO(data))
+            # the whole stream in one decoder call, not one per 64 KB
+            img.decodermaxblock = max(len(data), img.decodermaxblock)
+            if img.mode not in ("RGB", "L"):
+                img = img.convert("RGB")
+            return _pil_pixels(img)
+        except OSError as e:  # UnidentifiedImageError, a truncated stream
+            raise ValueError(f"malformed JPEG{where}: {e}") from e
+
+
+def _pil_pixels(img) -> np.ndarray:
+    """``np.asarray(img)`` as ``[H, W, C]`` with one call into PIL's packer
+    where ``Image.tobytes`` makes one per 64 KB. Each call gives the GIL up
+    and has to win it back from a pool of threads doing the same."""
+    from PIL import Image
+
+    img.load()
+    shape = (img.height, img.width, len(img.getbands()))
+    packer = Image._getencoder(img.mode, "raw", img.mode)
+    packer.setimage(img.im)
+    parts, status = [], 0
+    while not status:  # as ``tobytes`` reads it: 0 more to come, 1 the end
+        _, status, part = packer.encode(shape[0] * shape[1] * shape[2])
+        parts.append(part)
+    if status < 0:
+        raise OSError(f"encoder error {status} in tobytes")
+    return np.frombuffer(b"".join(parts), np.uint8).reshape(shape)
+
+
+def _decode_jpeg_native(data: bytes, where: str) -> np.ndarray:
     from distributeddeeplearningspark_tpu.utils import native
 
     try:
         out = native.jpeg_decode(data)
-        if out is not None:
-            return out
-    except native.JpegUnsupported:
-        pass  # progressive etc. → PIL
-    try:
-        import io
-
-        from PIL import Image
-
-        img = Image.open(io.BytesIO(data))
-        if img.mode not in ("RGB", "L"):
-            img = img.convert("RGB")
-        arr = np.asarray(img)
-        return arr[..., None] if arr.ndim == 2 else arr
-    except ImportError as e:  # pragma: no cover - environment-dependent
+    except ValueError as e:  # malformed, or a mode only PIL decodes
+        raise type(e)(f"{e}{where}") from e
+    if out is None:
         raise RuntimeError(
-            "no JPEG decoder available (native build failed and PIL absent)") from e
+            "no JPEG decoder available (PIL absent and the native build failed)")
+    return out
 
 
 def _augment_decision(img: np.ndarray, seed: int, size: int

@@ -47,8 +47,10 @@ from distributeddeeplearningspark_tpu.telemetry import spans
 _map_thread_ids = itertools.count()
 
 
-def _name_map_thread() -> None:
+def _start_map_thread(sink) -> None:
     spans.name_thread(f"dls-map-{next(_map_thread_ids)}")
+    # what the mapped function opens itself (``decode_jpeg``) finds it here
+    spans.bind_sink(sink)
 
 PartitionFn = Callable[[], Iterable[Any]]
 
@@ -134,15 +136,15 @@ class PartitionedDataset:
 
             # the probe of the feed whose thread pulls this partition (None
             # outside a feed with telemetry): thread-seconds in ``f`` add to
-            # its ``input_map_s``
+            # its ``input_map_s``, and the pool's threads have it bound
             sink = spans.bound_sink()
 
             def call(item: Any) -> Any:
                 with spans.span("dls.feed/map", sink):
                     return f(item)
 
-            with ThreadPoolExecutor(
-                    workers, initializer=_name_map_thread) as ex:
+            with ThreadPoolExecutor(workers, initializer=_start_map_thread,
+                                    initargs=(sink,)) as ex:
                 window: deque = deque()
                 for item in it:
                     window.append(ex.submit(call, item))

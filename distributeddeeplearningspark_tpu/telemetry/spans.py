@@ -42,6 +42,7 @@ COUNTERS = {
     "dls.feed/stack": "input_stack_s",
     "dls.feed/ring_full": "input_blocked_s",
     "dls.feed/map": "input_map_s",
+    "dls.feed/decode": "input_decode_s",
 }
 #: ``EventWriter.phase(name)`` also opens ``dls.phase/<name>`` (trace only)
 PHASE_PREFIX = "dls.phase/"
@@ -113,7 +114,7 @@ def _open_spans() -> list:
 def bind_sink(sink) -> None:
     """Make ``sink`` the calling thread's feed accumulator: what the feed's
     sections that have no ``probe`` argument (``host_batches``' stack,
-    ``map_parallel``'s calls) add to. ``None`` unbinds."""
+    ``map_parallel``'s calls, ``decode_jpeg``) add to. ``None`` unbinds."""
     _thread.sink = sink
 
 

@@ -88,6 +88,8 @@ def _build(srcs: list[str]) -> str | None:
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.dls_version.restype = ctypes.c_int
     lib.dls_num_threads.restype = ctypes.c_int
+    lib.dls_plan_threads.restype = ctypes.c_int
+    lib.dls_plan_threads.argtypes = [ctypes.c_int64, ctypes.c_int64]
     lib.dls_crop_flip_normalize_batch.argtypes = [
         _u8p, ctypes.c_int64, ctypes.c_int, ctypes.c_int, ctypes.c_int,
         _i32p, _i32p, _u8p, ctypes.c_int, ctypes.c_int, _f32p, _f32p, _f32p,
@@ -347,11 +349,15 @@ def resize_bilinear(image: np.ndarray, size: tuple[int, int]) -> np.ndarray:
 
 class JpegUnsupported(ValueError):
     """Valid JPEG but a coding mode outside baseline (progressive, 12-bit,
-    arithmetic, CMYK) — callers fall back to PIL."""
+    arithmetic, CMYK): only PIL decodes it."""
 
 
 def jpeg_decode(data: bytes) -> np.ndarray | None:
-    """Baseline JPEG bytes → uint8 HWC (csrc/dls_jpeg.cc).
+    """Baseline JPEG bytes → uint8 HWC (csrc/dls_jpeg.cc): the decoder of
+    :func:`..data.vision.decode_jpeg` where PIL cannot be imported, and of
+    nothing else (with PIL there, libjpeg-turbo decodes, four times faster on
+    a quiet thread; on subsampled chroma, which this decoder replicates and
+    libjpeg interpolates, the two differ by a level or so on average).
 
     Returns None when the native library is unavailable; raises
     :class:`JpegUnsupported` for non-baseline streams and ValueError for

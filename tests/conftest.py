@@ -85,7 +85,7 @@ _SLOW_PATTERNS = (
     "test_bert.py::test_gathered_mlm_head_matches_full_length",
     "test_flash_attention.py::test_flash_gqa_gradients",
     "test_flash_attention.py::test_flash_gradients_match_dense",
-    "test_real_data.py",           # on-disk dump/tsv/idx fixtures
+    "test_real_data.py::test_criteo_tsv_trains_dlrm_batch",  # a DLRM step
     # second pass (fast-tier --durations, 2026-07-30): everything ≥6s —
     # mostly whole-model jit compiles; cheaper siblings keep the coverage
     "test_resnet.py::test_resnet18_forward_shapes_and_dtypes",

@@ -182,3 +182,36 @@ def test_train_transform_native_matches_numpy(monkeypatch):
     monkeypatch.setattr(native, "_TRIED", True)
     without = tf(dict(ex))["image"]
     np.testing.assert_allclose(with_native, without, atol=1e-4, rtol=1e-4)
+
+
+def test_a_call_fans_out_by_its_work_not_by_the_hosts_cores(monkeypatch):
+    """One 224 x 224 x 3 example is too little work to start a thread for:
+    ``map_parallel``'s pool, as wide as the host, calls the kernel once an
+    image, and a thread an image and core cost more than the pixels."""
+    lib = native._load()
+    monkeypatch.delenv("DLS_NATIVE_THREADS", raising=False)
+    cores = lib.dls_num_threads()
+    example = 224 * 224 * 3
+    assert lib.dls_plan_threads(224, 224 * 3) == 1      # its rows
+    assert lib.dls_plan_threads(7, 32 * 224 * 3) == 1   # its row groups
+    assert lib.dls_plan_threads(1, example) == 1
+    assert lib.dls_plan_threads(0, example) == 1
+    # a batch of them, and one large frame, still use the host
+    assert lib.dls_plan_threads(256, example) == min(cores, 147)
+    assert lib.dls_plan_threads(2048, 2048 * 3) == min(cores, 48)
+    monkeypatch.setenv("DLS_NATIVE_THREADS", "1")
+    assert lib.dls_plan_threads(256, example) == 1
+
+
+def test_a_large_call_is_the_same_on_one_thread_and_on_many(monkeypatch):
+    img = _rand_u8((700, 900, 3), seed=5)
+    mean, std = vision.IMAGENET_MEAN, vision.IMAGENET_STD
+    monkeypatch.delenv("DLS_NATIVE_THREADS", raising=False)
+    assert native._load().dls_plan_threads(1024, 1024 * 3) > 1 \
+        or native._load().dls_num_threads() == 1
+    many = native.rrc_flip_normalize(img, (10, 20, 600, 800), True,
+                                     (1024, 1024), mean, std)
+    monkeypatch.setenv("DLS_NATIVE_THREADS", "1")   # read on every call
+    one = native.rrc_flip_normalize(img, (10, 20, 600, 800), True,
+                                    (1024, 1024), mean, std)
+    assert many.tobytes() == one.tobytes()

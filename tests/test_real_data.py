@@ -1,12 +1,16 @@
-"""Real-dataset ingestion (VERDICT r1 missing-#3): ImageNet folder with the
-native JPEG decoder, Criteo TSV, Wikipedia dumps.
+"""Real-dataset ingestion (VERDICT r1 missing-#3): ImageNet folder decoded by
+libjpeg-turbo through PIL (the native JPEG decoder where PIL is absent),
+Criteo TSV, Wikipedia dumps.
 
 Fixtures are generated with independent encoders (PIL JPEG, hand-written XML)
 so the parity is against a second implementation, not our own round-trip.
 """
 
+import contextlib
 import io
 import os
+import sys
+from unittest import mock
 
 import numpy as np
 import pytest
@@ -80,12 +84,12 @@ def test_native_jpeg_grayscale():
     assert np.abs(got.astype(int) - want.astype(int)).max() <= 2
 
 
-def test_native_jpeg_progressive_rejected_and_vision_falls_back():
+def test_native_jpeg_progressive_rejected_and_vision_decodes_it():
     arr = _smooth(48, 48, seed=3)
     data = _jpeg_bytes(arr, progressive=True)
     with pytest.raises(native.JpegUnsupported):
         native.jpeg_decode(data)
-    # the public decode path falls back to PIL transparently
+    # the public decode path never asks the native decoder while PIL imports
     out = vision.decode_jpeg(data)
     np.testing.assert_array_equal(out, _pil_decode(data))
 
@@ -101,6 +105,97 @@ def test_native_jpeg_batch_matches_single():
     assert batch is not None
     for d, got in zip(datas, batch):
         np.testing.assert_array_equal(got, native.jpeg_decode(d))
+
+
+# -- vision.decode_jpeg: PIL's pixels, the native decoder's where PIL is absent -
+
+def _cmyk_jpeg_bytes(arr: np.ndarray) -> bytes:
+    buf = io.BytesIO()
+    PIL.fromarray(arr, "RGB").convert("CMYK").save(buf, format="JPEG",
+                                                   quality=90)
+    return buf.getvalue()
+
+
+_JPEG_KINDS = {
+    "444": lambda: _jpeg_bytes(_smooth(96, 128), subsampling=0),
+    "422": lambda: _jpeg_bytes(_smooth(64, 96, seed=1), subsampling=1),
+    "420_odd_size": lambda: _jpeg_bytes(_smooth(251, 133, seed=2),
+                                        subsampling=2),
+    "grayscale": lambda: _jpeg_bytes(_smooth(80, 60, c=1, seed=7)[..., 0]),
+    "progressive": lambda: _jpeg_bytes(_smooth(48, 48, seed=3),
+                                       subsampling=2, progressive=True),
+    "cmyk": lambda: _cmyk_jpeg_bytes(_smooth(40, 56, seed=4)),
+}
+
+
+def _without_pil():
+    """``import PIL`` fails inside, as where it is not installed. Make the
+    test's JPEGs before entering: PIL loads its plugins by import."""
+    return mock.patch.dict(sys.modules, {"PIL": None})
+
+
+@pytest.mark.parametrize("source", ["bytes", "path"])
+@pytest.mark.parametrize("kind", list(_JPEG_KINDS))
+def test_decode_jpeg_returns_pils_pixels(kind, source, tmp_path):
+    data = _JPEG_KINDS[kind]()
+    img = PIL.open(io.BytesIO(data))
+    want = np.asarray(img if img.mode == "L" else img.convert("RGB"))
+    if want.ndim == 2:
+        want = want[..., None]
+    if source == "path":
+        (tmp_path / "x.JPEG").write_bytes(data)
+        got = vision.decode_jpeg(str(tmp_path / "x.JPEG"))
+    else:
+        got = vision.decode_jpeg(data)
+    assert got.dtype == np.uint8 and got.ndim == 3
+    assert got.shape[-1] == (1 if kind == "grayscale" else 3)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("kind", ["444", "422", "420_odd_size", "grayscale"])
+def test_decode_jpeg_without_pil_returns_the_native_decoders(kind):
+    data = _JPEG_KINDS[kind]()
+    want = native.jpeg_decode(data)
+    assert want is not None, "native library failed to build"
+    with _without_pil():
+        with pytest.raises(ImportError):
+            from PIL import Image  # noqa: F401
+        got = vision.decode_jpeg(data)
+    np.testing.assert_array_equal(got, want)
+    if kind == "420_odd_size":  # the two decoders are two: chroma differs
+        assert (got != vision.decode_jpeg(data)).any()
+
+
+def test_decode_jpeg_without_pil_refuses_progressive_by_name(tmp_path):
+    (tmp_path / "p.jpg").write_bytes(_JPEG_KINDS["progressive"]())
+    with _without_pil(), pytest.raises(native.JpegUnsupported, match="p.jpg"):
+        vision.decode_jpeg(tmp_path / "p.jpg")
+
+
+_MALFORMED = {
+    "no_jpeg_at_all": lambda: b"\xff\xd8\xff\xe0not a real jpeg at all",
+    "cut_in_the_tables": lambda: _JPEG_KINDS["444"]()[:300],
+    "empty": lambda: b"",
+    # libjpeg-turbo through PIL refuses a scan that ends early; the native
+    # decoder pads it, as it always has
+    "cut_in_the_scan": lambda: _JPEG_KINDS["444"]()[:1500],
+}
+
+
+@pytest.mark.parametrize("what,route", [
+    *((w, r) for w in list(_MALFORMED)[:3] for r in ("pil", "native")),
+    ("cut_in_the_scan", "pil")])
+def test_decode_jpeg_malformed_raises_value_error(what, route, tmp_path):
+    data = _MALFORMED[what]()
+    (tmp_path / "bad.JPEG").write_bytes(data)
+    with _without_pil() if route == "native" else contextlib.nullcontext():
+        with pytest.raises(ValueError):
+            vision.decode_jpeg(data)
+        with pytest.raises(ValueError, match="bad.JPEG"):
+            vision.decode_jpeg(str(tmp_path / "bad.JPEG"))
+        # a file that is not there is no malformed stream
+        with pytest.raises(FileNotFoundError):
+            vision.decode_jpeg(str(tmp_path / "nope.JPEG"))
 
 
 # -- ImageNet folder ---------------------------------------------------------
@@ -142,6 +237,31 @@ def test_imagenet_folder_raw_bytes_mode(tmp_path):
     ds = imagenet_folder(str(root), num_partitions=1, decode=False)
     e = ds.take(1)[0]
     assert isinstance(e["jpeg"], bytes) and e["jpeg"][:2] == b"\xff\xd8"
+
+
+@pytest.mark.parametrize("how", ["thread_pool", "two_worker_processes"])
+def test_imagenet_train_examples_identical_to_the_serial_map(tmp_path, how):
+    """The crop and flip are seeded by the decoded bytes, so every route has
+    to decode alike: the serial map in this thread, ``map_parallel``'s pool
+    and two worker processes all call the one ``decode_jpeg``."""
+    root = _make_imagenet(tmp_path, n_per_class=4)
+
+    def examples(**route):
+        ds = vision.imagenet_train(
+            imagenet_folder(str(root), num_partitions=2, decode=False),
+            size=32, seed=3, **route)
+        return [e for i in range(ds.num_partitions)
+                for e in ds.iter_partition(i)]
+
+    want = examples(num_threads=0)
+    got = examples(**({"num_threads": 4} if how == "thread_pool"
+                      else {"num_workers": 2}))
+    assert len(got) == len(want) == 8
+    for a, b in zip(got, want):
+        assert a.keys() == b.keys() == {"image", "label"}
+        assert int(a["label"]) == int(b["label"])
+        assert a["image"].shape == (32, 32, 3)
+        assert np.asarray(a["image"]).tobytes() == b["image"].tobytes()
 
 
 def test_imagenet_folder_missing_dir_raises(tmp_path):

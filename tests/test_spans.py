@@ -216,6 +216,82 @@ def test_map_parallel_keeps_order_and_counts_thread_seconds():
     assert "input_map_s" not in StarvationProbe().snapshot()
 
 
+def test_decode_seconds_reach_step_metrics_inside_the_maps(tmp_path,
+                                                          monkeypatch):
+    """``decode_jpeg`` opens ``dls.feed/decode`` itself and finds the feed's
+    sink bound in ``map_parallel``'s threads: its thread-seconds ride
+    ``step_metrics`` as ``input_decode_s``, a part of ``input_map_s``."""
+    import io
+
+    Image = pytest.importorskip("PIL.Image")
+    from distributeddeeplearningspark_tpu.data import vision
+
+    assert "dls.feed/decode" in spans.SPAN_NAMES
+    assert spans.COUNTERS["dls.feed/decode"] == "input_decode_s"
+    rng = np.random.default_rng(0)
+    jpegs = []
+    for i in range(32):
+        buf = io.BytesIO()
+        Image.fromarray(rng.integers(0, 255, (28, 28), np.uint8), "L").save(
+            buf, format="JPEG", quality=90)
+        jpegs.append({"jpeg": buf.getvalue(), "label": np.int32(i % 10)})
+
+    def to_example(ex):
+        assert spans.bound_sink() is not None    # bound when the thread began
+        img = vision.decode_jpeg(ex["jpeg"])
+        time.sleep(0.001)     # the rest of the map: a call that ends in one
+        # lap with its decode counted in the lap before cannot tip the sums
+        return {"image": img.astype(np.float32) / 255.0, "label": ex["label"]}
+
+    monkeypatch.setenv(telemetry.WORKDIR_ENV, str(tmp_path / "tele"))
+    spark = Session.builder.master("local[1]").getOrCreate()
+    ds = (PartitionedDataset.parallelize(jpegs, 2).repeat()
+          .map_parallel(to_example, num_threads=2))
+    trainer = Trainer(spark, LeNet5(), losses.softmax_xent, optax.sgd(0.01))
+    trainer.init(feed.stack_examples(_mnist_like(16)))
+    try:
+        trainer.fit(ds, batch_size=16, steps=8, log_every=4)
+    finally:
+        telemetry.reset()
+    laps = [e for e in telemetry.read_events(tmp_path / "tele")
+            if e["kind"] == "step_metrics"]
+    assert len(laps) == 2
+    assert all(e["input_decode_s"] > 0 for e in laps)
+    assert (sum(e["input_decode_s"] for e in laps)
+            <= sum(e["input_map_s"] for e in laps))
+    # a feed that decodes nothing has no such key
+    assert "input_decode_s" not in StarvationProbe().snapshot()
+
+
+def test_the_benchmarks_reader_of_decode_seconds():
+    """``benchmark/layer_metrics/feed_decode_us_per_item.py`` on hand-made
+    laps: thread-microseconds per item over the laps that have the counter;
+    the parent's laps, which lack it, read nothing and raise nothing."""
+    import importlib.util
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "feed_decode_us_per_item", os.path.join(
+            root, "benchmark", "layer_metrics", "feed_decode_us_per_item.py"))
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    lap = {"steps": 2, "lap_s": 1.0, "input_map_s": 4.0}
+    ctx = {"laps": [{**lap, "input_decode_s": 1.0},
+                    {**lap, "input_decode_s": 2.0}], "items_per_step": 250}
+    assert reader.read(ctx) == pytest.approx(3000.0)
+    assert reader.read({"laps": [lap, lap], "items_per_step": 250}) is None
+    assert reader.read({"laps": [], "items_per_step": 250}) is None
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        entry = [m for m in json.load(f)["per_layer"]
+                 if m["name"] == "feed_decode_us_per_item"]
+    assert entry == [{
+        "name": "feed_decode_us_per_item", "unit": "us/item",
+        "better": "lower", "source": "program_counter", "layer": "input",
+        "moves": "throughput", "workloads": ["resnet50_imagenet.fit_jpeg"]}]
+
+
 def test_put_seconds_are_the_loop_threads_and_ride_the_snapshot():
     clock = FakeClock()
     probe = StarvationProbe(clock=clock)
@@ -309,8 +385,9 @@ def test_a_traced_fit_holds_every_span_on_named_lines(tmp_path, monkeypatch):
     # benchmark's extract keys host lines by name and keeps the last)
     assert len(names) == len(set(names)), names
     by_line = dict(lines)
-    # this run saves no checkpoint and evaluates nothing
-    expected = (set(spans.COUNTERS) - {"dls.fit/checkpoint", "dls.fit/eval"}
+    # this run saves no checkpoint, evaluates nothing and decodes no JPEG
+    expected = (set(spans.COUNTERS) - {"dls.fit/checkpoint", "dls.fit/eval",
+                                       "dls.feed/decode"}
                 | {"train", spans.PHASE_PREFIX + "compile"})
     assert {s for found in by_line.values() for s in found} == expected
     producer = by_line["dls-prefetch"]
