@@ -340,8 +340,9 @@ def imagenet_train(dataset: PartitionedDataset, *, size: int = 224, seed: int = 
     Feed it ``imagenet_folder(root, decode=False)`` so JPEG decode happens
     INSIDE the (optionally parallel) transform — decode in the source
     iterator would stay on the single consumer thread and cap a host at one
-    core's ~50–100 img/s while a chip consumes thousands (``bench.py
-    --model input``). ``num_threads``: thread-pool decode/augment (the
+    core's rate while a chip consumes thousands (the cell
+    ``resnet50_imagenet.fit_jpeg`` of ``BENCHMARK.json`` runs this path).
+    ``num_threads``: thread-pool decode/augment (the
     Spark task-slots-per-executor analog; 0/1 = serial; augmentation is
     content-seeded per example, so thread scheduling cannot change WHICH
     augmentation an example gets — but concurrent native-kernel calls have
@@ -427,10 +428,9 @@ def imagenet_train_batched(
     """Record-path fast feed: yield READY train batches with whole-batch
     fused native augmentation.
 
-    Profiling the record path (BASELINE.md r3) put 38% of host time in
-    per-example augment calls, 24% in the np.stack batch copy, and most of
-    the rest in thread-pool bookkeeping. This feed removes all three at
-    once: records stream serially (cheap), crop/flip decisions stay
+    The record path's host time goes to per-example augment calls, the
+    np.stack batch copy and thread-pool bookkeeping. This feed removes all
+    three at once: records stream serially (cheap), crop/flip decisions stay
     per-example content-seeded (identical stream to ``train_transform``),
     and ONE ``dls_rrc_flip_normalize_varbatch`` call per batch crops,
     resizes, flips and normalizes every image directly into the

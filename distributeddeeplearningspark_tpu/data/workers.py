@@ -1,9 +1,8 @@
 """Multi-process input-pipeline worker pool — the host-side map, scaled out.
 
-BENCH_r05 measured the JPEG input path at 51.8 images/sec/host with
-``nproc: 1``: every decode/resize/crop/normalize ran on one Python thread,
-and the only concurrency in the whole feed was the lone ``dls-prefetch``
-daemon thread. This module is the fix PR 2's :class:`~.prefetch.
+With every decode/resize/crop/normalize on one Python thread, the only
+concurrency in the whole feed is the lone ``dls-prefetch`` daemon thread.
+This module is the fix PR 2's :class:`~.prefetch.
 StarvationProbe` measures the need for: the per-example decode/augment map
 (the Spark partitioned-map, executed host-side) fans out over ``N`` worker
 *processes* — real cores, no GIL — with three contracts the rest of the

@@ -108,28 +108,20 @@ def llama_model_flops_per_token(cfg, seq: int, *,
     convention published MFU numbers use, cf. the PaLM appendix formula).
 
     Exists because ``compiled.cost_analysis()`` cannot be trusted for the
-    SCANNED Llama step on any backend. r5 re-measurement (CPU, L∈{2,4,8},
-    scan on/off — tests/test_bench.py::
-    test_llama_model_flops_vs_cpu_cost_analysis): with ``scan_layers=True``
-    the reported count is L-INDEPENDENT (identical at L=2/4/8) — XLA cost
-    analysis reports the while/scan body ONCE, not × trip count — while
-    the unrolled step scales with L and lands within ~6–13% of this
-    formula (XLA counts 2 flops/MAC; the excess is elementwise work the
-    formula excludes). This corrects the r4 story ("the backend drops the
-    scanned backward; CPU counts fully at 1 flop/MAC"): the r4 CPU
-    cross-check passed inside its ±40% window only because the 2×
-    convention error and the scan-body undercount at L=4 happened to
-    cancel. The r4 fwd:frozen:full ratio evidence (1 : 2.11 : 3.01)
-    remains valid — ratios of same-L scanned counts share the undercount.
-    Deflated ``mfu`` from the raw compiled count (12% on the r4 device
-    record vs ~50% analytic) is therefore a structural property of
-    scanned models, not a backend bug.
+    SCANNED Llama step on any backend: XLA's cost analysis reports the
+    while/scan body ONCE, not × trip count, so with ``scan_layers=True`` the
+    count does not grow with depth, while the unrolled step scales with L
+    and lands within ~6–13% of this formula (XLA counts 2 flops/MAC; the
+    excess is elementwise work the formula excludes). Both are held by
+    ``tests/test_metrics_flops.py``. A low ``mfu`` from the raw compiled
+    count is therefore a structural property of scanned models, not a
+    backend bug.
 
     Counted: projection/FFN/head matmuls (embedding lookup is a gather),
     attention score/value matmuls (causal halving, q-head count — GQA does
     not change matmul FLOPs), LoRA adapter matmuls. Forward = 2·P; backward
     dx = 2·P again; backward dW = 2·P only for trainable params (the
-    frozen-base step excludes base dW — r2's +30% measured win). Not
+    frozen-base step excludes base dW). Not
     counted: elementwise/norm/softmax work and the optimizer (sub-1% at
     transformer shapes), remat recompute (model flops, not implementation
     flops — matches how published MFU is computed).

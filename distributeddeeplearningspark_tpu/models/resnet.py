@@ -15,11 +15,8 @@ TPU-first design decisions (vs. a torch translation):
 - **BatchNorm compute follows the activation dtype** (``norm_dtype=None`` →
   ``self.dtype``): flax upcasts the mean/var *statistics* to f32 internally
   and keeps scale/bias params f32 regardless, so only the normalize/affine
-  elementwise math runs in bf16 — measured on the dev v5e this alone is
-  134→101 ms/step on ResNet-50 b=256 (23.2%→30.7% MFU), because an f32 BN
-  sandwiched between bf16 convs pays convert+double-bandwidth on every
-  activation tensor (A/B on a scratch harness; the committed ``bench.py``
-  run of the same change landed at 103.0 ms / 30.16% — see BASELINE.md).
+  elementwise math runs in bf16: an f32 BN sandwiched between bf16 convs
+  pays a convert and double the bandwidth on every activation tensor.
   Set ``norm_dtype=jnp.float32`` to reproduce torch-default numerics; the
   weight-import parity tests get this implicitly by running the whole model
   at ``dtype=float32``, which the norm dtype follows.
@@ -47,19 +44,12 @@ def _norm_dtype(norm_dtype, dtype):
 
 
 class BottleneckBlock(nn.Module):
-    """1×1 → 3×3 → 1×1 bottleneck with projection shortcut when needed.
-
-    ``fused_conv_bn=True`` routes the two stride-1 1×1 conv→BN pairs through
-    the Pallas matmul-with-stats-epilogue kernel (``ops/conv_bn.py`` —
-    VERDICT r2 next-#2's byte-diet lever: the separate whole-activation
-    BN-statistics read disappears for the block's fattest tensors).
-    """
+    """1×1 → 3×3 → 1×1 bottleneck with projection shortcut when needed."""
 
     filters: int  # bottleneck width; output channels = 4 * filters
     strides: int = 1
     dtype: Any = jnp.bfloat16
     norm_dtype: Any = None  # None → follow self.dtype (see module docstring)
-    fused_conv_bn: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array, *, train: bool) -> jax.Array:
@@ -69,22 +59,9 @@ class BottleneckBlock(nn.Module):
             epsilon=1e-5, dtype=_norm_dtype(self.norm_dtype, self.dtype),
         )
 
-        def conv1x1_bn(features, name, zero_gamma=False):
-            from distributeddeeplearningspark_tpu.ops.conv_bn import Conv1x1BN
-
-            return Conv1x1BN(
-                features, dtype=self.dtype, norm_dtype=self.norm_dtype,
-                scale_init=(nn.initializers.zeros if zero_gamma
-                            else nn.initializers.ones),
-                name=name)
-
         residual = x
-        if self.fused_conv_bn:
-            y = conv1x1_bn(self.filters, "conv_bn_1")(x, train=train)
-            y = nn.relu(y)
-        else:
-            y = conv(self.filters, (1, 1))(x)
-            y = nn.relu(norm()(y))
+        y = conv(self.filters, (1, 1))(x)
+        y = nn.relu(norm()(y))
         # explicit (1,1) padding = torch semantics; flax SAME pads (0,1) on
         # stride-2, which would break pretrained-weight parity (resnet_io)
         y = conv(self.filters, (3, 3), strides=(self.strides, self.strides),
@@ -92,12 +69,8 @@ class BottleneckBlock(nn.Module):
         y = nn.relu(norm()(y))
         # zero-init gamma on the last BN: each block starts as identity,
         # the standard large-batch trick (Goyal et al.) — free accuracy.
-        if self.fused_conv_bn:
-            y = conv1x1_bn(4 * self.filters, "conv_bn_3",
-                           zero_gamma=True)(y, train=train)
-        else:
-            y = conv(4 * self.filters, (1, 1))(y)
-            y = norm(scale_init=nn.initializers.zeros)(y)
+        y = conv(4 * self.filters, (1, 1))(y)
+        y = norm(scale_init=nn.initializers.zeros)(y)
         if residual.shape != y.shape:
             residual = conv(4 * self.filters, (1, 1), strides=(self.strides, self.strides),
                             name="shortcut_conv")(residual)
@@ -147,7 +120,6 @@ class ResNet(nn.Module):
     width: int = 64
     dtype: Any = jnp.bfloat16
     norm_dtype: Any = None  # None → follow self.dtype (see module docstring)
-    fused_conv_bn: bool = False  # Pallas conv+BN-stats epilogue (bottlenecks)
 
     @nn.compact
     def __call__(self, batch: dict[str, jax.Array], *, train: bool = False) -> jax.Array:
@@ -159,14 +131,6 @@ class ResNet(nn.Module):
                          dtype=ndtype, name="stem_bn")(x)
         x = nn.relu(x)
         x = nn.max_pool(x, (3, 3), strides=(2, 2), padding=[(1, 1), (1, 1)])
-        is_bottleneck = (isinstance(self.block_cls, type)
-                         and issubclass(self.block_cls, BottleneckBlock))
-        if self.fused_conv_bn and not is_bottleneck:
-            raise ValueError(
-                "fused_conv_bn=True requires a BottleneckBlock block_cls "
-                f"(got {self.block_cls!r}) — BasicBlock has no 1×1 convs "
-                "to fuse")
-        kw = {"fused_conv_bn": self.fused_conv_bn} if is_bottleneck else {}
         for stage, n_blocks in enumerate(self.stage_sizes):
             for block in range(n_blocks):
                 x = self.block_cls(
@@ -174,7 +138,6 @@ class ResNet(nn.Module):
                     strides=2 if stage > 0 and block == 0 else 1,
                     dtype=self.dtype,
                     norm_dtype=self.norm_dtype,
-                    **kw,
                 )(x, train=train)
         x = jnp.mean(x, axis=(1, 2))  # global average pool
         return nn.Dense(self.num_classes, dtype=jnp.float32, name="head")(x)

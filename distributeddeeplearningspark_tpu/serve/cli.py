@@ -4,8 +4,7 @@ The serving sibling of ``dlsubmit``/``dlstatus``: builds an
 :class:`~.engine.InferenceEngine` over a model (params from a checkpoint
 directory when given, verified via the integrity manifests; fresh init
 otherwise), drives it with N closed-loop synthetic clients, and prints
-ONE JSON line with the latency/throughput evidence (the bench.py house
-convention). With ``--compare-sequential`` the same request count runs
+ONE JSON line with the latency/throughput evidence. With ``--compare-sequential`` the same request count runs
 single-request-at-a-time through the identical jitted forward, so the
 line carries the dynamic-batching speedup measured, not assumed. With
 ``--watch`` a :class:`~.reload.HotReloader` polls the checkpoint
@@ -456,7 +455,7 @@ def main(argv: list[str] | None = None) -> int:
         return fleet_main(args)
 
     workdir = args.workdir or args.checkpoint_dir
-    import jax  # noqa: F401 — heavy import AFTER argparse (bench.py house rule)
+    import jax  # noqa: F401 — heavy import AFTER argparse
 
     from distributeddeeplearningspark_tpu.serve import (
         HotReloader,

@@ -42,8 +42,7 @@ with three instruments that all land on the same JSONL bus:
 The reader side (:func:`anatomy_report`) is a pure jax-free fold over the
 event stream, like every other ``dlstatus`` section — jax imports in this
 module are all function-local so the CLI never pays (or requires) a
-backend. ``tools/perf_guard.py`` folds the same fields across BENCH
-records into the cross-run regression sentinel.
+backend.
 """
 
 from __future__ import annotations
@@ -147,7 +146,7 @@ class InstrumentedFunction:
     train step, the bucket-ladder length for a serve forward. A signature
     compiling twice, or the distinct count exceeding the expectation, flags
     the event ``recompile=True`` — the ``dlstatus --anatomy`` verdict and
-    ``bench.py``'s ``recompile_count`` read that flag.
+    ``compile_summary()["flagged_recompiles"]`` read that flag.
 
     A failure of ``lower().compile()`` (a Mosaic rejection, a compile-time
     out-of-memory) propagates to the caller: retrying it through plain jit
@@ -417,7 +416,7 @@ class InstrumentedFunction:
     # -- summaries ------------------------------------------------------------
 
     def compile_summary(self) -> dict[str, Any]:
-        """The wrapper-lifetime rollup bench records per arm."""
+        """The wrapper-lifetime rollup (the benchmark's ``correct`` reads it)."""
         with self._lock:
             recs = list(self.records)
         return {

@@ -1,13 +1,11 @@
 """Row-sparse embedding training — touched-rows-only table updates.
 
-Why this exists (measured on the dev v5e, `utils.profiling.op_breakdown` of
-the config-4 DLRM bench step, BASELINE.md r2): with the generic train step,
-**93% of DLRM device time is full-table work** — autodiff's dense
-scatter-add gradient over the [2.6M, 64] fused table (41.9%), full-table
-optimizer reads/writes (22.2%), and XLA layout copies of the whole table
-(29.5%) — while the actual 8192-example batch compute is <1%. A Criteo step
-touches at most ``batch × 26`` rows (~8% of the table), so updating every
-row every step is pure wasted HBM bandwidth. The reference's
+Why this exists: with the generic train step nearly all of DLRM's device
+time is full-table work — autodiff's dense scatter-add gradient over the
+[2.6M, 64] fused table, full-table optimizer reads and writes, and XLA
+layout copies of the whole table — while the batch's own compute is a
+sliver. A Criteo step touches at most ``batch × 26`` rows (~8% of the
+table), so updating every row every step is wasted HBM bandwidth. The reference's
 parameter-server-style table distribution gets row sparsity implicitly (only
 gathered rows ship gradients, SURVEY.md §2 'Wide&Deep/DLRM'); this module is
 the TPU-native equivalent, and the same trick torchrec fuses into its
@@ -102,7 +100,6 @@ def rowwise_adagrad_update(
     *,
     lr: float,
     eps: float = 1e-8,
-    scatter_impl: str = "xla",
 ) -> tuple[jax.Array, jax.Array]:
     """Apply row-wise AdaGrad to the rows named by ``ids`` only.
 
@@ -111,13 +108,6 @@ def rowwise_adagrad_update(
     AdaGrad). Duplicate ids are first combined by ``segment_sum``, so the
     result is deterministic and equals the dense update that a full gradient
     with those row sums would produce.
-
-    ``scatter_impl="pallas"`` routes the table scatter through the guarded
-    drop-semantics boundary ``ops.scatter_rows.scatter_add_rows_dropping``
-    (VERDICT r3 next-#6: the raw kernel must never see this function's OOB
-    sentinel padding). The tiny [V] accum scatter stays on XLA either way —
-    it is not the traffic the A/B is about. Flip the default only if the
-    ``--scatter-ab`` falsification experiment beats XLA's emitter on-chip.
     """
     v, d = table.shape
     flat = ids.reshape(-1)
@@ -141,17 +131,8 @@ def rowwise_adagrad_update(
     # unique() guarantees sorted, collision-free indices — assert both to XLA
     # so the TPU scatter emitter parallelizes instead of serializing updates
     # under collision-safety assumptions.
-    if scatter_impl == "pallas":
-        from distributeddeeplearningspark_tpu.ops.scatter_rows import (
-            scatter_add_rows_dropping)
-
-        new_table = scatter_add_rows_dropping(table, uniq, upd)
-    elif scatter_impl == "xla":
-        new_table = table.at[uniq].add(
-            upd, mode="drop", unique_indices=True, indices_are_sorted=True)
-    else:
-        raise ValueError(f"scatter_impl must be 'xla' or 'pallas', "
-                         f"got {scatter_impl!r}")
+    new_table = table.at[uniq].add(
+        upd, mode="drop", unique_indices=True, indices_are_sorted=True)
     new_accum = accum.at[uniq].set(
         new_acc_rows, mode="drop", unique_indices=True, indices_are_sorted=True)
     return new_table, new_accum

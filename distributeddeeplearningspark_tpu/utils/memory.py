@@ -6,8 +6,9 @@ is the checked-in memory analysis: a component-by-component byte budget for
 a (batch, seq, mesh, remat, LoRA) layout, validated against the live
 backend's compiled memory analysis where one is available (the test suite
 cross-checks the formula's activation model against jit-lowered cost
-analysis on small shapes; `bench.py --model llama --variant 7b` prints the
-report and attempts the real step when a chip is up).
+analysis on small shapes, and ``tests/test_compile_for_v5e.py`` holds it
+against the compiler's own ``memory_analysis()`` of the 0.9b LoRA step
+compiled for one described v5e chip).
 
 The budget model (bf16 params/activations, f32 LoRA optimizer state):
 

@@ -9,7 +9,7 @@ The minimal train→serve loop on one CPU (runs in CI — `tools/ci.sh serve`):
    engine pinned to single-request batches);
 4. mid-traffic, save a NEWER checkpoint and let the hot-reloader swap it
    in — zero dropped requests;
-5. print a latency/throughput summary (one JSON line, bench.py style).
+5. print a latency/throughput summary (one JSON line).
 
 ::
 

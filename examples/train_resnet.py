@@ -46,7 +46,7 @@ def main() -> None:
     p.add_argument("--records-dir", default=None,
                    help="preprocessed array-record dir (data/records.py): "
                         "stream pre-decoded frames instead of paying JPEG "
-                        "decode per epoch (11x+ per host — BASELINE.md r3). "
+                        "decode per epoch. "
                         "Create once with --materialize-records")
     p.add_argument("--materialize-records", default=None, metavar="OUT_DIR",
                    help="one-time: decode + shorter-side-resize --data-dir "

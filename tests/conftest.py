@@ -49,10 +49,8 @@ _SLOW_PATTERNS = (
     "test_profiling.py::test_profile_cli",
     "test_profiling.py::test_op_breakdown",
     "test_llama_gen.py",           # KV-cache decode rollouts (big compiles)
-    "test_bench.py::test_bench_kernels_interpret_smoke",  # interpret Pallas
-    "test_bench.py::test_pallas_smoke_interpret_parity",  # interpret Pallas
-    "test_bench.py::test_llama_model_flops_vs_cpu_cost_analysis",  # 0.9b-shape-free but compiles full tiny train steps (unrolled, 2 depths)
-    "test_bench.py::test_cost_analysis_is_scan_opaque",  # 2 more tiny compiles
+    "test_metrics_flops.py::test_llama_model_flops_vs_cpu_cost_analysis",  # compiles full tiny train steps (unrolled, 2 depths)
+    "test_metrics_flops.py::test_cost_analysis_is_scan_opaque",  # 2 more tiny compiles
     "test_checkpoint.py::test_trainer_resume",
     "test_checkpoint.py::test_roundtrip",
     "test_pipeline.py::test_pp_composes_with_tp_and_dp",
@@ -90,7 +88,6 @@ _SLOW_PATTERNS = (
     # mostly whole-model jit compiles; cheaper siblings keep the coverage
     "test_resnet.py::test_resnet18_forward_shapes_and_dtypes",
     "test_resnet.py::test_norm_dtype_follows_compute_dtype",
-    "test_conv_bn.py::test_resnet_fused_flag_end_to_end",
     "test_grad_accum.py::test_accum_multiple_steps_trains",
     "test_grad_accum.py::test_trainer_fit_accum_wiring",
     "test_grad_accum.py::test_accum_equals_full_batch_step",
@@ -112,8 +109,6 @@ _SLOW_PATTERNS = (
     "test_moe.py::test_predict_and_eval_get_plain_logits",
     "test_llama.py::TestLlamaPackedSegments",
     "test_llama.py::test_pp_rejects_segment_ids",
-    "test_conv_bn.py::TestConv1x1BN::test_gradients_match_unfused",
-    "test_bench.py::test_llama_7b_oom_returns_structured_evidence",
     "test_memory.py::test_param_count_matches_model_exactly",
     "test_llama.py::test_parity_with_transformers",
     "test_checkpoint.py::test_retention",

@@ -1,8 +1,6 @@
 """Device-side performance observatory (ISSUE 10): compile ledger,
-step anatomy, MFU arithmetic, memory watermarks, `dlstatus --anatomy`,
-and the tools/perf_guard.py regression sentinel."""
+step anatomy, MFU arithmetic, memory watermarks, `dlstatus --anatomy`."""
 
-import importlib.util
 import json
 import math
 import os
@@ -14,15 +12,6 @@ import pytest
 
 from distributeddeeplearningspark_tpu import status, telemetry
 from distributeddeeplearningspark_tpu.telemetry import anatomy, spans
-
-
-def _load_perf_guard():
-    path = os.path.join(os.path.dirname(__file__), "..", "tools",
-                        "perf_guard.py")
-    spec = importlib.util.spec_from_file_location("perf_guard", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 @pytest.fixture
@@ -536,97 +525,3 @@ def test_engine_warmup_emits_compile_phases(tmp_path):
     finally:
         eng.stop()
         telemetry.reset()
-
-
-# -- perf_guard ---------------------------------------------------------------
-
-
-def _bench_record(value, *, metric="resnet50_images_per_sec_per_chip",
-                  backend="tpu", step_time_ms=None, mfu=None,
-                  compile_s=None, recompile_count=None, spread_pct=None):
-    arm = {}
-    for k, v in (("images_per_sec_per_chip", value),
-                 ("step_time_ms", step_time_ms), ("mfu", mfu),
-                 ("compile_s", compile_s),
-                 ("recompile_count", recompile_count),
-                 ("spread_pct", spread_pct)):
-        if v is not None:
-            arm[k] = v
-    return {"metric": metric, "value": value, "unit": "images/sec/chip",
-            "extra": {"backend": backend, "resnet50": arm}}
-
-
-def test_perf_guard_ok_regressed_insufficient():
-    pg = _load_perf_guard()
-    hist = [_bench_record(100.0, step_time_ms=10.0, mfu=0.4),
-            _bench_record(104.0, step_time_ms=9.6, mfu=0.41),
-            _bench_record(98.0, step_time_ms=10.2, mfu=0.39)]
-
-    ok = pg.guard(_bench_record(101.0, step_time_ms=9.9, mfu=0.4), hist)
-    assert ok["verdict"] == "OK" and not ok["regressed"]
-
-    slow = pg.guard(_bench_record(80.0, step_time_ms=12.5, mfu=0.32), hist)
-    assert slow["verdict"] == "REGRESSED"
-    assert "resnet50.images_per_sec_per_chip" in slow["regressed"]
-    assert "resnet50.step_time_ms" in slow["regressed"]
-    assert "value:resnet50_images_per_sec_per_chip" in slow["regressed"]
-
-    # one prior record: every check lacks history -> explicit refusal
-    short = pg.guard(_bench_record(80.0), hist[:1])
-    assert short["verdict"] == "INSUFFICIENT_HISTORY"
-    assert all(c["status"] == "insufficient-history"
-               for c in short["checks"])
-
-
-def test_perf_guard_backend_and_metric_scoping():
-    """A host-degraded round must not be judged against chip history."""
-    pg = _load_perf_guard()
-    tpu_hist = [_bench_record(100.0), _bench_record(101.0)]
-    host = pg.guard(_bench_record(5.0, backend="host"), tpu_hist)
-    assert host["verdict"] == "INSUFFICIENT_HISTORY"
-    assert host["comparable_history"] == 0
-
-
-def test_perf_guard_recompile_and_compile_band():
-    pg = _load_perf_guard()
-    hist = [_bench_record(100.0, compile_s=10.0, recompile_count=0),
-            _bench_record(100.0, compile_s=14.0, recompile_count=0)]
-    # compile_s gets a widened (3x) band: +40% over baseline stays ok
-    ok = pg.guard(_bench_record(100.0, compile_s=16.0, recompile_count=0),
-                  hist)
-    assert ok["verdict"] == "OK"
-    # +60% trips even the widened band
-    slow = pg.guard(_bench_record(100.0, compile_s=20.0), hist)
-    assert "resnet50.compile_s" in slow["regressed"]
-    # ANY recompile over a clean baseline is a regression, band-free
-    storm = pg.guard(_bench_record(100.0, recompile_count=1), hist)
-    assert "resnet50.recompile_count" in storm["regressed"]
-
-
-def test_perf_guard_spread_widens_step_time_band():
-    pg = _load_perf_guard()
-    hist = [_bench_record(100.0, step_time_ms=10.0),
-            _bench_record(100.0, step_time_ms=10.0)]
-    # +18% step time with a self-reported 25% spread: inside the widened band
-    noisy = pg.guard(_bench_record(100.0, step_time_ms=11.8,
-                                   spread_pct=25.0), hist)
-    assert "resnet50.step_time_ms" not in noisy["regressed"]
-    tight = pg.guard(_bench_record(100.0, step_time_ms=11.8,
-                                   spread_pct=2.0), hist)
-    assert "resnet50.step_time_ms" in tight["regressed"]
-
-
-def test_perf_guard_cli_on_wrapper_records(tmp_path):
-    """The CLI reads the driver wrapper shape ({'rc', 'parsed'}) and skips
-    failed rounds when picking current/history."""
-    pg = _load_perf_guard()
-    recs = [(1, 0, _bench_record(100.0)), (2, 0, _bench_record(102.0)),
-            (3, 1, _bench_record(999.0)),  # failed round: ignored
-            (4, 0, _bench_record(101.0))]
-    for n, rc, parsed in recs:
-        (tmp_path / f"BENCH_r{n:02d}.json").write_text(
-            json.dumps({"n": n, "rc": rc, "parsed": parsed}))
-    assert pg.main(["--dir", str(tmp_path)]) == 0
-    (tmp_path / "BENCH_r05.json").write_text(
-        json.dumps({"n": 5, "rc": 0, "parsed": _bench_record(70.0)}))
-    assert pg.main(["--dir", str(tmp_path)]) == 1

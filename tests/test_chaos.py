@@ -15,7 +15,7 @@ Each drill is the end-to-end shape of one production failure mode:
 - **NaN spike** (``DLS_FAULT=nan@N``): ``fit(on_nonfinite=...)`` must
   contain the divergence (skip) or rewind past it (rollback).
 
-Run via ``bash tools/ci.sh chaos`` (appends its own SUITE_LOG.md line).
+Run via ``bash tools/ci.sh chaos`` (prints its own row).
 """
 
 import os

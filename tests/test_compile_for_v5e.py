@@ -48,6 +48,18 @@ def no_compile_cache():
     cc.reset_cache()
 
 
+@pytest.fixture
+def routed_as_on_the_chip(monkeypatch):
+    """The attention router and ``pallas_interpret`` ask the one platform
+    predicate, and this process is on the CPU: answer for the chip the
+    compile is for, here in the test and through no option of the program."""
+    from distributeddeeplearningspark_tpu.ops import attention
+    from distributeddeeplearningspark_tpu.utils import env
+
+    monkeypatch.setattr(env, "on_tpu", lambda: True)
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+
+
 @pytest.mark.parametrize("precision", [None, "highest"])
 def test_indexed_attention_kernels_compile_at_published_widths(
         one_chip, no_compile_cache, precision):
@@ -134,3 +146,146 @@ def test_routed_experts_split_over_four_chips_compile(four_chips,
     text = compiled.as_text()
     assert "ragged-dot" in text or "ragged_dot" in text
     assert " all-reduce(" in text or " all-reduce-start(" in text
+
+
+# -- the flash kernel in every regime a model uses ---------------------------
+
+#: name -> (batch, seq, q heads, kv heads, head size, causal, key mask,
+#: segment ids): Llama's causal d=128, BERT-base's key-padding mask d=64,
+#: grouped KV, and packed documents (mask + segment ids)
+FLASH_REGIMES = {
+    "causal_d128": (2, 1024, 4, 4, 128, True, False, False),
+    "masked_d64_bert": (2, 512, 12, 12, 64, False, True, False),
+    "gqa_causal_d128": (1, 1024, 8, 2, 128, True, False, False),
+    "masked_segments_d64": (2, 1024, 12, 12, 64, False, True, True),
+}
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "value_and_grad"])
+@pytest.mark.parametrize("regime", list(FLASH_REGIMES))
+def test_flash_kernel_compiles_in_every_regime(one_chip, no_compile_cache,
+                                               regime, grad):
+    """Mosaic accepts ``ops/flash_attention.py`` at model widths: the forward
+    kernel alone, and with both backward kernels. The numbers these kernels
+    give are held against ``_xla_attention`` in interpret mode by
+    ``tests/test_flash_attention.py``; here only the chip's compiler speaks."""
+    from distributeddeeplearningspark_tpu.ops.flash_attention import (
+        flash_attention)
+
+    b, s, h, hkv, d, causal, masked, segmented = FLASH_REGIMES[regime]
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [sds((b, s, h, d)), sds((b, s, hkv, d)), sds((b, s, hkv, d))]
+    if masked:
+        args.append(sds((b, s), jnp.int32))
+    if segmented:
+        args.append(sds((b, s), jnp.int32))
+
+    def scalar(q, k, v, *extra):
+        o = flash_attention(
+            q, k, v, causal=causal, interpret=False,
+            mask=extra[0] if masked else None,
+            segment_ids=extra[-1] if segmented else None)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    fn = jax.value_and_grad(scalar, argnums=(0, 1, 2)) if grad else scalar
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    kernels = (("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv") if grad
+               else ("flash_fwd",))
+    for name in kernels:
+        assert name in text, name
+    assert text.count("tpu_custom_call") >= len(kernels)
+
+
+def test_flash_on_mesh_compiles_for_four_chips(four_chips, no_compile_cache,
+                                               routed_as_on_the_chip):
+    """``ops/attention._flash_on_mesh`` at BERT-base's widths over ``data=4``
+    of the described 2x2: the kernel inside its ``shard_map`` (GSPMD refuses
+    to partition a Mosaic call), forward and backward, and no collective,
+    since attention mixes neither batch rows nor heads."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributeddeeplearningspark_tpu.ops import attention, ring_attention
+    from distributeddeeplearningspark_tpu.parallel.mesh import MeshSpec
+
+    mesh = MeshSpec(data=4).build(four_chips)
+    rows = NamedSharding(mesh, P(("data", "fsdp")))
+    qkv = jax.ShapeDtypeStruct((128, 512, 12, 64), jnp.bfloat16,
+                               sharding=rows)
+    mask = jax.ShapeDtypeStruct((128, 512), jnp.int32, sharding=rows)
+
+    def scalar(q, k, v, m):
+        o = attention._flash_on_mesh(q, k, v, bias=None, mask=m, causal=False,
+                                     scale=None, segment_ids=None)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    ring_attention.set_default_mesh(mesh)
+    try:
+        compiled = jax.jit(jax.grad(scalar, argnums=(0, 1, 2))).lower(
+            qkv, qkv, qkv, mask).compile()
+    finally:
+        ring_attention.set_default_mesh(None)
+    text = compiled.as_text()
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert name in text, name
+    assert "[32,12,512,64]" in text or "[384,512,64]" in text  # a chip's rows
+    assert " all-reduce(" not in text and " all-gather(" not in text
+
+
+def test_llama_09b_lora_step_fits_one_chip_as_budgeted(four_chips,
+                                                       no_compile_cache,
+                                                       routed_as_on_the_chip):
+    """The 0.9b LoRA train step (hidden 2048 x 16 layers x vocabulary 32k,
+    bf16 base, rank 16, four sequences of 2,048) compiled for ONE described
+    chip: the compiler's live bytes against ``utils/memory.py``'s analytic
+    budget, within the 10% window ``tests/test_memory.py`` states for it."""
+    import optax
+
+    from distributeddeeplearningspark_tpu.models import (
+        LlamaConfig, LlamaForCausalLM, llama_rules, lora_trainable)
+    from distributeddeeplearningspark_tpu.parallel.mesh import MeshSpec
+    from distributeddeeplearningspark_tpu.train import (
+        losses, optim, step as step_lib)
+    from distributeddeeplearningspark_tpu.utils.memory import (
+        GiB, llama_memory_report)
+
+    b, s = 4, 2048
+    cfg = LlamaConfig(
+        vocab_size=32000, hidden_size=2048, num_layers=16, num_heads=16,
+        num_kv_heads=8, intermediate_size=5632, max_position=s, lora_rank=16,
+        dtype="bfloat16", param_dtype="bfloat16", remat_policy="dots")
+    model = LlamaForCausalLM(cfg)
+    mesh = MeshSpec(data=1).build(four_chips[:1])
+    tx = optim.masked(optax.adamw(1e-4), lora_trainable)
+
+    def init_fn(rng):
+        variables = dict(model.init(
+            {"params": rng, "dropout": rng},
+            {"input_ids": jnp.zeros((b, s), jnp.int32)}, train=False))
+        params = variables.pop("params")
+        return step_lib.TrainState.create(
+            params=params, opt_state=tx.init(params), mutable=variables,
+            rng=rng, embed_state={})
+
+    abstract = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+    shardings = step_lib.state_shardings(abstract, mesh, llama_rules(cfg))
+    step = step_lib.jit_train_step(
+        step_lib.make_train_step(model.apply, tx, losses.causal_lm,
+                                 trainable=lora_trainable),
+        mesh, shardings)
+    batch = {"input_ids": jax.ShapeDtypeStruct((b, s), jnp.int32),
+             "loss_mask": jax.ShapeDtypeStruct((b, s), jnp.float32)}
+    compiled = step.lower(abstract, batch).compile()
+    # the program the chip runs: its router sends s=2048 to the flash kernel
+    assert "flash_fwd" in compiled.as_text()
+    ma = compiled.memory_analysis()
+    # donation aliases the state into the outputs: live bytes are the larger
+    # of the two plus the temporaries, not their sum
+    live = (max(ma.argument_size_in_bytes, ma.output_size_in_bytes)
+            + ma.temp_size_in_bytes)
+    budget = llama_memory_report(cfg, batch=b, seq=s, mesh_shape={})
+    assert budget.fits(16 * GiB), budget.to_dict()
+    assert abs(budget.total_bytes - live) / live < 0.10, (
+        budget.to_dict(), live / GiB)

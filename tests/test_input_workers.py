@@ -463,7 +463,7 @@ def jpeg_root(tmp_path_factory):
     return str(root)
 
 
-# Quarantine of the environmental byte-identity flake (SMOKE_LOG/ROADMAP:
+# Quarantine of the environmental byte-identity flake (ROADMAP:
 # fails identically on clean HEAD and polluted every tier-1 read). Probed
 # root cause on the shared CI box: the THREAD-POOL in-process arm
 # (``map_parallel`` with its default thread count) is nondeterministic

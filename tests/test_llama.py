@@ -355,12 +355,11 @@ class TestInt8Base:
                                    rq["total_gib_per_chip"])
 
     def test_quality_bound_at_bench_geometry(self):
-        """End-to-end quality bound at the REAL 0.9b bench geometry
-        (VERDICT r4 next-#4: the 5%-on-tiny-logits absmax argument was too
-        loose to say anything about config-5 quality). Quantizes a full
-        0.9b tree (hidden 2048 × 16 layers × vocab 32k, the exact
-        `bench._llama_09b_cfg` shape so it can't drift from the measured
-        series) and asserts the next-token cross-entropy delta on a
+        """End-to-end quality bound at a real 0.9b geometry (the
+        5%-on-tiny-logits absmax argument was too loose to say anything
+        about config-5 quality). Quantizes a full 0.9b tree (hidden 2048 ×
+        16 layers × vocab 32k, the shape ``tests/test_compile_for_v5e.py``
+        compiles for the chip) and asserts the next-token cross-entropy delta on a
         held-out synthetic corpus slice through the real `lm_dataset`
         path. Measured when written: ΔCE = +0.0024 nats (ppl ratio
         1.0024); the 0.01-nat bound is 4× that — tight enough to catch a
@@ -372,12 +371,14 @@ class TestInt8Base:
         ~2.5 min on one CPU core (two 0.9b forwards + quantize)."""
         import dataclasses
 
-        import bench
         from distributeddeeplearningspark_tpu.data import text as text_lib
 
         s = 128
-        cfg_d = dataclasses.replace(bench._llama_09b_cfg(seq=s), remat=False)
-        assert (cfg_d.hidden_size, cfg_d.num_layers) == (2048, 16)
+        cfg_d = LlamaConfig(
+            vocab_size=32000, hidden_size=2048, num_layers=16, num_heads=16,
+            num_kv_heads=8, intermediate_size=5632, max_position=s,
+            lora_rank=16, dtype="bfloat16", param_dtype="bfloat16",
+            remat=False, remat_policy="dots")
         model_d = LlamaForCausalLM(cfg_d)
         docs = text_lib.synthetic_wikipedia(12, num_partitions=1, seed=7)
         tok = text_lib.WordPieceTokenizer.train(docs.collect(),

@@ -111,8 +111,7 @@ def test_op_breakdown_parses_cpu_trace(tmp_path):
     """op_breakdown must read a real capture without TensorBoard's converter:
     aggregate per-op times from the busiest line and report a sane budget
     (CPU traces carry host/TFRT lines rather than a TPU 'XLA Ops' line —
-    the fallback path; the device path was exercised on the real chip, see
-    BASELINE.md r2 roofline entry)."""
+    the fallback path)."""
     d = str(tmp_path / "prof")
     with profiling.trace(d):
         x = jnp.ones((128, 128))

@@ -1,7 +1,7 @@
 """ResNet family + image pipeline tests (config 2, SURVEY.md §4).
 
 Small variants / tiny images keep CPU compile time bounded; the full
-ResNet-50 shape is exercised by bench.py on the real chip.
+ResNet-50 shape runs on the chip in the cell ``resnet50_imagenet.fit_jpeg``.
 """
 
 import jax

@@ -1,259 +1,28 @@
 #!/usr/bin/env python
-"""perf_guard — cross-run performance regression sentinel over BENCH records.
+"""perf_guard — within-run decline sentinel over a run's series store.
 
-The repo accumulates one ``BENCH_r<NN>.json`` per round, and until now a
-15% step-time regression (or a recompile creeping into a steady workload)
-was only caught by a human rereading them. This tool folds the rolling
-history into a baseline and verdicts the current round against it:
-
-- **Baseline** = the median of each comparable prior record's value for a
-  metric (medians shrug off one outlier round; ``bench.is_good_record``'s
-  rule decides which records count — rc 0, a real metric, not
-  ``bench_failed``/``backend_unavailable``). Records are comparable only
-  within one ``parsed.metric`` name and one ``extra.backend`` — a
-  host-degraded round must never be judged against chip numbers.
-- **Checks**: the headline ``parsed.value`` plus, per bench arm
-  (resnet50 / bert_base_mlm / llama_lora / llama_decode / dlrm /
-  input_pipeline), the direction-aware field set — throughput and MFU
-  regress when they *drop*, ``step_time_ms`` and ``compile_s`` when they
-  *grow*, and a nonzero ``recompile_count`` over a zero baseline is an
-  immediate regression (no band: a recompile storm is never noise).
-- **Noise band**: ``--band`` (default 15%) — a delta inside it is noise,
-  outside it a verdict. ``step_time_ms`` widens its band to the current
-  record's own measured ``spread_pct`` when that is larger (the record is
-  self-describing about its noise floor), and ``compile_s`` uses 3× the
-  band (compile times swing with host load).
-
-Verdicts: ``OK`` (rc 0), ``REGRESSED`` (rc 1, every tripped check named),
-``INSUFFICIENT_HISTORY`` (rc 0 — fewer than ``--min-history`` comparable
-prior records for every check; the sentinel refuses to guess).
+The health engine (``dlstatus WORKDIR --health``) records each run's series
+(``telemetry/series.py``). This tool splits every guarded series into time
+quartiles and compares the last quartile's mean with the first's: a decline,
+direction-aware, past ``--band`` (default 15%) is ``REGRESSED`` (rc 1) and
+names the series; ``OK`` and ``INSUFFICIENT_HISTORY`` (fewer than
+:data:`SERIES_MIN_BUCKETS` buckets in every guarded series) exit 0; no store
+under WORKDIR exits 2.
 
 ::
 
-    python tools/perf_guard.py                  # repo history, newest = current
-    python tools/perf_guard.py --current B.json # explicit candidate record
-    python tools/perf_guard.py --dir /tmp/hist --band 0.10 --json
-    python tools/perf_guard.py --series WORKDIR # within-run decline from the
-                                                # series store (quartile vs
-                                                # quartile, e.g. steps/sec
-                                                # ≥15%% down -> REGRESSED)
+    python tools/perf_guard.py --series WORKDIR [--band 0.10] [--json]
 
-Wired as ``tools/ci.sh perf-guard``: the current history must pass, and a
-synthetic 20%-slower record must trip rc≠0. jax-free by construction (it
-reads JSON files; CI runs it on any box).
+Speed across PRs is not judged here: the driver measures every cell of
+``BENCHMARK.json`` on the chip and keeps ``PERF_LEDGER.jsonl``.
 """
 
 from __future__ import annotations
 
 import argparse
-import glob
 import json
-import os
-import re
-import statistics
 import sys
 from typing import Any
-
-#: per-arm numeric fields guarded, with their regression direction.
-#: The shuffle fields carry their transport tag IN the name
-#: (``shuffle_columnar_keys_per_sec``, not a shared ``shuffle_keys_per_
-#: sec``) — that is the baseline scoping: a check only folds history
-#: records that measured the SAME transport arm, so pre-columnar rounds
-#: (which have no tagged fields) contribute nothing and the new arms are
-#: never judged against the tuple ceiling (they sit at
-#: insufficient-history until two tagged rounds exist).
-HIGHER_BETTER = ("images_per_sec_per_chip", "tokens_per_sec_per_chip",
-                 "examples_per_sec_per_chip", "host_images_per_sec",
-                 "decode_tokens_per_sec_per_chip", "mfu", "mfu_model",
-                 "shuffle_tuple_keys_per_sec",
-                 "shuffle_columnar_keys_per_sec",
-                 "shuffle_device_keys_per_sec",
-                 "columnar_speedup_vs_tuple",
-                 # the measured-layout-search winner's rate carries its own
-                 # name (NOT a shared steps_per_sec) — same scoping rule as
-                 # the shuffle transports: pre-plan BENCH history has no
-                 # such field, so the new series is never judged against an
-                 # incomparable baseline
-                 "plan_sweep_best_steps_per_sec",
-                 "steps_per_sec")
-#: pipeline_bubble_frac: idle fraction of the MPMD stage pipeline —
-#: growth means the transport or the 1F1B/GPipe schedule regressed even
-#: when wall-clock noise hides it in steps/sec.
-#: shuffle_recovery_overhead_pct: faulted-vs-clean wall-clock delta of
-#: the kill-a-mapper-and-a-reducer shuffle drill (ISSUE 14) — growth
-#: means lineage replay / retained-frame rebuild got more expensive.
-LOWER_BETTER = ("step_time_ms", "compile_s", "pipeline_bubble_frac",
-                "shuffle_recovery_overhead_pct")
-#: winner_rerun_new_compiles: re-running a plan sweep's winner on its kept
-#: executable must compile NOTHING — a nonzero count over a clean baseline
-#: means plan pinning broke (the sweep's whole point).
-ZERO_EXPECTED = ("recompile_count", "winner_rerun_new_compiles")
-
-#: bench arms whose records carry the fields above (bench.py `want` names).
-ARMS = ("resnet50", "bert_base_mlm", "llama_lora", "llama_decode", "dlrm",
-        "input_pipeline", "mpmd_pipeline", "plan_sweep")
-
-#: compile times swing with host load far more than steady-state step time.
-COMPILE_BAND_FACTOR = 3.0
-
-
-def _round_of(path: str) -> int:
-    m = re.search(r"r(\d+)", os.path.basename(path))
-    return int(m.group(1)) if m else -1
-
-
-def is_good_record(rc: int, parsed: Any) -> bool:
-    """bench.is_good_record's rule, restated jax-free (one semantic: a
-    record counts only when it is citable evidence, not a failure shape)."""
-    if rc != 0 or not isinstance(parsed, dict) or "metric" not in parsed:
-        return False
-    if parsed["metric"] in ("bench_failed", "backend_unavailable"):
-        return False
-    if (parsed["metric"] == "pallas_kernels_compiled"
-            and not parsed.get("value")):
-        return False
-    return True
-
-
-def load_record(path: str) -> dict | None:
-    """One BENCH file → its ``parsed`` payload (accepts both the driver
-    wrapper ``{"rc", "parsed": {...}}`` and a bare bench JSON line)."""
-    try:
-        with open(path) as f:
-            raw = json.load(f)
-    except (OSError, ValueError):
-        return None
-    if isinstance(raw, dict) and "parsed" in raw:
-        rc = int(raw.get("rc", 1))
-        parsed = raw.get("parsed")
-    else:
-        rc, parsed = 0, raw
-    if not is_good_record(rc, parsed):
-        return None
-    return parsed
-
-
-def _num(v: Any) -> float | None:
-    if isinstance(v, bool) or not isinstance(v, (int, float)):
-        return None
-    return float(v)
-
-
-#: headline metric-name suffixes whose direction is unambiguous
-#: (throughput: higher is better). Other headline metrics — e.g.
-#: memory_model_vs_compiler_pct, a signed delta where motion toward 0 is
-#: the improvement — have no guardable direction and are skipped rather
-#: than judged with an inverted verdict.
-_THROUGHPUT_SUFFIXES = ("_per_sec", "_per_chip", "_per_host")
-
-
-def _fields_of(parsed: dict) -> dict[str, float]:
-    """Flatten one record into {check name: value} (the guarded subset)."""
-    out: dict[str, float] = {}
-    v = _num(parsed.get("value"))
-    metric = str(parsed.get("metric") or "")
-    if v is not None and metric.endswith(_THROUGHPUT_SUFFIXES):
-        out[f"value:{metric}"] = v
-    extra = parsed.get("extra") or {}
-    for arm in ARMS:
-        rec = extra.get(arm)
-        if not isinstance(rec, dict):
-            continue
-        for key in HIGHER_BETTER + LOWER_BETTER + ZERO_EXPECTED:
-            x = _num(rec.get(key))
-            if x is not None:
-                out[f"{arm}.{key}"] = x
-        sp = _num(rec.get("spread_pct"))
-        if sp is not None:
-            out[f"{arm}.spread_pct"] = sp  # band widening, never checked
-    return out
-
-
-def _direction(check: str) -> str:
-    key = check.split(".", 1)[-1]
-    if check.startswith("value:") or key in HIGHER_BETTER:
-        return "higher"
-    if key in ZERO_EXPECTED:
-        return "zero"
-    return "lower"
-
-
-def guard(current: dict, history: list[dict], *, band: float = 0.15,
-          min_history: int = 2) -> dict:
-    """Judge ``current`` against ``history`` (prior parsed records).
-
-    Pure function (the tests drive it on synthetic records); the CLI wraps
-    it with file loading. Returns the verdict report."""
-    backend = (current.get("extra") or {}).get("backend")
-    metric = current.get("metric")
-    prior = [p for p in history
-             if p.get("metric") == metric
-             and (p.get("extra") or {}).get("backend") == backend]
-    cur_fields = _fields_of(current)
-    prior_fields = [_fields_of(p) for p in prior]
-    checks: list[dict] = []
-    for check, cur in sorted(cur_fields.items()):
-        if check.endswith(".spread_pct"):
-            continue
-        history_vals = [f[check] for f in prior_fields if check in f]
-        direction = _direction(check)
-        row: dict[str, Any] = {
-            "check": check, "direction": direction, "current": cur,
-            "history": len(history_vals),
-        }
-        if len(history_vals) < min_history:
-            row["status"] = "insufficient-history"
-            checks.append(row)
-            continue
-        base = statistics.median(history_vals)
-        row["baseline"] = base
-        if direction == "zero":
-            # a recompile over a clean baseline is never noise
-            row["status"] = ("REGRESSED" if cur > 0 and base == 0
-                             else "ok")
-            checks.append(row)
-            continue
-        eff_band = band
-        key = check.split(".", 1)[-1]
-        if key in ("compile_s", "shuffle_recovery_overhead_pct"):
-            # both swing with host load far more than steady-state
-            # throughput: compile times, and a single faulted-vs-clean
-            # wall-clock ratio whose numerator includes fork/respawn
-            # latency and poll cadences
-            eff_band = band * COMPILE_BAND_FACTOR
-        elif key == "step_time_ms":
-            arm = check.split(".", 1)[0]
-            spread = cur_fields.get(f"{arm}.spread_pct")
-            if spread is not None:
-                eff_band = max(eff_band, spread / 100.0)
-        row["band"] = round(eff_band, 4)
-        if base == 0:
-            row["status"] = "ok"  # nothing to regress from
-            checks.append(row)
-            continue
-        delta = (cur - base) / abs(base)
-        row["delta_pct"] = round(100.0 * delta, 2)
-        worse = -delta if direction == "higher" else delta
-        row["status"] = "REGRESSED" if worse > eff_band else "ok"
-        checks.append(row)
-    regressed = [c for c in checks if c["status"] == "REGRESSED"]
-    judged = [c for c in checks if c["status"] != "insufficient-history"]
-    if regressed:
-        verdict = "REGRESSED"
-    elif judged:
-        verdict = "OK"
-    else:
-        verdict = "INSUFFICIENT_HISTORY"
-    return {
-        "verdict": verdict,
-        "metric": metric,
-        "backend": backend,
-        "band": band,
-        "comparable_history": len(prior),
-        "checks": checks,
-        "regressed": [c["check"] for c in regressed],
-    }
-
 
 #: series the within-run judge guards, with direction (names from
 #: telemetry/series.py; the store's per-replica/tenant keys are matched by
@@ -278,8 +47,8 @@ def guard_series(buckets_by_key: dict[str, list[dict]], *,
     compare the last quartile's mean against the first's — a decline
     (direction-aware) past ``band`` is REGRESSED naming the series. Pure
     function over a :func:`telemetry.series.read_buckets` result; the CLI
-    wraps it with ``--series WORKDIR``. Same verdict ladder as
-    :func:`guard`."""
+    wraps it with ``--series WORKDIR``. ``INSUFFICIENT_HISTORY`` when no
+    guarded series has :data:`SERIES_MIN_BUCKETS` buckets yet."""
     checks: list[dict] = []
     for key, bs in sorted(buckets_by_key.items()):
         base_name = key.split("{", 1)[0]
@@ -331,9 +100,18 @@ def guard_series(buckets_by_key: dict[str, list[dict]], *,
     }
 
 
-def _series_main(args) -> int:
-    """``--series WORKDIR``: judge within-run decline from the store the
-    health engine recorded (no BENCH records involved)."""
+def main(argv: list[str] | None = None) -> int:
+    ap = argparse.ArgumentParser(
+        prog="perf_guard",
+        description="Within-run decline sentinel over a series store.")
+    ap.add_argument("--series", metavar="WORKDIR", required=True,
+                    help="judge within-run decline from WORKDIR's series "
+                         "store (last quartile vs first quartile of each "
+                         "guarded series, finest resolution)")
+    ap.add_argument("--band", type=float, default=0.15,
+                    help="noise band as a fraction (default 0.15 = 15%%)")
+    ap.add_argument("--json", action="store_true")
+    args = ap.parse_args(argv)
     from distributeddeeplearningspark_tpu.telemetry import series as series_lib
 
     ladder = series_lib.list_resolutions(args.series)
@@ -360,81 +138,6 @@ def _series_main(args) -> int:
                          f"  last-quartile={c['last_quartile_mean']}")
             if c.get("delta_pct") is not None:
                 line += f"  delta={c['delta_pct']:+.1f}%"
-            print(line)
-        if rep["regressed"]:
-            print(f"perf_guard: REGRESSED on {', '.join(rep['regressed'])}")
-    return 1 if rep["verdict"] == "REGRESSED" else 0
-
-
-def main(argv: list[str] | None = None) -> int:
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    ap = argparse.ArgumentParser(
-        prog="perf_guard",
-        description="Cross-run perf regression sentinel over BENCH records.")
-    ap.add_argument("--dir", default=here,
-                    help="directory holding the BENCH history "
-                         "(default: repo root)")
-    ap.add_argument("--glob", default="BENCH_*.json",
-                    help="history file pattern (default BENCH_*.json)")
-    ap.add_argument("--current", default=None,
-                    help="candidate record file (default: the newest "
-                         "round in the history)")
-    ap.add_argument("--band", type=float, default=0.15,
-                    help="noise band as a fraction (default 0.15 = 15%%)")
-    ap.add_argument("--min-history", type=int, default=2,
-                    help="comparable prior records a check needs "
-                         "(default 2)")
-    ap.add_argument("--series", metavar="WORKDIR", default=None,
-                    help="judge within-run decline from WORKDIR's series "
-                         "store (last quartile vs first quartile of each "
-                         "guarded series, finest resolution) instead of "
-                         "cross-round BENCH records")
-    ap.add_argument("--json", action="store_true")
-    args = ap.parse_args(argv)
-
-    if args.series is not None:
-        return _series_main(args)
-    paths = sorted(glob.glob(os.path.join(args.dir, args.glob)),
-                   key=_round_of)
-    if args.current:
-        cur = load_record(args.current)
-        cur_path = args.current
-        hist_paths = [p for p in paths
-                      if os.path.abspath(p) != os.path.abspath(args.current)]
-    else:
-        good = [(p, load_record(p)) for p in paths]
-        good = [(p, r) for p, r in good if r is not None]
-        if not good:
-            print("perf_guard: no usable BENCH records in "
-                  f"{args.dir}/{args.glob}", file=sys.stderr)
-            return 2
-        cur_path, cur = good[-1]
-        hist_paths = [p for p, _ in good[:-1]]
-    if cur is None:
-        print(f"perf_guard: current record {cur_path} is not a good bench "
-              f"record (failed round / wrong shape)", file=sys.stderr)
-        return 2
-    history = [r for r in (load_record(p) for p in hist_paths)
-               if r is not None]
-    rep = guard(cur, history, band=args.band, min_history=args.min_history)
-    rep["current_file"] = cur_path
-    if args.json:
-        print(json.dumps(rep))
-    else:
-        print(f"perf_guard: {rep['verdict']}  metric={rep['metric']}  "
-              f"backend={rep['backend']}  "
-              f"history={rep['comparable_history']} comparable record(s)  "
-              f"band={100 * args.band:.0f}%")
-        for c in rep["checks"]:
-            base = c.get("baseline")
-            line = (f"  [{c['status']:>22}] {c['check']}: "
-                    f"current={c['current']}")
-            if base is not None:
-                line += f"  baseline={round(base, 4)}"
-            if c.get("delta_pct") is not None:
-                line += f"  delta={c['delta_pct']:+.1f}%"
-            if c.get("band") is not None:
-                line += f"  band=±{100 * c['band']:.0f}%"
             print(line)
         if rep["regressed"]:
             print(f"perf_guard: REGRESSED on {', '.join(rep['regressed'])}")

@@ -48,8 +48,8 @@ if _HERE not in sys.path:  # runnable as a script from anywhere
 
 def _build_batch(cfg, batch_size: int, seq: int):
     """Deterministic content-addressed probe batch: every plan probes the
-    SAME bytes, and the digest rides every report so cross-round numbers
-    (bench.py's ``plan_sweep`` arm) are comparable by construction."""
+    SAME bytes, and the digest rides every report so numbers from
+    different runs are comparable by construction."""
     import numpy as np
 
     ids = np.stack([np.full((seq,), i % cfg.vocab_size, np.int32)

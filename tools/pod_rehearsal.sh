@@ -1,7 +1,7 @@
 #!/usr/bin/env bash
-# v4-32 launch rehearsal on the fake mesh (VERDICT r4 next-#9): pod time —
-# whenever it exists — must start from a TESTED script, not playbook prose.
-# Three acts, all executable with zero TPU hardware:
+# v4-32 launch rehearsal on the fake mesh: pod time — whenever it exists —
+# must start from a TESTED script, not playbook prose. Two acts, both
+# executable with zero TPU hardware:
 #
 #   1. The v4-32 PROCESS GEOMETRY: a 4-host × 8-device gang (32 global
 #      devices) launched exactly the way docs/POD_PLAYBOOK.md launches a
@@ -12,33 +12,23 @@
 #      fake devices through the real driver flags (fsdp=16 tensor=2 here —
 #      the tiny variant has 2 kv heads; the POD_PLAYBOOK 7B row's
 #      tensor=4 divides its 32 kv heads fine on a real pod).
-#   3. INPUT SIZING: measures this host's record-path rate through the
-#      real pipeline and prints the per-host thread budget the 4-host pod
-#      needs to feed 32 chips × 2500 img/s (PERFORMANCE.md's ~80k img/s
-#      host math) — the check that the feeding plan is arithmetic, not
-#      hope.
 #
-#   bash tools/pod_rehearsal.sh           # all three acts (~6 min, 1 core)
-#   bash tools/pod_rehearsal.sh 1 3       # a subset
+#   bash tools/pod_rehearsal.sh           # both acts
+#   bash tools/pod_rehearsal.sh 2         # one
 #
-# Appends one audit row per act to SMOKE_LOG.md.
+# Prints one row per act.
 set -u -o pipefail
 cd "$(dirname "$0")/.."
 export JAX_PLATFORMS=cpu
 export PYTHONPATH="$(pwd):${PYTHONPATH:-}"
 
-[ -f SMOKE_LOG.md ] || {
-  printf '# Driver smoke log (tools/smoke.sh)\n\n| when (UTC) | driver | ok | wall |\n|---|---|---|---|\n' > SMOKE_LOG.md
-}
-
 log_row() {  # name, ok, secs
   printf '| %s | %s | %s | %ss |\n' \
-    "$(date -u +%Y-%m-%dT%H:%M:%SZ)" "$1" "$2" "$3" >> SMOKE_LOG.md
-  echo "[$1] $2 (${3}s)"
+    "$(date -u +%Y-%m-%dT%H:%M:%SZ)" "$1" "$2" "$3"
 }
 
 overall=0
-if [ $# -eq 0 ]; then set -- 1 2 3; fi
+if [ $# -eq 0 ]; then set -- 1 2; fi
 for act in "$@"; do
   t0=$(date +%s)
   case "$act" in
@@ -70,34 +60,8 @@ for act in "$@"; do
       name="pod-rehearsal-2 (fsdp=16 x tensor=2, config-5)"
       pat="tokens_per_sec_per_chip"
       ;;
-    3)
-      out=$(python - <<'EOF' 2>&1
-import json, subprocess, sys
-r = subprocess.run(
-    [sys.executable, "bench.py", "--model", "input", "--iters", "2"],
-    capture_output=True, text=True, timeout=900)
-rec = json.loads(r.stdout.strip().splitlines()[-1])
-ip = rec["extra"]["input_pipeline"]
-rate = ip["record_batched_images_per_sec"]
-chips, per_chip, hosts = 32, 2500.0, 4
-need_per_host = chips * per_chip / hosts
-threads = need_per_host / max(rate, 1e-9)
-print(f"measured record-path rate: {rate:.1f} img/s on 1 core")
-print(f"pod demand: {chips} chips x {per_chip:.0f} img/s / {hosts} hosts "
-      f"= {need_per_host:.0f} img/s/host")
-print(f"thread budget: ceil({need_per_host:.0f}/{rate:.1f}) = "
-      f"{int(-(-need_per_host // max(rate, 1e-9)))} GIL-releasing decode "
-      f"threads/host (v4 hosts have 120 cores: "
-      f"{'FEASIBLE' if need_per_host / max(rate, 1e-9) < 120 else 'NOT FEASIBLE'})")
-print("input sizing ok")
-EOF
-)
-      rc=$?
-      name="pod-rehearsal-3 (input sizing)"
-      pat="input sizing ok"
-      ;;
     *)
-      echo "unknown act '$act'; valid: 1 2 3" >&2; exit 2 ;;
+      echo "unknown act '$act'; valid: 1 2" >&2; exit 2 ;;
   esac
   secs=$(( $(date +%s) - t0 ))
   if [ $rc -eq 0 ] && grep -q "$pat" <<<"$out"; then

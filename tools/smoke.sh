@@ -3,7 +3,7 @@
 # 8-device CPU mesh (the .claude/skills/verify playbook, executable).
 # Each driver must finish AND print its final-metrics line; MNIST must
 # actually learn (accuracy 1.0 on the synthetic set — the PR1 acceptance
-# shape). Appends one audit line per driver to SMOKE_LOG.md.
+# shape). Prints one row per driver.
 #
 #   bash tools/smoke.sh          # all five (~10 min on one contended core)
 #   bash tools/smoke.sh mnist [bert ...]   # a subset
@@ -24,10 +24,6 @@ CMD[dlrm]="python examples/train_dlrm.py --master local[2] --steps 30 --batch-si
 GREP[dlrm]="eval AUC"
 CMD[llama]="python examples/train_llama_lora.py --master local[2] --expert 2 --moe-experts 4 --segment-ids --steps 4"
 GREP[llama]="moe_aux"
-
-[ -f SMOKE_LOG.md ] || {
-  printf '# Driver smoke log (tools/smoke.sh)\n\n| when (UTC) | driver | ok | wall |\n|---|---|---|---|\n' > SMOKE_LOG.md
-}
 
 # "${@:-...}" expands to ONE word when $@ is empty, which sent the whole
 # default list into the unknown-driver branch (ADVICE r4, confirmed by
@@ -52,7 +48,6 @@ for d in "$@"; do
     echo "---- $d failed; last lines:"; tail -5 <<<"$out"
   fi
   printf '| %s | %s | %s | %ss |\n' \
-    "$(date -u +%Y-%m-%dT%H:%M:%SZ)" "$d" "$ok" "$secs" >> SMOKE_LOG.md
-  echo "[$d] $ok (${secs}s)"
+    "$(date -u +%Y-%m-%dT%H:%M:%SZ)" "$d" "$ok" "$secs"
 done
 exit $overall
