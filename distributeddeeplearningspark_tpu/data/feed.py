@@ -22,6 +22,7 @@ each host only materializes its addressable shard of the global batch.
 from __future__ import annotations
 
 import itertools
+import sys
 from typing import Any, Iterable, Iterator
 
 import jax
@@ -69,10 +70,147 @@ def stack_examples(examples: list[dict[str, Any]]) -> dict[str, np.ndarray]:
 
 
 def _stack(examples: list[dict[str, Any]]) -> dict[str, np.ndarray]:
-    """``stack_examples`` as the feed calls it: one ``dls.feed/stack``
-    section of the probe whose thread pulls these batches, if any."""
+    """``stack_examples`` as the feed's remainder paths call it: one
+    ``dls.feed/stack`` section of the probe whose thread pulls these
+    batches, if any."""
     with spans.span("dls.feed/stack", spans.bound_sink()):
         return stack_examples(examples)
+
+
+def _most_referred(arrays: dict[str, np.ndarray]) -> int:
+    return max(map(sys.getrefcount, arrays.values()), default=0)
+
+
+#: what :func:`_most_referred` reads of arrays that only their dict refers to
+_UNSHARED = _most_referred({"x": np.empty(0)})
+#: batches of arrays a stream keeps to fill again: the default prefetch
+#: ring's three, the one its consumer holds and the one being filled
+_KEPT_SLOTS = 5
+#: examples are copied into their batch this many bytes at a time: one image
+#: as it arrives, a whole batch of token windows with one ``np.stack`` a leaf
+_COPY_BYTES = 1 << 20
+
+
+class _Slots:
+    """The memory one ``host_batches`` stream fills its batches into.
+
+    A slot is the arrays of one batch. The stream keeps up to
+    ``_KEPT_SLOTS`` of them and writes a slot again only when nothing but
+    the slot itself refers to its arrays, which it reads off their
+    reference counts: a batch somebody kept, a view of one, a
+    ``device_put`` still reading one (jax holds the numpy argument until
+    the transfer is done) and a CPU device array aliasing one all count.
+    Where every kept slot is referred to, the batch gets new memory, as
+    every batch did before there were slots.
+    """
+
+    def __init__(self) -> None:
+        self._signature: tuple | None = None
+        self._kept: list[dict[str, np.ndarray]] = []
+        #: examples of the last batch's size that make ``_COPY_BYTES``
+        self.examples_a_copy = 1
+
+    def take(self, first: dict[str, Any], rows: int,
+             sink) -> dict[str, np.ndarray]:
+        """Arrays of ``rows`` rows shaped like ``first``, a batch's first
+        example (their contents are whatever was there). The section is
+        ``dls.feed/slot_reused`` or ``dls.feed/slot_new``; the feed's
+        probe counts them."""
+        leaves = {k: np.asarray(v) for k, v in first.items()}
+        signature = (rows, *((k, a.shape, a.dtype) for k, a in leaves.items()))
+        if signature != self._signature:  # the stream changed its shapes
+            self._signature, self._kept = signature, []
+            self.examples_a_copy = max(1, _COPY_BYTES // max(
+                1, sum(a.nbytes for a in leaves.values())))
+        free = next((s for s in self._kept if _most_referred(s) == _UNSHARED),
+                    None)
+        with spans.span("dls.feed/slot_reused" if free is not None
+                        else "dls.feed/slot_new", sink):
+            if free is None:
+                free = {k: np.empty((rows, *a.shape), a.dtype)
+                        for k, a in leaves.items()}
+                if len(self._kept) < _KEPT_SLOTS:
+                    self._kept.append(free)
+            # the caller's own dict: what it does to it leaves the slot whole
+            return dict(free)
+
+
+def _copy_rows(arrays: dict[str, np.ndarray], at: int,
+               examples: list[dict[str, Any]]) -> bool:
+    """``examples`` into rows ``at``... of ``arrays``; False where
+    ``np.stack`` would not have given these arrays (a key missing, another
+    shape, a dtype to promote). numpy copies a large row without the
+    interpreter lock."""
+    try:
+        for k, a in arrays.items():
+            rows = [np.asarray(e[k]) for e in examples]
+            if (len(rows) == 1 and rows[0].shape == a.shape[1:]
+                    and rows[0].dtype == a.dtype):
+                # what np.stack would do, without its wrapper: beside a
+                # busy pool that costs the producer 3% of its images
+                a[at] = rows[0]
+            else:
+                np.stack(rows, out=a[at:at + len(rows)], casting="no")
+    except (KeyError, TypeError, ValueError):
+        return False
+    return True
+
+
+def _assemble(segments: list[tuple[Iterator, int, bool]], slots: _Slots,
+              ) -> tuple[dict[str, np.ndarray] | None, list | None]:
+    """One batch out of ``segments``, each ``(stream, rows, local)``.
+
+    ``rows`` examples are pulled from each stream in turn. The local ones
+    are copied into their rows of the batch's arrays as they arrive,
+    ``_COPY_BYTES`` at a time, and let go at once, so a mapping pool works
+    on the next rows meanwhile and a worker pool's ring never carries a
+    batch of views; the others are only walked (every host advances every
+    shard, see :func:`host_batches`). Returns ``(batch, None)``, or
+    ``(None, rest)`` with every example pulled, in order, when a stream ran
+    short: the remainder paths stack those.
+    """
+    sink = spans.bound_sink()
+    local_rows = sum(rows for _, rows, local in segments if local)
+    arrays: dict[str, np.ndarray] | None = None
+    filled = 0
+    loose = False  # some examples did not fit the arrays: np.stack decides
+    parts: list[list | range] = []
+    short = False
+    for stream, rows, local in segments:
+        pulled = itertools.islice(stream, rows)
+        if not local or loose:
+            part: list | range = list(pulled)
+        else:
+            start = filled
+            while group := list(itertools.islice(pulled,
+                                                 slots.examples_a_copy)):
+                with spans.span("dls.feed/stack", sink):
+                    if arrays is None:
+                        arrays = slots.take(group[0], local_rows, sink)
+                    loose = not _copy_rows(arrays, filled, group)
+                if loose:
+                    break
+                filled += len(group)
+            part = range(start, filled)
+            if loose:
+                part = [*_rows_of(arrays, part), *group, *pulled]
+        short |= len(part) < rows
+        parts.append(part)
+    if not short and not loose:
+        return arrays, None
+    examples = [_rows_of(arrays, p) for p in parts]
+    if short:
+        return None, [e for part in examples for e in part]
+    return _stack([e for part, (_, _, local) in zip(examples, segments)
+                   if local for e in part]), None
+
+
+def _rows_of(arrays: dict[str, np.ndarray] | None, part: list | range) -> list:
+    """A segment's examples: themselves, or as views of the rows they were
+    copied to."""
+    if isinstance(part, list):
+        return part
+    return [{k: a[i] for k, a in arrays.items()} for i in part]
 
 
 def _round_robin(iters: list[Iterator]) -> Iterator:
@@ -124,11 +262,20 @@ def host_batches(
 ) -> Iterator[dict[str, np.ndarray]]:
     """Yield stacked host batches from an RDD of example dicts.
 
+    Each batch is what :func:`stack_examples` gives for its examples, filled
+    a row at a time as they arrive (:func:`_assemble`). **Whose memory a
+    batch is:** the caller's, for as long as it refers to it. The stream
+    fills its batches into a few kept sets of arrays (:class:`_Slots`) and
+    writes one again only once nothing refers to its arrays any more: not
+    the batch's dict, not a view of a leaf, not a transfer or a device array
+    that jax made from one. A caller that keeps every batch gets new memory
+    for each, as before.
+
     ``num_workers`` overrides the worker-process count of a pool-backed
     dataset (:class:`~.workers.WorkerMappedDataset`, e.g. from
     ``imagenet_train(num_workers=...)``): the per-example map fans out over
-    that many processes with shared-memory delivery, and ``stack_examples``
-    stacks the ring views straight into the batch. ``None`` keeps the
+    that many processes with shared-memory delivery, and each ring view is
+    copied straight into its row of the batch. ``None`` keeps the
     dataset's own setting (ultimately ``DLS_DATA_WORKERS``); 0 forces the
     in-process path. The batch stream is byte-identical either way —
     ordered delivery is part of the pool contract — so this knob is pure
@@ -170,88 +317,61 @@ def host_batches(
             f"multi-process feed needs batch_size ({batch_size}) divisible by "
             f"num_shards ({num_shards})"
         )
-    aligned = n_parts % num_shards == 0 and batch_size % num_shards == 0
-    if aligned and n_parts > 1:
-        # partition i → shard (i % num_shards); lockstep draw keeps pairing.
-        per_shard = batch_size // num_shards
+    # partitions line up with shards: partition i → shard (i % num_shards),
+    # and a lockstep draw keeps the pairing. Else the chained fallback: every
+    # host walks the same global stream in order and keeps only its shards'
+    # rows — correct but not bandwidth-minimal; align partitions to shards
+    # to avoid it.
+    aligned = (n_parts % num_shards == 0 and batch_size % num_shards == 0
+               and n_parts > 1)
+    per_shard = batch_size // num_shards
+    if aligned:
         # Infinite dataset (.repeat(), the training config): end-of-data can
         # never need cross-host agreement, so this host opens and walks ONLY
         # its own shards' partitions — per-host-local input IO at pod scale
         # (VERDICT r1 weak-5: the lockstep walk is for finite datasets only).
         local_only = (getattr(dataset, "is_infinite", False)
                       and shard_range is not None)
-        groups: list[list[Iterator] | None] = [None] * num_shards
+        segments = []
         for s in range(num_shards):
             if local_only and not (lo <= s < hi):
                 continue
-            groups[s] = [dataset.iter_partition(i)
-                         for i in range(s, n_parts, num_shards)]
-        shard_streams = [
-            None if g is None else (_round_robin(g) if len(g) > 1 else g[0])
-            for g in groups]
-        while True:
-            shard_chunks = []
-            short = False
-            for s in shard_streams:
-                if s is None:  # non-local shard of an infinite dataset
-                    shard_chunks.append([])
-                    continue
-                chunk = list(itertools.islice(s, per_shard))
-                if len(chunk) < per_shard:
-                    short = True
-                shard_chunks.append(chunk)
-            if short:
-                rest = [e for chunk in shard_chunks for e in chunk]
-                if not drop_remainder and pad_remainder and rest:
-                    batch = _pad_to_shards(rest, num_shards)
-                    if shard_range is not None:
-                        per = batch["eval_mask"].shape[0] // num_shards
-                        batch = {k: v[lo * per:hi * per]
-                                 for k, v in batch.items()}
-                    yield batch
-                elif not drop_remainder and shard_range is None:
-                    # legacy mode: keep only what divides evenly across
-                    # shards (GSPMD needs equal shard sizes)
-                    keep = len(rest) - len(rest) % num_shards
-                    if keep:
-                        yield _stack(rest[:keep])
-                return
-            yield checked(_stack(
-                [e for chunk in shard_chunks[lo:hi] for e in chunk]
-            ))
+            g = [dataset.iter_partition(i)
+                 for i in range(s, n_parts, num_shards)]
+            segments.append((_round_robin(g) if len(g) > 1 else g[0],
+                             per_shard, lo <= s < hi))
     else:
-        # chained fallback: every host walks the same global stream in order
-        # and keeps only its shards' rows — correct but not bandwidth-minimal;
-        # align partitions to shards to avoid it.
-        per_shard = batch_size // num_shards if batch_size % num_shards == 0 else None
         stream = itertools.chain.from_iterable(
             dataset.iter_partition(i) for i in range(n_parts)
         )
-        while True:
-            chunk = list(itertools.islice(stream, batch_size))
-            if len(chunk) < batch_size:
-                if chunk and not drop_remainder:
-                    if pad_remainder:
-                        batch = _pad_to_shards(chunk, num_shards)
-                        if shard_range is not None:
-                            per = batch["eval_mask"].shape[0] // num_shards
-                            batch = {k: v[lo * per:hi * per]
-                                     for k, v in batch.items()}
-                        yield batch
-                    elif shard_range is None:
-                        yield _stack(chunk)
-                return
-            if shard_range is not None:
-                assert per_shard is not None
-                chunk = chunk[lo * per_shard:hi * per_shard]
-            out = checked(_stack(chunk))
-            # release the example refs BEFORE the next islice refill: a
-            # worker-pool dataset's examples are views into the shared-
-            # memory ring (data/workers.py), and holding a full batch of
-            # them across the refill would make the ring carry 2× the
-            # batch bytes and stall on backpressure
-            chunk.clear()
-            yield out
+        if shard_range is None:
+            segments = [(stream, batch_size, True)]
+        else:
+            segments = [(stream, lo * per_shard, False),
+                        (stream, (hi - lo) * per_shard, True),
+                        (stream, (num_shards - hi) * per_shard, False)]
+    slots = _Slots()
+    while True:
+        batch, rest = _assemble(segments, slots)
+        if batch is None:
+            break
+        yield checked(batch)
+        # or this frame would still refer to the slot it has just handed on
+        del batch
+    if not rest or drop_remainder:
+        return
+    if pad_remainder:
+        batch = _pad_to_shards(rest, num_shards)
+        if shard_range is not None:
+            per = batch["eval_mask"].shape[0] // num_shards
+            batch = {k: v[lo * per:hi * per] for k, v in batch.items()}
+        yield batch
+    elif shard_range is None:
+        # legacy mode; shards in lockstep keep only what divides evenly
+        # across them (GSPMD needs equal shard sizes)
+        keep = len(rest) - (len(rest) % num_shards if aligned else 0)
+        if keep:
+            yield _stack(rest[:keep])
 
 
 def put_global(

@@ -64,7 +64,12 @@ class StarvationProbe:
     - ``input_assembly_s`` (``dls.feed/assemble``) — producer-side cost of
       building one host batch (decode/augment/stack), measured in the
       background thread; tells you WHY the ring ran dry. Of it,
-      ``input_stack_s`` (``dls.feed/stack``) is ``stack_examples``.
+      ``input_stack_s`` (``dls.feed/stack``) is the copying of the
+      examples into their rows of the batch (``feed._assemble``).
+    - ``input_slot_reused`` / ``input_slot_new`` (``dls.feed/slot_reused``,
+      ``dls.feed/slot_new``) — numbers, not seconds: the batches whose
+      arrays ``host_batches`` took from a slot it kept, and from new memory
+      because every kept slot was still referred to (or there was none yet).
     - ``input_blocked_s`` (``dls.feed/ring_full``) — the producer holding a
       finished batch with no room in the ring: the feed's headroom.
     - ``input_map_s`` (``dls.feed/map``) — thread-seconds inside
@@ -79,20 +84,22 @@ class StarvationProbe:
     ``snapshot(reset=True)`` returns-and-clears, giving per-lap gauges.
     """
 
-    #: the seconds-counters every snapshot carries (``input_map_s`` and
+    #: the counters that are numbers of sections, not their seconds
+    _COUNTED = ("input_slot_reused", "input_slot_new")
+    #: the counters every snapshot carries (``input_map_s`` and
     #: ``input_decode_s`` apart)
     _ALWAYS = ("input_wait_s", "input_put_s", "input_assembly_s",
-               "input_stack_s", "input_blocked_s")
+               "input_stack_s", "input_blocked_s", *_COUNTED)
 
     def __init__(self, clock=time.perf_counter):
         self.clock = clock
         self._lock = threading.Lock()
-        self._seconds: dict[str, float] = {}
+        self._sums: dict[str, float] = {}
         self._zero()
 
     def _zero(self) -> None:
         # a probe that has seen a parallel map, or a decode, keeps its key
-        self._seconds = dict.fromkeys((*self._ALWAYS, *self._seconds), 0.0)
+        self._sums = dict.fromkeys((*self._ALWAYS, *self._sums), 0.0)
         self._waits = 0
         self._wait_max = 0.0
         self._depth_sum = 0
@@ -104,7 +111,8 @@ class StarvationProbe:
         the feed's counters are inclusive, so ``inner_s`` is not taken off."""
         key = spans.COUNTERS[name]
         with self._lock:
-            self._seconds[key] = self._seconds.get(key, 0.0) + dt
+            self._sums[key] = self._sums.get(key, 0.0) + (
+                1 if key in self._COUNTED else dt)
             if key == "input_wait_s":
                 self._waits += 1
                 self._wait_max = max(self._wait_max, dt)
@@ -134,7 +142,7 @@ class StarvationProbe:
         epoch); the wait/assembly keys stay per-lap as before.
         """
         with self._lock:
-            out = {**self._seconds, "input_waits": self._waits,
+            out = {**self._sums, "input_waits": self._waits,
                    "input_wait_max_s": self._wait_max}
             if self._depth_n:
                 out["prefetch_depth_mean"] = self._depth_sum / self._depth_n
@@ -193,7 +201,7 @@ def _background(it: Iterator, *, maxsize: int,
 
     def worker() -> None:
         spans.name_thread("dls-prefetch")
-        # host_batches' stack and map_parallel's calls run under this
+        # host_batches' row copies and map_parallel's calls run under this
         # thread's pulls and have no probe argument: they find it here
         spans.bind_sink(probe)
         try:

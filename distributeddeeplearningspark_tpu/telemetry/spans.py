@@ -43,6 +43,11 @@ COUNTERS = {
     "dls.feed/ring_full": "input_blocked_s",
     "dls.feed/map": "input_map_s",
     "dls.feed/decode": "input_decode_s",
+    # of these two the NUMBER of sections is added, not their time: a batch's
+    # arrays came from a slot the feed kept, or from new memory
+    # (``data/feed._Slots``)
+    "dls.feed/slot_reused": "input_slot_reused",
+    "dls.feed/slot_new": "input_slot_new",
 }
 #: ``EventWriter.phase(name)`` also opens ``dls.phase/<name>`` (trace only)
 PHASE_PREFIX = "dls.phase/"
@@ -113,8 +118,9 @@ def _open_spans() -> list:
 
 def bind_sink(sink) -> None:
     """Make ``sink`` the calling thread's feed accumulator: what the feed's
-    sections that have no ``probe`` argument (``host_batches``' stack,
-    ``map_parallel``'s calls, ``decode_jpeg``) add to. ``None`` unbinds."""
+    sections that have no ``probe`` argument (``host_batches``' row copies
+    and slots, ``map_parallel``'s calls, ``decode_jpeg``) add to. ``None``
+    unbinds."""
     _thread.sink = sink
 
 
