@@ -560,6 +560,7 @@ def packed_token_windows(
     eos_id: int | None = None,
     num_partitions: int = 1,
     key: str = "tokens",
+    segment_ids: bool = False,
 ) -> PartitionedDataset:
     """PRE-tokenized documents -> full causal-LM windows, no tokenizer: what
     LM pre-training reads (token arrays in record shards, ``array_records``).
@@ -569,8 +570,12 @@ def packed_token_windows(
     followed by ``eos_id`` (if given), the stream is packed back to back by
     the one packer (:func:`_pack_token_windows`) and cut into windows of
     exactly ``seq_len``: ``{"input_ids": [seq_len] int32}``, no padding, no
-    loss mask, no segment ids; the stream's tail that does not fill a window
-    is left out. The windows are a function of the document stream alone:
+    loss mask; the stream's tail that does not fill a window is left out.
+    With ``segment_ids`` a window also holds ``"segment_ids": [seq_len]
+    int32`` from the same packer: the document of every position, counted
+    from 0 at the window's first (a document's EOS belongs to it), for models
+    whose operators must not cross a document boundary. The windows are a
+    function of the document stream alone:
     output partition ``p`` of ``num_partitions`` holds the contiguous run
     ``[W*p/P, W*(p+1)/P)`` of the ``W`` windows, so the partitions chained in
     order are byte for byte the same for any ``num_partitions``. The price
@@ -611,10 +616,14 @@ def packed_token_windows(
                     d = np.concatenate((np.asarray(d, np.int32), eos))
                     yield d[skip:] if n == 0 else d
 
-            packed = _pack_token_windows(tail_docs(), seq_len, segments=False)
-            for chunk, _, partial in itertools.islice(packed, w1 - w0):
+            packed = _pack_token_windows(tail_docs(), seq_len,
+                                         segments=segment_ids)
+            for chunk, seg, partial in itertools.islice(packed, w1 - w0):
                 assert not partial
-                yield {"input_ids": chunk.copy()}
+                ex = {"input_ids": chunk.copy()}
+                if segment_ids:
+                    ex["segment_ids"] = seg - seg[0]
+                yield ex
 
         return gen
 

@@ -1,0 +1,321 @@
+"""A decoder of two operators and two feed-forwards in a published pattern:
+gated short convolutions (:mod:`..ops.short_conv`) among causal
+grouped-query attention layers, a dense SwiGLU in the leading layers and
+routed experts behind a sigmoid router (:class:`..models.moe.RoutedExperts`,
+``score="sigmoid"``) in the rest, over PACKED documents that no operator
+crosses. The embedding is the head. Norms and the rotary embedding are the
+Llama path's (:mod:`.llama`), the fused head loss :mod:`..train.fused_ce`'s.
+
+Built from ``layer_types`` (``"conv"`` or ``"full_attention"`` a layer) and
+``num_dense_layers``. One block, ``x [B, S, hidden]``, ``seg[t]`` the
+document of position ``t``, ``pos[t] = t -`` the first position of that
+document in the window::
+
+    h  = x + OP(RMSNorm_op(x));   x' = h + FFN(RMSNorm_ffn(h))
+    OP conv:       (b, c, u) = split3(x Win);  v = b * u
+                   z[t] = sum_j w[:, j] * v[t-K+1+j]  within t's document
+                   OP = (c * z) Wout
+    OP attention:  q, k = rotary(RMSNorm_head(x Wq), pos), rotary(RMSNorm_head(
+                   x Wk), pos);  softmax over {s <= t, seg[s] = seg[t]} of
+                   q.k / sqrt(head_dim);  OP = (p v) Wo
+    FFN dense:     W2(silu(W1 x) * W3 x)                 (the leading layers)
+    FFN experts:   s = sigmoid(x Wg); top-k of s + b; weights s_e / (sum of
+                   the chosen s + 1e-6)
+
+``b`` is no parameter: it lives in the mutable collection
+``moe.BIAS_COLLECTION`` and a training step moves it by its own load counts
+(auxiliary-loss-free balancing), so there is no router loss term.
+
+Layout of the depth: the leading dense layers and whatever does not fill a
+period at the end are unrolled; the whole periods between them are ONE
+``nn.scan`` over a block of ``period`` layers with ``remat`` a layer, so the
+compile time does not grow with depth (the published 40 layers: 2 leading,
+9 periods of ``attention, conv, conv, conv``, 2 trailing).
+
+Batch: ``input_ids [B, S]`` int32 and, optionally, ``segment_ids [B, S]``
+int32 (``data/text.packed_token_windows(segment_ids=True)``), a document's
+positions being consecutive; without them a row is one document.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+from jax.sharding import PartitionSpec as P
+
+from distributeddeeplearningspark_tpu.models.llama import (
+    RMSNorm,
+    rotary_embedding,
+)
+from distributeddeeplearningspark_tpu.models.moe import (
+    BIAS_COLLECTION,
+    RoutedExperts,
+)
+from distributeddeeplearningspark_tpu.ops.attention import dot_product_attention
+from distributeddeeplearningspark_tpu.ops.flash_attention import FLASH_OUT_NAME
+from distributeddeeplearningspark_tpu.ops.short_conv import gated_short_conv
+from distributeddeeplearningspark_tpu.parallel.sharding import ShardingRules
+
+CONV, ATTENTION = "conv", "full_attention"
+#: the step's counters (docs/OBSERVABILITY.md): the first two are means over
+#: the expert layers, the third the largest over them, the last the batch's;
+#: ``losses.hybrid_moe_lm`` carries them into the step's metrics
+COUNTERS = ("moe_load_max_over_mean", "moe_rows_held_share",
+            "router_bias_abs_max", "attn_pairs_share")
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridDecoderConfig:
+    vocab_size: int = 65536
+    hidden_size: int = 2048
+    layer_types: tuple[str, ...] = (
+        (CONV, CONV) + (ATTENTION, CONV, CONV, CONV) * 9 + (ATTENTION, CONV))
+    num_dense_layers: int = 2
+    num_heads: int = 32
+    num_kv_heads: int = 8
+    head_dim: int = 64
+    rope_theta: float = 1e6
+    rms_eps: float = 1e-5
+    max_position: int = 128000
+    conv_taps: int = 3               # conv_L_cache
+    intermediate_size: int = 11776   # the dense layers' SwiGLU
+    # routed experts (models/moe.py RoutedExperts, sigmoid router)
+    num_experts: int = 64            # the router's width
+    experts_per_token: int = 4
+    expert_size: int = 1536
+    experts_held: tuple[int, int] | None = None   # (first, count); None: all
+    norm_topk_prob: bool = True
+    use_expert_bias: bool = True
+    bias_update_rate: float = 0.001
+    # False for a share trained without its exchange (RoutedExperts)
+    train_router: bool = True
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    def __post_init__(self):
+        unknown = set(self.layer_types) - {CONV, ATTENTION}
+        if unknown or not 0 <= self.num_dense_layers <= len(self.layer_types):
+            raise ValueError(f"layer_types {sorted(unknown)} unknown, or "
+                             f"{self.num_dense_layers} dense layers of "
+                             f"{len(self.layer_types)}")
+
+    def layout(self) -> tuple[tuple[str, ...], tuple[str, ...], int,
+                              tuple[str, ...]]:
+        """``(leading dense layers, one period, whole periods, trailing
+        layers)``: the period is the shortest the expert layers repeat
+        with."""
+        lead = self.layer_types[:self.num_dense_layers]
+        rest = self.layer_types[self.num_dense_layers:]
+        if not rest:
+            return lead, (), 0, ()
+        period = next(p for p in range(1, len(rest) + 1)
+                      if all(rest[i] == rest[i - p]
+                             for i in range(p, len(rest))))
+        whole = len(rest) // period
+        return lead, rest[:period], whole, rest[whole * period:]
+
+    @staticmethod
+    def tiny(**kw) -> "HybridDecoderConfig":
+        """One dense layer and two periods of (attention, conv), 128 wide, 8
+        experts of which 2 a token: CPU tests."""
+        base = dict(vocab_size=256, hidden_size=128,
+                    layer_types=(CONV, ATTENTION, CONV, ATTENTION, CONV),
+                    num_dense_layers=1, num_heads=4, num_kv_heads=2,
+                    head_dim=32, intermediate_size=192, num_experts=8,
+                    experts_per_token=2, expert_size=64, max_position=4096,
+                    dtype=jnp.float32)
+        base.update(kw)
+        return HybridDecoderConfig(**base)
+
+
+def _dense(cfg, feats, name, axis=-1):
+    return nn.DenseGeneral(feats, axis=axis, use_bias=False, dtype=cfg.dtype,
+                           param_dtype=cfg.param_dtype, name=name)
+
+
+class ShortConv(nn.Module):
+    """The gated short convolution between its two projections."""
+
+    cfg: HybridDecoderConfig
+
+    @nn.compact
+    def __call__(self, x, seg):
+        cfg = self.cfg
+        bcx = _dense(cfg, 3 * cfg.hidden_size, "in_proj")(x)
+        # taps of a depthwise convolution: fan-in is the number of taps
+        taps = self.param(
+            "taps", nn.initializers.variance_scaling(
+                1.0, "fan_in", "uniform", in_axis=1, out_axis=0),
+            (cfg.hidden_size, cfg.conv_taps), jnp.float32)
+        return _dense(cfg, cfg.hidden_size, "out_proj")(
+            gated_short_conv(bcx, taps, seg))
+
+
+class CausalAttention(nn.Module):
+    """Grouped-query attention inside a document, per-head q/k RMSNorm
+    before the rotary embedding, positions restarting with the document."""
+
+    cfg: HybridDecoderConfig
+
+    @nn.compact
+    def __call__(self, x, seg, pos):
+        cfg = self.cfg
+        q = _dense(cfg, (cfg.num_heads, cfg.head_dim), "wq")(x)
+        k = _dense(cfg, (cfg.num_kv_heads, cfg.head_dim), "wk")(x)
+        v = _dense(cfg, (cfg.num_kv_heads, cfg.head_dim), "wv")(x)
+        q = rotary_embedding(RMSNorm(cfg.rms_eps, cfg.dtype, name="q_norm")(q),
+                             pos, cfg.rope_theta)
+        k = rotary_embedding(RMSNorm(cfg.rms_eps, cfg.dtype, name="k_norm")(k),
+                             pos, cfg.rope_theta)
+        o = dot_product_attention(q, k, v, causal=True, segment_ids=seg)
+        return _dense(cfg, cfg.hidden_size, "wo", axis=(-2, -1))(o)
+
+
+class SwiGLU(nn.Module):
+    cfg: HybridDecoderConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        gate = _dense(cfg, cfg.intermediate_size, "w1")(x)
+        up = _dense(cfg, cfg.intermediate_size, "w3")(x)
+        return _dense(cfg, cfg.hidden_size, "w2")(nn.silu(gate) * up)
+
+
+class HybridLayer(nn.Module):
+    """``(x, seg, pos) -> (x, stats)``; ``stats`` is empty for a dense layer
+    and holds the expert layer's counters otherwise."""
+
+    cfg: HybridDecoderConfig
+    kind: str
+    dense: bool
+
+    @nn.compact
+    def __call__(self, x, seg, pos):
+        cfg = self.cfg
+        h = RMSNorm(cfg.rms_eps, cfg.dtype, name="operator_norm")(x)
+        if self.kind == CONV:
+            x = x + ShortConv(cfg, name="conv")(h, seg)
+        else:
+            x = x + CausalAttention(cfg, name="self_attn")(h, seg, pos)
+        h = RMSNorm(cfg.rms_eps, cfg.dtype, name="ffn_norm")(x)
+        if self.dense:
+            return x + SwiGLU(cfg, name="mlp")(h), {}
+        y, moe = RoutedExperts(
+            cfg.hidden_size, cfg.expert_size, cfg.num_experts,
+            cfg.experts_per_token, held=cfg.experts_held,
+            norm_topk=cfg.norm_topk_prob, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, score="sigmoid",
+            select_bias=cfg.use_expert_bias,
+            bias_update_rate=cfg.bias_update_rate,
+            train_router=cfg.train_router, name="moe")(h)
+        stats = {"moe_load_max_over_mean": moe["load_max_over_mean"],
+                 "moe_rows_held_share": moe["rows_held_share"],
+                 "router_bias_abs_max": moe.get("bias_abs_max",
+                                                jnp.float32(0.0))}
+        return x + y, stats
+
+
+def _layer_cls():
+    # remat a layer, keeping the flash kernel's output and log-sum-exp (bf16
+    # [B, S, heads, head_dim] and a float a row and head): the backward
+    # kernels need those and never the forward pass again. prevent_cse stays
+    # on: the leading and trailing layers are unrolled, and a scan of ONE
+    # period is unrolled by the compiler, where common-subexpression
+    # elimination would merge the replay with the forward pass and keep every
+    # layer's activations after all
+    return nn.remat(
+        HybridLayer,
+        policy=jax.checkpoint_policies.save_only_these_names(FLASH_OUT_NAME))
+
+
+class _Period(nn.Module):
+    """One period of expert layers: the block ``nn.scan`` repeats."""
+
+    cfg: HybridDecoderConfig
+    kinds: tuple[str, ...]
+
+    @nn.compact
+    def __call__(self, x, seg, pos):
+        per_layer = []
+        for j, kind in enumerate(self.kinds):
+            x, stats = _layer_cls()(self.cfg, kind, False,
+                                    name=f"layer_{j}")(x, seg, pos)
+            per_layer.append(stats)
+        return x, jax.tree.map(lambda *a: jnp.stack(a), *per_layer)
+
+
+def document_positions(seg: jax.Array) -> jax.Array:
+    """``[B, S]`` segment ids -> each position's index inside its document
+    (a document's positions being consecutive)."""
+    s = seg.shape[1]
+    idx = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), seg.shape)
+    starts = jnp.concatenate(
+        [jnp.ones_like(seg[:, :1], jnp.bool_), seg[:, 1:] != seg[:, :-1]], 1)
+    return idx - jax.lax.cummax(jnp.where(starts, idx, 0), axis=1)
+
+
+class HybridDecoderLM(nn.Module):
+    """``{"hidden" [B, S, hidden], "lm_head" [hidden, vocab]`` (the
+    embedding, transposed) and the counters :data:`COUNTERS` ``}``."""
+
+    cfg: HybridDecoderConfig
+
+    @nn.compact
+    def __call__(self, batch: dict[str, jax.Array], *, train: bool = False):
+        del train  # no dropout; the router's bias moves when it is mutable
+        cfg = self.cfg
+        ids = batch["input_ids"]
+        if ids.shape[1] > cfg.max_position:
+            raise ValueError(f"sequence length {ids.shape[1]} exceeds "
+                             f"max_position {cfg.max_position}")
+        seg = batch.get("segment_ids")
+        seg = (jnp.zeros(ids.shape, jnp.int32) if seg is None
+               else seg.astype(jnp.int32))
+        pos = document_positions(seg)
+        embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
+                         param_dtype=cfg.param_dtype, name="token_embed")
+        x = embed(ids)
+        lead, period, whole, trail = cfg.layout()
+        collected = []
+        for i, kind in enumerate(lead):
+            x, _ = _layer_cls()(cfg, kind, True, name=f"lead_{i}")(x, seg, pos)
+        if whole:
+            # ("intermediates": ``apply(capture_intermediates=...)`` reaches
+            # the layers inside the scan too)
+            x, stats = nn.scan(
+                _Period, variable_axes={"params": 0, BIAS_COLLECTION: 0,
+                                        "intermediates": 0},
+                split_rngs={"params": True}, in_axes=(nn.broadcast,
+                                                      nn.broadcast),
+                length=whole)(cfg, period, name="periods")(x, seg, pos)
+            collected.append(jax.tree.map(lambda a: a.reshape(-1), stats))
+        for i, kind in enumerate(trail):
+            x, stats = _layer_cls()(cfg, kind, False,
+                                    name=f"trail_{i}")(x, seg, pos)
+            collected.append(jax.tree.map(lambda a: a.reshape(1), stats))
+        x = RMSNorm(cfg.rms_eps, cfg.dtype, name="final_norm")(x)
+        out = {"hidden": x, "lm_head": embed.embedding.T}
+        for name, over_layers in zip(COUNTERS, (jnp.mean, jnp.mean, jnp.max)):
+            out[name] = (over_layers(jnp.concatenate(
+                [c[name] for c in collected]))
+                if collected else jnp.float32(0.0))
+        s = ids.shape[1]
+        out["attn_pairs_share"] = jnp.mean(
+            jnp.sum(pos.astype(jnp.float32) + 1.0, axis=1)) / (s * (s + 1) / 2)
+        return out
+
+
+def hybrid_decoder_rules(cfg: HybridDecoderConfig, *, fsdp: bool = True,
+                         fsdp_min_size: int = 2 ** 14) -> ShardingRules:
+    """Batch-parallel layouts only: the tied embedding over ``tensor`` by its
+    rows, auto-FSDP over the largest dim of what is left. The experts a
+    module holds are ITS rank's; exchanging tokens between ranks over
+    ``expert`` is not built (ROADMAP queue 2, A.1)."""
+    del cfg
+    rules = ((r"token_embed/embedding", P("tensor", None)),)
+    return ShardingRules(rules=rules, fsdp=fsdp, fsdp_min_size=fsdp_min_size)

@@ -1,5 +1,10 @@
-"""Routed experts: SwiGLU experts behind a softmax-then-top-k router, with no
-capacity and nothing dropped, computed for the experts a rank HOLDS.
+"""Routed experts: SwiGLU experts behind a top-k router, with no capacity and
+nothing dropped, computed for the experts a rank HOLDS. Two routers, one
+layer: ``score="softmax"`` (softmax over all experts, then top-k) and
+``score="sigmoid"`` (a sigmoid an expert, the top-k SELECTED on score plus a
+per-expert bias and WEIGHTED by the score without it; the bias is no
+parameter: it lives in the mutable collection :data:`BIAS_COLLECTION` and
+the step's own load counts move it, Wang et al. arXiv:2408.15664).
 
 One layer serves every caller: a model told which contiguous range of the
 experts it holds (:mod:`.sparse_decoder`: one expert-parallel rank's share,
@@ -39,6 +44,12 @@ from distributeddeeplearningspark_tpu.parallel.mesh import (
 
 #: the mesh axes that split TOKENS (batch rows and sequence positions)
 TOKEN_AXES = (*BATCH_AXES, AXIS_SEQ)
+#: the flax collection of the sigmoid router's selection bias: no gradient
+#: reaches it and no optimizer sees it; a step that asks for the collection
+#: as mutable gets it back moved by the step's load (``TrainState.mutable``)
+BIAS_COLLECTION = "router_bias"
+#: added to the sum of a token's top-k sigmoid scores before they divide
+SIGMOID_NORM_EPS = 1e-6
 
 
 def _zero_past(a, used):
@@ -94,15 +105,19 @@ def _rows_to_tokens_bwd(order, g):
 _rows_to_tokens.defvjp(_rows_to_tokens_fwd, _rows_to_tokens_bwd)
 
 
-def _held_experts(xf, router, w_gate, w_up, w_down, first, *, k: int,
-                  norm_topk: bool, dtype, per_rank=lambda a: a):
+def _held_experts(xf, router, w_gate, w_up, w_down, first, bias=None, *,
+                  k: int, norm_topk: bool, dtype, score: str = "softmax",
+                  train_router: bool = True, per_rank=lambda a: a):
     """The part of the layer that the ``n`` experts ``first .. first + n``
     add (``w_*`` are their kernels; ``first`` may be traced): ``xf [T, H] ->
     (y [T, H] float32, assignments of every expert [E] int32, the router's
-    probabilities summed over the tokens [E])``. ``per_rank`` marks the
-    tokens' rows as differing from rank to rank where the ranks' work on them
-    begins (inside a ``shard_map``; the routing before it is every rank's
-    alike)."""
+    probabilities (or sigmoid scores) summed over the tokens [E])``.
+    ``bias [E]`` (sigmoid only) is added to the scores for the SELECTION
+    alone. Without ``train_router`` the weights of a token's experts carry
+    no gradient (neither the router's kernel nor the tokens get one through
+    the routing). ``per_rank`` marks the tokens' rows as differing from rank to rank
+    where the ranks' work on them begins (inside a ``shard_map``; the routing
+    before it is every rank's alike)."""
     tokens, h = xf.shape
     e, n = router.shape[1], w_gate.shape[0]
     # float32 in earnest: on the TPU a float32 product is one bf16 pass
@@ -110,10 +125,22 @@ def _held_experts(xf, router, w_gate, w_up, w_down, first, *, k: int,
     # experts than the model's
     logits = jnp.dot(xf.astype(jnp.float32), router,
                      precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate, expert = jax.lax.top_k(probs, k)                         # [T, k]
-    if norm_topk:
-        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    if score == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate, expert = jax.lax.top_k(probs, k)                     # [T, k]
+        if norm_topk:
+            gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    else:
+        probs = jax.nn.sigmoid(logits)
+        chosen_by = probs if bias is None else (
+            probs + jax.lax.stop_gradient(bias.astype(jnp.float32)))
+        _, expert = jax.lax.top_k(chosen_by, k)
+        gate = jnp.take_along_axis(probs, expert, axis=-1)
+        if norm_topk:
+            gate = gate / (jnp.sum(gate, axis=-1, keepdims=True)
+                           + SIGMOID_NORM_EPS)
+    if not train_router:
+        gate = jax.lax.stop_gradient(gate)
     local = expert - first
     is_held = (local >= 0) & (local < n)
     # sort key: the held expert's index here, experts held elsewhere last
@@ -138,20 +165,20 @@ def _held_experts(xf, router, w_gate, w_up, w_down, first, *, k: int,
     return y, counts, jnp.sum(probs, axis=0)
 
 
-def _split_over_the_mesh(fn, mesh, first: int):
+def _split_over_the_mesh(fn, mesh, first: int, with_bias: bool = False):
     """``fn`` (:func:`_held_experts` but for ``first``) for ``x [B, S, H]``
     on a mesh: batch rows over (data, fsdp), positions over ``seq``, the
     experts' kernels over ``expert`` and their hidden width over ``tensor``.
     A rank computes the part of ITS experts and columns for ITS tokens; the
     parts add up over (expert, tensor), the statistics over the tokens'
     axes."""
-    def local(x, router, w_gate, w_up, w_down):
+    def local(x, router, w_gate, w_up, w_down, *bias):
         b, s, h = x.shape
         mine = first + jax.lax.axis_index(AXIS_EXPERT) * w_gate.shape[0]
         # (the transpose of "differs from rank to rank" is the sum of the
         # ranks' cotangents, which is what a token's gradient is)
         y, counts, probs = fn(
-            x.reshape(-1, h), router, w_gate, w_up, w_down, mine,
+            x.reshape(-1, h), router, w_gate, w_up, w_down, mine, *bias,
             per_rank=lambda a: jax.lax.pcast(
                 a, (AXIS_EXPERT, AXIS_TENSOR), to="varying"))
         return (jax.lax.psum(y, (AXIS_EXPERT, AXIS_TENSOR)).reshape(b, s, h),
@@ -162,30 +189,48 @@ def _split_over_the_mesh(fn, mesh, first: int):
     wide = P(AXIS_EXPERT, None, AXIS_TENSOR)
     return jax.shard_map(
         local, mesh=mesh,
-        in_specs=(tokens, P(), wide, wide, P(AXIS_EXPERT, AXIS_TENSOR, None)),
+        in_specs=(tokens, P(), wide, wide, P(AXIS_EXPERT, AXIS_TENSOR, None),
+                  *([P()] if with_bias else [])),
         out_specs=(tokens, P(), P()))
 
 
 class RoutedExperts(nn.Module):
     """``[B, S, H] -> ([B, S, H], stats)``.
 
-    ``g = softmax(x Wr)`` over all ``num_experts`` in float32; a token's
-    ``top_k`` largest, renormalised to sum 1 (``norm_topk``); ``y = sum over
-    the token's experts that are held of g_e * down_e(silu(gate_e x) *
-    up_e x)``. ``held = (first, count)`` is a contiguous range of experts: an
+    ``score="softmax"``: ``g = softmax(x Wr)`` over all ``num_experts`` in
+    float32; a token's ``top_k`` largest, renormalised to sum 1
+    (``norm_topk``). ``score="sigmoid"``: ``s = sigmoid(x Wr)``; a token's
+    experts are the ``top_k`` largest of ``s + b`` and their weights ``g_e =
+    s_e / (sum over the chosen of s + 1e-6)``, without ``b``
+    (``select_bias``: ``b [num_experts]`` float32 in the collection
+    :data:`BIAS_COLLECTION`, zeros at first; applied with that collection
+    mutable, the layer moves it by ``bias_update_rate * sign(mean_e(c_e) -
+    c_e)``, ``c`` being this call's assignment counts over ALL experts, and
+    otherwise only reads it). Either way ``y = sum over the token's experts
+    that are held of g_e * down_e(silu(gate_e x) * up_e x)``. ``held = (first, count)`` is a contiguous range of experts: an
     expert-parallel rank's share. The router keeps its full width and its k a
     token whatever is held; what the absent experts would have added is left
     out (their ranks add it, and the shares of all ranks sum to the whole
-    layer: ``tests/test_sparse_decoder.py``). ``held=None`` holds them all.
-    On a mesh of more than one device what is held is split once more over
-    the mesh's ``expert`` axis (module docstring).
+    layer: ``tests/test_sparse_decoder.py``, ``tests/test_hybrid_decoder.py``).
+    ``held=None`` holds them all. On a mesh of more than one device what is
+    held is split once more over the mesh's ``expert`` axis (module
+    docstring).
+
+    ``train_router=False`` stops the gradient at the weights ``g``: for a
+    share that is trained WITHOUT its exchange. Such a rank sees of a token's
+    output only what the experts it holds add, and that part of the gradient
+    teaches a router that learns to send its tokens elsewhere (the absent
+    experts add nothing, a held expert at random weights adds noise: on the
+    chip the held share of the assignments fell from an eighth to under
+    0.001% in 40 steps). The bias still moves.
 
     ``stats``: ``aux`` (Switch's balance loss over ALL experts from the top-k
     assignments, ``E * sum_e f_e * P_e`` with ``f_e`` the share of the
     ``T * k`` assignments and ``P_e`` the mean probability: 1 when uniform),
     ``load_max_over_mean`` (rows of the fullest held expert over the mean of
-    the held) and ``rows_held_share`` (assignments that land on held experts
-    over all).
+    the held), ``rows_held_share`` (assignments that land on held experts
+    over all) and, with ``select_bias``, ``bias_abs_max`` (of the bias this
+    call selected with).
     """
 
     hidden_size: int
@@ -196,6 +241,10 @@ class RoutedExperts(nn.Module):
     norm_topk: bool = True
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    score: str = "softmax"
+    select_bias: bool = False
+    bias_update_rate: float = 0.001
+    train_router: bool = True
 
     @nn.compact
     def __call__(self, x: jax.Array) -> tuple[jax.Array, dict]:
@@ -208,6 +257,10 @@ class RoutedExperts(nn.Module):
         first, n = self.held or (0, e)
         if not (1 <= k <= e and 0 <= first and first + n <= e and n >= 1):
             raise ValueError(f"top_k {k}, held {self.held} of {e} experts")
+        if self.score not in ("softmax", "sigmoid") or (
+                self.select_bias and self.score != "sigmoid"):
+            raise ValueError(f"score {self.score!r} (softmax or sigmoid), "
+                             f"select_bias {self.select_bias} (sigmoid only)")
         router = self.param("router", nn.initializers.lecun_normal(), (h, e),
                             jnp.float32)
         # lecun-normal by each expert's OWN fan-in: the leading axis counts
@@ -220,12 +273,18 @@ class RoutedExperts(nn.Module):
         w_up = self.param("w_up", init, (n, h, i), self.param_dtype)
         w_down = self.param("w_down", init, (n, i, h), self.param_dtype)
 
+        bias = self.variable(
+            BIAS_COLLECTION, "bias", jnp.zeros, (e,),
+            jnp.float32) if self.select_bias else None
+        chosen_with = () if bias is None else (bias.value,)
         fn = functools.partial(_held_experts, k=k, norm_topk=self.norm_topk,
-                               dtype=self.dtype)
+                               dtype=self.dtype, score=self.score,
+                               train_router=self.train_router)
         kernels = (router, w_gate, w_up, w_down)
         mesh = resolve_mesh()
         if mesh is None or mesh.size == 1:
-            y, counts, probs = fn(x.reshape(-1, h), *kernels, first)
+            y, counts, probs = fn(x.reshape(-1, h), *kernels, first,
+                                  *chosen_with)
         else:
             shape = dict(mesh.shape)
             rows = shape[BATCH_AXES[0]] * shape[BATCH_AXES[1]]
@@ -236,8 +295,9 @@ class RoutedExperts(nn.Module):
                     f"[B, S, H] with B dividing by data x fsdp and S by seq, "
                     f"the {n} experts held by expert, their width {i} by "
                     f"tensor")
-            y, counts, probs = _split_over_the_mesh(fn, mesh, first)(
-                x, *kernels)
+            y, counts, probs = _split_over_the_mesh(
+                fn, mesh, first, with_bias=bias is not None)(
+                x, *kernels, *chosen_with)
 
         assignments = jnp.float32(x.size // h * k)
         rows_f = counts[first:first + n].astype(jnp.float32)
@@ -248,4 +308,11 @@ class RoutedExperts(nn.Module):
             / jnp.maximum(jnp.mean(rows_f), 1.0),
             "rows_held_share": jnp.sum(rows_f) / assignments,
         }
+        if bias is not None:
+            stats["bias_abs_max"] = jnp.max(jnp.abs(chosen_with[0]))
+            if (self.is_mutable_collection(BIAS_COLLECTION)
+                    and not self.is_initializing()):
+                load = counts.astype(jnp.float32)
+                bias.value = chosen_with[0] + self.bias_update_rate * jnp.sign(
+                    jnp.mean(load) - load)
         return y.reshape(x.shape).astype(x.dtype), stats

@@ -57,6 +57,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from distributeddeeplearningspark_tpu.utils.env import pallas_interpret
@@ -66,6 +67,11 @@ from distributeddeeplearningspark_tpu.utils.env import pallas_interpret
 # exp(_MASK_VALUE - m) underflows to 0 for any real row max m.
 _MASK_VALUE = -1e30
 DEFAULT_BLOCK = 512
+#: ``jax.ad_checkpoint.checkpoint_name`` of the forward kernel's output and
+#: log-sum-exp: a ``remat`` policy that keeps them spares the replay the
+#: forward kernel (the backward kernels need those two, never the forward
+#: pass again); without such a policy the name does nothing
+FLASH_OUT_NAME = "flash_out"
 #: trailing dim for row-statistics (LSE/delta) arrays: the Mosaic block rule
 #: ("divisible by (8, 128) or equal to the array dim") is satisfied by making
 #: the minor dim exactly 8 and always blocking it whole.
@@ -489,6 +495,8 @@ def _flash_vjp_fwd(q, k, v, kv_mask, q_segs, kv_segs, scale, causal, group,
     o, lse = _flash_fwd(q, k, v, kv_mask, scale=scale, causal=causal,
                         group=group, block_q=block_q, block_k=block_k,
                         interpret=interpret, q_segs=q_segs, kv_segs=kv_segs)
+    o, lse = (checkpoint_name(o, FLASH_OUT_NAME),
+              checkpoint_name(lse, FLASH_OUT_NAME))
     return o, (q, k, v, kv_mask, o, lse, q_segs, kv_segs)
 
 

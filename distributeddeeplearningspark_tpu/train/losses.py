@@ -165,6 +165,27 @@ def sparse_moe_lm(outputs: dict[str, jax.Array], batch: dict[str, Any]
     return loss, metrics
 
 
+def hybrid_moe_lm(outputs: dict[str, jax.Array], batch: dict[str, Any]
+                  ) -> tuple[jax.Array, dict]:
+    """The loss of :class:`~..models.hybrid_decoder.HybridDecoderLM`:
+    next-token cross-entropy over the vocabulary the model holds, fused with
+    the (tied) head, at every position but the window's last; a target across
+    a document boundary is kept. No router term: the sigmoid router is
+    balanced by its bias, which the step moves outside the gradient. The
+    model's counters (``hybrid_decoder.COUNTERS``) ride along into the step's
+    metrics."""
+    from distributeddeeplearningspark_tpu.models.hybrid_decoder import COUNTERS
+    from distributeddeeplearningspark_tpu.train.fused_ce import (
+        chunked_softmax_xent,
+    )
+
+    per_tok = chunked_softmax_xent(outputs["hidden"][:, :-1],
+                                   outputs["lm_head"],
+                                   batch["input_ids"][:, 1:])
+    loss, metrics = _reduce_next_token(per_tok, batch)
+    return loss, {**metrics, **{k: outputs[k] for k in COUNTERS}}
+
+
 def _add_moe_aux(loss, metrics, outputs) -> tuple[jax.Array, dict]:
     """Fold a model-reported (already-weighted) MoE load-balance loss in."""
     if isinstance(outputs, dict) and "moe_aux" in outputs:

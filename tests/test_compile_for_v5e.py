@@ -152,12 +152,14 @@ def test_routed_experts_split_over_four_chips_compile(four_chips,
 
 #: name -> (batch, seq, q heads, kv heads, head size, causal, key mask,
 #: segment ids): Llama's causal d=128, BERT-base's key-padding mask d=64,
-#: grouped KV, and packed documents (mask + segment ids)
+#: grouped KV, packed documents (mask + segment ids), and the hybrid decoder's
+#: causal + grouped 32/8 + segment ids at d=64
 FLASH_REGIMES = {
     "causal_d128": (2, 1024, 4, 4, 128, True, False, False),
     "masked_d64_bert": (2, 512, 12, 12, 64, False, True, False),
     "gqa_causal_d128": (1, 1024, 8, 2, 128, True, False, False),
     "masked_segments_d64": (2, 1024, 12, 12, 64, False, True, True),
+    "gqa_causal_segments_d64": (1, 2048, 32, 8, 64, True, False, True),
 }
 
 
@@ -197,6 +199,34 @@ def test_flash_kernel_compiles_in_every_regime(one_chip, no_compile_cache,
     for name in kernels:
         assert name in text, name
     assert text.count("tpu_custom_call") >= len(kernels)
+
+
+def test_short_convolution_kernels_compile_at_published_widths(
+        one_chip, no_compile_cache):
+    """``shortconv_fwd`` and ``shortconv_bwd`` of ``ops/short_conv.py`` at one
+    window of the benchmark's fourth configuration: 32,768 positions, 2,048
+    channels behind a 6,144-wide projection, three taps, segment ids. The
+    sublane rotation, the three column blocks of one array and the 8-row
+    halos are what interpret mode cannot judge."""
+    from distributeddeeplearningspark_tpu.ops import short_conv
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def scalar(bcx, taps, seg):
+        y = short_conv.gated_short_conv_pallas(bcx, taps, seg,
+                                               interpret=False)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.value_and_grad(scalar, argnums=(0, 1))).lower(
+        sds((1, 32768, 6144)), sds((2048, 3), jnp.float32),
+        sds((1, 32768), jnp.int32)).compile()
+    text = compiled.as_text()
+    for name in ("shortconv_fwd", "shortconv_bwd"):
+        assert name in text, name
+    assert text.count("tpu_custom_call") >= 2
+    # one pass each: no [T, 6144] temporary beside the cotangent itself
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
 def test_flash_on_mesh_compiles_for_four_chips(four_chips, no_compile_cache,
