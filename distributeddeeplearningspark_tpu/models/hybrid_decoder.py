@@ -56,16 +56,20 @@ from distributeddeeplearningspark_tpu.models.moe import (
     RoutedExperts,
 )
 from distributeddeeplearningspark_tpu.ops.attention import dot_product_attention
-from distributeddeeplearningspark_tpu.ops.flash_attention import FLASH_OUT_NAME
+from distributeddeeplearningspark_tpu.ops.flash_attention import (
+    FLASH_OUT_NAME,
+    attn_blocks_walked_share,
+)
 from distributeddeeplearningspark_tpu.ops.short_conv import gated_short_conv
 from distributeddeeplearningspark_tpu.parallel.sharding import ShardingRules
 
 CONV, ATTENTION = "conv", "full_attention"
 #: the step's counters (docs/OBSERVABILITY.md): the first two are means over
-#: the expert layers, the third the largest over them, the last the batch's;
-#: ``losses.hybrid_moe_lm`` carries them into the step's metrics
+#: the expert layers, the third the largest over them, the last two the
+#: batch's; ``losses.hybrid_moe_lm`` carries them into the step's metrics
 COUNTERS = ("moe_load_max_over_mean", "moe_rows_held_share",
-            "router_bias_abs_max", "attn_pairs_share")
+            "router_bias_abs_max", "attn_pairs_share",
+            "attn_blocks_walked_share")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -307,6 +311,8 @@ class HybridDecoderLM(nn.Module):
         s = ids.shape[1]
         out["attn_pairs_share"] = jnp.mean(
             jnp.sum(pos.astype(jnp.float32) + 1.0, axis=1)) / (s * (s + 1) / 2)
+        # what the flash kernels walk of the triangle, by their own predicate
+        out["attn_blocks_walked_share"] = attn_blocks_walked_share(seg)
         return out
 
 

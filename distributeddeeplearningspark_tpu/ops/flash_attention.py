@@ -17,11 +17,27 @@ Supported masking (BASELINE.json config 3 needs this — BERT always attends
 under a key-padding mask):
 
 - ``causal`` — per-block: blocks strictly above the diagonal are skipped
-  entirely (their grid steps no-op), the diagonal block gets a positional mask.
+  (their grid steps no-op; without segment ids their keys are still
+  copied), the diagonal block gets a positional mask.
 - ``mask`` — a *key-only* padding mask ([B, Sk] or the BERT-style
   [B, 1, 1, Sk]); streamed into the kernel one [block_k] slice at a time, so
   no [S, S] mask tensor is ever built. Q-dependent masks are not expressible
   blockwise without a full mask tensor — those fall back to the XLA path.
+- ``segment_ids`` — packed documents: a pair is allowed only inside one
+  document, by an element mask in every block that is walked. A block is
+  walked only if a document can span it: the least and greatest id of its
+  queries and of its keys (:func:`segment_block_walk`, computed once in XLA
+  from ``q_segs`` and ``kv_segs``, which differ on a ring hop) must
+  intersect, and with ``causal`` it must not lie above the diagonal. The
+  bounds reach the kernels as two int32 tables in SMEM (scalar prefetch): the
+  kernel branches on them, and the index maps clamp the streamed block to the
+  hull of the walked ones, so a skipped step names the block already resident
+  and copies nothing. A skipped block would have left every accumulator as it
+  was, so the result has the bits of walking and masking it. Exact for
+  running ids (documents packed one after another), a superset for ids in
+  any order (pads at -1). :func:`attn_blocks_walked_share` counts, from the
+  same predicate, what is walked of the triangle. Without segment ids the
+  three calls take no table and are built as they always were.
 
 Masked logits use a large *finite* negative (never -inf: running-max
 subtraction would produce inf - inf = NaN on fully-masked blocks) and
@@ -101,6 +117,111 @@ def _grid_params(*semantics: str):
     return pltpu.CompilerParams(dimension_semantics=semantics)
 
 
+def _block_id_range(segs, block: int):
+    """[B, S] segment ids -> (least, greatest) id of each block, [B, S/block]."""
+    blocks = segs.reshape(segs.shape[0], -1, block)
+    return jnp.min(blocks, axis=-1), jnp.max(blocks, axis=-1)
+
+
+def _blocks_meet(q_lo, q_hi, k_lo, k_hi, qb, kb, *, causal, block_q, block_k):
+    """Whether score block ``(qb, kb)`` is walked: the id ranges of its
+    queries and of its keys intersect (a superset of "holds an allowed pair"
+    for ids in any order, exact for running ids) and, with ``causal``, it does
+    not lie strictly above the diagonal. On scalars inside the kernels, on
+    arrays in :func:`segment_block_walk`: one predicate for both."""
+    meet = jnp.logical_and(k_lo <= q_hi, k_hi >= q_lo)
+    if causal:
+        meet = jnp.logical_and(meet, kb * block_k < (qb + 1) * block_q)
+    return meet
+
+
+def segment_block_walk(q_segs, kv_segs, *, causal, block_q, block_k):
+    """What the three kernels walk under segment ids, from ``q_segs`` and
+    ``kv_segs`` [B, S] (they differ on a ring hop).
+
+    Returns ``(walk, q_side, k_side)``: ``walk`` bool [B, S/block_q,
+    S/block_k], the blocks computed; ``q_side`` int32 [4, B * S/block_q], per
+    (row, query block) the least and greatest id and the first and last key
+    block walked; ``k_side`` int32 [4, B * S/block_k], the same from the key
+    blocks' side. The two tables are the kernels' scalar-prefetch operands:
+    the id ranges decide (through :func:`_blocks_meet`) what is computed, the
+    hull ``[first, last]`` what the index maps fetch. A row or column with no
+    walked block gets the hull [0, 0]."""
+    q_lo, q_hi = _block_id_range(q_segs, block_q)
+    k_lo, k_hi = _block_id_range(kv_segs, block_k)
+    nq, nk = q_lo.shape[1], k_lo.shape[1]
+    qb = jnp.arange(nq, dtype=jnp.int32)[None, :, None]
+    kb = jnp.arange(nk, dtype=jnp.int32)[None, None, :]
+    walk = _blocks_meet(q_lo[:, :, None], q_hi[:, :, None], k_lo[:, None, :],
+                        k_hi[:, None, :], qb, kb, causal=causal,
+                        block_q=block_q, block_k=block_k)
+
+    def hull(ix, n, axis):   # integer min / max: no boolean reduction
+        last = jnp.maximum(jnp.max(jnp.where(walk, ix, -1), axis=axis), 0)
+        first = jnp.minimum(jnp.min(jnp.where(walk, ix, n), axis=axis), last)
+        return first, last
+
+    k_first, k_last = hull(kb, nk, 2)
+    q_first, q_last = hull(qb, nq, 1)
+    pack = lambda *cols: jnp.stack(cols).astype(jnp.int32).reshape(4, -1)
+    return (walk, pack(q_lo, q_hi, k_first, k_last),
+            pack(k_lo, k_hi, q_first, q_last))
+
+
+def attn_blocks_walked_share(segment_ids, *, block: int = DEFAULT_BLOCK):
+    """Blocks the causal kernels walk under ``segment_ids`` [B, S] over the
+    ``n (n + 1) / 2`` on or under the diagonal, mean over the rows: 1.0 for a
+    window that is one document. From :func:`segment_block_walk`, which also
+    hands the kernels their bounds. A length the kernels do not take (not a
+    multiple of the block) goes to the XLA path, which computes every pair."""
+    s = segment_ids.shape[1]
+    block = min(block, s)
+    if s % block:
+        return jnp.float32(1.0)
+    walk, _, _ = segment_block_walk(segment_ids, segment_ids, causal=True,
+                                    block_q=block, block_k=block)
+    n = s // block
+    return (jnp.mean(jnp.sum(walk, axis=(1, 2)).astype(jnp.float32))
+            / (n * (n + 1) / 2))
+
+
+def _within_hull(side, at, j):
+    """Index-map helper: block ``j`` clamped to the hull of the walked blocks
+    of table entry ``at``, so that a run of skipped steps names the block
+    already resident and Pallas issues no copy."""
+    return jnp.clip(j, side[2, at], side[3, at])
+
+
+def _streamed_key_block(heads: int, num_qb: int):
+    """The key block the forward and dQ grids ``(b, i, j)`` fetch at a step.
+    Index maps take the tables last (``*t``: none without segment ids, and
+    then the block is ``j`` as it always was)."""
+    def kv_blk(b, i, j, *t):
+        return _within_hull(t[0], b // heads * num_qb + i, j) if t else j
+    return kv_blk
+
+
+def _pallas(kernel, *, name, grid, in_specs, out_specs, out_shape,
+            scratch_shapes, tables, interpret):
+    """One ``pallas_call`` of this file. ``tables`` (the two of
+    :func:`segment_block_walk`, or none without segment ids) go ahead of the
+    operands as scalar prefetch: the kernel's first refs, the index maps' last
+    arguments."""
+    params = dict(out_shape=out_shape, interpret=interpret, name=name,
+                  compiler_params=_grid_params("parallel", "parallel",
+                                               "arbitrary"))
+    if not tables:
+        return pl.pallas_call(kernel, grid=grid, in_specs=in_specs,
+                              out_specs=out_specs,
+                              scratch_shapes=scratch_shapes, **params)
+    from jax.experimental.pallas import tpu as pltpu
+
+    call = pl.pallas_call(kernel, grid_spec=pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(tables), grid=grid, in_specs=in_specs,
+        out_specs=out_specs, scratch_shapes=scratch_shapes), **params)
+    return functools.partial(call, *tables)
+
+
 def _block_mask(qb, kb, s_blk, *, causal, mask_blk, block_q, block_k,
                 q_seg_blk=None, k_seg_blk=None):
     """(masked logits, allowed bool | None) for one [Bq, Bk] score block.
@@ -134,20 +255,51 @@ def _block_mask(qb, kb, s_blk, *, causal, mask_blk, block_q, block_k,
 # forward
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(*refs, scale: float, causal: bool, has_mask: bool,
-                has_segs: bool, num_kb: int, block_q: int, block_k: int):
-    q_ref, k_ref, v_ref = refs[:3]            # [1, Bq, D], [1, Bk, D]
-    i = 3
-    mask_ref = refs[i] if has_mask else None  # [1, 1, Bk] int32 (lane-major)
+def _split_refs(refs, fixed: int, has_mask: bool, has_segs: bool):
+    """A kernel's refs in the order :func:`_pallas` hands them over:
+    ``(tables, the fixed operands, mask, q ids, k ids, outputs + scratch)``.
+
+    ``mask`` [1, 1, Bk] int32 (lane-major); the segment ids on the q side in
+    STAT layout [1, Bq, STAT] (sublane read), on the k side lane-major
+    [1, 1, Bk]; ``tables`` the two SMEM tables of :func:`segment_block_walk`.
+    """
+    tables, refs = (refs[:2], refs[2:]) if has_segs else ((), refs)
+    i = fixed
+    mask_ref = refs[i] if has_mask else None
     i += int(has_mask)
-    # packed-sequence segment ids: q side in STAT layout [1, Bq, STAT]
-    # (sublane read), k side lane-major [1, 1, Bk]
-    qseg_ref = refs[i] if has_segs else None
-    kseg_ref = refs[i + 1] if has_segs else None
+    qseg_ref, kseg_ref = (refs[i], refs[i + 1]) if has_segs else (None, None)
     i += 2 * int(has_segs)
-    o_ref, lse_ref = refs[i], refs[i + 1]     # [1, Bq, D], [1, Bq, STAT]
-    acc_ref, m_ref, l_ref = refs[i + 2:]      # VMEM scratch
-    qb, kb = pl.program_id(1), pl.program_id(2)
+    return tables, refs[:fixed], mask_ref, qseg_ref, kseg_ref, refs[i:]
+
+
+def _when_walked(compute, tables, b, qb, kb, *, heads, causal, num_qb, num_kb,
+                 block_q, block_k):
+    """Run ``compute`` for the blocks that can hold an allowed pair: under
+    segment ids (``tables``; grid row ``b`` belongs to batch row ``b //
+    heads``) those :func:`_blocks_meet` names, else those on or under the
+    diagonal (``causal``), else all. A skipped block would have left the
+    accumulators as they are, to the bit."""
+    if tables:
+        q_side, k_side = tables
+        qi, ki = b // heads * num_qb + qb, b // heads * num_kb + kb
+        pl.when(_blocks_meet(
+            q_side[0, qi], q_side[1, qi], k_side[0, ki], k_side[1, ki], qb, kb,
+            causal=causal, block_q=block_q, block_k=block_k))(compute)
+    elif causal:
+        # blocks strictly above the diagonal contribute nothing
+        pl.when(kb * block_k < (qb + 1) * block_q)(compute)
+    else:
+        compute()
+
+
+def _fwd_kernel(*refs, scale: float, causal: bool, has_mask: bool,
+                has_segs: bool, heads: int, num_qb: int, num_kb: int,
+                block_q: int, block_k: int):
+    tables, (q_ref, k_ref, v_ref), mask_ref, qseg_ref, kseg_ref, rest = (
+        _split_refs(refs, 3, has_mask, has_segs))  # [1, Bq, D], [1, Bk, D]
+    o_ref, lse_ref = rest[:2]                 # [1, Bq, D], [1, Bq, STAT]
+    acc_ref, m_ref, l_ref = rest[2:]          # VMEM scratch
+    b, qb, kb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(kb == 0)
     def _init():
@@ -180,11 +332,9 @@ def _fwd_kernel(*refs, scale: float, causal: bool, has_mask: bool,
                      preferred_element_type=jnp.float32)  # [Bq, D]
         acc_ref[:] = acc_ref[:] * corr[:, None] + pv
 
-    if causal:
-        # blocks strictly above the diagonal contribute nothing
-        pl.when(kb * block_k < (qb + 1) * block_q)(compute)
-    else:
-        compute()
+    _when_walked(compute, tables, b, qb, kb, heads=heads, causal=causal,
+                 num_qb=num_qb, num_kb=num_kb, block_q=block_q,
+                 block_k=block_k)
 
     @pl.when(kb == num_kb - 1)
     def _finalize():
@@ -200,45 +350,54 @@ def _fwd_kernel(*refs, scale: float, causal: bool, has_mask: bool,
 def _flash_fwd(q, k, v, kv_mask, *, scale, causal, group, block_q, block_k,
                interpret, q_segs=None, kv_segs=None):
     bh, s, d = q.shape
-    bhkv = k.shape[0]
     num_qb, num_kb = s // block_q, s // block_k
-    grid = (bh, num_qb, num_kb)
     has_mask = kv_mask is not None
     has_segs = q_segs is not None
     if has_segs != (kv_segs is not None):
         raise ValueError("q_segs and kv_segs must be passed together")
     heads = (bh // kv_mask.shape[0] if has_mask
              else bh // q_segs.shape[0] if has_segs else 0)
+    tables = (segment_block_walk(q_segs, kv_segs, causal=causal,
+                                 block_q=block_q, block_k=block_k)[1:]
+              if has_segs else ())
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, has_mask=has_mask,
-        has_segs=has_segs, num_kb=num_kb, block_q=block_q, block_k=block_k,
+        has_segs=has_segs, heads=heads, num_qb=num_qb, num_kb=num_kb,
+        block_q=block_q, block_k=block_k,
     )
+
+    kv_blk = _streamed_key_block(heads, num_qb)
     in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // group, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // group, j, 0)),
+        pl.BlockSpec((1, block_q, d), lambda b, i, j, *t: (b, i, 0)),
+        pl.BlockSpec((1, block_k, d),
+                     lambda b, i, j, *t: (b // group, kv_blk(b, i, j, *t), 0)),
+        pl.BlockSpec((1, block_k, d),
+                     lambda b, i, j, *t: (b // group, kv_blk(b, i, j, *t), 0)),
     ]
     operands = [q, k, v]
+    # lane-oriented [B, 1, Sk]: a [block_k] slice lands in the lane dim
+    lane_spec = pl.BlockSpec(
+        (1, 1, block_k),
+        lambda b, i, j, *t: (b // heads, 0, kv_blk(b, i, j, *t)))
     if has_mask:
-        # lane-oriented [B, 1, Sk]: a [block_k] slice lands in the lane dim
-        in_specs.append(
-            pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b // heads, 0, j)))
+        in_specs.append(lane_spec)
         operands.append(kv_mask[:, None, :])
     if has_segs:
         in_specs.append(pl.BlockSpec((1, block_q, STAT_LANES),
-                                     lambda b, i, j: (b // heads, i, 0)))
+                                     lambda b, i, j, *t: (b // heads, i, 0)))
         operands.append(_seg_stat(q_segs))
-        in_specs.append(
-            pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b // heads, 0, j)))
+        in_specs.append(lane_spec)
         operands.append(kv_segs[:, None, :])
     vmem = _vmem()
-    o, lse = pl.pallas_call(
+    o, lse = _pallas(
         kernel,
-        grid=grid,
+        name="flash_fwd",
+        grid=(bh, num_qb, num_kb),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, STAT_LANES), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d), lambda b, i, j, *t: (b, i, 0)),
+            pl.BlockSpec((1, block_q, STAT_LANES),
+                         lambda b, i, j, *t: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), q.dtype),
@@ -249,9 +408,8 @@ def _flash_fwd(q, k, v, kv_mask, *, scale, causal, group, block_q, block_k,
             vmem((block_q, 128), jnp.float32),  # m (col 0 used)
             vmem((block_q, 128), jnp.float32),  # l (col 0 used)
         ],
-        compiler_params=_grid_params("parallel", "parallel", "arbitrary"),
+        tables=tables,
         interpret=interpret,
-        name="flash_fwd",
     )(*operands)
     return o, lse[..., 0]
 
@@ -261,16 +419,12 @@ def _flash_fwd(q, k, v, kv_mask, *, scale, causal, group, block_q, block_k,
 # ---------------------------------------------------------------------------
 
 def _bwd_dq_kernel(*refs, scale: float, causal: bool, has_mask: bool,
-                   has_segs: bool, num_kb: int, block_q: int, block_k: int):
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
-    i = 6
-    mask_ref = refs[i] if has_mask else None
-    i += int(has_mask)
-    qseg_ref = refs[i] if has_segs else None
-    kseg_ref = refs[i + 1] if has_segs else None
-    i += 2 * int(has_segs)
-    dq_ref, acc_ref = refs[i], refs[i + 1]
-    qb, kb = pl.program_id(1), pl.program_id(2)
+                   has_segs: bool, heads: int, num_qb: int, num_kb: int,
+                   block_q: int, block_k: int):
+    tables, fixed, mask_ref, qseg_ref, kseg_ref, (dq_ref, acc_ref) = (
+        _split_refs(refs, 6, has_mask, has_segs))
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = fixed
+    b, qb, kb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(kb == 0)
     def _init():
@@ -297,10 +451,9 @@ def _bwd_dq_kernel(*refs, scale: float, causal: bool, has_mask: bool,
         ds = p * (dp - delta_ref[0, :, 0][:, None])                # [Bq, Bk]
         acc_ref[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
-    if causal:
-        pl.when(kb * block_k < (qb + 1) * block_q)(compute)
-    else:
-        compute()
+    _when_walked(compute, tables, b, qb, kb, heads=heads, causal=causal,
+                 num_qb=num_qb, num_kb=num_kb, block_q=block_q,
+                 block_k=block_k)
 
     @pl.when(kb == num_kb - 1)
     def _finalize():
@@ -308,22 +461,19 @@ def _bwd_dq_kernel(*refs, scale: float, causal: bool, has_mask: bool,
 
 
 def _bwd_dkv_kernel(*refs, scale: float, causal: bool, has_mask: bool,
-                    has_segs: bool, num_qb: int, group: int, block_q: int,
-                    block_k: int):
+                    has_segs: bool, heads: int, num_qb: int, num_kb: int,
+                    group: int, block_q: int, block_k: int):
     """dK/dV for ONE kv head, accumulating over its `group` q heads × q blocks.
 
     Grid: (B·Hkv, num_kb, group·num_qb) — the innermost index j interleaves
     (q head in group, q block); the index maps select q row b·group + j//num_qb.
+    ``heads`` here counts the KV heads of a batch row.
     """
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
-    i = 6
-    mask_ref = refs[i] if has_mask else None
-    i += int(has_mask)
-    qseg_ref = refs[i] if has_segs else None
-    kseg_ref = refs[i + 1] if has_segs else None
-    i += 2 * int(has_segs)
-    dk_ref, dv_ref, dk_acc, dv_acc = refs[i:]
-    kb, j = pl.program_id(1), pl.program_id(2)
+    tables, fixed, mask_ref, qseg_ref, kseg_ref, rest = (
+        _split_refs(refs, 6, has_mask, has_segs))
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = fixed
+    dk_ref, dv_ref, dk_acc, dv_acc = rest
+    b, kb, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     qb = j % num_qb
 
     @pl.when(j == 0)
@@ -358,10 +508,9 @@ def _bwd_dkv_kernel(*refs, scale: float, causal: bool, has_mask: bool,
         dk_acc[:] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
 
-    if causal:
-        pl.when(kb * block_k < (qb + 1) * block_q)(compute)
-    else:
-        compute()
+    _when_walked(compute, tables, b, qb, kb, heads=heads, causal=causal,
+                 num_qb=num_qb, num_kb=num_kb, block_q=block_q,
+                 block_k=block_k)
 
     @pl.when(j == group * num_qb - 1)
     def _finalize():
@@ -381,6 +530,9 @@ def _flash_bwd(res, g, *, scale, causal, group, block_q, block_k, interpret):
     has_segs = q_segs is not None
     heads = (bh // kv_mask.shape[0] if has_mask
              else bh // q_segs.shape[0] if has_segs else 0)
+    tables = (segment_block_walk(q_segs, kv_segs, causal=causal,
+                                 block_q=block_q, block_k=block_k)[1:]
+              if has_segs else ())
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     # row stats travel as [bh, s, STAT_LANES] (Mosaic block rule — see module
     # docstring); the replication is a cheap transient, the residual is 2-D
@@ -388,80 +540,87 @@ def _flash_bwd(res, g, *, scale, causal, group, block_q, block_k, interpret):
     lse3, delta3 = stat(lse), stat(delta)
     stat_spec = lambda ix: pl.BlockSpec((1, block_q, STAT_LANES), ix)
     mask3 = kv_mask[:, None, :] if has_mask else None
+    shared = dict(scale=scale, causal=causal, has_mask=has_mask,
+                  has_segs=has_segs, num_qb=num_qb, num_kb=num_kb,
+                  block_q=block_q, block_k=block_k)
     vmem = _vmem()
 
+    kv_blk = _streamed_key_block(heads, num_qb)
+    q_row = lambda b, i, j, *t: (b, i, 0)
+    kv_row = lambda b, i, j, *t: (b // group, kv_blk(b, i, j, *t), 0)
+    lane_spec = pl.BlockSpec(
+        (1, 1, block_k),
+        lambda b, i, j, *t: (b // heads, 0, kv_blk(b, i, j, *t)))
     in_specs_q = [
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),          # q
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // group, j, 0)),  # k
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // group, j, 0)),  # v
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),          # do
-        stat_spec(lambda b, i, j: (b, i, 0)),                              # lse
-        stat_spec(lambda b, i, j: (b, i, 0)),                              # delta
+        pl.BlockSpec((1, block_q, d), q_row),    # q
+        pl.BlockSpec((1, block_k, d), kv_row),   # k
+        pl.BlockSpec((1, block_k, d), kv_row),   # v
+        pl.BlockSpec((1, block_q, d), q_row),    # do
+        stat_spec(q_row),                        # lse
+        stat_spec(q_row),                        # delta
     ]
     operands = [q, k, v, do, lse3, delta3]
     if has_mask:
-        in_specs_q.append(
-            pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b // heads, 0, j)))
+        in_specs_q.append(lane_spec)
         operands.append(mask3)
     if has_segs:
-        in_specs_q.append(pl.BlockSpec((1, block_q, STAT_LANES),
-                                       lambda b, i, j: (b // heads, i, 0)))
+        in_specs_q.append(stat_spec(lambda b, i, j, *t: (b // heads, i, 0)))
         operands.append(_seg_stat(q_segs))
-        in_specs_q.append(
-            pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b // heads, 0, j)))
+        in_specs_q.append(lane_spec)
         operands.append(kv_segs[:, None, :])
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          has_mask=has_mask, has_segs=has_segs, num_kb=num_kb,
-                          block_q=block_q, block_k=block_k),
+    dq = _pallas(
+        functools.partial(_bwd_dq_kernel, heads=heads, **shared),
+        name="flash_bwd_dq",
         grid=(bh, num_qb, num_kb),
         in_specs=in_specs_q,
-        out_specs=[pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))],
+        out_specs=[pl.BlockSpec((1, block_q, d), q_row)],
         out_shape=[jax.ShapeDtypeStruct((bh, s, d), q.dtype)],
         scratch_shapes=[vmem((block_q, d), jnp.float32)],
-        compiler_params=_grid_params("parallel", "parallel", "arbitrary"),
+        tables=tables,
         interpret=interpret,
-        name="flash_bwd_dq",
     )(*operands)[0]
 
     # dK/dV: grid batch dim is B·Hkv; inner dim sweeps (group, q block) so the
     # accumulators fold every q head of the group into one kv-head gradient.
-    kvheads = (bhkv // max(kv_mask.shape[0], 1)) if has_mask else 0
+    kvheads = (bhkv // kv_mask.shape[0] if has_mask
+               else bhkv // kv_segs.shape[0] if has_segs else 0)
+
+    def q_blk(b, i, j, *t):  # the query block streamed at this step
+        qb = j % num_qb
+        return _within_hull(t[1], b // kvheads * num_kb + i, qb) if t else qb
+
+    q_head = lambda b, j: b * group + j // num_qb
+    q_stream = lambda b, i, j, *t: (q_head(b, j), q_blk(b, i, j, *t), 0)
+    kv_own = lambda b, i, j, *t: (b, i, 0)
     in_specs_kv = [
-        pl.BlockSpec((1, block_q, d),
-                     lambda b, i, j: (b * group + j // num_qb, j % num_qb, 0)),  # q
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),               # k
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),               # v
-        pl.BlockSpec((1, block_q, d),
-                     lambda b, i, j: (b * group + j // num_qb, j % num_qb, 0)),  # do
-        stat_spec(lambda b, i, j: (b * group + j // num_qb, j % num_qb, 0)),    # lse
-        stat_spec(lambda b, i, j: (b * group + j // num_qb, j % num_qb, 0)),    # delta
+        pl.BlockSpec((1, block_q, d), q_stream),  # q
+        pl.BlockSpec((1, block_k, d), kv_own),    # k
+        pl.BlockSpec((1, block_k, d), kv_own),    # v
+        pl.BlockSpec((1, block_q, d), q_stream),  # do
+        stat_spec(q_stream),                      # lse
+        stat_spec(q_stream),                      # delta
     ]
     operands_kv = [q, k, v, do, lse3, delta3]
+    lane_own = pl.BlockSpec((1, 1, block_k),
+                            lambda b, i, j, *t: (b // kvheads, 0, i))
     if has_mask:
-        in_specs_kv.append(
-            pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b // kvheads, 0, i)))
+        in_specs_kv.append(lane_own)
         operands_kv.append(mask3)
     if has_segs:
-        kvh = bhkv // kv_segs.shape[0]
-        in_specs_kv.append(pl.BlockSpec(
-            (1, block_q, STAT_LANES),
-            lambda b, i, j: ((b * group + j // num_qb) // heads,
-                             j % num_qb, 0)))
+        in_specs_kv.append(stat_spec(
+            lambda b, i, j, *t: (q_head(b, j) // heads,
+                                 q_blk(b, i, j, *t), 0)))
         operands_kv.append(_seg_stat(q_segs))
-        in_specs_kv.append(
-            pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b // kvh, 0, i)))
+        in_specs_kv.append(lane_own)
         operands_kv.append(kv_segs[:, None, :])
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          has_mask=has_mask, has_segs=has_segs, num_qb=num_qb,
-                          group=group, block_q=block_q, block_k=block_k),
+    dk, dv = _pallas(
+        functools.partial(_bwd_dkv_kernel, heads=kvheads, group=group,
+                          **shared),
+        name="flash_bwd_dkv",
         grid=(bhkv, num_kb, group * num_qb),
         in_specs=in_specs_kv,
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-        ],
+        out_specs=[pl.BlockSpec((1, block_k, d), kv_own),
+                   pl.BlockSpec((1, block_k, d), kv_own)],
         out_shape=[
             jax.ShapeDtypeStruct((bhkv, s, d), k.dtype),
             jax.ShapeDtypeStruct((bhkv, s, d), v.dtype),
@@ -470,9 +629,8 @@ def _flash_bwd(res, g, *, scale, causal, group, block_q, block_k, interpret):
             vmem((block_k, d), jnp.float32),
             vmem((block_k, d), jnp.float32),
         ],
-        compiler_params=_grid_params("parallel", "parallel", "arbitrary"),
+        tables=tables,
         interpret=interpret,
-        name="flash_bwd_dkv",
     )(*operands_kv)
     return dq, dk, dv
 

@@ -160,6 +160,8 @@ FLASH_REGIMES = {
     "gqa_causal_d128": (1, 1024, 8, 2, 128, True, False, False),
     "masked_segments_d64": (2, 1024, 12, 12, 64, False, True, True),
     "gqa_causal_segments_d64": (1, 2048, 32, 8, 64, True, False, True),
+    # one window of the benchmark's fourth configuration (fit_seg32k)
+    "cell_seg32k": (1, 32768, 32, 8, 64, True, False, True),
 }
 
 
@@ -199,6 +201,53 @@ def test_flash_kernel_compiles_in_every_regime(one_chip, no_compile_cache,
     for name in kernels:
         assert name in text, name
     assert text.count("tpu_custom_call") >= len(kernels)
+
+
+@pytest.mark.parametrize("regime", list(FLASH_REGIMES))
+def test_flash_bounds_operands_follow_the_segment_ids(regime):
+    """The two scalar-prefetch tables of ``segment_block_walk`` go to the
+    three kernels with segment ids and ONLY then: without them (BERT's regime
+    among these) the calls are built as before the kernels could skip, same
+    grid, same operands, nothing prefetched. (Lowered for the described v5e,
+    the regimes without segment ids give the parent's StableHLO and Mosaic
+    bodies to the byte, locations stripped: PERF.md section 6, PR 31.)"""
+    from distributeddeeplearningspark_tpu.ops.flash_attention import (
+        DEFAULT_BLOCK, flash_attention)
+
+    b, s, h, hkv, d, causal, masked, segmented = FLASH_REGIMES[regime]
+    sds = jax.ShapeDtypeStruct
+    args = [sds((b, s, h, d), jnp.bfloat16)] + 2 * [
+        sds((b, s, hkv, d), jnp.bfloat16)]
+    args += (masked + segmented) * [sds((b, s), jnp.int32)]
+
+    def scalar(q, k, v, *extra):
+        o = flash_attention(
+            q, k, v, causal=causal, interpret=False,
+            mask=extra[0] if masked else None,
+            segment_ids=extra[-1] if segmented else None)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    calls = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grid_mapping = eqn.params["grid_mapping"]
+                calls[eqn.params["name"]] = (
+                    len(eqn.invars), grid_mapping.num_index_operands,
+                    grid_mapping.grid)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(jax.grad(scalar, argnums=(0, 1, 2)))(*args).jaxpr)
+    tables = 2 * segmented
+    extra = masked + 2 * segmented + tables
+    n = s // min(DEFAULT_BLOCK, s)
+    assert calls == {
+        "flash_fwd": (3 + extra, tables, (b * h, n, n)),
+        "flash_bwd_dq": (6 + extra, tables, (b * h, n, n)),
+        "flash_bwd_dkv": (6 + extra, tables, (b * hkv, n, h // hkv * n)),
+    }
 
 
 def test_short_convolution_kernels_compile_at_published_widths(

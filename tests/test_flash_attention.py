@@ -316,6 +316,171 @@ class TestSegmentIds:
             flash_attention(q, k, v, segment_ids=jnp.zeros((2, 64), jnp.int32))
 
 
+# -- blocks no document spans are skipped (PR 31) -----------------------------
+
+def _every_block_meets(monkeypatch):
+    """Patch the shared bounds so that every block's id range is everything:
+    the predicate then walks what the kernels walked before they could skip
+    (all blocks, or those on or under the diagonal with ``causal``)."""
+    from distributeddeeplearningspark_tpu.ops import flash_attention as fa
+
+    def whole_range(segs, block):
+        shape = (segs.shape[0], segs.shape[1] // block)
+        info = jnp.iinfo(jnp.int32)
+        return (jnp.full(shape, info.min, jnp.int32),
+                jnp.full(shape, info.max, jnp.int32))
+
+    monkeypatch.setattr(fa, "_block_id_range", whole_range)
+
+
+def _ids_of(lengths, s):
+    """Running ids of documents of the given lengths, the last one filling
+    the rest of the window."""
+    ids = np.repeat(np.arange(len(lengths)), lengths)[:s]
+    return np.concatenate([ids, np.full(s - len(ids), len(lengths))])
+
+
+def _skip_case(name):
+    """-> (q, k, v, mask [B, S] | None, q_segs, kv_segs, causal), block 64."""
+    if name == "causal_gqa_documents":
+        # row 0: a document that ends inside a block (at 100), one that ends
+        # on a block edge (at 192), a one-token document (192), the rest;
+        # row 1: one document fills the window
+        s = 320
+        segs = np.stack([_ids_of([100, 92, 1], s), np.zeros(s, np.int64)])
+        q, k, v = _qkv(b=2, s=s, h=4, d=64, hkv=1, seed=31)
+        return q, k, v, None, segs, segs, True
+    if name == "masked_segments_pads":
+        # BERT packing: no causal, key mask, pads carry -1
+        s = 256
+        segs = np.stack([_ids_of([70, 58, 64], s), _ids_of([130], s)])
+        segs[0, 230:] = -1
+        segs[1, 200:] = -1
+        q, k, v = _qkv(b=2, s=s, h=2, d=32, seed=32)
+        return q, k, v, (segs >= 0).astype(np.int32), segs, segs, False
+    if name == "unordered_ids_two_sides":
+        # a ring hop: the keys are another shard's, ids in no order. Blocks
+        # of 64: the queries' ranges are [6,7] [0,1] [2,4] [9,9], the keys'
+        # [5,5] [1,9] [0,0] [3,7]: 8 of 16 blocks meet, 4 hold an allowed
+        # pair, and the third query block is allowed nothing at all
+        s = 256
+        q_segs = np.repeat(np.array([6, 7, 0, 1, 4, 2, 9, 9]), 32)[None, :]
+        kv_segs = np.repeat(np.array([5, 5, 1, 9, 0, 0, 7, 3]), 32)[None, :]
+        q, k, v = _qkv(b=1, s=s, h=2, d=32, seed=33)
+        return q, k, v, None, q_segs, kv_segs, False
+    raise KeyError(name)
+
+
+SKIP_CASES = ("causal_gqa_documents", "masked_segments_pads",
+              "unordered_ids_two_sides")
+
+
+def _run_kernels(case):
+    """Forward and the three gradients through the kernels themselves."""
+    from distributeddeeplearningspark_tpu.ops import flash_attention as fa
+
+    q, k, v, mask, q_segs, kv_segs, causal = case
+    flat = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, *x.shape[1::2])
+    qf, kf, vf = flat(q), flat(k), flat(v)
+    mask = None if mask is None else jnp.asarray(mask, jnp.int32)
+    q_segs = jnp.asarray(q_segs, jnp.int32)
+    kv_segs = jnp.asarray(kv_segs, jnp.int32)
+    opts = dict(scale=q.shape[-1] ** -0.5, causal=causal,
+                group=q.shape[2] // k.shape[2], block_q=64, block_k=64,
+                interpret=True)
+    o, lse = fa._flash_fwd(qf, kf, vf, mask, q_segs=q_segs, kv_segs=kv_segs,
+                           **opts)
+    do = jnp.asarray(np.random.default_rng(5).normal(0, 1, o.shape), o.dtype)
+    dq, dk, dv = fa._flash_bwd((qf, kf, vf, mask, o, lse, q_segs, kv_segs),
+                               do, **opts)
+    return {"o": o, "lse": lse, "dq": dq, "dk": dk, "dv": dv}
+
+
+@pytest.mark.parametrize("name", SKIP_CASES)
+def test_skipping_kernels_give_the_bits_of_the_walking_ones(name, monkeypatch):
+    """A block in which nothing is allowed leaves ``m``, ``l``, ``acc`` and
+    the three gradient accumulators as they were, so the kernels that skip it
+    must give what the kernels that walk and mask it give: EQUAL, not close."""
+    from distributeddeeplearningspark_tpu.ops import flash_attention as fa
+
+    case = _skip_case(name)
+    q_segs, kv_segs, causal = (jnp.asarray(case[4], jnp.int32),
+                               jnp.asarray(case[5], jnp.int32), case[6])
+    plan = lambda: fa.segment_block_walk(q_segs, kv_segs, causal=causal,
+                                         block_q=64, block_k=64)[0]
+    skipping = _run_kernels(case)
+    walked = int(jnp.sum(plan()))
+    _every_block_meets(monkeypatch)
+    walking = _run_kernels(case)
+    n = q_segs.shape[1] // 64
+    every = q_segs.shape[0] * (n * (n + 1) // 2 if causal else n * n)
+    assert int(jnp.sum(plan())) == every   # the patch walks as the parent did
+    assert walked < every                  # and the case has blocks to skip
+    for key, want in walking.items():
+        np.testing.assert_array_equal(np.asarray(skipping[key]),
+                                      np.asarray(want), err_msg=key)
+    assert np.isfinite(np.asarray(skipping["o"])).all()
+
+
+def _blocks_with_an_allowed_pair(q_segs, kv_segs, causal, block):
+    """Brute force, from the dense allowed mask."""
+    q_segs, kv_segs = np.asarray(q_segs), np.asarray(kv_segs)
+    allowed = q_segs[:, :, None] == kv_segs[:, None, :]
+    if causal:
+        s = q_segs.shape[1]
+        allowed &= np.arange(s)[:, None] >= np.arange(s)[None, :]
+    b, s, _ = allowed.shape
+    return allowed.reshape(b, s // block, block, s // block, block).any((2, 4))
+
+
+@pytest.mark.parametrize("name", SKIP_CASES)
+def test_walked_blocks_against_the_dense_mask(name):
+    """The predicate walks exactly the blocks that hold an allowed pair when
+    the ids run (documents packed one after another), and a superset of them
+    for ids in any order; the counter is its count over the triangle."""
+    from distributeddeeplearningspark_tpu.ops import flash_attention as fa
+
+    _, _, _, _, q_segs, kv_segs, causal = _skip_case(name)
+    walk, q_side, k_side = fa.segment_block_walk(
+        jnp.asarray(q_segs, jnp.int32), jnp.asarray(kv_segs, jnp.int32),
+        causal=causal, block_q=64, block_k=64)
+    walk = np.asarray(walk)
+    need = _blocks_with_an_allowed_pair(q_segs, kv_segs, causal, 64)
+    assert not (need & ~walk).any()
+    if name == "causal_gqa_documents":
+        np.testing.assert_array_equal(walk, need)
+        share = float(fa.attn_blocks_walked_share(
+            jnp.asarray(q_segs, jnp.int32), block=64))
+        n = q_segs.shape[1] // 64
+        assert share == pytest.approx(
+            need.sum() / q_segs.shape[0] / (n * (n + 1) / 2))
+        assert share == pytest.approx(walk.sum() / 2 / 15)
+        # row 0 by hand, documents [0, 100) [100, 192) {192} [193, 320): the
+        # five query blocks meet 1, 2, 2, 1 and 2 key blocks
+        assert walk[0].sum() == 1 + 2 + 2 + 1 + 2 and walk[1].sum() == 15
+    # the hulls the index maps clamp to hold every walked block
+    b, nq, nk = walk.shape
+    k_first, k_last = np.asarray(q_side[2:]).reshape(2, b, nq)
+    q_first, q_last = np.asarray(k_side[2:]).reshape(2, b, nk)
+    for r, i, j in zip(*np.nonzero(walk)):
+        assert k_first[r, i] <= j <= k_last[r, i]
+        assert q_first[r, j] <= i <= q_last[r, j]
+
+
+def test_a_window_of_one_document_walks_the_whole_triangle():
+    from distributeddeeplearningspark_tpu.ops.flash_attention import (
+        attn_blocks_walked_share,
+    )
+
+    one = jnp.zeros((2, 256), jnp.int32)
+    assert float(attn_blocks_walked_share(one, block=64)) == 1.0
+    # 64-token documents on the block's grid: the diagonal alone, 4 of 10
+    many = jnp.asarray(np.arange(256)[None, :] // 64, jnp.int32)
+    assert float(attn_blocks_walked_share(many, block=64)) == pytest.approx(0.4)
+    # shorter than a block: one block, walked
+    assert float(attn_blocks_walked_share(one[:, :48])) == 1.0
+
+
 def test_the_three_kernels_carry_stable_names():
     """A trace tells a kernel by its name: the gradient of a flash-routed
     attention (``impl="flash"``, key-padding mask, as BERT calls it) holds

@@ -21,7 +21,7 @@ CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 NEW_METRICS = ("shortconv_ms_per_step", "shortconv_roofline",
                "flash_causal_ms_per_step", "flash_causal_roofline",
                "experts_ms_per_step", "experts_load_max_over_mean",
-               "attn_pairs_share")
+               "attn_pairs_share", "attn_blocks_walked_share")
 
 
 def _load(path):
@@ -125,6 +125,14 @@ TOY = {
         router_width=8, num_experts=4, experts_held=[2, 4],
         num_experts_per_tok=2, moe_intermediate_size=64,
         compute_dtype="float32",
+        # ten times the cell's rate. The harness warms up for five seconds
+        # and measures one, so the number of steps that move the bias is the
+        # machine's speed: 170 here alone (|b| 0.17, ``selection_without_bias``
+        # moves ``experts_energy`` by 0.19), about 8 on the driver's machine
+        # under six test workers (0.0089 against the 0.01 asked of a fault).
+        # The term grows by 1.1 a unit of |b|: at 0.01 a step, 8 steps give
+        # 0.09, and a fast machine stops near |b| 0.3 (the load is even)
+        assumed_values={"router_bias_update_rate": 0.01},
         check={"examples": 1, "loss_abs_tol": 1e-4, "grad_rel_tol": 1e-3,
                "term_weights": {"experts_energy": 1.0, "boundary_energy": 1.0,
                                 "expert_probe": 1.0, "boundary_probe": 1.0},
@@ -151,6 +159,9 @@ def tree(tmp_path, monkeypatch):
 
     root = _copy_of_the_benchmark(tmp_path / "checkout")
     monkeypatch.setattr(seedcache, "ROOT", str(root / "benchmark" / ".cache"))
+    # ``runner.measure`` sets it for its process and never takes it back:
+    # set here first, it is restored when the test ends
+    monkeypatch.setenv("DLS_TELEMETRY_DIR", str(tmp_path / "telemetry"))
     return root
 
 
@@ -171,6 +182,8 @@ def test_the_cell_rehearses_at_a_toy_size(tree):
     # the counters' readers find the step's own outputs in step_metrics
     assert 1.0 <= r["metrics"]["experts_load_max_over_mean"]["value"] < 4.0
     assert 0.0 < r["metrics"]["attn_pairs_share"]["value"] <= 100.0
+    assert (r["metrics"]["attn_pairs_share"]["value"]
+            <= r["metrics"]["attn_blocks_walked_share"]["value"] <= 100.0)
     facts = r["facts"]["layer_facts"]["experts_load_max_over_mean"]
     assert facts["router_bias_abs_max_last"] > 0       # the step moved it
     # a CPU run has no device plane: the device-trace readers return nothing
@@ -260,6 +273,7 @@ def controls(tmp_path_factory):
     mod = _load(str(root / "benchmark" / "controls" / "lfm2_24b_a2b.py"))
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(seedcache, "ROOT", str(root / "benchmark" / ".cache"))
+        mp.setenv("DLS_TELEMETRY_DIR", str(root / "telemetry"))  # restored
         return mod, mod.run(2 ** 31 + 11, 1.0, mod.ALL, master="local[1]",
                             root=str(root))
 
@@ -423,9 +437,9 @@ def test_rooflines_count_executions_from_the_trace_and_stay_under_100():
             events.append(_ev(f"{name}.1", t, ms, op="custom-call"))
             t += ms
     laps = [_lap(attn_pairs_share=0.3, moe_load_max_over_mean=2.0,
-                 router_bias_abs_max=0.01),
+                 router_bias_abs_max=0.01, attn_blocks_walked_share=0.35),
             _lap(attn_pairs_share=0.5, moe_load_max_over_mean=4.0,
-                 router_bias_abs_max=0.02)]
+                 router_bias_abs_max=0.02, attn_blocks_walked_share=0.55)]
     ctx = _ctx(events, steps=1, laps=laps)
     assert _reader("shortconv_roofline")(ctx) == pytest.approx(25.0)
     assert _reader("flash_causal_roofline")(ctx) == pytest.approx(25.0)
@@ -434,6 +448,9 @@ def test_rooflines_count_executions_from_the_trace_and_stay_under_100():
     assert ctx["facts"]["flash_causal_roofline"][
         "pairs_share_of_the_laps"] == pytest.approx(0.4)
     assert _reader("attn_pairs_share")(ctx) == pytest.approx(40.0)
+    assert _reader("attn_blocks_walked_share")(ctx) == pytest.approx(45.0)
+    assert ctx["facts"]["attn_blocks_walked_share"] == {
+        "laps": 2, "min": 0.35, "max": 0.55}
     assert _reader("experts_load_max_over_mean")(ctx) == pytest.approx(3.0)
     # a window packed with one document: the whole causal triangle is
     # required, and a kernel AT its roofline reads 100, not more

@@ -138,6 +138,9 @@ def tree(tmp_path, monkeypatch):
                     ignore=shutil.ignore_patterns(".cache", "out",
                                                   "__pycache__", "tests"))
     monkeypatch.setattr(seedcache, "ROOT", str(root / "benchmark" / ".cache"))
+    # ``runner.measure`` sets it for its process and never takes it back:
+    # set here first, it is restored when the test ends
+    monkeypatch.setenv("DLS_TELEMETRY_DIR", str(tmp_path / "telemetry"))
     return root
 
 
@@ -211,6 +214,7 @@ def controls(tmp_path_factory):
     mod = _load(str(root / "benchmark" / "controls" / "keye_vl2_30b_a3b.py"))
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(seedcache, "ROOT", str(root / "benchmark" / ".cache"))
+        mp.setenv("DLS_TELEMETRY_DIR", str(root / "telemetry"))  # restored
         return mod.run(2 ** 31 + 11, 1.0, mod.ALL, flips=True,
                        master="local[1]")
 
