@@ -1,12 +1,18 @@
-"""A decoder of two operators and two feed-forwards in a published pattern:
-gated short convolutions (:mod:`..ops.short_conv`) among causal
-grouped-query attention layers, a dense SwiGLU in the leading layers and
+"""A decoder of three operators and two feed-forwards in a published pattern:
+gated short convolutions (:mod:`..ops.short_conv`), causal grouped-query
+attention layers and causal LATENT attention layers (low-rank query and
+key-value paths, one rotary key shared by all heads, value heads narrower
+than the query's and key's), a dense SwiGLU in the leading layers and
 routed experts behind a sigmoid router (:class:`..models.moe.RoutedExperts`,
-``score="sigmoid"``) in the rest, over PACKED documents that no operator
-crosses. The embedding is the head. Norms and the rotary embedding are the
+``score="sigmoid"``; optionally beside a shared expert and scaled) in the
+rest, over PACKED documents that no operator crosses. The embedding is the
+head unless ``tie_embeddings`` is off (it is then drawn N(0, 1), and the head
+a kernel of its own). Norms and the rotary embedding are the
 Llama path's (:mod:`.llama`), the fused head loss :mod:`..train.fused_ce`'s.
+``mtp_layers = 1`` adds DeepSeek-V3's multi-token-prediction module (below).
 
-Built from ``layer_types`` (``"conv"`` or ``"full_attention"`` a layer) and
+Built from ``layer_types`` (``"conv"``, ``"full_attention"`` or
+``"latent_attention"`` a layer) and
 ``num_dense_layers``. One block, ``x [B, S, hidden]``, ``seg[t]`` the
 document of position ``t``, ``pos[t] = t -`` the first position of that
 document in the window::
@@ -18,9 +24,28 @@ document in the window::
     OP attention:  q, k = rotary(RMSNorm_head(x Wq), pos), rotary(RMSNorm_head(
                    x Wk), pos);  softmax over {s <= t, seg[s] = seg[t]} of
                    q.k / sqrt(head_dim);  OP = (p v) Wo
+    OP latent:     c_q = RMSNorm(x Wdq);  [q_nope | q_rot] = c_q Wuq  (a head)
+                   [c | k_r] = x Wdkv;  [k_nope | v] = RMSNorm(c) Wukv (a head)
+                   q_rot, k_r = rotary(., pos) over adjacent pairs; k_r is ONE
+                   head that every query head reads; softmax as above of
+                   (q_nope.k_nope + q_rot.k_r) / sqrt(nope + rot);  OP = (p v) Wo
     FFN dense:     W2(silu(W1 x) * W3 x)                 (the leading layers)
     FFN experts:   s = sigmoid(x Wg); top-k of s + b; weights s_e / (sum of
-                   the chosen s + 1e-6)
+                   the chosen s + 1e-6) times ``routed_scaling_factor``;
+                   plus shared(x), a SwiGLU every token passes, where
+                   ``shared_expert_size`` is not 0
+
+Multi-token prediction, depth 1 (arXiv:2412.19437 eq. 21-25), with ``h`` the
+last block's output BEFORE the final norm::
+
+    u_t = W_eh [RMSNorm_e(Emb(x_{t+1})) ; RMSNorm_h(h_t)];  one more block of
+    the last layer's kind over u;  ``mtp_hidden`` = RMSNorm_mtp(that)
+
+``losses.latent_moe_lm`` takes ``mtp_hidden`` through the MAIN head against
+``x_{t+2}`` and adds ``mtp_loss_weight`` times that term. The module runs
+over all S rows (S - 1 divides by no kernel's block): the last row's "next
+token" is id 0, and the loss leaves out the last TWO rows' targets; causal
+attention keeps every other row what S - 1 rows would give.
 
 ``b`` is no parameter: it lives in the mutable collection
 ``moe.BIAS_COLLECTION`` and a training step moves it by its own load counts
@@ -63,7 +88,7 @@ from distributeddeeplearningspark_tpu.ops.flash_attention import (
 from distributeddeeplearningspark_tpu.ops.short_conv import gated_short_conv
 from distributeddeeplearningspark_tpu.parallel.sharding import ShardingRules
 
-CONV, ATTENTION = "conv", "full_attention"
+CONV, ATTENTION, LATENT = "conv", "full_attention", "latent_attention"
 #: the step's counters (docs/OBSERVABILITY.md): the first two are means over
 #: the expert layers, the third the largest over them, the last two the
 #: batch's; ``losses.hybrid_moe_lm`` carries them into the step's metrics
@@ -97,11 +122,26 @@ class HybridDecoderConfig:
     bias_update_rate: float = 0.001
     # False for a share trained without its exchange (RoutedExperts)
     train_router: bool = True
+    routed_scaling_factor: float = 1.0
+    shared_expert_size: int = 0      # 0: no shared expert
+    # latent attention (layers of kind LATENT; its rotary embedding is over
+    # adjacent pairs, DeepSeek's ``rope_interleave``)
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    tie_embeddings: bool = True      # False: a head of its own, ``lm_head``
+    mtp_layers: int = 0              # num_nextn_predict_layers: 0 or 1
+    mtp_loss_weight: float = 0.1
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
 
     def __post_init__(self):
-        unknown = set(self.layer_types) - {CONV, ATTENTION}
+        if self.mtp_layers not in (0, 1):
+            raise ValueError(f"mtp_layers {self.mtp_layers}: a module of "
+                             f"depth 1 is built, or none")
+        unknown = set(self.layer_types) - {CONV, ATTENTION, LATENT}
         if unknown or not 0 <= self.num_dense_layers <= len(self.layer_types):
             raise ValueError(f"layer_types {sorted(unknown)} unknown, or "
                              f"{self.num_dense_layers} dense layers of "
@@ -134,6 +174,20 @@ class HybridDecoderConfig:
                     dtype=jnp.float32)
         base.update(kw)
         return HybridDecoderConfig(**base)
+
+    @staticmethod
+    def tiny_latent(**kw) -> "HybridDecoderConfig":
+        """DeepSeek-V3's shape at a CPU test's size: one dense and two expert
+        layers of latent attention (4 heads of 16 + 8 / 16), 8 experts of
+        which 2 a token beside a shared one, scaled 2.5, an untied head and
+        the MTP module."""
+        base = dict(layer_types=(LATENT,) * 3, num_dense_layers=1,
+                    q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
+                    qk_rope_head_dim=8, v_head_dim=16, rms_eps=1e-6,
+                    shared_expert_size=64, routed_scaling_factor=2.5,
+                    tie_embeddings=False, mtp_layers=1)
+        base.update(kw)
+        return HybridDecoderConfig.tiny(**base)
 
 
 def _dense(cfg, feats, name, axis=-1):
@@ -179,6 +233,43 @@ class CausalAttention(nn.Module):
         return _dense(cfg, cfg.hidden_size, "wo", axis=(-2, -1))(o)
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2/V3) inside a document: q
+    through a normed latent of ``q_lora_rank``, keys' position-free part and
+    values through a normed latent of ``kv_lora_rank``, ONE rotary key of
+    ``qk_rope_head_dim`` read by every query head; q and k ``qk_nope_head_dim
+    + qk_rope_head_dim`` wide, v and the output ``v_head_dim``. The shared
+    key is broadcast to the heads and k materialised ``[B, S, heads, nope +
+    rot]`` before the attention call; nothing is padded to the wider size."""
+
+    cfg: HybridDecoderConfig
+    packed: bool   # the batch carries segment ids; else no mask is streamed
+
+    @nn.compact
+    def __call__(self, x, seg, pos):
+        cfg = self.cfg
+        heads, nope, rot = (cfg.num_heads, cfg.qk_nope_head_dim,
+                            cfg.qk_rope_head_dim)
+        norm = lambda name: RMSNorm(cfg.rms_eps, cfg.dtype, name=name)
+        rotary = lambda t: rotary_embedding(t, pos, cfg.rope_theta,
+                                            interleaved=True)
+        c_q = norm("q_norm")(_dense(cfg, cfg.q_lora_rank, "wq_a")(x))
+        c_kr = _dense(cfg, cfg.kv_lora_rank + rot, "wkv_a")(x)
+        c_kv = norm("kv_norm")(c_kr[..., :cfg.kv_lora_rank])
+        k_rot = c_kr[..., cfg.kv_lora_rank:]
+        # (written only where a caller asks for "intermediates" as mutable)
+        self.sow("intermediates", "latents", (c_q, c_kv))
+        q = _dense(cfg, (heads, nope + rot), "wq_b")(c_q)
+        kv = _dense(cfg, (heads, nope + cfg.v_head_dim), "wkv_b")(c_kv)
+        q = jnp.concatenate([q[..., :nope], rotary(q[..., nope:])], axis=-1)
+        k_rot = jnp.broadcast_to(rotary(k_rot[:, :, None, :]),
+                                 (*kv.shape[:3], rot))
+        k = jnp.concatenate([kv[..., :nope], k_rot], axis=-1)
+        o = dot_product_attention(q, k, kv[..., nope:], causal=True,
+                                  segment_ids=seg if self.packed else None)
+        return _dense(cfg, cfg.hidden_size, "wo", axis=(-2, -1))(o)
+
+
 class SwiGLU(nn.Module):
     cfg: HybridDecoderConfig
 
@@ -197,6 +288,7 @@ class HybridLayer(nn.Module):
     cfg: HybridDecoderConfig
     kind: str
     dense: bool
+    packed: bool = True   # the batch carries segment ids (LATENT reads it)
 
     @nn.compact
     def __call__(self, x, seg, pos):
@@ -204,6 +296,9 @@ class HybridLayer(nn.Module):
         h = RMSNorm(cfg.rms_eps, cfg.dtype, name="operator_norm")(x)
         if self.kind == CONV:
             x = x + ShortConv(cfg, name="conv")(h, seg)
+        elif self.kind == LATENT:
+            x = x + LatentAttention(cfg, self.packed, name="self_attn")(
+                h, seg, pos)
         else:
             x = x + CausalAttention(cfg, name="self_attn")(h, seg, pos)
         h = RMSNorm(cfg.rms_eps, cfg.dtype, name="ffn_norm")(x)
@@ -216,7 +311,9 @@ class HybridLayer(nn.Module):
             param_dtype=cfg.param_dtype, score="sigmoid",
             select_bias=cfg.use_expert_bias,
             bias_update_rate=cfg.bias_update_rate,
-            train_router=cfg.train_router, name="moe")(h)
+            train_router=cfg.train_router,
+            routed_scale=cfg.routed_scaling_factor,
+            shared_size=cfg.shared_expert_size, name="moe")(h)
         stats = {"moe_load_max_over_mean": moe["load_max_over_mean"],
                  "moe_rows_held_share": moe["rows_held_share"],
                  "router_bias_abs_max": moe.get("bias_abs_max",
@@ -242,15 +339,37 @@ class _Period(nn.Module):
 
     cfg: HybridDecoderConfig
     kinds: tuple[str, ...]
+    packed: bool = True
 
     @nn.compact
     def __call__(self, x, seg, pos):
         per_layer = []
         for j, kind in enumerate(self.kinds):
-            x, stats = _layer_cls()(self.cfg, kind, False,
+            x, stats = _layer_cls()(self.cfg, kind, False, self.packed,
                                     name=f"layer_{j}")(x, seg, pos)
             per_layer.append(stats)
         return x, jax.tree.map(lambda *a: jnp.stack(a), *per_layer)
+
+
+class MTPModule(nn.Module):
+    """DeepSeek-V3's multi-token-prediction module of depth 1 (module
+    docstring): ``(h [B, S, hidden] before the final norm, the NEXT tokens'
+    embedding rows) -> (mtp_hidden, the block's expert counters)``. The
+    embedding half comes first in ``eh_proj``'s input, as the released
+    weights are laid out."""
+
+    cfg: HybridDecoderConfig
+    packed: bool
+
+    @nn.compact
+    def __call__(self, h, next_rows, seg, pos):
+        cfg = self.cfg
+        norm = lambda name: RMSNorm(cfg.rms_eps, cfg.dtype, name=name)
+        u = _dense(cfg, cfg.hidden_size, "eh_proj")(jnp.concatenate(
+            [norm("enorm")(next_rows), norm("hnorm")(h)], axis=-1))
+        u, stats = _layer_cls()(cfg, cfg.layer_types[-1], False, self.packed,
+                                name="block")(u, seg, pos)
+        return norm("final_norm")(u), stats
 
 
 def document_positions(seg: jax.Array) -> jax.Array:
@@ -265,7 +384,9 @@ def document_positions(seg: jax.Array) -> jax.Array:
 
 class HybridDecoderLM(nn.Module):
     """``{"hidden" [B, S, hidden], "lm_head" [hidden, vocab]`` (the
-    embedding, transposed) and the counters :data:`COUNTERS` ``}``."""
+    embedding, transposed, or the head's own kernel), the counters
+    :data:`COUNTERS` and, with the MTP module, ``"mtp_hidden" [B, S, hidden]``
+    and ``"mtp_weight"`` ``}``."""
 
     cfg: HybridDecoderConfig
 
@@ -278,16 +399,26 @@ class HybridDecoderLM(nn.Module):
             raise ValueError(f"sequence length {ids.shape[1]} exceeds "
                              f"max_position {cfg.max_position}")
         seg = batch.get("segment_ids")
-        seg = (jnp.zeros(ids.shape, jnp.int32) if seg is None
-               else seg.astype(jnp.int32))
+        packed = seg is not None
+        seg = (seg.astype(jnp.int32) if packed
+               else jnp.zeros(ids.shape, jnp.int32))
         pos = document_positions(seg)
-        embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
-                         param_dtype=cfg.param_dtype, name="token_embed")
+        # an embedding that is also the head keeps flax's 1 / sqrt(hidden); one
+        # of its own is N(0, 1) (torch's default), so that a token's row, and
+        # not what the first attention layer averages over its prefix, leads
+        # the residual stream: at 1 / sqrt(hidden) every position of a long
+        # window routes alike from the first step on
+        embed = nn.Embed(
+            cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, name="token_embed",
+            **({} if cfg.tie_embeddings else
+               {"embedding_init": nn.initializers.normal(1.0)}))
         x = embed(ids)
         lead, period, whole, trail = cfg.layout()
         collected = []
         for i, kind in enumerate(lead):
-            x, _ = _layer_cls()(cfg, kind, True, name=f"lead_{i}")(x, seg, pos)
+            x, _ = _layer_cls()(cfg, kind, True, packed,
+                                name=f"lead_{i}")(x, seg, pos)
         if whole:
             # ("intermediates": ``apply(capture_intermediates=...)`` reaches
             # the layers inside the scan too)
@@ -296,14 +427,25 @@ class HybridDecoderLM(nn.Module):
                                         "intermediates": 0},
                 split_rngs={"params": True}, in_axes=(nn.broadcast,
                                                       nn.broadcast),
-                length=whole)(cfg, period, name="periods")(x, seg, pos)
+                length=whole)(cfg, period, packed, name="periods")(x, seg, pos)
             collected.append(jax.tree.map(lambda a: a.reshape(-1), stats))
         for i, kind in enumerate(trail):
-            x, stats = _layer_cls()(cfg, kind, False,
+            x, stats = _layer_cls()(cfg, kind, False, packed,
                                     name=f"trail_{i}")(x, seg, pos)
             collected.append(jax.tree.map(lambda a: a.reshape(1), stats))
+        out = {}
+        if cfg.mtp_layers:
+            next_ids = jnp.concatenate(
+                [ids[:, 1:], jnp.zeros_like(ids[:, :1])], axis=1)
+            out["mtp_hidden"], stats = MTPModule(cfg, packed, name="mtp")(
+                x, embed(next_ids), seg, pos)
+            out["mtp_weight"] = jnp.float32(cfg.mtp_loss_weight)
+            collected.append(jax.tree.map(lambda a: a.reshape(1), stats))
         x = RMSNorm(cfg.rms_eps, cfg.dtype, name="final_norm")(x)
-        out = {"hidden": x, "lm_head": embed.embedding.T}
+        head = (embed.embedding.T if cfg.tie_embeddings else self.param(
+            "lm_head", nn.initializers.lecun_normal(),
+            (cfg.hidden_size, cfg.vocab_size), cfg.param_dtype))
+        out.update(hidden=x, lm_head=head)
         for name, over_layers in zip(COUNTERS, (jnp.mean, jnp.mean, jnp.max)):
             out[name] = (over_layers(jnp.concatenate(
                 [c[name] for c in collected]))
@@ -323,5 +465,6 @@ def hybrid_decoder_rules(cfg: HybridDecoderConfig, *, fsdp: bool = True,
     module holds are ITS rank's; exchanging tokens between ranks over
     ``expert`` is not built (ROADMAP queue 2, A.1)."""
     del cfg
-    rules = ((r"token_embed/embedding", P("tensor", None)),)
+    rules = ((r"token_embed/embedding", P("tensor", None)),
+             (r"lm_head", P(None, "tensor")))
     return ShardingRules(rules=rules, fsdp=fsdp, fsdp_min_size=fsdp_min_size)

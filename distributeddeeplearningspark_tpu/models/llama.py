@@ -184,13 +184,21 @@ def _remat_policy(name: str | None):
     return policies[name]
 
 
-def rotary_embedding(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Apply RoPE to [B,S,H,D] in f32, half-split (rotate-half) convention."""
+def rotary_embedding(x: jax.Array, positions: jax.Array, theta: float, *,
+                     interleaved: bool = False) -> jax.Array:
+    """Apply RoPE to [B,S,H,D] in f32: half-split (rotate-half) convention,
+    or with ``interleaved`` over ADJACENT pairs ``(2i, 2i+1)`` (DeepSeek's
+    ``rope_interleave``), the result in the same adjacent layout."""
     d = x.shape[-1]
     inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     angles = positions.astype(jnp.float32)[..., None] * inv_freq      # [B,S,D/2]
     cos = jnp.cos(angles)[:, :, None, :]                              # [B,S,1,D/2]
     sin = jnp.sin(angles)[:, :, None, :]
+    if interleaved:
+        pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], d // 2, 2)
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+        return out.reshape(x.shape).astype(x.dtype)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

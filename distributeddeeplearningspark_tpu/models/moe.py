@@ -107,14 +107,16 @@ _rows_to_tokens.defvjp(_rows_to_tokens_fwd, _rows_to_tokens_bwd)
 
 def _held_experts(xf, router, w_gate, w_up, w_down, first, bias=None, *,
                   k: int, norm_topk: bool, dtype, score: str = "softmax",
-                  train_router: bool = True, per_rank=lambda a: a):
+                  train_router: bool = True, routed_scale: float = 1.0,
+                  per_rank=lambda a: a):
     """The part of the layer that the ``n`` experts ``first .. first + n``
     add (``w_*`` are their kernels; ``first`` may be traced): ``xf [T, H] ->
     (y [T, H] float32, assignments of every expert [E] int32, the router's
     probabilities (or sigmoid scores) summed over the tokens [E])``.
     ``bias [E]`` (sigmoid only) is added to the scores for the SELECTION
-    alone. Without ``train_router`` the weights of a token's experts carry
-    no gradient (neither the router's kernel nor the tokens get one through
+    alone; ``routed_scale`` multiplies the weights (DeepSeek-V3's
+    ``routed_scaling_factor``). Without ``train_router`` the weights of a
+    token's experts carry no gradient (neither the router's kernel nor the tokens get one through
     the routing). ``per_rank`` marks the tokens' rows as differing from rank to rank
     where the ranks' work on them begins (inside a ``shard_map``; the routing
     before it is every rank's alike)."""
@@ -139,6 +141,8 @@ def _held_experts(xf, router, w_gate, w_up, w_down, first, bias=None, *,
         if norm_topk:
             gate = gate / (jnp.sum(gate, axis=-1, keepdims=True)
                            + SIGMOID_NORM_EPS)
+    if routed_scale != 1.0:
+        gate = gate * routed_scale
     if not train_router:
         gate = jax.lax.stop_gradient(gate)
     local = expert - first
@@ -207,7 +211,14 @@ class RoutedExperts(nn.Module):
     mutable, the layer moves it by ``bias_update_rate * sign(mean_e(c_e) -
     c_e)``, ``c`` being this call's assignment counts over ALL experts, and
     otherwise only reads it). Either way ``y = sum over the token's experts
-    that are held of g_e * down_e(silu(gate_e x) * up_e x)``. ``held = (first, count)`` is a contiguous range of experts: an
+    that are held of g_e * down_e(silu(gate_e x) * up_e x)``, with ``g``
+    times ``routed_scale`` where that is not 1, plus, with ``shared_size >
+    0``, a SHARED expert ``down_s(silu(gate_s x) * up_s x)`` of that width
+    that every token passes and every rank computes whole (it belongs to no
+    rank's share: the shares of all ranks sum to the whole layer with the
+    shared expert counted ONCE; applied with ``"intermediates"`` mutable the
+    layer sows the routed part alone there as ``routed``). ``held = (first,
+    count)`` is a contiguous range of experts: an
     expert-parallel rank's share. The router keeps its full width and its k a
     token whatever is held; what the absent experts would have added is left
     out (their ranks add it, and the shares of all ranks sum to the whole
@@ -245,6 +256,8 @@ class RoutedExperts(nn.Module):
     select_bias: bool = False
     bias_update_rate: float = 0.001
     train_router: bool = True
+    routed_scale: float = 1.0
+    shared_size: int = 0
 
     @nn.compact
     def __call__(self, x: jax.Array) -> tuple[jax.Array, dict]:
@@ -279,7 +292,8 @@ class RoutedExperts(nn.Module):
         chosen_with = () if bias is None else (bias.value,)
         fn = functools.partial(_held_experts, k=k, norm_topk=self.norm_topk,
                                dtype=self.dtype, score=self.score,
-                               train_router=self.train_router)
+                               train_router=self.train_router,
+                               routed_scale=self.routed_scale)
         kernels = (router, w_gate, w_up, w_down)
         mesh = resolve_mesh()
         if mesh is None or mesh.size == 1:
@@ -315,4 +329,14 @@ class RoutedExperts(nn.Module):
                 load = counts.astype(jnp.float32)
                 bias.value = chosen_with[0] + self.bias_update_rate * jnp.sign(
                     jnp.mean(load) - load)
-        return y.reshape(x.shape).astype(x.dtype), stats
+        y = y.reshape(x.shape).astype(x.dtype)
+        # (written only where a caller asks for "intermediates" as mutable)
+        self.sow("intermediates", "routed", y)
+        if self.shared_size:
+            dense = lambda feats, name: nn.Dense(
+                feats, use_bias=False, dtype=self.dtype,
+                param_dtype=self.param_dtype, name=name)
+            y = y + dense(h, "shared_down")(
+                nn.silu(dense(self.shared_size, "shared_gate")(x))
+                * dense(self.shared_size, "shared_up")(x))
+        return y, stats

@@ -21,6 +21,10 @@ Models call :func:`dot_product_attention`; the implementation is chosen by
 learned per-query selection of keys (:mod:`.indexed_attention`), with the
 same choice of a kernel path and an XLA path and the same ``shard_map``.
 
+``v`` (and the output) may have another head size than ``q`` and ``k``
+(latent attention's 192 / 128): every implementation but the ring and ulysses
+paths takes the two as they are, and the default scale is ``q``'s.
+
 All implementations take/return ``[batch, seq, heads, head_dim]`` (BSHD
 layout — batch and sequence leading so (data, fsdp) batch sharding and
 ``seq``-axis context parallelism shard the first two dims without transposes).
@@ -62,7 +66,7 @@ def dot_product_attention(
     streams them blockwise, the XLA path expands them into the mask.
     """
     if impl == "auto":
-        impl = _pick_impl(q, k, bias, mask)
+        impl = _pick_impl(q, k, bias, mask, v)
     if impl == "flash":
         return _flash_on_mesh(q, k, v, bias=bias, mask=mask, causal=causal,
                               scale=scale, segment_ids=segment_ids)
@@ -228,7 +232,7 @@ def _flash_min_seq() -> int:
         return FLASH_MIN_SEQ
 
 
-def _pick_impl(q: jax.Array, k: jax.Array, bias, mask) -> str:
+def _pick_impl(q: jax.Array, k: jax.Array, bias, mask, v=None) -> str:
     # Flash kernel requires TPU, block-divisible seq, lane-divisible head_dim,
     # a mask (if any) in key-only padding form — and a sequence long enough
     # that blockwise beats XLA's fused softmax (see FLASH_MIN_SEQ).
@@ -241,7 +245,9 @@ def _pick_impl(q: jax.Array, k: jax.Array, bias, mask) -> str:
         return "xla"
     if s < _flash_min_seq():
         return "xla"
-    if s % 512 or d % 8 or h % k.shape[2]:
+    # (each head size by itself: latent attention's 192 / 128 qualifies)
+    if s % 512 or d % 8 or (v is not None and v.shape[-1] % 8) \
+            or h % k.shape[2]:
         return "xla"
     from distributeddeeplearningspark_tpu.ops.ring_attention import resolve_mesh
 

@@ -50,6 +50,13 @@ BlockSpec index maps (q row r reads kv row ``r // group``) — the grouped KV
 is never materialized at Q-head width, which is the whole point (the
 reference-style ``repeat_interleave`` would copy KV ``group``× in HBM).
 
+**Two head sizes** (latent attention: q and k carry a position-free and a
+rotary part, 128 + 64 = 192 wide, v and o 128): ``q, k [.., d_qk]`` and ``v,
+o, do [.., d_v]`` go to the three kernels as they are. Nothing is padded to
+the wider of the two: the P V product, its backward and the bytes of v, o and
+do are the model's own. With ``d_qk == d_v`` the three calls are built as
+they always were (same grid, specs, scratch and names).
+
 Layout: [B, S, H, D] (BSHD) at the API, flattened to [B·H, S, D] /
 [B·Hkv, S, D] for the kernels (head-major order, so consecutive q rows share
 a kv row).
@@ -63,8 +70,14 @@ less HBM than the 128-lane layout the stock jax kernel uses — and the
 key-padding mask travels lane-oriented as [B, 1, Sk] so a [block_k] slice
 lands in the lane dim of the score block.
 
-Shape contract (checked): S divisible by the block sizes; D a multiple of 8
-(Mosaic pads the lane dim; 128-multiples are fastest, BERT's 64 is fine).
+Shape contract (checked): S divisible by the block sizes; each head size a
+multiple of 8. A head size is the LAST dim of its blocks and is always
+blocked whole, so it is legal as "equal to the array dim" whatever it is:
+BERT's 64 (half a lane tile) and latent attention's 192 (one and a half: no
+multiple of the 128 lanes) both compile; Mosaic lays such an operand out in
+whole 128-lane tiles in VMEM (192 takes the room of 256 there, and the MXU
+passes of a 192-deep contraction those of 256), HBM holds and the copies
+move the array's own bytes. 128-multiples waste nothing.
 """
 
 from __future__ import annotations
@@ -350,6 +363,7 @@ def _fwd_kernel(*refs, scale: float, causal: bool, has_mask: bool,
 def _flash_fwd(q, k, v, kv_mask, *, scale, causal, group, block_q, block_k,
                interpret, q_segs=None, kv_segs=None):
     bh, s, d = q.shape
+    dv = v.shape[-1]   # the value heads' width: o's too, d (q's and k's) or not
     num_qb, num_kb = s // block_q, s // block_k
     has_mask = kv_mask is not None
     has_segs = q_segs is not None
@@ -371,7 +385,7 @@ def _flash_fwd(q, k, v, kv_mask, *, scale, causal, group, block_q, block_k,
         pl.BlockSpec((1, block_q, d), lambda b, i, j, *t: (b, i, 0)),
         pl.BlockSpec((1, block_k, d),
                      lambda b, i, j, *t: (b // group, kv_blk(b, i, j, *t), 0)),
-        pl.BlockSpec((1, block_k, d),
+        pl.BlockSpec((1, block_k, dv),
                      lambda b, i, j, *t: (b // group, kv_blk(b, i, j, *t), 0)),
     ]
     operands = [q, k, v]
@@ -395,16 +409,16 @@ def _flash_fwd(q, k, v, kv_mask, *, scale, causal, group, block_q, block_k,
         grid=(bh, num_qb, num_kb),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j, *t: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j, *t: (b, i, 0)),
             pl.BlockSpec((1, block_q, STAT_LANES),
                          lambda b, i, j, *t: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, s, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, s, STAT_LANES), jnp.float32),
         ],
         scratch_shapes=[
-            vmem((block_q, d), jnp.float32),    # acc
+            vmem((block_q, dv), jnp.float32),   # acc
             vmem((block_q, 128), jnp.float32),  # m (col 0 used)
             vmem((block_q, 128), jnp.float32),  # l (col 0 used)
         ],
@@ -524,7 +538,7 @@ def _flash_bwd(res, g, *, scale, causal, group, block_q, block_k, interpret):
     kv_segs = res[7] if len(res) > 7 else None
     do = g
     bh, s, d = q.shape
-    bhkv = k.shape[0]
+    bhkv, dv = k.shape[0], v.shape[-1]
     num_qb, num_kb = s // block_q, s // block_k
     has_mask = kv_mask is not None
     has_segs = q_segs is not None
@@ -554,8 +568,8 @@ def _flash_bwd(res, g, *, scale, causal, group, block_q, block_k, interpret):
     in_specs_q = [
         pl.BlockSpec((1, block_q, d), q_row),    # q
         pl.BlockSpec((1, block_k, d), kv_row),   # k
-        pl.BlockSpec((1, block_k, d), kv_row),   # v
-        pl.BlockSpec((1, block_q, d), q_row),    # do
+        pl.BlockSpec((1, block_k, dv), kv_row),  # v
+        pl.BlockSpec((1, block_q, dv), q_row),   # do
         stat_spec(q_row),                        # lse
         stat_spec(q_row),                        # delta
     ]
@@ -595,8 +609,8 @@ def _flash_bwd(res, g, *, scale, causal, group, block_q, block_k, interpret):
     in_specs_kv = [
         pl.BlockSpec((1, block_q, d), q_stream),  # q
         pl.BlockSpec((1, block_k, d), kv_own),    # k
-        pl.BlockSpec((1, block_k, d), kv_own),    # v
-        pl.BlockSpec((1, block_q, d), q_stream),  # do
+        pl.BlockSpec((1, block_k, dv), kv_own),   # v
+        pl.BlockSpec((1, block_q, dv), q_stream), # do
         stat_spec(q_stream),                      # lse
         stat_spec(q_stream),                      # delta
     ]
@@ -620,14 +634,14 @@ def _flash_bwd(res, g, *, scale, causal, group, block_q, block_k, interpret):
         grid=(bhkv, num_kb, group * num_qb),
         in_specs=in_specs_kv,
         out_specs=[pl.BlockSpec((1, block_k, d), kv_own),
-                   pl.BlockSpec((1, block_k, d), kv_own)],
+                   pl.BlockSpec((1, block_k, dv), kv_own)],
         out_shape=[
             jax.ShapeDtypeStruct((bhkv, s, d), k.dtype),
-            jax.ShapeDtypeStruct((bhkv, s, d), v.dtype),
+            jax.ShapeDtypeStruct((bhkv, s, dv), v.dtype),
         ],
         scratch_shapes=[
             vmem((block_k, d), jnp.float32),
-            vmem((block_k, d), jnp.float32),
+            vmem((block_k, dv), jnp.float32),
         ],
         tables=tables,
         interpret=interpret,
@@ -710,7 +724,9 @@ def flash_attention(
     """BSHD flash attention (Pallas). Differentiable (custom VJP).
 
     ``mask`` may be a key-only padding mask (see :func:`as_kv_mask`); ``k``/
-    ``v`` may carry fewer (grouped) heads than ``q`` (GQA).
+    ``v`` may carry fewer (grouped) heads than ``q`` (GQA). ``v``'s head size
+    may differ from ``q``'s and ``k``'s (module docstring); the output has
+    ``v``'s, and the default ``scale`` is ``q``'s ``d_qk ** -0.5``.
     ``segment_ids`` ([B, S] int32, VERDICT r2 #4): packed-sequence document
     ids — position i may attend to j only when ``segment_ids[b, i] ==
     segment_ids[b, j]``, so multiple short documents packed into one row
@@ -723,8 +739,9 @@ def flash_attention(
         raise NotImplementedError(
             "flash kernel does not take additive bias; use impl='xla'")
     b, sq, h, d = q.shape
-    if k.shape != v.shape:
-        raise ValueError(f"k/v shapes must match: {k.shape} vs {v.shape}")
+    if k.shape[:3] != v.shape[:3]:
+        raise ValueError(f"k/v shapes must match but for the head size: "
+                         f"{k.shape} vs {v.shape}")
     bk, sk, hkv, dk = k.shape
     if (bk, dk) != (b, d) or sk != sq:
         raise ValueError(f"q/k shape mismatch: {q.shape} vs {k.shape}")
@@ -767,4 +784,4 @@ def flash_attention(
 
     o = _flash(flat(q), flat(k), flat(v), kv_mask, segs, segs,
                scale, causal, group, block_q, block_k, interpret)
-    return o.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+    return o.reshape(b, h, sq, v.shape[-1]).transpose(0, 2, 1, 3)

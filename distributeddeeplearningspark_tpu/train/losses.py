@@ -186,6 +186,31 @@ def hybrid_moe_lm(outputs: dict[str, jax.Array], batch: dict[str, Any]
     return loss, {**metrics, **{k: outputs[k] for k in COUNTERS}}
 
 
+def latent_moe_lm(outputs: dict[str, jax.Array], batch: dict[str, Any]
+                  ) -> tuple[jax.Array, dict]:
+    """:func:`hybrid_moe_lm` plus the multi-token-prediction term of a
+    :class:`~..models.hybrid_decoder.HybridDecoderLM` with ``mtp_layers = 1``:
+    ``loss = lm_loss + mtp_weight * mtp_nll``. ``mtp_nll`` is the module's
+    hidden states through the SAME head against the token TWO positions on,
+    at every row but the window's last two (the module ran over all S rows,
+    its last on a pad id); ``lm_loss`` the next-token term alone
+    (``perplexity`` is of that). Head and embedding get gradients from both."""
+    from distributeddeeplearningspark_tpu.train.fused_ce import (
+        chunked_softmax_xent,
+    )
+
+    lm_loss, metrics = hybrid_moe_lm(outputs, batch)
+    per_tok = chunked_softmax_xent(outputs["mtp_hidden"][:, :-2],
+                                   outputs["lm_head"],
+                                   batch["input_ids"][:, 2:])
+    # (the reduction shifts a ``loss_mask`` by one; this term's by two)
+    mtp_nll, _ = _reduce_next_token(per_tok, {
+        k: v[:, 1:] if k == "loss_mask" else v for k, v in batch.items()})
+    loss = lm_loss + outputs["mtp_weight"] * mtp_nll
+    return loss, {**metrics, "loss": loss, "lm_loss": lm_loss,
+                  "mtp_nll": mtp_nll}
+
+
 def _add_moe_aux(loss, metrics, outputs) -> tuple[jax.Array, dict]:
     """Fold a model-reported (already-weighted) MoE load-balance loss in."""
     if isinstance(outputs, dict) and "moe_aux" in outputs:

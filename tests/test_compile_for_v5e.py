@@ -150,10 +150,11 @@ def test_routed_experts_split_over_four_chips_compile(four_chips,
 
 # -- the flash kernel in every regime a model uses ---------------------------
 
-#: name -> (batch, seq, q heads, kv heads, head size, causal, key mask,
-#: segment ids): Llama's causal d=128, BERT-base's key-padding mask d=64,
-#: grouped KV, packed documents (mask + segment ids), and the hybrid decoder's
-#: causal + grouped 32/8 + segment ids at d=64
+#: name -> (batch, seq, q heads, kv heads, head size (or that of q and k, and
+#: v's), causal, key mask, segment ids): Llama's causal d=128, BERT-base's
+#: key-padding mask d=64, grouped KV, packed documents (mask + segment ids),
+#: the hybrid decoder's causal + grouped 32/8 + segment ids at d=64, and
+#: latent attention's 192 / 128
 FLASH_REGIMES = {
     "causal_d128": (2, 1024, 4, 4, 128, True, False, False),
     "masked_d64_bert": (2, 512, 12, 12, 64, False, True, False),
@@ -162,7 +163,34 @@ FLASH_REGIMES = {
     "gqa_causal_segments_d64": (1, 2048, 32, 8, 64, True, False, True),
     # one window of the benchmark's fourth configuration (fit_seg32k)
     "cell_seg32k": (1, 32768, 32, 8, 64, True, False, True),
+    # one window of the benchmark's fifth configuration (fit_s16k): q and k
+    # 192 wide (no multiple of the 128 lanes), v 128
+    "cell_mla_s16k": (1, 16384, 32, 32, (192, 128), True, False, False),
 }
+
+
+def _flash_regime(regime, sharding=None):
+    """``(scalar function of (q, k, v, *extra), its abstract arguments)``."""
+    from distributeddeeplearningspark_tpu.ops.flash_attention import (
+        flash_attention)
+
+    b, s, h, hkv, d, causal, masked, segmented = FLASH_REGIMES[regime]
+    d_qk, d_v = d if isinstance(d, tuple) else (d, d)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    args = [sds((b, s, h, d_qk)), sds((b, s, hkv, d_qk)), sds((b, s, hkv, d_v))]
+    args += (masked + segmented) * [sds((b, s), jnp.int32)]
+
+    def scalar(q, k, v, *extra):
+        o = flash_attention(
+            q, k, v, causal=causal, interpret=False,
+            mask=extra[0] if masked else None,
+            segment_ids=extra[-1] if segmented else None)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    return scalar, args
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "value_and_grad"])
@@ -173,27 +201,7 @@ def test_flash_kernel_compiles_in_every_regime(one_chip, no_compile_cache,
     kernel alone, and with both backward kernels. The numbers these kernels
     give are held against ``_xla_attention`` in interpret mode by
     ``tests/test_flash_attention.py``; here only the chip's compiler speaks."""
-    from distributeddeeplearningspark_tpu.ops.flash_attention import (
-        flash_attention)
-
-    b, s, h, hkv, d, causal, masked, segmented = FLASH_REGIMES[regime]
-
-    def sds(shape, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    args = [sds((b, s, h, d)), sds((b, s, hkv, d)), sds((b, s, hkv, d))]
-    if masked:
-        args.append(sds((b, s), jnp.int32))
-    if segmented:
-        args.append(sds((b, s), jnp.int32))
-
-    def scalar(q, k, v, *extra):
-        o = flash_attention(
-            q, k, v, causal=causal, interpret=False,
-            mask=extra[0] if masked else None,
-            segment_ids=extra[-1] if segmented else None)
-        return jnp.sum(o.astype(jnp.float32) ** 2)
-
+    scalar, args = _flash_regime(regime, one_chip)
     fn = jax.value_and_grad(scalar, argnums=(0, 1, 2)) if grad else scalar
     text = jax.jit(fn).lower(*args).compile().as_text()
     kernels = (("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv") if grad
@@ -212,20 +220,10 @@ def test_flash_bounds_operands_follow_the_segment_ids(regime):
     the regimes without segment ids give the parent's StableHLO and Mosaic
     bodies to the byte, locations stripped: PERF.md section 6, PR 31.)"""
     from distributeddeeplearningspark_tpu.ops.flash_attention import (
-        DEFAULT_BLOCK, flash_attention)
+        DEFAULT_BLOCK)
 
-    b, s, h, hkv, d, causal, masked, segmented = FLASH_REGIMES[regime]
-    sds = jax.ShapeDtypeStruct
-    args = [sds((b, s, h, d), jnp.bfloat16)] + 2 * [
-        sds((b, s, hkv, d), jnp.bfloat16)]
-    args += (masked + segmented) * [sds((b, s), jnp.int32)]
-
-    def scalar(q, k, v, *extra):
-        o = flash_attention(
-            q, k, v, causal=causal, interpret=False,
-            mask=extra[0] if masked else None,
-            segment_ids=extra[-1] if segmented else None)
-        return jnp.sum(o.astype(jnp.float32) ** 2)
+    b, s, h, hkv, _, _, masked, segmented = FLASH_REGIMES[regime]
+    scalar, args = _flash_regime(regime)
 
     calls = {}
 
@@ -368,3 +366,149 @@ def test_llama_09b_lora_step_fits_one_chip_as_budgeted(four_chips,
     assert budget.fits(16 * GiB), budget.to_dict()
     assert abs(budget.total_bytes - live) / live < 0.10, (
         budget.to_dict(), live / GiB)
+
+
+# -- what the accepted cells run lowers to the programs it lowered to ---------
+
+def _without_locations(text: str) -> str:
+    """A lowered module's StableHLO with every Mosaic kernel's body decoded
+    from its bytecode to MLIR text, and every source location dropped from
+    both: what is left changes only if the PROGRAM does (a docstring that
+    moves a kernel's lines does not)."""
+    import base64
+    import re
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    bodies = []
+
+    def decoded(match):
+        ctx = jax_mlir.make_ir_context()
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True   # the versioned dialect
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(match.group(1)))
+            bodies.append(module.operation.get_asm(enable_debug_info=False))
+        return f'\\22body\\22: \\22<body {len(bodies) - 1}>\\22'
+
+    text = re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', decoded, text)
+    text = re.sub(r" loc\([^\n]*\)$", "", text, flags=re.M)
+    text = re.sub(r"^#loc.*$", "", text, flags=re.M)
+    return text + "\n".join(bodies)
+
+
+def _digest(lowered) -> str:
+    import hashlib
+
+    return hashlib.sha256(
+        _without_locations(lowered.as_text()).encode()).hexdigest()[:16]
+
+
+#: sha256 (first 16 hex digits) of what each regime's value_and_grad lowered
+#: to, for the described v5e, in the tree of PR 31 (commit 3c1af97), computed
+#: by this file's own functions in that tree. A PR that changes a kernel on
+#: purpose replaces the digests of the regimes it meant to change, and says
+#: so; one that did not mean to has changed a program an accepted cell runs.
+PARENT_FLASH_LOWERINGS = {
+    "causal_d128": "3f6694519fca4569",
+    "masked_d64_bert": "aa842b73d3a24dc8",
+    "gqa_causal_d128": "18bcb92e7418be0c",
+    "masked_segments_d64": "de6843e027485bb3",
+    "gqa_causal_segments_d64": "86b44de2bf948bdd",
+    "cell_seg32k": "a9fc14772bb40ecc",
+}
+#: the same of ``lfm2_24b_a2b.fit_seg32k``'s whole train step (the
+#: configuration's model, loss and optimizer at the cell's window)
+PARENT_LFM2_STEP_LOWERING = "3be2722c198400b2"
+
+
+@pytest.mark.parametrize("regime", list(PARENT_FLASH_LOWERINGS))
+def test_flash_regimes_of_the_accepted_cells_lower_as_in_the_parent(
+        one_chip, no_compile_cache, regime):
+    """With ``d_qk == d_v`` the three ``pallas_call``s are built exactly as
+    before the kernels took two head sizes: same StableHLO around them, same
+    Mosaic bodies, locations stripped."""
+    scalar, args = _flash_regime(regime, one_chip)
+    lowered = jax.jit(jax.value_and_grad(scalar, argnums=(0, 1, 2))).lower(
+        *args)
+    assert _digest(lowered) == PARENT_FLASH_LOWERINGS[regime]
+
+
+def _lowered_step_of(name, traffic_name, devices, segment_ids):
+    """The train step of a benchmark configuration at its cell's window,
+    lowered for ONE described chip (state and batch abstract)."""
+    import json
+    import os
+
+    from benchmark.harness import runner
+    from distributeddeeplearningspark_tpu.parallel.mesh import MeshSpec
+    from distributeddeeplearningspark_tpu.parallel.sharding import (
+        ShardingRules)
+    from distributeddeeplearningspark_tpu.train import step as step_lib
+
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    mod = runner.load_module(os.path.join(root, "configs", name + ".py"))
+    with open(os.path.join(root, "configs", name + ".json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, "traffic", traffic_name + ".json")) as f:
+        traffic = json.load(f)
+    built = mod.build(cfg, traffic)
+    model, tx = built["model"], built["tx"]
+    b, s = traffic["per_chip_batch"], traffic["seq_len"]
+    batch = {"input_ids": jax.ShapeDtypeStruct((b, s), jnp.int32)}
+    if segment_ids:
+        batch["segment_ids"] = jax.ShapeDtypeStruct((b, s), jnp.int32)
+
+    def init_fn(rng):
+        variables = dict(model.init(
+            {"params": rng}, {k: jnp.zeros(v.shape, v.dtype)
+                              for k, v in batch.items()}, train=False))
+        params = variables.pop("params")
+        return step_lib.TrainState.create(
+            params=params, opt_state=tx.init(params), mutable=variables,
+            rng=rng, embed_state={})
+
+    abstract = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+    mesh = MeshSpec(data=1).build(devices[:1])
+    step = step_lib.jit_train_step(
+        step_lib.make_train_step(model.apply, tx, built["loss"]), mesh,
+        step_lib.state_shardings(abstract, mesh, ShardingRules()))
+    return step.lower(abstract, batch)
+
+
+def test_the_lfm2_train_step_lowers_as_in_the_parent(
+        four_chips, no_compile_cache, routed_as_on_the_chip, monkeypatch):
+    """``models/hybrid_decoder.py`` gained a third operator kind, a head of
+    its own and the MTP module, ``RoutedExperts`` a shared expert and a
+    factor: with none of them asked for, the step of ``fit_seg32k`` (flash
+    and short-convolution kernels, the experts, the fused head loss, AdamW)
+    lowers to the program it was."""
+    from distributeddeeplearningspark_tpu.ops import short_conv
+
+    monkeypatch.setattr(short_conv, "on_tpu", lambda: True)
+    lowered = _lowered_step_of("lfm2_24b_a2b", "fit_seg32k", four_chips, True)
+    text = lowered.as_text()
+    for name in ("flash_fwd", "flash_bwd_dkv", "shortconv_fwd",
+                 "shortconv_bwd"):
+        assert name in text, name
+    assert _digest(lowered) == PARENT_LFM2_STEP_LOWERING
+
+
+def test_the_joyai_train_step_compiles_for_one_chip_with_room_to_spare(
+        four_chips, no_compile_cache, routed_as_on_the_chip):
+    """``joyai_llm_flash.fit_s16k``'s whole step for ONE described chip: the
+    three flash kernels at 192 / 128 in it, 491.7M parameters' state and the
+    step's temporaries inside the chip's 16 GiB."""
+    compiled = _lowered_step_of("joyai_llm_flash", "fit_s16k", four_chips,
+                                False).compile()
+    text = compiled.as_text()
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert name in text, name
+    assert "[1,32,16384,16384]" not in text      # no [B, H, S, S] array
+    ma = compiled.memory_analysis()
+    live = (max(ma.argument_size_in_bytes, ma.output_size_in_bytes)
+            + ma.temp_size_in_bytes)
+    assert 0.25 * 16 * 2 ** 30 < live < 15 * 2 ** 30, live / 2 ** 30

@@ -90,3 +90,29 @@ def test_ensure_cpu_devices_only_acts_on_a_bare_cpu_rehearsal(monkeypatch):
 
     monkeypatch.setattr(jax.config, "update", live_backend)
     env.ensure_cpu_devices(4)  # the caller's own device check reports
+
+
+def test_the_package_and_a_session_load_no_model_and_no_kernel():
+    """``import distributeddeeplearningspark_tpu``, ``Session`` and
+    ``Trainer`` load what they loaded before the latent-attention decoder
+    was added (the list is PR 31's, module for module): a model's layers and
+    kernels load when a model asks for them, so no cell's ``setup_s`` pays
+    for a configuration it does not run."""
+    probe = _run(["-c", (
+        "import sys\n"
+        "import distributeddeeplearningspark_tpu\n"
+        "from distributeddeeplearningspark_tpu import Session, Trainer\n"
+        "Session.builder.master('local[1]').getOrCreate()\n"
+        "print(' '.join(sorted(m.split('.', 1)[1] for m in sys.modules\n"
+        "    if m.startswith('distributeddeeplearningspark_tpu.'))))\n")],
+        JAX_PLATFORMS="cpu")
+    assert probe.returncode == 0, probe.stderr
+    assert probe.stdout.strip().splitlines()[-1].split() == [
+        "cli", "data", "data.dataframe", "data.feed", "data.prefetch",
+        "data.records", "faults", "metrics", "parallel",
+        "parallel.collectives", "parallel.mesh", "parallel.plan",
+        "parallel.reshard", "parallel.sharding", "rdd", "session",
+        "telemetry", "telemetry.anatomy", "telemetry.spans", "train",
+        "train.losses", "train.optim", "train.state", "train.step",
+        "train.trainer", "utils", "utils.env", "utils.profiling",
+        "utils.sanitize"]

@@ -508,3 +508,109 @@ def test_the_three_kernels_carry_stable_names():
 
     walk(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v).jaxpr)
     assert sorted(names) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+
+
+# -- two head sizes: q, k wider than v (latent attention's 192 / 128) ---------
+
+def _qkv_two_sizes(b, s, h, d_qk, d_v, seed=0):
+    rng = np.random.default_rng(seed)
+    mk = lambda d: jnp.asarray(rng.normal(0, 1, (b, s, h, d)), jnp.float32)
+    return mk(d_qk), mk(d_qk), mk(d_v)
+
+
+@pytest.mark.parametrize("segmented", [False, True], ids=["plain", "segments"])
+@pytest.mark.parametrize("d_qk, d_v", [(192, 128), (24, 16)])
+def test_flash_takes_a_value_head_narrower_than_the_query_and_key(
+        d_qk, d_v, segmented):
+    """``q, k [.., d_qk]`` and ``v, o, do [.., d_v]`` go to the three kernels
+    as they are (interpreted): output, log-sum-exp and the three gradients
+    against ``_xla_attention``, causal, with and without segment ids; nothing
+    is padded to the wider size, and the default scale is ``d_qk ** -0.5``."""
+    from distributeddeeplearningspark_tpu.ops import flash_attention as fa
+
+    b, s, h = 2, 128, 2
+    q, k, v = _qkv_two_sizes(b, s, h, d_qk, d_v, seed=d_qk)
+    segs = _seg_ids(b, s, [[0, 50], [0, 30, 100]]) if segmented else None
+    seg_mask = (None if segs is None else
+                segs[:, None, :, None] == segs[:, None, None, :])
+
+    def dense(q, k, v):
+        return _xla_attention(q, k, v, bias=None, mask=seg_mask, causal=True,
+                              scale=None)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, segment_ids=segs,
+                               block_q=64, block_k=64)
+
+    got, want = flash(q, k, v), dense(q, k, v)
+    assert got.shape == (b, s, h, d_v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=3e-5, rtol=3e-5)
+    # the scale the kernel took is the query's: 1 / sqrt(d_qk), not d_v's
+    other = flash_attention(q, k, v, causal=True, segment_ids=segs,
+                            scale=d_v ** -0.5, block_q=64, block_k=64)
+    assert float(jnp.abs(other - want).max()) > 1e-3
+    w = jnp.asarray(np.random.default_rng(1).normal(size=got.shape),
+                    jnp.float32)
+    gf = jax.grad(lambda *a: jnp.sum(flash(*a) * w), argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(lambda *a: jnp.sum(dense(*a) * w), argnums=(0, 1, 2))(q, k, v)
+    for a, b_, like in zip(gf, gd, (q, k, v)):
+        assert a.shape == like.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   atol=3e-4, rtol=3e-4)
+    # the row statistic the backward kernels read: log-sum-exp of the scores
+    flat = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, s, x.shape[-1])
+    _, lse = fa._flash_fwd(
+        flat(q), flat(k), flat(v), None, scale=d_qk ** -0.5, causal=True,
+        group=1, block_q=64, block_k=64, interpret=True, q_segs=segs,
+        kv_segs=segs)
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d_qk ** -0.5
+    ok = jnp.tril(jnp.ones((s, s), bool))[None, None]
+    if seg_mask is not None:
+        ok = ok & seg_mask
+    want_lse = jax.nn.logsumexp(jnp.where(ok, logits, -jnp.inf), axis=-1)
+    np.testing.assert_allclose(np.asarray(lse).reshape(b, h, s),
+                               np.asarray(want_lse), atol=3e-5, rtol=3e-5)
+
+
+def test_two_head_sizes_route_to_the_kernel_on_a_tpu(monkeypatch):
+    """``_pick_impl`` reads each head size by itself: 192 / 128 qualifies (192
+    is no multiple of the 128 lanes, but a multiple of 8), and a mesh splits
+    the call as any other."""
+    from distributeddeeplearningspark_tpu.ops import attention, ring_attention
+    from distributeddeeplearningspark_tpu.parallel.mesh import MeshSpec
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    q = jnp.zeros((1, 1024, 32, 192))
+    v = jnp.zeros((1, 1024, 32, 128))
+    assert _pick_impl(q, q, None, None, v) == "flash"
+    assert _pick_impl(q, q, None, None, v[..., :100]) == "xla"   # 100 % 8
+    assert _pick_impl(q[..., :100], q[..., :100], None, None, v) == "xla"
+    monkeypatch.setattr(attention, "on_tpu", lambda: False)
+    mesh = MeshSpec(data=2, tensor=2).build(jax.devices()[:4])
+    monkeypatch.setattr(ring_attention, "_default_mesh", mesh)
+    q, k, v = _qkv_two_sizes(4, 128, 4, 24, 16, seed=2)
+
+    def run(impl):
+        def loss(q, k, v):
+            o = attention.dot_product_attention(q, k, v, causal=True,
+                                                impl=impl)
+            return jnp.sum(o ** 2), o
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    (_, o), g = run("flash")(q, k, v)
+    (_, o_ref), g_ref = run("xla")(q, k, v)
+    assert o.shape == (4, 128, 4, 16)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), atol=2e-5)
+    for got, want in zip(g, g_ref):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-4)
+
+
+def test_flash_rejects_keys_and_values_that_differ_but_for_the_head_size():
+    q, k, v = _qkv_two_sizes(1, 64, 2, 24, 16)
+    with pytest.raises(ValueError, match="k/v shapes"):
+        flash_attention(q, k, v[:, :, :1])
+    with pytest.raises(ValueError, match="q/k shape"):
+        flash_attention(q, k[..., :16], v)

@@ -42,6 +42,11 @@ FAMILIES = {
     "dlrm_sparse_tables": ("train_dlrm.py", [
         "--master", "local[8]", "--vocab-size", "50", "--num-sparse", "4",
         "--embed-dim", "8", "--batch-size", "32", "--eval-examples", "32"]),
+    # latent attention, a shared expert beside the routed ones (their
+    # ``shard_map`` over the mesh) and the MTP module's second loss term
+    "latent_moe_lm": ("train_latent_moe_lm.py", [
+        "--master", "local[8]", "--variant", "tiny", "--seq-len", "128",
+        "--batch-size", "8"]),
 }
 
 #: counters of ``spans.COUNTERS`` that only some feeds write: the map's
@@ -89,6 +94,7 @@ def run(request, tmp_path_factory):
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("DLS_TELEMETRY_DIR", tele_dir)
         mp.setattr(sys, "argv", [script, *argv])
+        mp.syspath_prepend(os.path.join(ROOT, "examples"))  # scripts' siblings
         mp.setattr(Trainer, "fit", three_steps)
         runpy.run_path(os.path.join(ROOT, "examples", script),
                        run_name="__main__")

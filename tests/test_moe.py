@@ -142,6 +142,66 @@ class TestRoutedExperts:
         for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
             np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
 
+    @pytest.mark.parametrize("scale", [1.0, 2.5])
+    def test_a_shared_expert_beside_the_routed_ones_and_their_factor(
+            self, scale):
+        """``shared_size``: a SwiGLU every token passes, added to the routed
+        part; ``routed_scale``: the routed part's weights times it. Against
+        the layer's definition in numpy; the routed part alone is what the
+        layer sows where "intermediates" is asked for."""
+        x = _x(b=2, s=16, seed=6)
+        m = RoutedExperts(16, 32, num_experts=4, top_k=2, dtype=jnp.float32,
+                          routed_scale=scale, shared_size=24)
+        v = m.init(jax.random.PRNGKey(6), x)
+        p = v["params"]
+        assert set(p) == {"router", "w_gate", "w_up", "w_down", "shared_gate",
+                          "shared_up", "shared_down"}
+        assert p["shared_gate"]["kernel"].shape == (16, 24)
+        (y, _), seen = m.apply(v, x, mutable=["intermediates"])
+        xf = np.asarray(x, np.float64).reshape(-1, 16)
+        a = xf @ np.asarray(p["shared_gate"]["kernel"], np.float64)
+        shared = (a / (1 + np.exp(-a)) * (
+            xf @ np.asarray(p["shared_up"]["kernel"], np.float64))
+        ) @ np.asarray(p["shared_down"]["kernel"], np.float64)
+        routed = scale * _dense_experts(x, p, 2)
+        np.testing.assert_allclose(
+            np.asarray(seen["intermediates"]["routed"][0]).reshape(-1, 16),
+            routed, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(y).reshape(-1, 16),
+                                   routed + shared, atol=1e-5)
+        assert set(m.apply(v, x)[1]) == {"aux", "load_max_over_mean",
+                                         "rows_held_share"}
+
+    def test_the_shared_expert_is_whole_on_every_rank_of_a_mesh(
+            self, eight_devices):
+        """Over ``data=2 x expert=2 x tensor=2`` the routed part is the
+        ``psum`` of the ranks' parts and the shared expert is added ONCE,
+        outside the ``shard_map``: the one-device layer, forward and
+        backward."""
+        x = _x(b=4, s=8, seed=7)
+        m = RoutedExperts(16, 32, num_experts=4, top_k=2, dtype=jnp.float32,
+                          score="sigmoid", select_bias=True, routed_scale=2.5,
+                          shared_size=16)
+        v = m.init(jax.random.PRNGKey(7), x)
+        cot = _x(b=4, s=8, seed=8)
+
+        def f(params, x):
+            y, stats = m.apply({**v, "params": params}, x)
+            return jnp.sum(y * cot), (y, stats)
+
+        want = jax.value_and_grad(f, argnums=(0, 1), has_aux=True)(
+            v["params"], x)
+        mesh = MeshSpec(data=2, expert=2, tensor=2).build(eight_devices)
+        ring_attention.set_default_mesh(mesh)
+        try:
+            with mesh:
+                got = jax.jit(jax.value_and_grad(
+                    f, argnums=(0, 1), has_aux=True))(v["params"], x)
+        finally:
+            ring_attention.set_default_mesh(None)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
+
     def test_a_mesh_the_experts_do_not_divide_by_is_refused(
             self, eight_devices):
         x = _x(b=4, s=8)
