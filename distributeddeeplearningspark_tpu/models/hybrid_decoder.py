@@ -83,6 +83,7 @@ from distributeddeeplearningspark_tpu.models.moe import (
 from distributeddeeplearningspark_tpu.ops.attention import dot_product_attention
 from distributeddeeplearningspark_tpu.ops.flash_attention import (
     FLASH_OUT_NAME,
+    attn_blocks_masked_share,
     attn_blocks_walked_share,
 )
 from distributeddeeplearningspark_tpu.ops.short_conv import gated_short_conv
@@ -90,11 +91,11 @@ from distributeddeeplearningspark_tpu.parallel.sharding import ShardingRules
 
 CONV, ATTENTION, LATENT = "conv", "full_attention", "latent_attention"
 #: the step's counters (docs/OBSERVABILITY.md): the first two are means over
-#: the expert layers, the third the largest over them, the last two the
+#: the expert layers, the third the largest over them, the last three the
 #: batch's; ``losses.hybrid_moe_lm`` carries them into the step's metrics
 COUNTERS = ("moe_load_max_over_mean", "moe_rows_held_share",
             "router_bias_abs_max", "attn_pairs_share",
-            "attn_blocks_walked_share")
+            "attn_blocks_walked_share", "attn_blocks_masked_share")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -455,6 +456,8 @@ class HybridDecoderLM(nn.Module):
             jnp.sum(pos.astype(jnp.float32) + 1.0, axis=1)) / (s * (s + 1) / 2)
         # what the flash kernels walk of the triangle, by their own predicate
         out["attn_blocks_walked_share"] = attn_blocks_walked_share(seg)
+        # and, of those, what needs its mask (dQ and dK/dV leave it out elsewhere)
+        out["attn_blocks_masked_share"] = attn_blocks_masked_share(seg)
         return out
 
 

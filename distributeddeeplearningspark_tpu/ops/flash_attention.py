@@ -17,8 +17,10 @@ Supported masking (BASELINE.json config 3 needs this — BERT always attends
 under a key-padding mask):
 
 - ``causal`` — per-block: blocks strictly above the diagonal are skipped
-  (their grid steps no-op; without segment ids their keys are still
-  copied), the diagonal block gets a positional mask.
+  (their grid steps no-op and, in every regime, copy nothing: the index maps
+  clamp the streamed block to the row's last block on the diagonal), a block
+  the diagonal crosses gets a positional mask, a block strictly under it
+  none.
 - ``mask`` — a *key-only* padding mask ([B, Sk] or the BERT-style
   [B, 1, 1, Sk]); streamed into the kernel one [block_k] slice at a time, so
   no [S, S] mask tensor is ever built. Q-dependent masks are not expressible
@@ -37,7 +39,24 @@ under a key-padding mask):
   running ids (documents packed one after another), a superset for ids in
   any order (pads at -1). :func:`attn_blocks_walked_share` counts, from the
   same predicate, what is walked of the triangle. Without segment ids the
-  three calls take no table and are built as they always were.
+  three calls take no table.
+
+**Three classes of grid step.** A step does only what its block needs, by
+what the kernel can observe (the static regime, the step's ``(qb, kb)`` and,
+under segment ids, the two tables): *nothing* (above the diagonal, or no
+document spans it: no compute and no copy), *whole* (every pair allowed: it
+lies strictly under the diagonal where ``causal`` and, under segment ids, its
+queries and its keys are all of ONE document, :func:`_block_whole`), *edge*
+(every other walked block: the diagonal, a document boundary). In dQ and
+dK/dV a *whole* block runs the body without :func:`_block_mask` and without
+the select on ``p``, both identities there, so the bits are those of
+masking; an *edge* block the masking body. The forward kernel masks every
+walked block: measured on the chip its mask is free at both head sizes
+(something else binds its step) and a second body only made it slower. A
+regime with a key mask has no *whole* class (what a key block's mask holds
+is not known to the kernel) and one without any mask no *edge*: their bodies
+are emitted once, as they always were. :func:`attn_blocks_masked_share`
+counts, from the same predicate, the *edge* blocks among the walked.
 
 Masked logits use a large *finite* negative (never -inf: running-max
 subtraction would produce inf - inf = NaN on fully-masked blocks) and
@@ -148,6 +167,23 @@ def _blocks_meet(q_lo, q_hi, k_lo, k_hi, qb, kb, *, causal, block_q, block_k):
     return meet
 
 
+def _block_whole(q_lo, q_hi, k_lo, k_hi, qb, kb, *, causal, block_q, block_k):
+    """Whether a WALKED score block ``(qb, kb)`` allows every pair in it, so
+    that masking it is the identity: its queries and its keys are all of one
+    document (the four bounds equal; ``None`` without segment ids; exact for
+    ids in any order and on a ring hop) and, with ``causal``, its last key
+    does not come after its first query. A key mask is not seen here: a
+    regime with one has no such class. On scalars inside the kernels, on
+    arrays in :func:`attn_blocks_masked_share`: one predicate for both."""
+    whole = True
+    if q_lo is not None:
+        whole = jnp.logical_and(jnp.logical_and(q_lo == q_hi, k_lo == k_hi),
+                                q_lo == k_lo)
+    if causal:
+        whole = jnp.logical_and(whole, (kb + 1) * block_k - 1 <= qb * block_q)
+    return whole
+
+
 def segment_block_walk(q_segs, kv_segs, *, causal, block_q, block_k):
     """What the three kernels walk under segment ids, from ``q_segs`` and
     ``kv_segs`` [B, S] (they differ on a ring hop).
@@ -181,6 +217,21 @@ def segment_block_walk(q_segs, kv_segs, *, causal, block_q, block_k):
             pack(k_lo, k_hi, q_first, q_last))
 
 
+def segment_block_classes(q_segs, kv_segs, *, causal, block_q, block_k):
+    """``(walk, whole)`` bool [B, S/block_q, S/block_k]: the blocks the
+    kernels compute under these ids and, of them, those that need no mask,
+    by the kernels' own two predicates on the tables they are handed."""
+    walk, q_side, k_side = segment_block_walk(
+        q_segs, kv_segs, causal=causal, block_q=block_q, block_k=block_k)
+    b, nq, nk = walk.shape
+    whole = _block_whole(
+        *q_side[:2].reshape(2, b, nq, 1), *k_side[:2].reshape(2, b, 1, nk),
+        jnp.arange(nq, dtype=jnp.int32)[None, :, None],
+        jnp.arange(nk, dtype=jnp.int32)[None, None, :],
+        causal=causal, block_q=block_q, block_k=block_k)
+    return walk, jnp.logical_and(walk, whole)
+
+
 def attn_blocks_walked_share(segment_ids, *, block: int = DEFAULT_BLOCK):
     """Blocks the causal kernels walk under ``segment_ids`` [B, S] over the
     ``n (n + 1) / 2`` on or under the diagonal, mean over the rows: 1.0 for a
@@ -198,6 +249,24 @@ def attn_blocks_walked_share(segment_ids, *, block: int = DEFAULT_BLOCK):
             / (n * (n + 1) / 2))
 
 
+def attn_blocks_masked_share(segment_ids, *, block: int = DEFAULT_BLOCK):
+    """Of the blocks the causal kernels walk under ``segment_ids`` [B, S],
+    the share that needs its mask (*edge*: dQ and dK/dV run the masking body
+    there and the body without a mask in the rest), over the batch: ``2 / (n
+    + 1)`` for a window of ``n`` blocks that is one document (the diagonal
+    alone), more with every document boundary. From :func:`_block_whole`,
+    which the kernels branch on. A length the kernels do not take goes to the
+    XLA path, which masks every pair."""
+    s = segment_ids.shape[1]
+    block = min(block, s)
+    if s % block:
+        return jnp.float32(1.0)
+    walk, whole = segment_block_classes(
+        segment_ids, segment_ids, causal=True, block_q=block, block_k=block)
+    walked = jnp.sum(walk).astype(jnp.float32)
+    return (walked - jnp.sum(whole)) / jnp.maximum(walked, 1.0)
+
+
 def _within_hull(side, at, j):
     """Index-map helper: block ``j`` clamped to the hull of the walked blocks
     of table entry ``at``, so that a run of skipped steps names the block
@@ -205,12 +274,19 @@ def _within_hull(side, at, j):
     return jnp.clip(j, side[2, at], side[3, at])
 
 
-def _streamed_key_block(heads: int, num_qb: int):
+def _streamed_key_block(heads: int, num_qb: int, *, causal: bool,
+                        block_q: int, block_k: int):
     """The key block the forward and dQ grids ``(b, i, j)`` fetch at a step.
-    Index maps take the tables last (``*t``: none without segment ids, and
-    then the block is ``j`` as it always was)."""
+    Index maps take the tables last (``*t``: none without segment ids). A step
+    that computes nothing names the block already resident: under segment ids
+    the hull of the walked ones, else with ``causal`` the row's last block on
+    the diagonal, else (every block is walked) ``j``."""
     def kv_blk(b, i, j, *t):
-        return _within_hull(t[0], b // heads * num_qb + i, j) if t else j
+        if t:
+            return _within_hull(t[0], b // heads * num_qb + i, j)
+        if causal:
+            return jnp.minimum(j, jax.lax.div((i + 1) * block_q - 1, block_k))
+        return j
     return kv_blk
 
 
@@ -285,24 +361,36 @@ def _split_refs(refs, fixed: int, has_mask: bool, has_segs: bool):
     return tables, refs[:fixed], mask_ref, qseg_ref, kseg_ref, refs[i:]
 
 
-def _when_walked(compute, tables, b, qb, kb, *, heads, causal, num_qb, num_kb,
-                 block_q, block_k):
-    """Run ``compute`` for the blocks that can hold an allowed pair: under
-    segment ids (``tables``; grid row ``b`` belongs to batch row ``b //
-    heads``) those :func:`_blocks_meet` names, else those on or under the
-    diagonal (``causal``), else all. A skipped block would have left the
-    accumulators as they are, to the bit."""
+def _by_class(compute, tables, b, qb, kb, *, heads, causal, classed, num_qb,
+              num_kb, block_q, block_k):
+    """Run ``compute(masked)`` as the class of block ``(qb, kb)`` needs
+    (module docstring). *Nothing*: not at all; a skipped block would have
+    left the accumulators as they are, to the bit. Walked are, under segment
+    ids (``tables``; grid row ``b`` belongs to batch row ``b // heads``), the
+    blocks :func:`_blocks_meet` names, else those on or under the diagonal
+    (``causal``), else all. With ``classed`` a walked block that is *whole*
+    by :func:`_block_whole` runs ``compute(False)``, the body without the
+    mask; every other one, and without ``classed`` every walked one,
+    ``compute(True)``."""
+    bounds = dict(causal=causal, block_q=block_q, block_k=block_k)
+    ids = (None,) * 4
+    walked = True
     if tables:
         q_side, k_side = tables
         qi, ki = b // heads * num_qb + qb, b // heads * num_kb + kb
-        pl.when(_blocks_meet(
-            q_side[0, qi], q_side[1, qi], k_side[0, ki], k_side[1, ki], qb, kb,
-            causal=causal, block_q=block_q, block_k=block_k))(compute)
+        ids = (q_side[0, qi], q_side[1, qi], k_side[0, ki], k_side[1, ki])
+        walked = _blocks_meet(*ids, qb, kb, **bounds)
     elif causal:
         # blocks strictly above the diagonal contribute nothing
-        pl.when(kb * block_k < (qb + 1) * block_q)(compute)
-    else:
-        compute()
+        walked = kb * block_k < (qb + 1) * block_q
+    if not classed or not (tables or causal):   # (no mask at all: no edge)
+        pl.when(walked)(lambda: compute(True))
+        return
+
+    @pl.when(walked)    # the second test inside: a skipped step pays for one
+    def _():
+        jax.lax.cond(_block_whole(*ids, qb, kb, **bounds),
+                     lambda: compute(False), lambda: compute(True))
 
 
 def _fwd_kernel(*refs, scale: float, causal: bool, has_mask: bool,
@@ -320,17 +408,19 @@ def _fwd_kernel(*refs, scale: float, causal: bool, has_mask: bool,
         m_ref[:] = jnp.full_like(m_ref, _MASK_VALUE)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    def compute():
+    def compute(masked):
         q = q_ref[0].astype(jnp.float32) * scale          # [Bq, D]
         k = k_ref[0].astype(jnp.float32)                  # [Bk, D]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # [Bq, Bk]
-        s, allowed = _block_mask(
-            qb, kb, s, causal=causal,
-            mask_blk=mask_ref[0, 0] if has_mask else None,
-            block_q=block_q, block_k=block_k,
-            q_seg_blk=qseg_ref[0, :, 0] if has_segs else None,
-            k_seg_blk=kseg_ref[0, 0] if has_segs else None)
+        allowed = None
+        if masked:
+            s, allowed = _block_mask(
+                qb, kb, s, causal=causal,
+                mask_blk=mask_ref[0, 0] if has_mask else None,
+                block_q=block_q, block_k=block_k,
+                q_seg_blk=qseg_ref[0, :, 0] if has_segs else None,
+                k_seg_blk=kseg_ref[0, 0] if has_segs else None)
         m_prev = m_ref[:, 0]                              # [Bq]
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
         p = jnp.exp(s - m_cur[:, None])
@@ -345,9 +435,11 @@ def _fwd_kernel(*refs, scale: float, causal: bool, has_mask: bool,
                      preferred_element_type=jnp.float32)  # [Bq, D]
         acc_ref[:] = acc_ref[:] * corr[:, None] + pv
 
-    _when_walked(compute, tables, b, qb, kb, heads=heads, causal=causal,
-                 num_qb=num_qb, num_kb=num_kb, block_q=block_q,
-                 block_k=block_k)
+    # one body here: the mask is free in this kernel (its statistics bind it;
+    # PERF.md section 5), and a second body only lengthened its steps
+    _by_class(compute, tables, b, qb, kb, heads=heads, causal=causal,
+              classed=False, num_qb=num_qb, num_kb=num_kb, block_q=block_q,
+              block_k=block_k)
 
     @pl.when(kb == num_kb - 1)
     def _finalize():
@@ -380,7 +472,8 @@ def _flash_fwd(q, k, v, kv_mask, *, scale, causal, group, block_q, block_k,
         block_q=block_q, block_k=block_k,
     )
 
-    kv_blk = _streamed_key_block(heads, num_qb)
+    kv_blk = _streamed_key_block(heads, num_qb, causal=causal,
+                                 block_q=block_q, block_k=block_k)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j, *t: (b, i, 0)),
         pl.BlockSpec((1, block_k, d),
@@ -444,17 +537,19 @@ def _bwd_dq_kernel(*refs, scale: float, causal: bool, has_mask: bool,
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def compute():
+    def compute(masked):
         q = q_ref[0].astype(jnp.float32) * scale
         k = k_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        s, allowed = _block_mask(
-            qb, kb, s, causal=causal,
-            mask_blk=mask_ref[0, 0] if has_mask else None,
-            block_q=block_q, block_k=block_k,
-            q_seg_blk=qseg_ref[0, :, 0] if has_segs else None,
-            k_seg_blk=kseg_ref[0, 0] if has_segs else None)
+        allowed = None
+        if masked:
+            s, allowed = _block_mask(
+                qb, kb, s, causal=causal,
+                mask_blk=mask_ref[0, 0] if has_mask else None,
+                block_q=block_q, block_k=block_k,
+                q_seg_blk=qseg_ref[0, :, 0] if has_segs else None,
+                k_seg_blk=kseg_ref[0, 0] if has_segs else None)
         p = jnp.exp(s - lse_ref[0, :, 0][:, None])                 # [Bq, Bk]
         if allowed is not None:
             p = jnp.where(allowed, p, 0.0)
@@ -465,9 +560,10 @@ def _bwd_dq_kernel(*refs, scale: float, causal: bool, has_mask: bool,
         ds = p * (dp - delta_ref[0, :, 0][:, None])                # [Bq, Bk]
         acc_ref[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
-    _when_walked(compute, tables, b, qb, kb, heads=heads, causal=causal,
-                 num_qb=num_qb, num_kb=num_kb, block_q=block_q,
-                 block_k=block_k)
+    # (what a key block's mask holds is not known here: no *whole* class)
+    _by_class(compute, tables, b, qb, kb, heads=heads, causal=causal,
+              classed=not has_mask, num_qb=num_qb, num_kb=num_kb,
+              block_q=block_q, block_k=block_k)
 
     @pl.when(kb == num_kb - 1)
     def _finalize():
@@ -495,17 +591,19 @@ def _bwd_dkv_kernel(*refs, scale: float, causal: bool, has_mask: bool,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def compute():
+    def compute(masked):
         q = q_ref[0].astype(jnp.float32) * scale
         k = k_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # [Bq, Bk]
-        s, allowed = _block_mask(
-            qb, kb, s, causal=causal,
-            mask_blk=mask_ref[0, 0] if has_mask else None,
-            block_q=block_q, block_k=block_k,
-            q_seg_blk=qseg_ref[0, :, 0] if has_segs else None,
-            k_seg_blk=kseg_ref[0, 0] if has_segs else None)
+        allowed = None
+        if masked:
+            s, allowed = _block_mask(
+                qb, kb, s, causal=causal,
+                mask_blk=mask_ref[0, 0] if has_mask else None,
+                block_q=block_q, block_k=block_k,
+                q_seg_blk=qseg_ref[0, :, 0] if has_segs else None,
+                k_seg_blk=kseg_ref[0, 0] if has_segs else None)
         p = jnp.exp(s - lse_ref[0, :, 0][:, None])                 # [Bq, Bk]
         if allowed is not None:
             p = jnp.where(allowed, p, 0.0)
@@ -522,9 +620,10 @@ def _bwd_dkv_kernel(*refs, scale: float, causal: bool, has_mask: bool,
         dk_acc[:] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
 
-    _when_walked(compute, tables, b, qb, kb, heads=heads, causal=causal,
-                 num_qb=num_qb, num_kb=num_kb, block_q=block_q,
-                 block_k=block_k)
+    # (what a key block's mask holds is not known here: no *whole* class)
+    _by_class(compute, tables, b, qb, kb, heads=heads, causal=causal,
+              classed=not has_mask, num_qb=num_qb, num_kb=num_kb,
+              block_q=block_q, block_k=block_k)
 
     @pl.when(j == group * num_qb - 1)
     def _finalize():
@@ -559,7 +658,8 @@ def _flash_bwd(res, g, *, scale, causal, group, block_q, block_k, interpret):
                   block_q=block_q, block_k=block_k)
     vmem = _vmem()
 
-    kv_blk = _streamed_key_block(heads, num_qb)
+    kv_blk = _streamed_key_block(heads, num_qb, causal=causal,
+                                 block_q=block_q, block_k=block_k)
     q_row = lambda b, i, j, *t: (b, i, 0)
     kv_row = lambda b, i, j, *t: (b // group, kv_blk(b, i, j, *t), 0)
     lane_spec = pl.BlockSpec(
@@ -601,7 +701,11 @@ def _flash_bwd(res, g, *, scale, causal, group, block_q, block_k, interpret):
 
     def q_blk(b, i, j, *t):  # the query block streamed at this step
         qb = j % num_qb
-        return _within_hull(t[1], b // kvheads * num_kb + i, qb) if t else qb
+        if t:
+            return _within_hull(t[1], b // kvheads * num_kb + i, qb)
+        if causal:  # each head's first query block at or under the key block
+            return jnp.maximum(qb, jax.lax.div(i * block_k, block_q))
+        return qb
 
     q_head = lambda b, j: b * group + j // num_qb
     q_stream = lambda b, i, j, *t: (q_head(b, j), q_blk(b, i, j, *t), 0)

@@ -407,29 +407,36 @@ def _digest(lowered) -> str:
 
 
 #: sha256 (first 16 hex digits) of what each regime's value_and_grad lowered
-#: to, for the described v5e, in the tree of PR 31 (commit 3c1af97), computed
-#: by this file's own functions in that tree. A PR that changes a kernel on
-#: purpose replaces the digests of the regimes it meant to change, and says
-#: so; one that did not mean to has changed a program an accepted cell runs.
+#: to, for the described v5e, computed by this file's own functions: the two
+#: regimes with a key mask (both BERT cells run ``masked_d64_bert``) in the
+#: tree of PR 31 (commit 3c1af97), and they have not moved since; the causal
+#: regimes without one in the tree of PR 33, which meant to change them (the
+#: index maps clamp above the diagonal, dQ and dK/dV carry a second body).
+#: A PR that changes a kernel on purpose replaces the digests of the regimes
+#: it meant to change, and says so; one that did not mean to has changed a
+#: program an accepted cell runs.
 PARENT_FLASH_LOWERINGS = {
-    "causal_d128": "3f6694519fca4569",
+    "causal_d128": "3f85d2a655fc82de",
     "masked_d64_bert": "aa842b73d3a24dc8",
-    "gqa_causal_d128": "18bcb92e7418be0c",
+    "gqa_causal_d128": "74fd2eb0428c2d07",
     "masked_segments_d64": "de6843e027485bb3",
-    "gqa_causal_segments_d64": "86b44de2bf948bdd",
-    "cell_seg32k": "a9fc14772bb40ecc",
+    "gqa_causal_segments_d64": "252ea21bfb3e9ecc",
+    "cell_seg32k": "088695e3219ba773",
+    "cell_mla_s16k": "c68d4331ee8a8f3a",
 }
 #: the same of ``lfm2_24b_a2b.fit_seg32k``'s whole train step (the
-#: configuration's model, loss and optimizer at the cell's window)
-PARENT_LFM2_STEP_LOWERING = "3be2722c198400b2"
+#: configuration's model, loss and optimizer at the cell's window), in the
+#: tree of PR 33 (its kernels, and one more counter among the outputs)
+PARENT_LFM2_STEP_LOWERING = "7e32b460313c7c25"
 
 
 @pytest.mark.parametrize("regime", list(PARENT_FLASH_LOWERINGS))
 def test_flash_regimes_of_the_accepted_cells_lower_as_in_the_parent(
         one_chip, no_compile_cache, regime):
-    """With ``d_qk == d_v`` the three ``pallas_call``s are built exactly as
-    before the kernels took two head sizes: same StableHLO around them, same
-    Mosaic bodies, locations stripped."""
+    """Every regime lowers to the program its digest was taken from: same
+    StableHLO around the three ``pallas_call``s, same Mosaic bodies,
+    locations stripped. The regimes with a key mask hold the program both
+    BERT cells ran before the kernels classed their steps (PR 33)."""
     scalar, args = _flash_regime(regime, one_chip)
     lowered = jax.jit(jax.value_and_grad(scalar, argnums=(0, 1, 2))).lower(
         *args)
@@ -481,11 +488,10 @@ def _lowered_step_of(name, traffic_name, devices, segment_ids):
 
 def test_the_lfm2_train_step_lowers_as_in_the_parent(
         four_chips, no_compile_cache, routed_as_on_the_chip, monkeypatch):
-    """``models/hybrid_decoder.py`` gained a third operator kind, a head of
-    its own and the MTP module, ``RoutedExperts`` a shared expert and a
-    factor: with none of them asked for, the step of ``fit_seg32k`` (flash
-    and short-convolution kernels, the experts, the fused head loss, AdamW)
-    lowers to the program it was."""
+    """The step of ``fit_seg32k`` (flash and short-convolution kernels, the
+    experts, the fused head loss, AdamW) lowers to the program its digest
+    was taken from: a change to ``models/hybrid_decoder.py``, ``models/moe.py``
+    or an op that did not mean to reach this cell has not."""
     from distributeddeeplearningspark_tpu.ops import short_conv
 
     monkeypatch.setattr(short_conv, "on_tpu", lambda: True)

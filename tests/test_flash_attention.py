@@ -375,19 +375,18 @@ SKIP_CASES = ("causal_gqa_documents", "masked_segments_pads",
               "unordered_ids_two_sides")
 
 
-def _run_kernels(case):
+def _run_kernels(case, block_q=64, block_k=64):
     """Forward and the three gradients through the kernels themselves."""
     from distributeddeeplearningspark_tpu.ops import flash_attention as fa
 
     q, k, v, mask, q_segs, kv_segs, causal = case
     flat = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, *x.shape[1::2])
     qf, kf, vf = flat(q), flat(k), flat(v)
-    mask = None if mask is None else jnp.asarray(mask, jnp.int32)
-    q_segs = jnp.asarray(q_segs, jnp.int32)
-    kv_segs = jnp.asarray(kv_segs, jnp.int32)
+    int32 = lambda x: None if x is None else jnp.asarray(x, jnp.int32)
+    mask, q_segs, kv_segs = int32(mask), int32(q_segs), int32(kv_segs)
     opts = dict(scale=q.shape[-1] ** -0.5, causal=causal,
-                group=q.shape[2] // k.shape[2], block_q=64, block_k=64,
-                interpret=True)
+                group=q.shape[2] // k.shape[2], block_q=block_q,
+                block_k=block_k, interpret=True)
     o, lse = fa._flash_fwd(qf, kf, vf, mask, q_segs=q_segs, kv_segs=kv_segs,
                            **opts)
     do = jnp.asarray(np.random.default_rng(5).normal(0, 1, o.shape), o.dtype)
@@ -479,6 +478,214 @@ def test_a_window_of_one_document_walks_the_whole_triangle():
     assert float(attn_blocks_walked_share(many, block=64)) == pytest.approx(0.4)
     # shorter than a block: one block, walked
     assert float(attn_blocks_walked_share(one[:, :48])) == 1.0
+
+
+# -- a step does only what its block needs (PR 33) ----------------------------
+
+def _class_case(name):
+    """-> (case as :func:`_skip_case` gives it, block_q, block_k)."""
+    if name in ("causal", "causal_bq64_bk32", "causal_bq32_bk64"):
+        q, k, v = _qkv(b=1, s=256, h=2, d=32, seed=41)
+        bq, bk = {"causal": (64, 64), "causal_bq64_bk32": (64, 32),
+                  "causal_bq32_bk64": (32, 64)}[name]
+        return (q, k, v, None, None, None, True), bq, bk
+    if name == "causal_gqa":
+        q, k, v = _qkv(b=2, s=256, h=4, d=32, hkv=1, seed=42)
+        return (q, k, v, None, None, None, True), 64, 64
+    if name == "causal_documents":
+        return _skip_case("causal_gqa_documents"), 64, 64
+    if name == "documents_bq64_bk32":
+        # a document edge at 96 lies on a key block's edge and inside a
+        # query block; the second row is one document
+        s = 256
+        segs = np.stack([_ids_of([96, 70], s), np.zeros(s, np.int64)])
+        q, k, v = _qkv(b=2, s=s, h=2, d=32, seed=43)
+        return (q, k, v, None, segs, segs, True), 64, 32
+    if name == "two_sided_ids":
+        # a ring hop, not causal, ids in no order. Blocks of 64: the queries'
+        # ranges are [3,3] [0,1] [5,5] [2,2], the keys' [5,5] [3,3] [2,2]
+        # [0,4]: three blocks are whole, (0, 1), (2, 0) and (3, 2)
+        q_segs = np.repeat(np.array([3, 3, 0, 1, 5, 5, 2, 2]), 32)[None, :]
+        kv_segs = np.repeat(np.array([5, 5, 3, 3, 2, 2, 0, 4]), 32)[None, :]
+        q, k, v = _qkv(b=1, s=256, h=2, d=32, seed=44)
+        return (q, k, v, None, q_segs, kv_segs, False), 64, 64
+    if name == "causal_key_mask":
+        q, k, v = _qkv(b=2, s=256, h=2, d=32, seed=45)
+        mask = np.ones((2, 256), np.int32)
+        mask[0, 200:] = 0
+        return (q, k, v, mask, None, None, True), 64, 64
+    if name == "causal_192_128":
+        q, k, v = _qkv_two_sizes(1, 256, 2, 192, 128, seed=46)
+        return (q, k, v, None, None, None, True), 64, 64
+    raise KeyError(name)
+
+
+CLASS_CASES = ("causal", "causal_gqa", "causal_documents", "two_sided_ids",
+               "causal_key_mask", "causal_192_128", "causal_bq64_bk32",
+               "causal_bq32_bk64", "documents_bq64_bk32")
+
+
+def _allowed(q_segs, kv_segs, causal, b, s):
+    """The dense allowed mask [B, S, S] of a regime without a key mask."""
+    allowed = np.ones((b, s, s), bool)
+    if q_segs is not None:
+        allowed &= (np.asarray(q_segs)[:, :, None]
+                    == np.asarray(kv_segs)[:, None, :])
+    if causal:
+        allowed &= np.arange(s)[:, None] >= np.arange(s)[None, :]
+    return allowed
+
+
+def _blocks(allowed, block_q, block_k, every):
+    b, s, _ = allowed.shape
+    tiles = allowed.reshape(b, s // block_q, block_q, s // block_k, block_k)
+    return tiles.all((2, 4)) if every else tiles.any((2, 4))
+
+
+def _classes(q_segs, kv_segs, causal, b, s, block_q, block_k):
+    """(walked, whole of them) [B, S/block_q, S/block_k] by the kernels'
+    predicates: on the tables under segment ids, without ids on the block
+    coordinates alone (the plain causal regime hands the kernels no table)."""
+    from distributeddeeplearningspark_tpu.ops import flash_attention as fa
+
+    if q_segs is not None:
+        return [np.asarray(x) for x in fa.segment_block_classes(
+            jnp.asarray(q_segs, jnp.int32), jnp.asarray(kv_segs, jnp.int32),
+            causal=causal, block_q=block_q, block_k=block_k)]
+    qb = np.arange(s // block_q)[None, :, None]
+    kb = np.arange(s // block_k)[None, None, :]
+    walk = np.broadcast_to(kb * block_k < (qb + 1) * block_q,
+                           (b, s // block_q, s // block_k))
+    whole = fa._block_whole(None, None, None, None, qb, kb, causal=causal,
+                            block_q=block_q, block_k=block_k)
+    return walk, walk & np.asarray(whole)
+
+
+@pytest.mark.parametrize("name", CLASS_CASES)
+def test_classed_kernels_give_the_bits_of_the_kernels_that_mask_every_block(
+        name, monkeypatch):
+    """In a *whole* block ``allowed`` is all true, so the select on the scores
+    and the select on ``p`` are identities: the kernels that leave both out
+    there must give what the kernels that mask every walked block give, in
+    output, log-sum-exp and the three gradients: EQUAL, not close. The
+    predicate names a block *whole* exactly when the dense mask allows every
+    pair in it (a regime with a key mask has no such class)."""
+    from distributeddeeplearningspark_tpu.ops import flash_attention as fa
+
+    case, bq, bk = _class_case(name)
+    q, mask, q_segs, kv_segs, causal = case[0], *case[3:]
+    classed = _run_kernels(case, bq, bk)
+    if mask is None:
+        b, s = q.shape[:2]
+        walk, whole = _classes(q_segs, kv_segs, causal, b, s, bq, bk)
+        allowed = _allowed(q_segs, kv_segs, causal, b, s)
+        np.testing.assert_array_equal(whole,
+                                      _blocks(allowed, bq, bk, every=True))
+        assert not (_blocks(allowed, bq, bk, every=False) & ~walk).any()
+        # the case has blocks of both bodies
+        assert whole.any() and (walk & ~whole).any()
+    monkeypatch.setattr(fa, "_block_whole", lambda *a, **kw: False)
+    masking = _run_kernels(case, bq, bk)
+    for key, want in masking.items():
+        np.testing.assert_array_equal(np.asarray(classed[key]),
+                                      np.asarray(want), err_msg=key)
+    assert np.isfinite(np.asarray(classed["o"])).all()
+
+
+def _named_blocks(eqn, operand):
+    """Per grid step in the order the grid runs (last index fastest), the
+    block indices the index map of ``operand`` names: [steps, ndim]."""
+    mapping = eqn.params["grid_mapping"]
+    index_map = mapping.block_mappings[operand].index_map_jaxpr
+    at = np.stack(np.meshgrid(*[np.arange(n) for n in mapping.grid],
+                              indexing="ij"), -1).reshape(-1, len(mapping.grid))
+    named = jax.vmap(lambda *ix: jax.core.eval_jaxpr(
+        index_map.jaxpr, index_map.consts, *ix))(*jnp.asarray(at, jnp.int32).T)
+    return at, np.stack([np.broadcast_to(np.asarray(x), at.shape[:1])
+                         for x in named], -1)
+
+
+@pytest.mark.parametrize("bq, bk", [(64, 64), (64, 32), (32, 64)])
+def test_plain_causal_index_maps_fetch_only_what_a_walked_step_reads(bq, bk):
+    """Without segment ids, too, a step above the diagonal names the block
+    already resident (Pallas copies a block only when its index changes). In
+    the forward and dQ grids the streamed block changes only ON a walked
+    step; in dK/dV (a group of 4: the sweep of every query head starts above
+    the diagonal) a skipped step names the block of its head's FIRST walked
+    step, which that step reads. In all three the blocks named along the grid,
+    runs of one block taken once, are exactly those of the walked steps: no
+    copy is made that no step reads, and none is left out."""
+    from distributeddeeplearningspark_tpu.ops import flash_attention as fa
+
+    s, group = 256, 4
+    q = jnp.zeros((group, s, 32))
+    k, v, do = jnp.zeros((1, s, 32)), jnp.zeros((1, s, 16)), jnp.zeros(
+        (group, s, 16))
+    opts = dict(scale=1.0, causal=True, group=group, block_q=bq, block_k=bk,
+                interpret=True)
+
+    def both(q, k, v):
+        o, lse = fa._flash_fwd(q, k, v, None, **opts)
+        return fa._flash_bwd((q, k, v, None, o, lse, None, None), do, **opts)
+
+    calls = {e.params["name"]: e for e in jax.make_jaxpr(both)(q, k, v).eqns
+             if e.primitive.name == "pallas_call"}
+    runs = lambda rows: [r for i, r in enumerate(rows)
+                         if i == 0 or r != rows[i - 1]]
+    # (kernel, the streamed operands, whether a change needs a walked step)
+    for name, streamed, strict in (("flash_fwd", (1, 2), True),
+                                   ("flash_bwd_dq", (1, 2), True),
+                                   ("flash_bwd_dkv", (0, 3, 4, 5), False)):
+        for operand in streamed:
+            at, named = _named_blocks(calls[name], operand)
+            if name == "flash_bwd_dkv":
+                qb, kb = at[:, 2] % (s // bq), at[:, 1]
+            else:
+                qb, kb = at[:, 1], at[:, 2]
+            walked = kb * bk < (qb + 1) * bq
+            assert 0 < walked.sum() < len(walked)
+            rows = [tuple(r) for r in named]
+            changed = np.array([False] + [a != b for a, b in
+                                          zip(rows[1:], rows[:-1])])
+            if strict:
+                assert not (changed & ~walked).any(), (name, operand)
+            assert runs(rows) == runs([r for r, w in zip(rows, walked) if w])
+            # a walked step names its own block: the step's, of its head
+            own = kb if name != "flash_bwd_dkv" else qb
+            assert (named[walked, 1] == own[walked]).all()
+
+
+@pytest.mark.parametrize("name", ["one_document", "documents",
+                                  "documents_on_block_edges", "two_rows"])
+def test_masked_share_against_the_dense_mask(name):
+    """``attn_blocks_masked_share``: a walked block is *whole* iff the dense
+    causal in-document mask allows EVERY pair in it, *edge* otherwise; the
+    counter is the edge blocks over the walked, over the batch."""
+    from distributeddeeplearningspark_tpu.ops import flash_attention as fa
+
+    s, block = 512, 64
+    segs = {
+        "one_document": np.zeros((1, s), np.int64),
+        "documents": _ids_of([100, 92, 1, 200], s)[None, :],
+        "documents_on_block_edges": (np.arange(s) // 128)[None, :],
+        "two_rows": np.stack([_ids_of([130, 250], s), np.zeros(s, np.int64)]),
+    }[name]
+    allowed = _allowed(segs, segs, True, segs.shape[0], s)
+    walked = _blocks(allowed, block, block, every=False)
+    whole = _blocks(allowed, block, block, every=True)
+    want = (walked & ~whole).sum() / walked.sum()
+    got = float(fa.attn_blocks_masked_share(jnp.asarray(segs, jnp.int32),
+                                            block=block))
+    assert got == pytest.approx(want)
+    n = s // block
+    if name == "one_document":
+        assert got == pytest.approx(2 / (n + 1))      # the diagonal alone
+    if name == "documents_on_block_edges":
+        # four documents of two blocks: 2 of 3 walked blocks on the diagonal
+        assert got == pytest.approx(2 / 3)
+    # a length the kernels do not take goes to the XLA path: all masked
+    assert float(fa.attn_blocks_masked_share(
+        jnp.asarray(segs[:, :500], jnp.int32), block=block)) == 1.0
 
 
 def test_the_three_kernels_carry_stable_names():

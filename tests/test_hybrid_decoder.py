@@ -330,16 +330,20 @@ def test_the_step_moves_the_bias_outside_the_gradient(accum_steps):
     assert int(kept.step) == 2
 
 
-@pytest.mark.parametrize("ends, want", [
-    ((), 1.0),                        # one document fills the window
-    ((600, 1024, 1500), 0.6),         # four: see the count below
+@pytest.mark.parametrize("ends, want, masked", [
+    ((), 1.0, 0.4),                   # one document fills the window
+    ((600, 1024, 1500), 0.6, 1.0),    # four: see the count below
 ], ids=["one_document", "four_documents"])
-def test_the_walked_share_of_the_triangle_reaches_the_steps_metrics(ends, want):
+def test_the_walked_share_of_the_triangle_reaches_the_steps_metrics(
+        ends, want, masked):
     """``attn_blocks_walked_share`` through ``losses.hybrid_moe_lm``: what the
     flash kernels' own predicate walks of the 10 blocks of 512 x 512 on or
     under the diagonal of a 2,048-window. Documents [0, 600) [600, 1024)
     [1024, 1500) [1500, 2048): the four query blocks hold documents {0},
-    {0, 1}, {2, 3}, {3} and meet 1, 2, 1 and 2 key blocks, 6 of 10."""
+    {0, 1}, {2, 3}, {3} and meet 1, 2, 1 and 2 key blocks, 6 of 10. And
+    ``attn_blocks_masked_share``: of the walked, the blocks that need their
+    mask: the diagonal's 4 of one document's 10; all 6 of the four documents'
+    (4 on the diagonal, and (1, 0) and (3, 2) hold a document's edge)."""
     cfg = HybridDecoderConfig.tiny(layer_types=(CONV, ATTENTION),
                                    num_dense_layers=1)
     seg = np.searchsorted(np.asarray(ends), np.arange(2048), side="right")
@@ -354,6 +358,7 @@ def test_the_walked_share_of_the_triangle_reaches_the_steps_metrics(ends, want):
     assert 0.0 < share <= 1.0 and share == pytest.approx(want)
     # never under what attention requires of the window
     assert share >= float(metrics["attn_pairs_share"])
+    assert float(metrics["attn_blocks_masked_share"]) == pytest.approx(masked)
 
 
 def test_the_bias_is_saved_and_restored_with_the_state(tmp_path):

@@ -184,6 +184,8 @@ def test_the_cell_rehearses_at_a_toy_size(tree):
     assert 0.0 < r["metrics"]["attn_pairs_share"]["value"] <= 100.0
     assert (r["metrics"]["attn_pairs_share"]["value"]
             <= r["metrics"]["attn_blocks_walked_share"]["value"] <= 100.0)
+    # one block of 256 x 256, on the diagonal: it is walked, and masked
+    assert r["metrics"]["attn_blocks_masked_share"]["value"] == 100.0
     facts = r["facts"]["layer_facts"]["experts_load_max_over_mean"]
     assert facts["router_bias_abs_max_last"] > 0       # the step moved it
     # a CPU run has no device plane: the device-trace readers return nothing
@@ -437,9 +439,11 @@ def test_rooflines_count_executions_from_the_trace_and_stay_under_100():
             events.append(_ev(f"{name}.1", t, ms, op="custom-call"))
             t += ms
     laps = [_lap(attn_pairs_share=0.3, moe_load_max_over_mean=2.0,
-                 router_bias_abs_max=0.01, attn_blocks_walked_share=0.35),
+                 router_bias_abs_max=0.01, attn_blocks_walked_share=0.35,
+                 attn_blocks_masked_share=0.25),
             _lap(attn_pairs_share=0.5, moe_load_max_over_mean=4.0,
-                 router_bias_abs_max=0.02, attn_blocks_walked_share=0.55)]
+                 router_bias_abs_max=0.02, attn_blocks_walked_share=0.55,
+                 attn_blocks_masked_share=0.45)]
     ctx = _ctx(events, steps=1, laps=laps)
     assert _reader("shortconv_roofline")(ctx) == pytest.approx(25.0)
     assert _reader("flash_causal_roofline")(ctx) == pytest.approx(25.0)
@@ -451,6 +455,12 @@ def test_rooflines_count_executions_from_the_trace_and_stay_under_100():
     assert _reader("attn_blocks_walked_share")(ctx) == pytest.approx(45.0)
     assert ctx["facts"]["attn_blocks_walked_share"] == {
         "laps": 2, "min": 0.35, "max": 0.55}
+    assert _reader("attn_blocks_masked_share")(ctx) == pytest.approx(35.0)
+    assert ctx["facts"]["attn_blocks_masked_share"] == {
+        "laps": 2, "min": 0.25, "max": 0.45}
+    # a program that lacks the counter (the parent) gives nothing
+    assert _reader("attn_blocks_masked_share")(
+        _ctx(events, steps=1, laps=[_lap(attn_pairs_share=0.3)])) is None
     assert _reader("experts_load_max_over_mean")(ctx) == pytest.approx(3.0)
     # a window packed with one document: the whole causal triangle is
     # required, and a kernel AT its roofline reads 100, not more

@@ -230,6 +230,9 @@ def test_the_cell_rehearses_at_a_toy_size(tree):
     assert laps[-1]["router_bias_abs_max"] > 0         # the step moved it
     assert laps[-1]["loss"] == pytest.approx(
         laps[-1]["lm_loss"] + 0.1 * laps[-1]["mtp_nll"], rel=1e-5)
+    # one block of 256 x 256, on the diagonal: it is walked, and masked
+    assert laps[-1]["attn_blocks_masked_share"] == 1.0
+    assert r["metrics"]["attn_blocks_masked_share"]["value"] == 100.0
     # a CPU run has no device plane: the device-trace readers return nothing
     for name in NEW_METRICS + ("device_step_ms", "mfu"):
         assert name not in r["metrics"], name
