@@ -21,8 +21,14 @@ Public API (stable surface):
     Session, PartitionedDataset, MeshSpec, Trainer, TrainState
 """
 
-import importlib
-from typing import TYPE_CHECKING
+import time
+
+#: the first line the package runs: where ``telemetry.anatomy.STARTUP``, the
+#: ledger of the process's start, is anchored
+_T_IMPORT = time.perf_counter()
+
+import importlib  # noqa: E402
+from typing import TYPE_CHECKING  # noqa: E402
 
 __version__ = "0.1.0"
 
@@ -51,7 +57,12 @@ if TYPE_CHECKING:  # static analyzers see the real names
 
 def __getattr__(name: str):
     if name in _EXPORTS:
-        value = getattr(importlib.import_module(_EXPORTS[name]), name)
+        # both jax-free; the lazy imports below pull in jax, flax, optax, orbax
+        from distributeddeeplearningspark_tpu.telemetry import anatomy, spans
+
+        with spans.span("dls.start/import", anatomy.STARTUP.sink()):
+            module = importlib.import_module(_EXPORTS[name])
+        value = getattr(module, name)
         globals()[name] = value  # cache: next access skips the import
         return value
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -51,6 +51,7 @@ import jax
 
 from distributeddeeplearningspark_tpu.parallel.mesh import MeshSpec, num_data_shards
 from distributeddeeplearningspark_tpu.rdd import PartitionedDataset
+from distributeddeeplearningspark_tpu.telemetry import anatomy, spans
 
 logger = logging.getLogger("distributeddeeplearningspark_tpu")
 
@@ -107,7 +108,8 @@ class Session:
                 # dlsubmit launch flags arrive via env and lose to explicit
                 # .config()/.master() calls in the driver script.
                 conf = {**conf_from_env(), **self._conf}
-                sess = _create_session(conf)
+                with spans.span("dls.start/session", anatomy.STARTUP.sink()):
+                    sess = _create_session(conf)
                 Session._active = sess
                 return sess
 
@@ -304,6 +306,10 @@ def _create_session(conf: dict[str, str]) -> Session:
             num_processes=int(os.environ.get("DLS_NUM_PROCESSES", "1")),
             process_id=int(os.environ.get("DLS_PROCESS_ID", "0")),
         )
+    # the session's first look at the devices, ahead of `_parse_master` and
+    # `MeshSpec.build`, which ask again: the runtime comes up here
+    with spans.span("dls.start/backend", anatomy.STARTUP.sink()):
+        jax.devices()
     master = conf.get("spark.master")
     devices, spec = _parse_master(master, conf)
     mesh = spec.build(devices)

@@ -92,6 +92,15 @@ present):
   additionally spans a ``compile`` *phase* so goodput accounts the
   stall. ``dlstatus --anatomy`` renders the ledger and its recompile
   verdict.
+- ``startup`` — ONCE a process, when the first lap of its first ``fit``
+  closes (:class:`.anatomy.StartupLedger`): the start in seconds, from the
+  package's import to that boundary. The ``dls.start/*`` sections' own
+  time (``import_s``, ``session_s``, ``backend_s``, ``sample_s``,
+  ``init_state_s``, ``fit_unaccounted_s``), the first lap's parts from
+  that lap's own records (``first_lower_s``, ``first_backend_s``,
+  ``first_batch_s``, ``first_dispatch_s``, ``first_drain_s``) and
+  ``caller_s`` (the caller's own code) sum to ``to_first_lap_s``;
+  ``steps`` of that lap and ``attempt`` ride along.
 - ``memory`` — a device-memory watermark sample (:mod:`.anatomy`), one
   per metrics lap: ``bytes_in_use_max`` / ``peak_bytes_in_use_max`` /
   ``peak_bytes_reserved_max`` / ``bytes_limit_min`` / ``headroom_bytes``

@@ -5,7 +5,7 @@ Every observability layer so far watches the host side — goodput wall-clock
 (:mod:`.trace`). Nothing watched the device/compiler dimension: a silent
 recompile storm, shrinking HBM headroom, or a 15% step-time regression was
 invisible until a human reread BENCH files. This module closes that gap
-with three instruments that all land on the same JSONL bus:
+with four instruments that all land on the same JSONL bus:
 
 - **Compile ledger** (:func:`instrument` / :class:`InstrumentedFunction`):
   a wrapper around a jitted callable that owns the lower→compile path via
@@ -31,6 +31,10 @@ with three instruments that all land on the same JSONL bus:
   per-backend peak-FLOPs table (``DLS_PEAK_FLOPS`` override; a labeled
   nominal figure on CPU so host drills still get a finite, comparable
   number). The gauges ride each ``step_metrics`` record.
+- **Startup ledger** (:class:`StartupLedger`, the process's one
+  :data:`STARTUP`): the ``dls.start/*`` sections from the package's import
+  to the close of the first lap, which tile that time and ride ONE
+  ``startup`` record a process.
 - **HBM watermarks** (:func:`memory_watermarks`): jax device memory stats
   (``bytes_in_use`` / ``peak_bytes_in_use`` / ``peak_bytes_reserved`` /
   ``bytes_limit``) where the backend exposes them, live-buffer byte totals
@@ -54,6 +58,7 @@ import threading
 import time
 from typing import Any, Callable, Iterable
 
+from distributeddeeplearningspark_tpu import _T_IMPORT
 from distributeddeeplearningspark_tpu import telemetry as telemetry_lib
 from distributeddeeplearningspark_tpu.telemetry import spans
 
@@ -502,7 +507,6 @@ class StepAnatomy:
         with self._lock:
             self._lap_t0 = self.clock()
             self._seconds = dict.fromkeys(self._KEYS, 0.0)
-            self._dispatches = 0
 
     def add(self, name: str, dt: float, inner_s: float = 0.0) -> None:
         """One closed section (:func:`~.spans.span`'s sink side): its own
@@ -510,8 +514,6 @@ class StepAnatomy:
         key = spans.COUNTERS[name]
         with self._lock:
             self._seconds[key] += dt - inner_s
-            if key == "device_dispatch_s":
-                self._dispatches += 1
 
     def now(self) -> float:
         """The anatomy clock (pass to :meth:`lap` as its close timestamp
@@ -530,10 +532,9 @@ class StepAnatomy:
             now = self.clock()
         with self._lock:
             wall = max(0.0, now - self._lap_t0)
-            sec, dispatches = self._seconds, self._dispatches
+            sec = self._seconds
             self._lap_t0 = now
             self._seconds = dict.fromkeys(self._KEYS, 0.0)
-            self._dispatches = 0
         device = sec["device_dispatch_s"] + sec["device_drain_s"]
         feed = float(input_wait_s or 0.0)
         host = max(0.0, wall - device - sec["compile_in_lap_s"] - feed)
@@ -547,7 +548,6 @@ class StepAnatomy:
             "unaccounted_s": round(
                 wall - sum(sec.values()) - feed - float(input_put_s or 0.0),
                 6),
-            "device_dispatches": dispatches,
             "num_chips": int(num_chips),
         }
         peak, source = resolve_peak_flops()
@@ -563,6 +563,113 @@ class StepAnatomy:
                         flops_per_step * steps / device / max(1, num_chips)
                         / peak, 6)
         return rec
+
+
+# -- the start of the process -------------------------------------------------
+
+
+class StartupLedger:
+    """The start of a process, from the package's import to the close of its
+    first lap, split into named sections.
+
+    The ``dls.start/*`` sink of :func:`~.spans.span`, with
+    :class:`StepAnatomy`'s contract: a section's own time is counted, without
+    the sections nested in it, so the sections tile the time they cover. The
+    first lap's own split is not measured twice: :meth:`first_lap` takes it
+    from that lap's :class:`StepAnatomy` record, the feed's snapshot and the
+    step's compile records (all of them inside ``dls.start/fit``, whose own
+    time is what is left over), and closes the ledger with the one
+    ``startup`` record:
+
+    - every ``dls.start/*`` counter of :data:`.spans.COUNTERS`, in seconds;
+    - ``first_lower_s`` / ``first_backend_s`` (the train step's compiles up
+      to that lap: tracing and lowering, XLA or the cache load),
+      ``first_batch_s`` (the lap's ``input_wait_s`` + ``input_put_s``: the
+      feed's threads start, the first assembly, the first put),
+      ``first_dispatch_s`` and ``first_drain_s``;
+    - ``caller_s``: the caller's own code, which is the time between the
+      program's outer sections and the first lap's ``dls.fit/callbacks``;
+    - ``to_first_lap_s`` (anchor to the lap's close), the ``steps`` of that
+      lap and the ``attempt``.
+
+    The counters and ``caller_s`` sum to ``to_first_lap_s`` by construction:
+    ``caller_s`` is the residual, so a fault of the accounting shows as a
+    ``caller_s`` or a ``fit_unaccounted_s`` below zero. A ``fit`` without a
+    telemetry writer has no lap record and no feed snapshot to hand over
+    (it builds no accumulator): its ``first_batch_s``, ``first_dispatch_s``
+    and ``first_drain_s`` read 0 and that time, with the callbacks', stays
+    in ``fit_unaccounted_s``; the compile records exist either way. From
+    then on :meth:`sink` is ``None`` (a later ``Trainer`` or ``fit`` is
+    a bare span) and :meth:`add` adds nothing. No jax import.
+    """
+
+    def __init__(self, clock=time.perf_counter, t0: float | None = None):
+        self.clock = clock
+        self._t0 = clock() if t0 is None else t0
+        self._lock = threading.Lock()
+        self._seconds = {key: 0.0 for name, key in spans.COUNTERS.items()
+                         if name.startswith(spans.START_PREFIX)}
+        self._record: dict[str, Any] | None = None
+
+    def sink(self) -> "StartupLedger | None":
+        """This ledger while the start is open, ``None`` once it is on
+        record: what the ``dls.start/*`` sections pass to ``span``."""
+        return self if self._record is None else None
+
+    def add(self, name: str, dt: float, inner_s: float = 0.0) -> None:
+        """One closed section (:func:`~.spans.span`'s sink side): its own
+        time, without the ``inner_s`` of sections nested in it."""
+        key = spans.COUNTERS[name]
+        with self._lock:
+            if self._record is None:
+                self._seconds[key] += dt - inner_s
+
+    def first_lap(self, *, steps: int, lap: dict[str, Any],
+                  feed: dict[str, Any], compiles: Iterable[dict],
+                  attempt: int = 0, now: float | None = None
+                  ) -> dict[str, Any]:
+        """Close the start at the first lap's close and return the record.
+
+        ``lap`` is that lap's :meth:`StepAnatomy.lap` record, ``feed`` the
+        probe's snapshot of it, ``compiles`` the compile-ledger records of
+        the train step up to it; ``now`` pins the close to the true sync
+        boundary (default: the call). ``dls.start/fit`` has been left."""
+        if now is None:
+            now = self.clock()
+        compiles = list(compiles)
+        first = {
+            "first_lower_s": sum(c.get("lower_s", 0.0) for c in compiles),
+            # the jit fallback cannot tell its lowering from the backend's part
+            "first_backend_s": sum(c.get("backend_s", c.get("compile_s", 0.0))
+                                   for c in compiles),
+            "first_batch_s": (float(feed.get("input_wait_s", 0.0) or 0.0)
+                              + float(feed.get("input_put_s", 0.0) or 0.0)),
+            "first_dispatch_s": float(lap.get("device_dispatch_s", 0.0)),
+            "first_drain_s": float(lap.get("device_drain_s", 0.0)),
+        }
+        with self._lock:
+            if self._record is not None:
+                return dict(self._record)
+            rec: dict[str, Any] = {**self._seconds, **first}
+            # all of it ran inside dls.start/fit; the callbacks are the
+            # caller's, and the residual below hands them to caller_s
+            rec["fit_unaccounted_s"] -= (sum(first.values())
+                                         + float(lap.get("callbacks_s", 0.0)))
+            to_first_lap_s = now - self._t0
+            rec["caller_s"] = to_first_lap_s - sum(rec.values())
+            rec.update(to_first_lap_s=to_first_lap_s, steps=int(steps),
+                       attempt=int(attempt))
+            self._record = rec
+            return dict(rec)
+
+    def summary(self) -> dict[str, Any] | None:
+        """The ``startup`` record, or ``None`` while the start is open."""
+        with self._lock:
+            return None if self._record is None else dict(self._record)
+
+
+#: the process's one ledger, anchored at the first line the package ran
+STARTUP = StartupLedger(t0=_T_IMPORT)
 
 
 # -- HBM watermarks -----------------------------------------------------------

@@ -7,9 +7,10 @@ profiler's trace on the clock of the device's ops whenever a profiler runs
 ``start_trace``) and costs a fraction of a microsecond when none does. And,
 given a ``sink``, it adds the section's ``perf_counter`` time to the per-lap
 accumulator that rides ``step_metrics``: :class:`~.anatomy.StepAnatomy` for
-the loop thread, :class:`~..data.prefetch.StarvationProbe` for the feed. No
-sink (telemetry off) means a bare ``TraceAnnotation``. Nothing here writes
-to the JSONL stream.
+the loop thread, :class:`~..data.prefetch.StarvationProbe` for the feed,
+and once a process :class:`~.anatomy.StartupLedger` for the sections of the
+start. No sink (telemetry off; the start on record) means a bare
+``TraceAnnotation``. Nothing here writes to the JSONL stream.
 
 No jax import at module level: ``telemetry/__init__`` imports this for
 :func:`~..telemetry.phase`, and the reader side must stay jax-free. A
@@ -24,9 +25,14 @@ import ctypes
 import sys
 import threading
 
-#: span name -> the per-lap counter in ``step_metrics`` its time adds to.
-#: Loop thread first, then the feed's threads (docs/OBSERVABILITY.md "Device
-#: anatomy" says where each is opened).
+#: every ``dls.start/*`` section: the start of the process, up to the close
+#: of its first lap
+START_PREFIX = "dls.start/"
+
+#: span name -> the counter its time adds to: per lap in ``step_metrics``
+#: (loop thread first, then the feed's threads), once a process in the
+#: ``startup`` record for the ``dls.start/*`` names (docs/OBSERVABILITY.md
+#: "Device anatomy" says where each is opened).
 COUNTERS = {
     "dls.feed/wait": "input_wait_s",
     "dls.feed/put": "input_put_s",
@@ -48,6 +54,14 @@ COUNTERS = {
     # (``data/feed._Slots``)
     "dls.feed/slot_reused": "input_slot_reused",
     "dls.feed/slot_new": "input_slot_new",
+    # the start (``anatomy.StartupLedger``); ``dls.start/fit`` holds every
+    # section below it, so its own time is the start that no section covers
+    "dls.start/import": "import_s",
+    "dls.start/session": "session_s",
+    "dls.start/backend": "backend_s",
+    "dls.start/fit": "fit_unaccounted_s",
+    "dls.start/sample": "sample_s",
+    "dls.start/init_state": "init_state_s",
 }
 #: ``EventWriter.phase(name)`` also opens ``dls.phase/<name>`` (trace only)
 PHASE_PREFIX = "dls.phase/"

@@ -8,6 +8,7 @@ feeding prefetched sharded batches, periodic metrics, and checkpoint hooks.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import logging
 import os
@@ -188,10 +189,11 @@ class Trainer:
 
     def init(self, sample_batch: dict[str, Any]) -> TrainState:
         """Initialize sharded state from one host example batch."""
-        self.state, self.state_shardings = step_lib.init_state(
-            self.model, self.tx, sample_batch, self.mesh, self.rules,
-            seed=self.seed, sparse_embed=self.sparse_embed, plan=self.plan,
-        )
+        with spans.span("dls.start/init_state", anatomy_lib.STARTUP.sink()):
+            self.state, self.state_shardings = step_lib.init_state(
+                self.model, self.tx, sample_batch, self.mesh, self.rules,
+                seed=self.seed, sparse_embed=self.sparse_embed, plan=self.plan,
+            )
         if self.mutable_keys == () and self.state.mutable:
             self.mutable_keys = tuple(self.state.mutable.keys())
         self._build_train_step()
@@ -620,388 +622,416 @@ class Trainer:
         Returns (final state, summary metrics). The loop never blocks on the
         device except at metric log points — steps dispatch asynchronously.
         """
-        if on_nonfinite not in ("raise", "skip", "rollback"):
-            raise ValueError(
-                f"on_nonfinite must be 'raise'|'skip'|'rollback', got "
-                f"{on_nonfinite!r}")
-        if on_nonfinite == "skip" and self.sparse_embed:
-            raise ValueError(
-                "on_nonfinite='skip' is not supported with sparse_embed "
-                "tables (the row-sparse step has no update guard); use "
-                "'rollback' or 'raise'")
-        rebuild = False
-        need_guard = on_nonfinite == "skip"
-        if need_guard != self._guard_nonfinite:
-            self._guard_nonfinite = need_guard
-            rebuild = True
-        if accum_steps is not None and accum_steps != self.accum_steps:
-            if self.sparse_embed:
+        # the process's start ends where this fit's first lap closes
+        # (telemetry/anatomy.StartupLedger). `dls.start/fit` holds every
+        # section up to there; it is left by hand at that boundary, and by
+        # this `with` if fit ends or raises sooner
+        start = anatomy_lib.STARTUP.sink()
+        with contextlib.ExitStack() as start_fit:
+            if start is not None:
+                start_fit.enter_context(spans.span("dls.start/fit", start))
+            if on_nonfinite not in ("raise", "skip", "rollback"):
                 raise ValueError(
-                    "accum_steps is not supported with sparse_embed tables "
-                    "(train/embed.py) — recommender batches are already large; "
-                    "scale batch_size instead")
-            self.accum_steps = accum_steps
-            rebuild = True
-        if rebuild and self.state is not None:
-            # recompile once with the settled (guard, accum) combination
-            self._build_train_step()
-        if self.state is None:
-            sample = self._sample_batch(dataset, batch_size)
-            self.init(sample)
-        assert self._train_step is not None
-        if batch_size % self.accum_steps:
-            raise ValueError(
-                f"batch_size {batch_size} must divide by accum_steps "
-                f"{self.accum_steps}")
-
-        if epochs is not None:
-            dataset = dataset.repeat(epochs)
-
-        meter = Meter(
-            examples_per_step=batch_size,
-            tokens_per_step=batch_size * tokens_per_example,
-            num_chips=self.mesh.devices.size,
-        )
-        # run telemetry: per-lap step_metrics + phase spans + heartbeats into
-        # the workdir's JSONL stream (docs/OBSERVABILITY.md). None when no
-        # workdir is resolvable — then fit costs nothing extra.
-        tele = self._telemetry()
-        probe = StarvationProbe() if tele is not None else None
-        # per-lap device/host/input anatomy (docs/OBSERVABILITY.md "Device
-        # anatomy"): the instrumented step adds each dispatch and compile
-        # to it, every `spans.span(name, anat)` below adds its section, and
-        # the closed lap's split rides the step_metrics record. Telemetry
-        # off: no accumulator, and each span is a bare TraceAnnotation.
-        anat = anatomy_lib.StepAnatomy() if tele is not None else None
-        if isinstance(self._train_step, anatomy_lib.InstrumentedFunction):
-            self._train_step.attach_anatomy(anat)
-
-        def tele_phase(name: str):
-            return (tele.phase(name) if tele is not None
-                    else spans.span(spans.PHASE_PREFIX + name))
-
-        mlog = MetricLogger(log_every=log_every, tensorboard_dir=tensorboard_dir,
-                            telemetry=tele)
-        step_i = int(jax.device_get(self.state.step))
-        if tele is not None:
-            tele.emit("phase", name="run", edge="begin", step=step_i,
-                      attempt=int(os.environ.get("DLS_RESTART", "0") or 0))
-            # baseline heartbeat BEFORE the first (long) compile: a host
-            # that stalls during startup is then localizable by heartbeat
-            # age, not only by its phase-begin record
-            tele.heartbeat(step=step_i)
-        # opt-in gang-barrier latency sample per metrics lap (a replicated
-        # scalar psum timed host-side): in a straggling gang every healthy
-        # host's sample grows by the straggler's lag, which is the fleet
-        # table's comms-wait column (DLS_COMMS_PROBE=1, docs/OBSERVABILITY)
-        comms_probe = (tele is not None
-                       and collectives.collective_probes_enabled())
-        # trace window is relative to THIS loop's first step, and stop must
-        # sync on the live state or async dispatch truncates the capture
-        profiler = profiling.StepProfiler(
-            profile, start_offset=step_i,
-            sync=lambda: jax.block_until_ready(self.state.params),
-        )
-        flops_pending = measure_flops
-        meter.start()
-        if anat is not None:
-            # start the anatomy lap clock at the SAME instant as the meter:
-            # the two walls are measured independently and must agree
-            anat.reset()
-
-        lap_start = step_i
-        last_metrics: dict[str, float] = {}
-        skip = 0
-        if data_state and data_state.get("examples_seen"):
-            stored_bs = data_state.get("batch_size")
-            if stored_bs is not None and int(stored_bs) != batch_size:
+                    f"on_nonfinite must be 'raise'|'skip'|'rollback', got "
+                    f"{on_nonfinite!r}")
+            if on_nonfinite == "skip" and self.sparse_embed:
                 raise ValueError(
-                    f"resume batch_size mismatch: checkpoint was written with "
-                    f"batch_size={int(stored_bs)}, fit() called with "
-                    f"{batch_size} — the examples_seen fast-forward would "
-                    f"land mid-batch; resume with the original batch size")
-            skip = int(data_state["examples_seen"]) // batch_size
-        got_batch = False
-        # fallback gate for drivers not launched through the test workers
-        # (those already died pre-rendezvous): on a relaunch, a die_host
-        # target must not train — the machine it stands in for is gone
-        faults.die_if_dead_host_on_relaunch()
-        fault = faults.get()
-        # the graceful-preemption notice is scoped out of get(): every rank
-        # consults it (the trainer coordinates the drain no matter which
-        # host is doomed — survivors are the ones re-gathering shards)
-        preempt = faults.sigterm_fault()
-        # the scheduler's runtime notice channel: a file path in the env
-        # (scheduler-launched jobs only — unset keeps the poll at zero
-        # cost). Polled at step boundaries; the notice's step floor is how
-        # every rank lands on the same drain step despite observing the
-        # file at slightly different wall-clock times.
-        notice_path = faults.preempt_notice_path()
-        skipped_dev = None  # device-side cumulative skip count (stays async)
-        n_skipped = 0
-        rollbacks = 0
-        # extra batches the feed consumed beyond step_i (rollback rewinds the
-        # model, never the stream) — folded into examples_seen so a resume
-        # fast-forwards to the TRUE stream position, not step_i's. A resumed
-        # run inherits the previous run's offset (skip beyond state.step IS
-        # that drift) so re-checkpointing doesn't quietly drop it.
-        rolled_back_batches = max(0, skip - step_i)
-        try:
-            for batch in self._feed(dataset, batch_size, skip_batches=skip,
-                                    probe=probe):
-                got_batch = True
-                if steps is not None and step_i >= steps:
-                    break
-                if flops_pending:
-                    # lower+compile for cost analysis blocks like the first
-                    # step's compile does — same goodput category
-                    with tele_phase("compile"):
-                        meter.set_flops(self.compiled_cost(batch))
-                    flops_pending = False
-                if fault is not None and step_i + 1 == fault.step \
-                        and fault.kind in ("nan", "crash", "hang", "die_host"):
-                    kind = fault.kind
-                    # one-shot: a rollback rewinds step_i past the trigger,
-                    # and re-poisoning the retrained window would turn one
-                    # injected spike into an unrecoverable loop
-                    fault = None
-                    if kind == "nan":
-                        batch = faults.nan_batch(batch)
-                    elif kind in ("crash", "die_host"):
-                        faults.crash()
-                    else:
-                        faults.hang()
-                profiler.observe(step_i)
-                # the step marker is always written: the profiler may be the
-                # caller's (utils/profiling.trace, a benchmark), not
-                # `profile=`'s
-                with profiling.step_annotation(step_i):
-                    # compiles (the first dispatch AND any mid-run shape
-                    # change) are spanned, timed, and cost-analyzed by the
-                    # instrumented step itself (telemetry/anatomy.py), so
-                    # no first-dispatch phase wrap is needed here
-                    self.state, metrics = self._train_step(self.state, batch)
-                metrics = dict(metrics)
-                metrics.pop("weight", None)  # eval-aggregation detail, not a log line
-                step_i += 1
-                if self._guard_nonfinite and "skipped" in metrics:
-                    # eager device-side add per step — no host sync; fetched
-                    # only at log boundaries
-                    s = metrics["skipped"]
-                    skipped_dev = s if skipped_dev is None else skipped_dev + s
-                if step_i % log_every == 0 or (steps is not None and step_i >= steps):
-                    if (meter.flops_per_step is None
-                            and getattr(self._train_step, "flops_per_step",
-                                        None)):
-                        # the ledger already cost-analyzed the compiled step,
-                        # so MFU comes free — no measure_flops double compile
-                        meter.set_flops(self._train_step.flops_per_step)
-                    # device_get blocks until this step's metrics exist, so the
-                    # lap boundary is a true device-sync point — timing is honest.
-                    with spans.span("dls.fit/sync", anat):
-                        fetched = jax.device_get(metrics)
-                    last_metrics = meter.lap(step_i - lap_start, fetched)
-                    lap_start = step_i
-                    # close the anatomy lap at the SAME sync point the
-                    # meter lapped at — the log rendering below belongs to
-                    # the next lap on both clocks, or the two walls drift
-                    lap_s, lap_n = meter.last_lap or (0.0, 0)
-                    lap_close = anat.now() if anat is not None else None
-                    # opened after the boundary and closed after lap(): the
-                    # emits are the NEXT lap's on the anatomy's clock too
-                    with spans.span("dls.fit/emit", anat):
-                        snap = probe.snapshot() if probe is not None else {}
-                        anat_rec: dict = {}
-                        if anat is not None:
-                            anat_rec = anat.lap(
-                                steps=lap_n,
-                                input_wait_s=snap.get("input_wait_s", 0.0),
-                                input_put_s=snap.get("input_put_s", 0.0),
-                                flops_per_step=getattr(
-                                    self._train_step, "flops_per_step",
-                                    None),
-                                num_chips=self.mesh.devices.size,
-                                now=lap_close,
-                            )
-                        mlog.log(step_i, {**last_metrics, **meter.summary()})
-                        _touch_heartbeat()
-                        if tele is not None:
-                            tele.step_metrics(
-                                step_i, steps=lap_n, lap_s=lap_s,
-                                metrics=last_metrics, **snap, **anat_rec)
-                            tele.emit("memory",
-                                      **anatomy_lib.memory_watermarks())
-                            tele.heartbeat(step=step_i)
-                            if comms_probe:
-                                collectives.barrier_probe(self.mesh)
-                    if on_nonfinite == "raise":
-                        sanitize.assert_all_finite(last_metrics, step=step_i)
-                    elif on_nonfinite == "skip":
-                        if skipped_dev is not None:
-                            new_skipped = int(jax.device_get(skipped_dev))
-                            if new_skipped > n_skipped:
-                                mlog.event(
-                                    step_i, "skip",
-                                    skipped_steps=new_skipped,
-                                    nonfinite=sanitize.nonfinite_metrics(last_metrics))
-                            n_skipped = new_skipped
-                            if n_skipped > nonfinite_budget:
-                                raise FloatingPointError(
-                                    f"skipped {n_skipped} non-finite steps, "
-                                    f"over nonfinite_budget={nonfinite_budget} "
-                                    f"— this divergence is persistent, not a "
-                                    f"transient spike; last metrics: "
-                                    f"{last_metrics}")
-                    else:  # rollback
-                        bad = sanitize.nonfinite_metrics(last_metrics)
-                        if bad:
-                            rollbacks += 1
-                            if rollbacks > max_rollbacks:
-                                raise FloatingPointError(
-                                    f"non-finite metrics at step {step_i} "
-                                    f"after exhausting max_rollbacks="
-                                    f"{max_rollbacks}: {bad}")
-                            if self.checkpointer is None:
-                                raise FloatingPointError(
-                                    f"on_nonfinite='rollback' needs a "
-                                    f"checkpointer with a saved step; "
-                                    f"non-finite at step {step_i}: {bad}")
-                            try:
-                                last_bad = None
-                                while True:
-                                    self.restore()
-                                    if sanitize.tree_all_finite(
-                                            self.state.params):
-                                        break
-                                    # byte-intact but numerically poisoned
-                                    # (divergence was checkpointed before a
-                                    # log boundary could see it): discard
-                                    # and walk back further
-                                    ckpt_step = int(
-                                        jax.device_get(self.state.step))
-                                    if ckpt_step == last_bad:
-                                        # quarantine didn't take (read-only
-                                        # fs, non-0 process): refuse to spin
-                                        raise RuntimeError(
-                                            f"could not quarantine poisoned "
-                                            f"checkpoint step {ckpt_step}")
-                                    last_bad = ckpt_step
-                                    logger.warning(
-                                        "rollback target step %d holds "
-                                        "non-finite params; quarantining "
-                                        "and walking back further",
-                                        ckpt_step)
-                                    self.checkpointer.quarantine(ckpt_step)
-                            except Exception as e:
-                                raise FloatingPointError(
-                                    f"rollback from non-finite metrics at "
-                                    f"step {step_i} failed ({e}); bad "
-                                    f"metrics: {bad}") from e
-                            rolled_to = int(jax.device_get(self.state.step))
-                            mlog.event(step_i, "rollback", to_step=rolled_to,
-                                       window=step_i - rolled_to, nonfinite=bad)
-                            rolled_back_batches += step_i - rolled_to
-                            step_i = rolled_to
-                            lap_start = step_i
-                            last_metrics = {}
-                            # the feed keeps streaming forward — the model
-                            # rewound, the poisonous batch window did not
-                            continue
-                if sanitize_every and step_i % sanitize_every == 0:
-                    sanitize.assert_replicas_in_sync(self.state.params)
-                if callbacks:
-                    # the callers' time (a benchmark's window, its
-                    # start_trace / stop_trace), not the program's
-                    with spans.span("dls.fit/callbacks", anat):
-                        for cb in callbacks:
-                            cb(step_i, last_metrics)
-                doomed_now: int | None = None
-                if preempt is not None and step_i >= preempt.step:
-                    doomed_now = faults.fault_host()
-                elif notice_path is not None:
-                    notice = faults.read_preempt_notice(notice_path)
-                    if notice is not None and step_i >= notice.step:
-                        doomed_now = notice.host
-                if doomed_now is not None:
-                    # preemption notice: drain (the step above completed),
-                    # hand off live state, exit BEFORE any further
-                    # checkpoint write — the resume point is THIS step
-                    self._graceful_drain(
-                        step_i,
-                        examples_seen=(step_i + rolled_back_batches)
-                        * batch_size,
-                        batch_size=batch_size, doomed=doomed_now)
-                    break
-                if checkpoint_every and self.checkpointer and step_i % checkpoint_every == 0:
-                    with spans.span("dls.fit/checkpoint", anat):
-                        self.checkpointer.save(
-                            step_i, self.state,
-                            data_state={"examples_seen":
-                                        (step_i + rolled_back_batches)
-                                        * batch_size,
-                                        "batch_size": batch_size},
-                        )
-                    if (fault is not None and fault.kind == "truncate_ckpt"
-                            and step_i >= fault.step):
-                        # kill-mid-finalize drill: make the save durable +
-                        # manifested, tear its bytes, die without warning
-                        self.checkpointer.wait()
-                        faults.truncate_latest_checkpoint(
-                            self.checkpointer.directory)
-                        faults.crash()
-                if eval_every and eval_dataset is not None and step_i % eval_every == 0:
-                    with spans.span("dls.fit/eval", anat), tele_phase("eval"):
-                        emetrics = self.evaluate(eval_dataset, batch_size=batch_size)
-                    mlog.log(step_i, {f"eval_{k}": v for k, v in emetrics.items()})
-        finally:
-            # flush the trace and tensorboard even when a step/sanitizer blows
-            # up mid-window — a crashed run's trace is the one you want most
-            profiler.stop()
-            if isinstance(self._train_step, anatomy_lib.InstrumentedFunction):
-                # detach so a later fit() on this trainer gets a fresh lap
-                # accumulator, not this run's dangling one
-                self._train_step.attach_anatomy(None)
-            if tele is not None:
-                # close the run span on every exit the interpreter survives;
-                # a SIGKILL'd run leaves the stream open-ended, which is the
-                # signal dlstatus reads as "died mid-run"
-                tele.emit("phase", name="run", edge="end", step=step_i)
-            mlog.close()
+                    "on_nonfinite='skip' is not supported with sparse_embed "
+                    "tables (the row-sparse step has no update guard); use "
+                    "'rollback' or 'raise'")
+            rebuild = False
+            need_guard = on_nonfinite == "skip"
+            if need_guard != self._guard_nonfinite:
+                self._guard_nonfinite = need_guard
+                rebuild = True
+            if accum_steps is not None and accum_steps != self.accum_steps:
+                if self.sparse_embed:
+                    raise ValueError(
+                        "accum_steps is not supported with sparse_embed tables "
+                        "(train/embed.py) — recommender batches are already large; "
+                        "scale batch_size instead")
+                self.accum_steps = accum_steps
+                rebuild = True
+            if rebuild and self.state is not None:
+                # recompile once with the settled (guard, accum) combination
+                self._build_train_step()
+            if self.state is None:
+                with spans.span("dls.start/sample", start):
+                    sample = self._sample_batch(dataset, batch_size)
+                self.init(sample)
+            assert self._train_step is not None
+            if batch_size % self.accum_steps:
+                raise ValueError(
+                    f"batch_size {batch_size} must divide by accum_steps "
+                    f"{self.accum_steps}")
 
-        if skip and not got_batch:
-            raise RuntimeError(
-                f"resume fast-forward consumed the whole dataset: skipping "
-                f"{skip} batches (examples_seen="
-                f"{int(data_state['examples_seen'])}) exhausted the feed "
-                f"before the first post-resume step — pass a .repeat() "
-                f"dataset or fewer epochs-already-trained")
-        jax.block_until_ready(self.state.params)
-        summary = {**meter.summary(), **last_metrics}
-        if on_nonfinite == "skip":
-            if skipped_dev is not None:
-                n_skipped = int(jax.device_get(skipped_dev))
-            summary["skipped_steps"] = float(n_skipped)
-            if n_skipped:
-                logger.warning("run skipped %d non-finite step(s) "
-                               "(on_nonfinite='skip')", n_skipped)
-        elif on_nonfinite == "rollback":
-            summary["rollbacks"] = float(rollbacks)
-        if (self.checkpointer and checkpoint_every
-                and self.preempted_at is None):
-            # a drained run already committed its live handoff; a final
-            # checkpoint here would advance the walk-back point past the
-            # handoff and muddy the "no walk-back" resume invariant
-            self.checkpointer.save(
-                step_i, self.state,
-                data_state={"examples_seen":
-                            (step_i + rolled_back_batches) * batch_size,
-                            "batch_size": batch_size},
+            if epochs is not None:
+                dataset = dataset.repeat(epochs)
+
+            meter = Meter(
+                examples_per_step=batch_size,
+                tokens_per_step=batch_size * tokens_per_example,
+                num_chips=self.mesh.devices.size,
             )
-            self.checkpointer.wait()
-        # timing laps are closed — safe to wait for the async device-time
-        # budget log so short jobs still surface it before returning
-        profiler.join_breakdown()
-        return self.state, summary
+            # run telemetry: per-lap step_metrics + phase spans + heartbeats into
+            # the workdir's JSONL stream (docs/OBSERVABILITY.md). None when no
+            # workdir is resolvable — then fit costs nothing extra.
+            tele = self._telemetry()
+            probe = StarvationProbe() if tele is not None else None
+            # per-lap device/host/input anatomy (docs/OBSERVABILITY.md "Device
+            # anatomy"): the instrumented step adds each dispatch and compile
+            # to it, every `spans.span(name, anat)` below adds its section, and
+            # the closed lap's split rides the step_metrics record. Telemetry
+            # off: no accumulator, and each span is a bare TraceAnnotation.
+            anat = anatomy_lib.StepAnatomy() if tele is not None else None
+            if isinstance(self._train_step, anatomy_lib.InstrumentedFunction):
+                self._train_step.attach_anatomy(anat)
+            step_compiles = getattr(self._train_step, "records", [])
+            compiled_before = len(step_compiles)
+            attempt = int(os.environ.get("DLS_RESTART", "0") or 0)
+
+            def tele_phase(name: str):
+                return (tele.phase(name) if tele is not None
+                        else spans.span(spans.PHASE_PREFIX + name))
+
+            mlog = MetricLogger(log_every=log_every, tensorboard_dir=tensorboard_dir,
+                                telemetry=tele)
+            step_i = int(jax.device_get(self.state.step))
+            if tele is not None:
+                tele.emit("phase", name="run", edge="begin", step=step_i,
+                          attempt=attempt)
+                # baseline heartbeat BEFORE the first (long) compile: a host
+                # that stalls during startup is then localizable by heartbeat
+                # age, not only by its phase-begin record
+                tele.heartbeat(step=step_i)
+            # opt-in gang-barrier latency sample per metrics lap (a replicated
+            # scalar psum timed host-side): in a straggling gang every healthy
+            # host's sample grows by the straggler's lag, which is the fleet
+            # table's comms-wait column (DLS_COMMS_PROBE=1, docs/OBSERVABILITY)
+            comms_probe = (tele is not None
+                           and collectives.collective_probes_enabled())
+            # trace window is relative to THIS loop's first step, and stop must
+            # sync on the live state or async dispatch truncates the capture
+            profiler = profiling.StepProfiler(
+                profile, start_offset=step_i,
+                sync=lambda: jax.block_until_ready(self.state.params),
+            )
+            flops_pending = measure_flops
+            meter.start()
+            if anat is not None:
+                # start the anatomy lap clock at the SAME instant as the meter:
+                # the two walls are measured independently and must agree
+                anat.reset()
+
+            lap_start = step_i
+            last_metrics: dict[str, float] = {}
+            skip = 0
+            if data_state and data_state.get("examples_seen"):
+                stored_bs = data_state.get("batch_size")
+                if stored_bs is not None and int(stored_bs) != batch_size:
+                    raise ValueError(
+                        f"resume batch_size mismatch: checkpoint was written with "
+                        f"batch_size={int(stored_bs)}, fit() called with "
+                        f"{batch_size} — the examples_seen fast-forward would "
+                        f"land mid-batch; resume with the original batch size")
+                skip = int(data_state["examples_seen"]) // batch_size
+            got_batch = False
+            # fallback gate for drivers not launched through the test workers
+            # (those already died pre-rendezvous): on a relaunch, a die_host
+            # target must not train — the machine it stands in for is gone
+            faults.die_if_dead_host_on_relaunch()
+            fault = faults.get()
+            # the graceful-preemption notice is scoped out of get(): every rank
+            # consults it (the trainer coordinates the drain no matter which
+            # host is doomed — survivors are the ones re-gathering shards)
+            preempt = faults.sigterm_fault()
+            # the scheduler's runtime notice channel: a file path in the env
+            # (scheduler-launched jobs only — unset keeps the poll at zero
+            # cost). Polled at step boundaries; the notice's step floor is how
+            # every rank lands on the same drain step despite observing the
+            # file at slightly different wall-clock times.
+            notice_path = faults.preempt_notice_path()
+            skipped_dev = None  # device-side cumulative skip count (stays async)
+            n_skipped = 0
+            rollbacks = 0
+            # extra batches the feed consumed beyond step_i (rollback rewinds the
+            # model, never the stream) — folded into examples_seen so a resume
+            # fast-forwards to the TRUE stream position, not step_i's. A resumed
+            # run inherits the previous run's offset (skip beyond state.step IS
+            # that drift) so re-checkpointing doesn't quietly drop it.
+            rolled_back_batches = max(0, skip - step_i)
+            try:
+                for batch in self._feed(dataset, batch_size, skip_batches=skip,
+                                        probe=probe):
+                    got_batch = True
+                    if steps is not None and step_i >= steps:
+                        break
+                    if flops_pending:
+                        # lower+compile for cost analysis blocks like the first
+                        # step's compile does — same goodput category
+                        with tele_phase("compile"):
+                            meter.set_flops(self.compiled_cost(batch))
+                        flops_pending = False
+                    if fault is not None and step_i + 1 == fault.step \
+                            and fault.kind in ("nan", "crash", "hang", "die_host"):
+                        kind = fault.kind
+                        # one-shot: a rollback rewinds step_i past the trigger,
+                        # and re-poisoning the retrained window would turn one
+                        # injected spike into an unrecoverable loop
+                        fault = None
+                        if kind == "nan":
+                            batch = faults.nan_batch(batch)
+                        elif kind in ("crash", "die_host"):
+                            faults.crash()
+                        else:
+                            faults.hang()
+                    profiler.observe(step_i)
+                    # the step marker is always written: the profiler may be the
+                    # caller's (utils/profiling.trace, a benchmark), not
+                    # `profile=`'s
+                    with profiling.step_annotation(step_i):
+                        # compiles (the first dispatch AND any mid-run shape
+                        # change) are spanned, timed, and cost-analyzed by the
+                        # instrumented step itself (telemetry/anatomy.py), so
+                        # no first-dispatch phase wrap is needed here
+                        self.state, metrics = self._train_step(self.state, batch)
+                    metrics = dict(metrics)
+                    metrics.pop("weight", None)  # eval-aggregation detail, not a log line
+                    step_i += 1
+                    if self._guard_nonfinite and "skipped" in metrics:
+                        # eager device-side add per step — no host sync; fetched
+                        # only at log boundaries
+                        s = metrics["skipped"]
+                        skipped_dev = s if skipped_dev is None else skipped_dev + s
+                    if step_i % log_every == 0 or (steps is not None and step_i >= steps):
+                        if (meter.flops_per_step is None
+                                and getattr(self._train_step, "flops_per_step",
+                                            None)):
+                            # the ledger already cost-analyzed the compiled step,
+                            # so MFU comes free — no measure_flops double compile
+                            meter.set_flops(self._train_step.flops_per_step)
+                        # device_get blocks until this step's metrics exist, so the
+                        # lap boundary is a true device-sync point — timing is honest.
+                        with spans.span("dls.fit/sync", anat):
+                            fetched = jax.device_get(metrics)
+                        last_metrics = meter.lap(step_i - lap_start, fetched)
+                        lap_start = step_i
+                        # close the anatomy lap at the SAME sync point the
+                        # meter lapped at — the log rendering below belongs to
+                        # the next lap on both clocks, or the two walls drift
+                        lap_s, lap_n = meter.last_lap or (0.0, 0)
+                        lap_close = anat.now() if anat is not None else None
+                        if start is not None:
+                            # the process's first lap has closed, and its start
+                            start_fit.close()
+                            start_close = start.clock()
+                        # opened after the boundary and closed after lap(): the
+                        # emits are the NEXT lap's on the anatomy's clock too
+                        with spans.span("dls.fit/emit", anat):
+                            snap = probe.snapshot() if probe is not None else {}
+                            anat_rec: dict = {}
+                            if anat is not None:
+                                anat_rec = anat.lap(
+                                    steps=lap_n,
+                                    input_wait_s=snap.get("input_wait_s", 0.0),
+                                    input_put_s=snap.get("input_put_s", 0.0),
+                                    flops_per_step=getattr(
+                                        self._train_step, "flops_per_step",
+                                        None),
+                                    num_chips=self.mesh.devices.size,
+                                    now=lap_close,
+                                )
+                            if start is not None:
+                                # (no writer: no lap record and no snapshot,
+                                # and the lap's feed wait, dispatches and
+                                # drain stay in `fit_unaccounted_s`)
+                                startup = start.first_lap(
+                                    steps=lap_n, lap=anat_rec, feed=snap,
+                                    compiles=step_compiles[compiled_before:],
+                                    attempt=attempt, now=start_close)
+                                start = None
+                                if tele is not None:
+                                    # once a process, ahead of its first lap
+                                    tele.emit("startup", **startup)
+                            mlog.log(step_i, {**last_metrics, **meter.summary()})
+                            _touch_heartbeat()
+                            if tele is not None:
+                                tele.step_metrics(
+                                    step_i, steps=lap_n, lap_s=lap_s,
+                                    metrics=last_metrics, **snap, **anat_rec)
+                                tele.emit("memory",
+                                          **anatomy_lib.memory_watermarks())
+                                tele.heartbeat(step=step_i)
+                                if comms_probe:
+                                    collectives.barrier_probe(self.mesh)
+                        if on_nonfinite == "raise":
+                            sanitize.assert_all_finite(last_metrics, step=step_i)
+                        elif on_nonfinite == "skip":
+                            if skipped_dev is not None:
+                                new_skipped = int(jax.device_get(skipped_dev))
+                                if new_skipped > n_skipped:
+                                    mlog.event(
+                                        step_i, "skip",
+                                        skipped_steps=new_skipped,
+                                        nonfinite=sanitize.nonfinite_metrics(last_metrics))
+                                n_skipped = new_skipped
+                                if n_skipped > nonfinite_budget:
+                                    raise FloatingPointError(
+                                        f"skipped {n_skipped} non-finite steps, "
+                                        f"over nonfinite_budget={nonfinite_budget} "
+                                        f"— this divergence is persistent, not a "
+                                        f"transient spike; last metrics: "
+                                        f"{last_metrics}")
+                        else:  # rollback
+                            bad = sanitize.nonfinite_metrics(last_metrics)
+                            if bad:
+                                rollbacks += 1
+                                if rollbacks > max_rollbacks:
+                                    raise FloatingPointError(
+                                        f"non-finite metrics at step {step_i} "
+                                        f"after exhausting max_rollbacks="
+                                        f"{max_rollbacks}: {bad}")
+                                if self.checkpointer is None:
+                                    raise FloatingPointError(
+                                        f"on_nonfinite='rollback' needs a "
+                                        f"checkpointer with a saved step; "
+                                        f"non-finite at step {step_i}: {bad}")
+                                try:
+                                    last_bad = None
+                                    while True:
+                                        self.restore()
+                                        if sanitize.tree_all_finite(
+                                                self.state.params):
+                                            break
+                                        # byte-intact but numerically poisoned
+                                        # (divergence was checkpointed before a
+                                        # log boundary could see it): discard
+                                        # and walk back further
+                                        ckpt_step = int(
+                                            jax.device_get(self.state.step))
+                                        if ckpt_step == last_bad:
+                                            # quarantine didn't take (read-only
+                                            # fs, non-0 process): refuse to spin
+                                            raise RuntimeError(
+                                                f"could not quarantine poisoned "
+                                                f"checkpoint step {ckpt_step}")
+                                        last_bad = ckpt_step
+                                        logger.warning(
+                                            "rollback target step %d holds "
+                                            "non-finite params; quarantining "
+                                            "and walking back further",
+                                            ckpt_step)
+                                        self.checkpointer.quarantine(ckpt_step)
+                                except Exception as e:
+                                    raise FloatingPointError(
+                                        f"rollback from non-finite metrics at "
+                                        f"step {step_i} failed ({e}); bad "
+                                        f"metrics: {bad}") from e
+                                rolled_to = int(jax.device_get(self.state.step))
+                                mlog.event(step_i, "rollback", to_step=rolled_to,
+                                           window=step_i - rolled_to, nonfinite=bad)
+                                rolled_back_batches += step_i - rolled_to
+                                step_i = rolled_to
+                                lap_start = step_i
+                                last_metrics = {}
+                                # the feed keeps streaming forward — the model
+                                # rewound, the poisonous batch window did not
+                                continue
+                    if sanitize_every and step_i % sanitize_every == 0:
+                        sanitize.assert_replicas_in_sync(self.state.params)
+                    if callbacks:
+                        # the callers' time (a benchmark's window, its
+                        # start_trace / stop_trace), not the program's
+                        with spans.span("dls.fit/callbacks", anat):
+                            for cb in callbacks:
+                                cb(step_i, last_metrics)
+                    doomed_now: int | None = None
+                    if preempt is not None and step_i >= preempt.step:
+                        doomed_now = faults.fault_host()
+                    elif notice_path is not None:
+                        notice = faults.read_preempt_notice(notice_path)
+                        if notice is not None and step_i >= notice.step:
+                            doomed_now = notice.host
+                    if doomed_now is not None:
+                        # preemption notice: drain (the step above completed),
+                        # hand off live state, exit BEFORE any further
+                        # checkpoint write — the resume point is THIS step
+                        self._graceful_drain(
+                            step_i,
+                            examples_seen=(step_i + rolled_back_batches)
+                            * batch_size,
+                            batch_size=batch_size, doomed=doomed_now)
+                        break
+                    if checkpoint_every and self.checkpointer and step_i % checkpoint_every == 0:
+                        with spans.span("dls.fit/checkpoint", anat):
+                            self.checkpointer.save(
+                                step_i, self.state,
+                                data_state={"examples_seen":
+                                            (step_i + rolled_back_batches)
+                                            * batch_size,
+                                            "batch_size": batch_size},
+                            )
+                        if (fault is not None and fault.kind == "truncate_ckpt"
+                                and step_i >= fault.step):
+                            # kill-mid-finalize drill: make the save durable +
+                            # manifested, tear its bytes, die without warning
+                            self.checkpointer.wait()
+                            faults.truncate_latest_checkpoint(
+                                self.checkpointer.directory)
+                            faults.crash()
+                    if eval_every and eval_dataset is not None and step_i % eval_every == 0:
+                        with spans.span("dls.fit/eval", anat), tele_phase("eval"):
+                            emetrics = self.evaluate(eval_dataset, batch_size=batch_size)
+                        mlog.log(step_i, {f"eval_{k}": v for k, v in emetrics.items()})
+            finally:
+                # flush the trace and tensorboard even when a step/sanitizer blows
+                # up mid-window — a crashed run's trace is the one you want most
+                profiler.stop()
+                if isinstance(self._train_step, anatomy_lib.InstrumentedFunction):
+                    # detach so a later fit() on this trainer gets a fresh lap
+                    # accumulator, not this run's dangling one
+                    self._train_step.attach_anatomy(None)
+                if tele is not None:
+                    # close the run span on every exit the interpreter survives;
+                    # a SIGKILL'd run leaves the stream open-ended, which is the
+                    # signal dlstatus reads as "died mid-run"
+                    tele.emit("phase", name="run", edge="end", step=step_i)
+                mlog.close()
+
+            if skip and not got_batch:
+                raise RuntimeError(
+                    f"resume fast-forward consumed the whole dataset: skipping "
+                    f"{skip} batches (examples_seen="
+                    f"{int(data_state['examples_seen'])}) exhausted the feed "
+                    f"before the first post-resume step — pass a .repeat() "
+                    f"dataset or fewer epochs-already-trained")
+            jax.block_until_ready(self.state.params)
+            summary = {**meter.summary(), **last_metrics}
+            if on_nonfinite == "skip":
+                if skipped_dev is not None:
+                    n_skipped = int(jax.device_get(skipped_dev))
+                summary["skipped_steps"] = float(n_skipped)
+                if n_skipped:
+                    logger.warning("run skipped %d non-finite step(s) "
+                                   "(on_nonfinite='skip')", n_skipped)
+            elif on_nonfinite == "rollback":
+                summary["rollbacks"] = float(rollbacks)
+            if (self.checkpointer and checkpoint_every
+                    and self.preempted_at is None):
+                # a drained run already committed its live handoff; a final
+                # checkpoint here would advance the walk-back point past the
+                # handoff and muddy the "no walk-back" resume invariant
+                self.checkpointer.save(
+                    step_i, self.state,
+                    data_state={"examples_seen":
+                                (step_i + rolled_back_batches) * batch_size,
+                                "batch_size": batch_size},
+                )
+                self.checkpointer.wait()
+            # timing laps are closed — safe to wait for the async device-time
+            # budget log so short jobs still surface it before returning
+            profiler.join_breakdown()
+            return self.state, summary
 
     def evaluate(self, dataset: PartitionedDataset, *, batch_size: int) -> dict[str, float]:
         """Weighted-mean metrics over the full dataset, tail batch included.
@@ -1099,6 +1129,15 @@ class Trainer:
                            row_out)
                 else:
                     yield row_out
+
+    def startup_summary(self) -> dict[str, Any] | None:
+        """The process's ``startup`` record (:class:`~..telemetry.anatomy.
+        StartupLedger`: the ``dls.start/*`` sections and the first lap's
+        parts, in seconds, which sum to ``to_first_lap_s``), with or without
+        a telemetry writer (without one that lap's feed wait, dispatches and
+        drain are not split out of ``fit_unaccounted_s``); ``None`` until the
+        first lap of the process's first ``fit`` has closed."""
+        return anatomy_lib.STARTUP.summary()
 
     def compiled_cost(self, batch: dict[str, Any]) -> float | None:
         """FLOPs per step from XLA cost analysis (for MFU reporting).

@@ -82,7 +82,6 @@ def test_sections_and_unaccounted_tile_the_lap_nested_and_repeated():
     # the 2.25 s handed in as wait and put come off what no section covers
     assert rec["anatomy_wall_s"] == pytest.approx(11.375)
     assert rec["device_dispatch_s"] == pytest.approx(3.0)
-    assert rec["device_dispatches"] == 3
     assert rec["eval_s"] == pytest.approx(3.0)           # its own time only
     assert rec["device_drain_s"] == pytest.approx(0.25)
     assert rec["emit_s"] == pytest.approx(0.125)
@@ -102,13 +101,20 @@ def test_sections_and_unaccounted_tile_the_lap_nested_and_repeated():
 
 def test_every_counter_has_one_home():
     """One list of names: each counter of the list belongs to the loop's
-    accumulator or to the feed's, and each accumulator takes every name of
-    its own."""
+    accumulator, to the feed's or to the start's, and each accumulator takes
+    every name of its own."""
     anat, probe = anatomy.StepAnatomy(), StarvationProbe()
-    loop = {k for k in spans.COUNTERS.values() if not k.startswith("input_")}
+    start = anatomy.StartupLedger()
+    of_start = {k for n, k in spans.COUNTERS.items()
+                if n.startswith(spans.START_PREFIX)}
+    loop = {k for k in spans.COUNTERS.values()
+            if not k.startswith("input_")} - of_start
     assert loop == set(anat.lap(steps=0)) & set(spans.COUNTERS.values())
     for name, key in spans.COUNTERS.items():
-        (probe if key.startswith("input_") else anat).add(name, 1.0)
+        (probe if key.startswith("input_") else
+         start if key in of_start else anat).add(name, 1.0)
+    assert of_start <= set(start.first_lap(steps=0, lap={}, feed={},
+                                           compiles=[]))
     snap = probe.snapshot()
     assert {k for k in spans.COUNTERS.values() if k.startswith("input_")} \
         <= set(snap)
@@ -122,9 +128,15 @@ def test_without_a_sink_the_helper_is_a_bare_trace_annotation():
     assert type(telemetry.phase("restore")) is jax.profiler.TraceAnnotation
 
 
-def test_fit_without_telemetry_builds_no_accumulator(monkeypatch):
+@pytest.mark.parametrize("first_fit", [True, False])
+def test_fit_without_telemetry_builds_no_accumulator(monkeypatch, first_fit):
     monkeypatch.delenv(telemetry.WORKDIR_ENV, raising=False)
     telemetry.reset()
+    # the process's first fit, whose start is still open, and a later one
+    start = anatomy.StartupLedger()
+    monkeypatch.setattr(anatomy, "STARTUP", start)
+    if not first_fit:
+        start.first_lap(steps=0, lap={}, feed={}, compiles=[])
     built = []
     for cls in (anatomy.StepAnatomy, StarvationProbe):
         real = cls.__init__
@@ -136,14 +148,22 @@ def test_fit_without_telemetry_builds_no_accumulator(monkeypatch):
     real_span = spans.span
     monkeypatch.setattr(
         spans, "span",
-        lambda name, sink=None: (sinks.append(sink), real_span(name, sink))[1])
+        lambda name, sink=None: (sinks.append((name, sink)),
+                                 real_span(name, sink))[1])
     spark = Session.builder.master("local[1]").getOrCreate()
     ds = PartitionedDataset.parallelize(_mnist_like(32), 2).repeat()
     trainer = Trainer(spark, LeNet5(), losses.softmax_xent, optax.sgd(0.01))
     trainer.fit(ds, batch_size=8, steps=4, log_every=2,
                 callbacks=[lambda step, metrics: None])
     assert built == []
-    assert len(sinks) > 10 and all(s is None for s in sinks)
+    # the sections of the start, which run once a process, have its ledger
+    of_start = [s for n, s in sinks if n.startswith(spans.START_PREFIX)]
+    assert of_start and all(s is (start if first_fit else None)
+                            for s in of_start)
+    rest = [s for n, s in sinks if not n.startswith(spans.START_PREFIX)]
+    assert len(rest) > 10 and all(s is None for s in rest)
+    assert trainer._train_step._anatomy is None
+    assert (start.summary()["steps"] == 2) is first_fit
 
 
 # -- the feed's accumulator ---------------------------------------------------
@@ -337,7 +357,6 @@ def test_compile_event_splits_lower_from_backend(tmp_path):
     assert summary["total_backend_s"] == e["backend_s"]
     lap = anat.lap(steps=1)
     assert lap["compile_in_lap_s"] == pytest.approx(e["compile_s"], rel=0.2)
-    assert lap["device_dispatches"] == 1
 
 
 # -- one real trace -----------------------------------------------------------
@@ -362,6 +381,12 @@ def _lines_with_spans(xplane):
 
 
 def test_a_traced_fit_holds_every_span_on_named_lines(tmp_path, monkeypatch):
+    # a process whose start is on record, whatever ran before this test: no
+    # `dls.start/*` section on the loop's line (tests/test_startup_spans.py
+    # has the profile that holds them)
+    done = anatomy.StartupLedger()
+    done.first_lap(steps=0, lap={}, feed={}, compiles=[])
+    monkeypatch.setattr(anatomy, "STARTUP", done)
     monkeypatch.setenv(telemetry.WORKDIR_ENV, str(tmp_path / "tele"))
     spark = Session.builder.master("local[1]").getOrCreate()
     ds = (PartitionedDataset.parallelize(_mnist_like(), 2).repeat()
@@ -390,8 +415,9 @@ def test_a_traced_fit_holds_every_span_on_named_lines(tmp_path, monkeypatch):
     assert len(names) == len(set(names)), names
     by_line = dict(lines)
     # this run saves no checkpoint, evaluates nothing and decodes no JPEG
-    expected = (set(spans.COUNTERS) - {"dls.fit/checkpoint", "dls.fit/eval",
-                                       "dls.feed/decode"}
+    of_start = {n for n in spans.COUNTERS if n.startswith(spans.START_PREFIX)}
+    expected = (set(spans.COUNTERS) - of_start
+                - {"dls.fit/checkpoint", "dls.fit/eval", "dls.feed/decode"}
                 | {"train", spans.PHASE_PREFIX + "compile"})
     # (whether eight steps get to fill a kept slot again is the threads' race)
     reused = {"dls.feed/slot_reused"}
