@@ -84,8 +84,10 @@ def _most_referred(arrays: dict[str, np.ndarray]) -> int:
 #: what :func:`_most_referred` reads of arrays that only their dict refers to
 _UNSHARED = _most_referred({"x": np.empty(0)})
 #: batches of arrays a stream keeps to fill again: the default prefetch
-#: ring's three, the one its consumer holds and the one being filled
-_KEPT_SLOTS = 5
+#: ring's three, the one its consumer holds, the one being filled and, where
+#: a mapping pool fills the rows, the one begun ahead of it (the pool gets the
+#: next batch's rows before this batch is waited for, so it never drains)
+_KEPT_SLOTS = 6
 #: examples are copied into their batch this many bytes at a time: one image
 #: as it arrives, a whole batch of token windows with one ``np.stack`` a leaf
 _COPY_BYTES = 1 << 20
@@ -105,30 +107,43 @@ class _Slots:
     """
 
     def __init__(self) -> None:
+        #: rows, then ``(key, shape, dtype)`` of each leaf of an example
         self._signature: tuple | None = None
         self._kept: list[dict[str, np.ndarray]] = []
         #: examples of the last batch's size that make ``_COPY_BYTES``
         self.examples_a_copy = 1
 
-    def take(self, first: dict[str, Any], rows: int,
+    def knows(self, rows: int) -> bool:
+        """A batch of ``rows`` rows has been shaped before: :meth:`take`
+        needs no first example."""
+        return self._signature is not None and self._signature[0] == rows
+
+    def forget(self) -> None:
+        """The next batch is shaped by its own first example again."""
+        self._signature = None
+
+    def take(self, first: dict[str, Any] | None, rows: int,
              sink) -> dict[str, np.ndarray]:
         """Arrays of ``rows`` rows shaped like ``first``, a batch's first
-        example (their contents are whatever was there). The section is
+        example, or like the last batch's where ``first`` is None (their
+        contents are whatever was there). The section is
         ``dls.feed/slot_reused`` or ``dls.feed/slot_new``; the feed's
         probe counts them."""
-        leaves = {k: np.asarray(v) for k, v in first.items()}
-        signature = (rows, *((k, a.shape, a.dtype) for k, a in leaves.items()))
-        if signature != self._signature:  # the stream changed its shapes
-            self._signature, self._kept = signature, []
-            self.examples_a_copy = max(1, _COPY_BYTES // max(
-                1, sum(a.nbytes for a in leaves.values())))
+        if first is not None:
+            leaves = {k: np.asarray(v) for k, v in first.items()}
+            signature = (rows, *((k, a.shape, a.dtype)
+                                 for k, a in leaves.items()))
+            if signature != self._signature:  # the stream changed its shapes
+                self._signature, self._kept = signature, []
+                self.examples_a_copy = max(1, _COPY_BYTES // max(
+                    1, sum(a.nbytes for a in leaves.values())))
         free = next((s for s in self._kept if _most_referred(s) == _UNSHARED),
                     None)
         with spans.span("dls.feed/slot_reused" if free is not None
                         else "dls.feed/slot_new", sink):
             if free is None:
-                free = {k: np.empty((rows, *a.shape), a.dtype)
-                        for k, a in leaves.items()}
+                free = {k: np.empty((rows, *shape), dtype)
+                        for k, shape, dtype in self._signature[1:]}
                 if len(self._kept) < _KEPT_SLOTS:
                     self._kept.append(free)
             # the caller's own dict: what it does to it leaves the slot whole
@@ -156,53 +171,152 @@ def _copy_rows(arrays: dict[str, np.ndarray], at: int,
     return True
 
 
-def _assemble(segments: list[tuple[Iterator, int, bool]], slots: _Slots,
-              ) -> tuple[dict[str, np.ndarray] | None, list | None]:
-    """One batch out of ``segments``, each ``(stream, rows, local)``.
+class _Assembly:
+    """One batch out of ``segments``, each ``(stream, rows, local)``: begun
+    when made, whole when :meth:`finish` returns.
 
-    ``rows`` examples are pulled from each stream in turn. The local ones
-    are copied into their rows of the batch's arrays as they arrive,
-    ``_COPY_BYTES`` at a time, and let go at once, so a mapping pool works
-    on the next rows meanwhile and a worker pool's ring never carries a
-    batch of views; the others are only walked (every host advances every
-    shard, see :func:`host_batches`). Returns ``(batch, None)``, or
-    ``(None, rest)`` with every example pulled, in order, when a stream ran
-    short: the remainder paths stack those.
+    ``rows`` examples are taken from each stream in turn. A local segment
+    whose stream offers ``fill`` (a :meth:`~..rdd.PartitionedDataset.
+    map_parallel` partition) is ASKED for its rows: the stream's pool
+    writes each example it makes into its row of the batch's arrays, this
+    thread does nothing once an example, and :meth:`finish` waits for the
+    pool once (``asked``; the batch's arrays are shaped like the last
+    batch's, and a stream's first batch takes one example through ``next``
+    to shape them by). From any other stream the local examples are pulled
+    and copied into their rows as they arrive, ``_COPY_BYTES`` at a time,
+    and let go at once, so a worker pool's ring never carries a batch of
+    views; the segments of other hosts are only walked (every host advances
+    every shard, see :func:`host_batches`). What decides is what the stream
+    can do, looked at once a segment.
     """
-    sink = spans.bound_sink()
-    local_rows = sum(rows for _, rows, local in segments if local)
-    arrays: dict[str, np.ndarray] | None = None
-    filled = 0
-    loose = False  # some examples did not fit the arrays: np.stack decides
-    parts: list[list | range] = []
-    short = False
-    for stream, rows, local in segments:
-        pulled = itertools.islice(stream, rows)
-        if not local or loose:
-            part: list | range = list(pulled)
-        else:
-            start = filled
-            while group := list(itertools.islice(pulled,
-                                                 slots.examples_a_copy)):
-                with spans.span("dls.feed/stack", sink):
-                    if arrays is None:
-                        arrays = slots.take(group[0], local_rows, sink)
-                    loose = not _copy_rows(arrays, filled, group)
+
+    def __init__(self, segments: list[tuple[Iterator, int, bool]],
+                 slots: "_Slots"):
+        self._slots = slots
+        self._arrays: dict[str, np.ndarray] | None = None
+        #: a segment's examples, in order, in pieces: a range of rows they
+        #: were copied to, a list of loose examples, or what a ``fill``
+        #: handed back, which becomes pieces when waited for
+        self._parts: list[tuple[list, bool]] = []
+        self.short = False   # a stream ran out inside this batch
+        self.asked = False   # some rows are a pool's to write
+        self._by_map = True  # ... and every local row is
+        try:
+            self._begin(segments, spans.bound_sink())
+        except BaseException:
+            self.abandon()
+            raise
+
+    def _begin(self, segments, sink) -> None:
+        local_rows = sum(rows for _, rows, local in segments if local)
+        filled = 0
+        loose = False  # some examples did not fit the arrays: np.stack decides
+        for stream, rows, local in segments:
+            fill = getattr(stream, "fill", None) if local and not loose else None
+            pieces: list = []
+            self._parts.append((pieces, local))
+            if fill is not None:
+                got = 0
+                if not self._slots.knows(local_rows) and self._arrays is None:
+                    self._by_map = False
+                    first = list(itertools.islice(stream, 1))
+                    if first:
+                        with spans.span("dls.feed/stack", sink):
+                            self._arrays = self._slots.take(
+                                first[0], local_rows, sink)
+                            _copy_rows(self._arrays, filled, first)
+                        pieces.append(range(filled, filled + 1))
+                        got = 1
+                elif self._arrays is None:
+                    with spans.span("dls.feed/stack", sink):
+                        self._arrays = self._slots.take(None, local_rows, sink)
+                if self._arrays is not None:
+                    asked = fill(self._arrays, filled + got, rows - got)
+                    pieces.append(asked)
+                    self.asked = True
+                    got += asked.taken
+                filled += got
+            elif not local or loose:
+                pieces.append(list(itertools.islice(stream, rows)))
+                got = len(pieces[0])
+            else:
+                self._by_map = False
+                pulled = itertools.islice(stream, rows)
+                got = 0
+                while group := list(itertools.islice(
+                        pulled, self._slots.examples_a_copy)):
+                    with spans.span("dls.feed/stack", sink):
+                        if self._arrays is None:
+                            self._arrays = self._slots.take(
+                                group[0], local_rows, sink)
+                        loose = not _copy_rows(self._arrays, filled + got,
+                                               group)
+                    if loose:
+                        break
+                    got += len(group)
+                pieces.append(range(filled, filled + got))
+                filled += got
                 if loose:
-                    break
-                filled += len(group)
-            part = range(start, filled)
-            if loose:
-                part = [*_rows_of(arrays, part), *group, *pulled]
-        short |= len(part) < rows
-        parts.append(part)
-    if not short and not loose:
-        return arrays, None
-    examples = [_rows_of(arrays, p) for p in parts]
-    if short:
-        return None, [e for part in examples for e in part]
-    return _stack([e for part, (_, _, local) in zip(examples, segments)
-                   if local for e in part]), None
+                    pieces.append([*group, *pulled])
+                    got += len(pieces[1])
+            self.short |= got < rows
+
+    def _waited(self) -> list[tuple[list, bool]]:
+        """Every segment's pieces once no pool thread writes any more: the
+        first exception in row order is raised after the last has been
+        waited for."""
+        parts, self._parts = self._parts, []
+        waited: list[tuple[list, bool]] = []
+        failed = None
+        for pieces, local in parts:
+            out: list = []
+            for piece in pieces:
+                if isinstance(piece, (list, range)):
+                    out.append(piece)
+                    continue
+                try:
+                    out.extend(piece.wait())
+                except BaseException as e:  # noqa: BLE001 — raised below
+                    failed = failed or e
+            waited.append((out, local))
+        if failed is not None:
+            self._arrays = None
+            raise failed
+        return waited
+
+    def abandon(self) -> None:
+        """Give the batch up; returns when no thread writes into it."""
+        for pieces, _ in self._parts:
+            for piece in pieces:
+                if not isinstance(piece, (list, range)):
+                    piece.cancel()
+        try:
+            self._waited()
+        except BaseException:  # noqa: BLE001 — the batch is given up
+            pass
+        self._arrays = None
+
+    def finish(self) -> tuple[dict[str, np.ndarray] | None, list | None]:
+        """``(batch, None)``, or ``(None, rest)`` with every example taken,
+        in order, when a stream ran short: the remainder paths stack
+        those (the rows that were filled, as views of them)."""
+        parts = self._waited()
+        arrays, self._arrays = self._arrays, None
+        loose = any(isinstance(piece, list) and piece
+                    for pieces, local in parts if local for piece in pieces)
+        if not self.short and not loose:
+            if self.asked and self._by_map:
+                with spans.span("dls.feed/filled_by_map", spans.bound_sink()):
+                    pass
+            return arrays, None
+        examples = [[e for piece in pieces for e in _rows_of(arrays, piece)]
+                    for pieces, _ in parts]
+        if self.short:
+            return None, [e for part in examples for e in part]
+        if self.asked:  # what a row is has changed: look at the next again
+            self._slots.forget()
+        return _stack([e for part, (_, local) in zip(examples, parts)
+                       if local for e in part]), None
 
 
 def _rows_of(arrays: dict[str, np.ndarray] | None, part: list | range) -> list:
@@ -263,13 +377,26 @@ def host_batches(
     """Yield stacked host batches from an RDD of example dicts.
 
     Each batch is what :func:`stack_examples` gives for its examples, filled
-    a row at a time as they arrive (:func:`_assemble`). **Whose memory a
-    batch is:** the caller's, for as long as it refers to it. The stream
-    fills its batches into a few kept sets of arrays (:class:`_Slots`) and
-    writes one again only once nothing refers to its arrays any more: not
-    the batch's dict, not a view of a leaf, not a transfer or a device array
+    a row at a time (:class:`_Assembly`). **Which thread writes a row.**
+    Where a shard of this host is ONE partition of a ``map_parallel``
+    dataset (``imagenet_train``; its stream offers ``fill``), the pool's
+    threads write each example they make into its row, the thread that
+    pulls these batches does nothing once an example, and the next batch's
+    rows are handed to the pool before this batch is waited for, so two
+    batches are being filled at once. Every other stream stays on the pull
+    path, where the pulling thread copies the examples into their rows as
+    they arrive: plain iterators (token windows), a
+    :class:`~.workers.WorkerMappedDataset`'s ring views, several partitions
+    chained into one stream, and more than one partition dealt in turn to
+    a shard. The bytes are the same either way. **Whose memory a batch
+    is:** the caller's, for as long as it refers to it. The stream fills
+    its batches into a few kept sets of arrays (:class:`_Slots`) and writes
+    one again only once nothing refers to its arrays any more: not the
+    batch's dict, not a view of a leaf, not a transfer or a device array
     that jax made from one. A caller that keeps every batch gets new memory
-    for each, as before.
+    for each, as before. A batch is handed on only when every thread that
+    writes into it has finished, and when the stream ends, however it ends,
+    no thread writes into any of them any more.
 
     ``num_workers`` overrides the worker-process count of a pool-backed
     dataset (:class:`~.workers.WorkerMappedDataset`, e.g. from
@@ -341,9 +468,11 @@ def host_batches(
             segments.append((_round_robin(g) if len(g) > 1 else g[0],
                              per_shard, lo <= s < hi))
     else:
-        stream = itertools.chain.from_iterable(
-            dataset.iter_partition(i) for i in range(n_parts)
-        )
+        # one partition is handed on as its own iterator: chaining would
+        # hide what it can do (``fill``)
+        stream = (dataset.iter_partition(0) if n_parts == 1
+                  else itertools.chain.from_iterable(
+                      dataset.iter_partition(i) for i in range(n_parts)))
         if shard_range is None:
             segments = [(stream, batch_size, True)]
         else:
@@ -351,13 +480,34 @@ def host_batches(
                         (stream, (hi - lo) * per_shard, True),
                         (stream, (num_shards - hi) * per_shard, False)]
     slots = _Slots()
-    while True:
-        batch, rest = _assemble(segments, slots)
-        if batch is None:
-            break
-        yield checked(batch)
-        # or this frame would still refer to the slot it has just handed on
-        del batch
+    begun: list[_Assembly] = []  # the batch being filled, the one ahead
+    failed: BaseException | None = None
+    try:
+        while failed is None:
+            if not begun:
+                begun.append(_Assembly(segments, slots))
+            if begun[0].asked and not begun[0].short:
+                # a pool is writing this batch's rows: it gets the next
+                # batch's before this one is waited for, or it would drain
+                # at every batch's end. What that raises waits its turn.
+                try:
+                    begun.append(_Assembly(segments, slots))
+                except Exception as e:  # noqa: BLE001 — raised below
+                    failed = e
+            batch, rest = begun.pop(0).finish()
+            if batch is None:
+                break
+            yield checked(batch)
+            # or this frame would still refer to the slot it has just
+            # handed on
+            del batch
+        if failed is not None:
+            raise failed
+    finally:
+        # no pool thread writes once this stream has ended, however it did
+        for each in begun:
+            each.abandon()
+        del begun[:]
     if not rest or drop_remainder:
         return
     if pad_remainder:

@@ -65,11 +65,17 @@ class StarvationProbe:
       building one host batch (decode/augment/stack), measured in the
       background thread; tells you WHY the ring ran dry. Of it,
       ``input_stack_s`` (``dls.feed/stack``) is the copying of the
-      examples into their rows of the batch (``feed._assemble``).
+      examples into their rows of the batch by the producer itself
+      (``feed._Assembly``), and once a batch the taking of its slot.
     - ``input_slot_reused`` / ``input_slot_new`` (``dls.feed/slot_reused``,
       ``dls.feed/slot_new``) — numbers, not seconds: the batches whose
       arrays ``host_batches`` took from a slot it kept, and from new memory
       because every kept slot was still referred to (or there was none yet).
+    - ``input_filled_by_map`` (``dls.feed/filled_by_map``) — a number: the
+      batches whose local rows were all written by the threads of the
+      ``map_parallel`` pool that made them (``feed._Assembly``), so that
+      the producer did nothing once an example; 0 where no stream offers
+      ``fill``.
     - ``input_blocked_s`` (``dls.feed/ring_full``) — the producer holding a
       finished batch with no room in the ring: the feed's headroom.
     - ``input_map_s`` (``dls.feed/map``) — thread-seconds inside
@@ -85,7 +91,7 @@ class StarvationProbe:
     """
 
     #: the counters that are numbers of sections, not their seconds
-    _COUNTED = ("input_slot_reused", "input_slot_new")
+    _COUNTED = ("input_slot_reused", "input_slot_new", "input_filled_by_map")
     #: the counters every snapshot carries (``input_map_s`` and
     #: ``input_decode_s`` apart)
     _ALWAYS = ("input_wait_s", "input_put_s", "input_assembly_s",

@@ -32,9 +32,11 @@ Both pyspark camelCase and pythonic snake_case spellings are provided.
 
 from __future__ import annotations
 
+import collections
 import functools
 import itertools
 import random
+import threading
 from typing import Any, Callable, Iterable, Iterator, Sequence
 
 import numpy as np
@@ -53,6 +55,247 @@ def _start_map_thread(sink) -> None:
     spans.bind_sink(sink)
 
 PartitionFn = Callable[[], Iterable[Any]]
+
+
+class _MappedStream:
+    """``f`` over one partition's iterator by a pool of ``workers`` threads,
+    in the iterator's order: what :meth:`PartitionedDataset.map_parallel`
+    makes of a partition.
+
+    ``next`` keeps a sliding window of ``2 x workers`` calls in flight and
+    hands their results over one by one. :meth:`fill` asks for rows of a
+    batch instead. The pool starts at the first of either, on the thread
+    that asks (whose feed sink its threads bind), and ends when the stream
+    does: exhausted or failed (what it was handed before is still done), or
+    let go of.
+    """
+
+    def __init__(self, it: Iterable[Any], f: Callable[[Any], Any],
+                 workers: int):
+        self._pool = None
+        self._closed = False  # let go of: nothing more is made or written
+        self._ended = False   # the upstream iterator has run out
+        self._it = iter(it)
+        self._f = f
+        self._workers = workers
+        self._window: collections.deque = collections.deque()
+        self._sink = None
+
+    def __iter__(self) -> "_MappedStream":
+        return self
+
+    def _started(self):
+        if self._pool is None:
+            from concurrent.futures import ThreadPoolExecutor
+
+            # the probe of the feed whose thread pulls this partition (None
+            # outside a feed with telemetry): thread-seconds in ``f`` add to
+            # its ``input_map_s``, and the pool's threads have it bound
+            self._sink = spans.bound_sink()
+            self._pool = ThreadPoolExecutor(
+                self._workers, initializer=_start_map_thread,
+                initargs=(self._sink,))
+        return self._pool
+
+    def _call(self, item: Any) -> Any:
+        if self._closed:
+            return None
+        with spans.span("dls.feed/map", self._sink):
+            return self._f(item)
+
+    def __next__(self) -> Any:
+        if self._closed or (self._ended and not self._window):
+            raise StopIteration
+        try:
+            pool = self._started()
+            while not self._ended and len(self._window) < 2 * self._workers:
+                try:
+                    item = next(self._it)
+                except StopIteration:
+                    self._ended = True
+                    break
+                self._window.append(pool.submit(self._call, item))
+            if self._window:
+                handed = self._window.popleft()
+                self._let_the_pool_go()
+                return handed.result()
+            self._let_the_pool_go()
+        except BaseException:
+            self._fail()
+            raise
+        raise StopIteration
+
+    def _fail(self) -> None:
+        """The upstream iterator or ``f`` raised: the stream has ended, as a
+        generator would have. Rows asked for before are still written (a
+        batch begun earlier is whole, and is handed on before the failure
+        is raised)."""
+        self._ended = True
+        self._window.clear()
+        if self._pool is not None:
+            self._let_the_pool_go()
+
+    def _let_the_pool_go(self) -> None:
+        """Once the upstream iterator has ended and nothing more will be
+        handed to the pool, its threads end when they have done what they
+        were handed."""
+        if self._ended and not self._window:
+            self._pool.shutdown(wait=False)
+
+    def fill(self, arrays: dict[str, np.ndarray], at: int, n: int) -> "_Fill":
+        """The next ``n`` examples into rows ``at``... of ``arrays``, each
+        written by the pool thread that made it.
+
+        The calling thread takes the ``n`` items off the upstream iterator
+        at once (what ``next`` had already asked for comes first, so the
+        order holds) and hands them to the pool in runs of consecutive
+        rows, a few runs a thread. A run writes each example under the test
+        of ``data/feed._copy_rows`` (the arrays' keys, the row's shape, the
+        same dtype), inside the call's ``dls.feed/map`` section, and from
+        the first that does not fit keeps its examples loose. Returns at
+        once; :meth:`_Fill.wait` is what the caller waits on, once."""
+        fill = _Fill(arrays)
+        if self._closed or (self._ended and not self._window):
+            return fill._asked(0)
+        try:
+            pool = self._started()
+            early = [self._window.popleft()
+                     for _ in range(min(n, len(self._window)))]
+            items = [] if self._ended else list(
+                itertools.islice(self._it, n - len(early)))
+            fill.taken = len(early) + len(items)
+            run = max(1, -(-n // (4 * self._workers)))
+            runs = [(made, todo[i:i + run]) for made, todo in
+                    ((True, early), (False, items))
+                    for i in range(0, len(todo), run)]
+            fill._asked(len(runs))
+            row = at
+            for k, (made, todo) in enumerate(runs):
+                pool.submit(self._run, fill, k, row, todo, made)
+                row += len(todo)
+            self._ended |= fill.taken < n
+            self._let_the_pool_go()
+        except BaseException:
+            fill.cancel()
+            self._fail()
+            raise
+        return fill
+
+    def _run(self, fill: "_Fill", k: int, row: int, todo: list,
+             made: bool) -> None:
+        """One run of a :meth:`fill`, on a pool thread: ``todo`` are items
+        for ``f``, or (``made``) the futures of calls ``next`` had asked
+        for, which lie ahead of this run in the pool's queue."""
+        from distributeddeeplearningspark_tpu.data.feed import _copy_rows
+
+        start, loose, arrays = row, None, fill.arrays
+        try:
+            for x in todo:
+                if self._closed or fill.failed_before(k):
+                    raise _Stopped
+                example = x.result() if made else None
+                with spans.span("dls.feed/map", self._sink):
+                    if not made:
+                        example = self._f(x)
+                    if loose is None and _copy_rows(arrays, row, [example]):
+                        row += 1
+                    elif loose is None:
+                        loose = []
+                if loose is not None:
+                    loose.append(example)
+            result: Any = (range(start, row), loose)
+        except BaseException as e:  # handed to the thread that waits
+            result = e
+        # or the thread that waits could wake, look for a free slot and find
+        # this frame still referring to one (``data/feed._Slots``)
+        del arrays
+        fill._done(k, result)
+
+    def __del__(self) -> None:
+        # nobody can ask any more: what the pool has not begun does nothing
+        self._closed = True
+        if self._pool is not None:
+            self._pool.shutdown(wait=False)
+
+
+class _Stopped(Exception):
+    """A run that gave up: a run of earlier rows had raised (that comes
+    first in row order), or the fill was cancelled or its stream let go of
+    (then nobody asks)."""
+
+
+class _Fill:
+    """What :meth:`_MappedStream.fill` hands back at once.
+
+    ``taken`` says how many examples the stream had for the rows asked for
+    (known when ``fill`` returns); :meth:`wait` blocks until every run has
+    finished, so that no thread writes into ``arrays`` after it, and lets go
+    of the arrays: the stream's own references must not make a feed's slot
+    look busy (``data/feed._Slots`` reads reference counts)."""
+
+    def __init__(self, arrays: dict[str, np.ndarray]):
+        self.arrays: dict[str, np.ndarray] | None = arrays
+        self.taken = 0
+        self._results: list = []
+        self._left = 0
+        self._failed = None  # the first run, in row order, that raised
+        self._lock = threading.Lock()
+        self._finished = threading.Event()
+
+    def _asked(self, runs: int) -> "_Fill":
+        self._results = [None] * runs
+        self._left = runs
+        if not runs:
+            self.arrays = None
+            self._finished.set()
+        return self
+
+    def _done(self, k: int, result: Any) -> None:
+        with self._lock:
+            self._results[k] = result
+            if (isinstance(result, BaseException)
+                    and not isinstance(result, _Stopped)
+                    and (self._failed is None or k < self._failed)):
+                self._failed = k
+            self._left -= 1
+            if self._left:
+                return
+        self.arrays = None
+        self._finished.set()
+
+    def failed_before(self, k: int) -> bool:
+        """A run of earlier rows raised (or the fill was cancelled): the
+        batch is lost, and run ``k`` need not go on."""
+        return self._failed is not None and self._failed < k
+
+    def cancel(self) -> None:
+        """Runs that have not finished stop at their next example."""
+        with self._lock:
+            self._failed = -1
+
+    def wait(self) -> list:
+        """Blocks until the last run has finished. Returns the rows asked
+        for, in order, as pieces: a ``range`` of rows that were written, or
+        a list of examples that did not fit the arrays (then ``np.stack``
+        decides, in the caller). Raises what ``f`` raised, the first in row
+        order, as ``next`` would have."""
+        self._finished.wait()
+        pieces: list = []
+        for result in self._results:
+            if isinstance(result, _Stopped):
+                raise RuntimeError(
+                    "these rows were given up: the fill was cancelled")
+            if isinstance(result, BaseException):
+                raise result
+            rows, loose = result
+            if rows:
+                if pieces and isinstance(pieces[-1], range):
+                    pieces[-1] = range(pieces[-1].start, rows.stop)
+                else:
+                    pieces.append(rows)
+            if loose:
+                pieces.append(loose)
+        return pieces
 
 
 class PartitionedDataset:
@@ -122,6 +365,16 @@ class PartitionedDataset:
         pools would oversubscribe by ``num_partitions×``. Compose
         ``.repeat()`` BEFORE this (like ``shuffle``) so one pool lives
         across epochs instead of draining and respawning per pass.
+
+        **Which thread writes a row.** A partition's stream is an iterator
+        with one more method, :meth:`_MappedStream.fill`: asked for the next
+        ``n`` examples as rows of a batch's arrays, the pool's threads
+        write what they made into the rows themselves, and the asking
+        thread does nothing once an example (``data/feed.host_batches``
+        asks where a shard is one such stream). Whoever iterates
+        (``collect``, ``take``, ``write_array_records``, a loop) gets each
+        result handed over by ``next``, as ever; both may be mixed on one
+        stream and keep its order.
         """
         import os
 
@@ -130,30 +383,8 @@ class PartitionedDataset:
         workers = num_threads or min(
             32, max(1, (os.cpu_count() or 4) // max(self.num_partitions, 1)))
 
-        def per_partition(it: Iterable[Any]) -> Iterator[Any]:
-            from collections import deque
-            from concurrent.futures import ThreadPoolExecutor
-
-            # the probe of the feed whose thread pulls this partition (None
-            # outside a feed with telemetry): thread-seconds in ``f`` add to
-            # its ``input_map_s``, and the pool's threads have it bound
-            sink = spans.bound_sink()
-
-            def call(item: Any) -> Any:
-                with spans.span("dls.feed/map", sink):
-                    return f(item)
-
-            with ThreadPoolExecutor(workers, initializer=_start_map_thread,
-                                    initargs=(sink,)) as ex:
-                window: deque = deque()
-                for item in it:
-                    window.append(ex.submit(call, item))
-                    if len(window) >= 2 * workers:
-                        yield window.popleft().result()
-                while window:
-                    yield window.popleft().result()
-
-        return self.map_partitions(per_partition)
+        return self.map_partitions(
+            lambda it: _MappedStream(it, f, workers))
 
     def flat_map(self, f: Callable[[Any], Iterable[Any]]) -> "PartitionedDataset":
         return self.map_partitions(lambda it: itertools.chain.from_iterable(map(f, it)))

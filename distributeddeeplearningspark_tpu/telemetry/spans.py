@@ -49,11 +49,13 @@ COUNTERS = {
     "dls.feed/ring_full": "input_blocked_s",
     "dls.feed/map": "input_map_s",
     "dls.feed/decode": "input_decode_s",
-    # of these two the NUMBER of sections is added, not their time: a batch's
-    # arrays came from a slot the feed kept, or from new memory
-    # (``data/feed._Slots``)
+    # of these three the NUMBER of sections is added, not their time: a
+    # batch's arrays came from a slot the feed kept, or from new memory
+    # (``data/feed._Slots``); every local row of a batch was written by the
+    # pool of the ``map_parallel`` stream it came from (``_Assembly``)
     "dls.feed/slot_reused": "input_slot_reused",
     "dls.feed/slot_new": "input_slot_new",
+    "dls.feed/filled_by_map": "input_filled_by_map",
     # the start (``anatomy.StartupLedger``); ``dls.start/fit`` holds every
     # section below it, so its own time is the start that no section covers
     "dls.start/import": "import_s",

@@ -191,6 +191,201 @@ def test_examples_np_stack_would_promote_or_refuse_still_are(copies):
                   list(_as_it_was(ds, 8)))
 
 
+# -- rows a ``map_parallel`` pool writes itself --------------------------------
+
+
+def _plus_one(ex):
+    return {k: v + 1 for k, v in ex.items()}
+
+
+MAPPED_LAYOUTS = {  # partitions, shards, shard ranges; does the feed ask?
+    "chained_one_shard": (1, 1, [None], True),
+    "chained_one_partition_two_shards": (1, 2, [None, (0, 1), (1, 2)], True),
+    "aligned_a_partition_a_shard": (2, 2, [None, (0, 1), (1, 2)], True),
+    "chained": (3, 2, [None, (1, 2)], False),
+    "aligned_round_robin": (4, 2, [None, (0, 1)], False),
+}
+
+
+def _mapped(examples, parts, f=_plus_one, threads=3):
+    return PartitionedDataset.parallelize(examples, parts).map_parallel(
+        f, num_threads=threads)
+
+
+@pytest.mark.parametrize("remainder", REMAINDERS)
+@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("layout,shard_range", [
+    (name, r) for name, (_, _, ranges, _) in MAPPED_LAYOUTS.items()
+    for r in ranges])
+def test_every_batch_of_a_mapped_stream_is_what_stack_examples_gave(
+        layout, shard_range, kind, remainder):
+    """The pool writes the rows where a shard is ONE ``map_parallel``
+    partition, and is pulled from where partitions are chained or dealt in
+    turn: the same bytes either way, the finite stream's tail included."""
+    parts, shards, _, asks = MAPPED_LAYOUTS[layout]
+    ds = _mapped([KINDS[kind](i) for i in range(45)], parts)
+    kw = dict(num_shards=shards, shard_range=shard_range,
+              **REMAINDERS[remainder])
+    want = list(_as_it_was(ds, 8, **kw))
+    stream, probe = _counted(ds, 8, **kw)
+    n = 0
+    for got in stream:
+        _same_batches([got], [want[n]])
+        n += 1
+        del got
+    assert n == len(want) >= 5
+    # the five whole batches but the stream's first, whose first example
+    # shaped the slot (the tail's rows are filled too, and stacked as views)
+    assert probe.snapshot()["input_filled_by_map"] == (4 if asks else 0)
+    # and kept, every batch in memory of its own
+    _same_batches(list(host_batches(ds, 8, **kw)), want)
+
+
+def test_an_infinite_mapped_stream_fills_batch_after_batch():
+    ds = PartitionedDataset.parallelize(
+        [_image(i) for i in range(20)], 1).repeat().map_parallel(
+            _plus_one, num_threads=4)
+    want = _as_it_was(ds, 8)
+    stream, probe = _counted(ds, 8)
+    for _ in range(30):       # 20 does not divide by 8: every phase of it
+        batch = next(stream)
+        _same_batches([batch], [next(want)])
+        del batch
+    stream.close()
+    snap = probe.snapshot()
+    assert snap["input_filled_by_map"] >= 29
+    # two are being filled at once, so two slots serve a caller that keeps
+    # nothing; every later batch went into one of them
+    assert snap["input_slot_new"] == 2 and snap["input_slot_reused"] >= 28
+
+
+@pytest.mark.parametrize("odd", ["dtype", "shape", "a_key_more", "a_key_less",
+                                 "changing_shapes"])
+def test_a_mapped_example_that_does_not_fit_its_row_is_np_stacks_to_decide(odd):
+    def f(ex):
+        i = int(ex["label"])
+        out = dict(ex)
+        if odd == "dtype" and i % 8 == 5:
+            out["image"] = ex["image"].astype(np.float64)
+        if odd == "shape" and i == 13:
+            out["image"] = ex["image"][:8]
+        if odd == "a_key_more" and i == 11:
+            out["more"] = np.int32(1)
+        if odd == "a_key_less" and i == 11:
+            del out["label"]
+        if odd == "changing_shapes":
+            out["image"] = ex["image"][:4 + i // 8]
+        return out
+
+    ds = _mapped([_image(i) for i in range(40)], 1, f)
+    if odd in ("shape", "a_key_less"):
+        with pytest.raises(ValueError) as asked:
+            list(host_batches(ds, 8))
+        with pytest.raises(ValueError) as was:
+            list(_as_it_was(ds, 8))
+        assert str(asked.value) == str(was.value)
+        return
+    got = []
+    for batch in host_batches(ds, 8):
+        got.append({k: v.copy() for k, v in batch.items()})
+        del batch
+    _same_batches(got, list(_as_it_was(ds, 8)))
+    if odd == "dtype":
+        assert got[0]["image"].dtype == np.float64
+
+
+def test_a_failure_in_the_mapped_function_surfaces_and_nothing_writes_after(
+        monkeypatch):
+    """Row 21 (third batch) raises. The batches before it arrive, the error
+    is the function's own, and once it is out no pool thread writes into
+    any slot the stream ever took: the batch begun ahead is given up and
+    waited for first."""
+    import time
+
+    class Boom(RuntimeError):
+        pass
+
+    def f(ex):
+        time.sleep(0.002)
+        if int(ex["label"]) == 21:
+            raise Boom("row 21")
+        return _plus_one(ex)
+
+    taken = []
+    real_take = feed._Slots.take
+
+    def take(self, first, rows, sink):
+        arrays = real_take(self, first, rows, sink)
+        taken.append(dict(arrays))
+        return arrays
+
+    monkeypatch.setattr(feed._Slots, "take", take)
+    ds = PartitionedDataset.parallelize(
+        [_image(i) for i in range(64)], 1).repeat().map_parallel(
+            f, num_threads=4)
+    want = _as_it_was(PartitionedDataset.parallelize(
+        [_plus_one(_image(i)) for i in range(16)], 1), 8)
+    stream = host_batches(ds, 8)
+    for _ in range(2):
+        _same_batches([next(stream)], [next(want)])
+    with pytest.raises(Boom, match="row 21"):
+        next(stream)
+    assert len(taken) >= 3     # the failed batch's, and the one begun ahead
+    after = [{k: zlib.crc32(v.tobytes()) for k, v in a.items()} for a in taken]
+    time.sleep(0.1)
+    assert after == [{k: zlib.crc32(v.tobytes()) for k, v in a.items()}
+                     for a in taken]
+    assert next(stream, None) is None
+
+
+def test_a_failure_of_the_upstream_iterator_waits_its_turn():
+    """The walk for the batch begun ahead hits the upstream's error while
+    the batch before it is still being filled: that batch arrives whole,
+    then the error, the upstream's own, as on the pull path."""
+    def upstream():
+        for i in range(64):
+            if i == 20:
+                raise OSError("the disk went away")
+            yield _image(i)
+
+    import time
+
+    def slowly(ex):   # the second batch is still being filled by then
+        time.sleep(0.01)
+        return _plus_one(ex)
+
+    ds = PartitionedDataset.from_generators([upstream]).map_parallel(
+        slowly, num_threads=3)
+    want = list(_as_it_was(PartitionedDataset.parallelize(
+        [_plus_one(_image(i)) for i in range(16)], 1), 8))
+    stream = host_batches(ds, 8)
+    _same_batches([next(stream), next(stream)], want)
+    with pytest.raises(OSError, match="the disk went away"):
+        next(stream)
+    assert next(stream, None) is None
+
+
+def test_a_stream_let_go_of_mid_batch_stops_writing():
+    """``close()`` of the batch stream (what ``fit`` does when its steps are
+    done) waits for the batch begun ahead."""
+    import time
+
+    def f(ex):
+        time.sleep(0.002)
+        return _plus_one(ex)
+
+    ds = PartitionedDataset.parallelize(
+        [_image(i) for i in range(64)], 1).repeat().map_parallel(
+            f, num_threads=4)
+    stream = host_batches(ds, 16)
+    first, second = next(stream), next(stream)
+    stream.close()
+    digest = zlib.crc32(second["image"].tobytes())
+    time.sleep(0.1)
+    assert zlib.crc32(second["image"].tobytes()) == digest
+    _same_batches([first, second], list(itertools.islice(_as_it_was(ds, 16), 2)))
+
+
 # -- whose memory a batch is --------------------------------------------------
 
 
@@ -283,14 +478,18 @@ def test_more_batches_alive_than_slots_get_new_memory_not_an_error():
 # -- through the prefetch ring, onto the (CPU) device -------------------------
 
 
+@pytest.mark.parametrize("rows_by", ["the_producer", "the_maps_pool"])
 @pytest.mark.parametrize("kind", KINDS)
-def test_device_batches_hold_what_their_host_batches_held_at_put(kind, copies):
+def test_device_batches_hold_what_their_host_batches_held_at_put(kind, copies,
+                                                                 rows_by):
     """On the CPU backend a device array may alias the numpy array it was
     made from, for as long as it lives: the slot must stay unwritten. The
     consumer holds the last two device batches, as ``fit`` does."""
     batches = 4 * feed._KEPT_SLOTS
     ds = PartitionedDataset.parallelize(
         [KINDS[kind](i) for i in range(8 * batches)], 1)
+    if rows_by == "the_maps_pool":
+        ds = ds.map_parallel(_plus_one, num_threads=3)
     want = list(_as_it_was(ds, 8))
     mesh = single_device_mesh()
     at_put = []
@@ -315,8 +514,13 @@ def test_device_batches_hold_what_their_host_batches_held_at_put(kind, copies):
         n += 1
     assert n == batches >= 3 * feed._KEPT_SLOTS
     snap = probe.snapshot()
-    assert snap["input_slot_new"] + snap["input_slot_reused"] == batches
+    # (a finite mapped stream takes one more slot, for the batch begun
+    # ahead that finds the stream ended)
+    mapped = rows_by == "the_maps_pool"
+    assert (snap["input_slot_new"] + snap["input_slot_reused"]
+            == batches + mapped)
     assert snap["input_slot_reused"] > 0
+    assert snap["input_filled_by_map"] == (batches - 1 if mapped else 0)
 
 
 def test_two_threads_and_a_short_switch_interval_never_share_a_slot(copies):
@@ -378,6 +582,49 @@ def test_the_slot_counters_are_on_the_one_list_and_in_every_snapshot():
     assert probe.snapshot()["input_slot_reused"] == 0
 
 
+def test_the_fill_counter_is_on_the_one_list_and_in_every_snapshot():
+    assert spans.COUNTERS["dls.feed/filled_by_map"] == "input_filled_by_map"
+    assert "dls.feed/filled_by_map" in spans.SPAN_NAMES
+    assert prefetch.StarvationProbe().snapshot()["input_filled_by_map"] == 0
+    probe = prefetch.StarvationProbe()
+    probe.add("dls.feed/filled_by_map", 0.25)
+    probe.add("dls.feed/filled_by_map", 0.25)
+    assert probe.snapshot()["input_filled_by_map"] == 2
+    # a token stream offers no ``fill``: every lap reads 0, and its few
+    # copies stay the producer's
+    ds = PartitionedDataset.parallelize([_tokens(i) for i in range(64)], 1)
+    stream, probe = _counted(ds, 8)
+    for batch in stream:
+        del batch
+    snap = probe.snapshot()
+    assert snap["input_filled_by_map"] == 0
+    assert snap["input_slot_new"] + snap["input_slot_reused"] == 8
+
+
+def test_the_producer_copies_one_example_of_a_mapped_stream(monkeypatch):
+    """The check for ``fill`` is once a segment a batch, and after the
+    stream's first example the producer's thread copies nothing."""
+    import threading
+
+    by_thread = []
+    real_copy = feed._copy_rows
+
+    def copy(arrays, at, examples):
+        by_thread.append((threading.current_thread().name, len(examples)))
+        return real_copy(arrays, at, examples)
+
+    monkeypatch.setattr(feed, "_copy_rows", copy)
+    ds = _mapped([_image(i) for i in range(64)], 1)
+    stream, probe = _counted(ds, 8)
+    for batch in stream:
+        del batch
+    mine = threading.current_thread().name
+    assert [n for name, n in by_thread if name == mine] == [1]
+    assert sum(n for name, n in by_thread if name != mine) == 63
+    assert all(name.startswith("ThreadPoolExecutor") or name == mine
+               for name, _ in by_thread)
+
+
 def test_the_row_copies_are_the_stack_seconds_of_an_image_stream():
     ds = PartitionedDataset.parallelize([_image(i) for i in range(64)], 1)
     stream, probe = _counted(ds, 8)
@@ -388,17 +635,20 @@ def test_the_row_copies_are_the_stack_seconds_of_an_image_stream():
     assert snap["input_slot_new"] + snap["input_slot_reused"] == 8
 
 
-def test_the_benchmarks_reader_of_the_slot_counters():
+def _benchmarks_reader(name):
     import importlib.util
     import os
 
     path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
-                        "benchmark", "layer_metrics",
-                        "feed_slot_reuse_share.py")
-    spec = importlib.util.spec_from_file_location("feed_slot_reuse_share",
-                                                  path)
+                        "benchmark", "layer_metrics", name + ".py")
+    spec = importlib.util.spec_from_file_location(name, path)
     reader = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(reader)
+    return reader
+
+
+def test_the_benchmarks_reader_of_the_slot_counters():
+    reader = _benchmarks_reader("feed_slot_reuse_share")
     laps = [{"steps": 2, "input_slot_reused": 2.0, "input_slot_new": 0.0},
             {"steps": 2, "input_slot_reused": 1.0, "input_slot_new": 1.0}]
     assert reader.read({"laps": laps}) == pytest.approx(75.0)
@@ -406,4 +656,21 @@ def test_the_benchmarks_reader_of_the_slot_counters():
     assert reader.read({"laps": [{"steps": 2, "input_stack_s": 0.1}]}) is None
     assert reader.read({"laps": [{"steps": 0, "input_slot_reused": 0.0,
                                   "input_slot_new": 0.0}]}) is None
+    assert reader.read({"laps": []}) is None
+
+
+def test_the_benchmarks_reader_of_the_fill_counter():
+    reader = _benchmarks_reader("feed_map_fill_share")
+    lap = {"steps": 2, "input_slot_reused": 2.0, "input_slot_new": 0.0}
+    assert reader.read({"laps": [
+        {**lap, "input_filled_by_map": 2.0},
+        {**lap, "input_slot_reused": 1.0, "input_slot_new": 1.0,
+         "input_filled_by_map": 1.0}]}) == pytest.approx(75.0)
+    # a token cell: batches, none of them filled by a pool
+    assert reader.read({"laps": [{**lap, "input_filled_by_map": 0.0}]}) == 0.0
+    # the parent's laps have no such key; a window with no batch reads nothing
+    assert reader.read({"laps": [lap]}) is None
+    assert reader.read({"laps": [{"steps": 0, "input_slot_reused": 0.0,
+                                  "input_slot_new": 0.0,
+                                  "input_filled_by_map": 0.0}]}) is None
     assert reader.read({"laps": []}) is None

@@ -1,6 +1,7 @@
 """PartitionedDataset (RDD-shaped) semantics tests."""
 
 import numpy as np
+import pytest
 
 from distributeddeeplearningspark_tpu.rdd import PartitionedDataset
 
@@ -224,6 +225,100 @@ class TestMapParallel:
         it = inf.iter_partition(0)
         got = [next(it) for _ in range(50)]
         assert len(got) == 50 and got[:4] == [1, 2, 3, 4]  # partition 0 = first contiguous slice
+
+    @staticmethod
+    def _rows(n):
+        return {"x": np.full((n, 3), -1, np.int64),
+                "y": np.full(n, -1.0, np.float32)}
+
+    @staticmethod
+    def _row(i):
+        return {"x": np.arange(3, dtype=np.int64) + 10 * i,
+                "y": np.float32(i) / 4}
+
+    def test_fill_and_next_mixed_on_one_stream_keep_the_order(self):
+        """``next`` asks for 2 x threads calls ahead; a ``fill`` after it
+        must deliver those first, then go on where they end, and a ``next``
+        after a ``fill`` goes on where the fill's rows end."""
+        ds = PartitionedDataset.parallelize(list(range(100)), 1)
+        stream = ds.map_parallel(self._row, num_threads=3).iter_partition(0)
+        assert hasattr(stream, "fill") and iter(stream) is stream
+        got = [next(stream)["x"][0] // 10, next(stream)["x"][0] // 10]
+        arrays = self._rows(40)
+        first = stream.fill(arrays, 5, 30)     # 4 of the window, 26 new
+        second = stream.fill(arrays, 35, 5)    # before the first is waited on
+        assert (first.taken, second.taken) == (30, 5)
+        assert first.wait() == [range(5, 35)] and second.wait() == [range(35, 40)]
+        got += (arrays["x"][5:40, 0] // 10).tolist()
+        assert (arrays["x"][:5] == -1).all()   # rows nobody asked for
+        assert (arrays["y"][5:40] * 4).tolist() == list(range(2, 37))
+        got += [next(stream)["x"][0] // 10 for _ in range(3)]
+        tail = self._rows(80)
+        last = stream.fill(tail, 0, 80)        # the stream ends inside it
+        assert last.taken == 60 and last.wait() == [range(60)]
+        got += (tail["x"][:60, 0] // 10).tolist()
+        assert got == list(range(100))
+        assert (tail["x"][60:] == -1).all()
+        # ended: nothing more either way
+        assert stream.fill(tail, 0, 4).taken == 0
+        assert stream.fill(tail, 0, 4).wait() == []
+        assert next(stream, None) is None
+        # the handles let go of the arrays: a feed's slot must not look busy
+        assert first.arrays is None and last.arrays is None
+
+    def test_fill_hands_back_loose_what_does_not_fit_its_row(self):
+        def odd(i):
+            row = self._row(i)
+            if i == 7:
+                row["x"] = row["x"].astype(np.int32)     # another dtype
+            if i == 21:
+                row["x"] = np.arange(4, dtype=np.int64)  # another shape
+            if i == 22:
+                del row["y"]                             # a key missing
+            return row
+
+        ds = PartitionedDataset.parallelize(list(range(32)), 1)
+        stream = ds.map_parallel(odd, num_threads=2).iter_partition(0)
+        arrays = self._rows(32)
+        pieces = stream.fill(arrays, 0, 32).wait()   # runs of 4 rows
+        assert [p if isinstance(p, range) else len(p) for p in pieces] == [
+            range(0, 7), 1, range(8, 21), 3, range(24, 32)]
+        # a run keeps everything from its first misfit on loose, in order
+        assert pieces[1][0]["x"].dtype == np.int32
+        assert [int(e["x"][0]) for e in pieces[3]] == [0, 220, 230]
+        assert "y" not in pieces[3][1]
+        written = np.r_[0:7, 8:21, 24:32]
+        assert (arrays["x"][written, 0] == 10 * written).all()
+
+    def test_fill_raises_the_first_failure_in_row_order_after_every_run(self):
+        import threading
+        import time
+
+        late = threading.Event()
+
+        def f(i):
+            if i == 5:
+                late.wait(5)      # the earlier row fails LATER in time
+                raise KeyError("row 5")
+            if i == 20:
+                try:
+                    raise ValueError("row 20")
+                finally:
+                    late.set()
+            time.sleep(0.001)
+            return self._row(i)
+
+        ds = PartitionedDataset.parallelize(list(range(48)), 1)
+        stream = ds.map_parallel(f, num_threads=4).iter_partition(0)
+        arrays = self._rows(48)
+        asked = stream.fill(arrays, 0, 48)
+        with pytest.raises(KeyError, match="row 5"):
+            asked.wait()
+        # every run had finished: nothing is written after the raise
+        before = {k: v.copy() for k, v in arrays.items()}
+        time.sleep(0.05)
+        assert all((arrays[k] == before[k]).all() for k in arrays)
+        assert asked.arrays is None
 
     def test_imagenet_train_parallel_equals_serial(self, tmp_path):
         """Content-seeded augmentation: thread scheduling cannot change the

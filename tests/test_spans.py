@@ -389,7 +389,9 @@ def test_a_traced_fit_holds_every_span_on_named_lines(tmp_path, monkeypatch):
     monkeypatch.setattr(anatomy, "STARTUP", done)
     monkeypatch.setenv(telemetry.WORKDIR_ENV, str(tmp_path / "tele"))
     spark = Session.builder.master("local[1]").getOrCreate()
-    ds = (PartitionedDataset.parallelize(_mnist_like(), 2).repeat()
+    # one partition: the feed asks its pool to fill the rows (a stream that
+    # is pulled opens the same sections but ``dls.feed/filled_by_map``)
+    ds = (PartitionedDataset.parallelize(_mnist_like(), 1).repeat()
           .map_parallel(dict, num_threads=2))
     trainer = Trainer(spark, LeNet5(), losses.softmax_xent, optax.sgd(0.01))
     # fit would draw this sample itself, through pools of its own whose
@@ -425,7 +427,7 @@ def test_a_traced_fit_holds_every_span_on_named_lines(tmp_path, monkeypatch):
     producer = by_line["dls-prefetch"]
     assert set(producer) | reused == {
         "dls.feed/assemble", "dls.feed/stack", "dls.feed/ring_full",
-        "dls.feed/slot_new", "dls.feed/slot_reused"}
+        "dls.feed/slot_new", "dls.feed/slot_reused", "dls.feed/filled_by_map"}
     # a row's copy is a part of the assembly, and a batch's first takes the
     # slot (the assembly that the end of the trace cut off is not in it)
     for outer, inner in (("dls.feed/assemble", "dls.feed/stack"),

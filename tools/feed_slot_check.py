@@ -80,7 +80,11 @@ def main() -> int:
         "wrong": wrong,
         "refs_to_image_before_and_after_put": sorted(set(refs)),
         "input_slot_reused": snap["input_slot_reused"],
-        "input_slot_new": snap["input_slot_new"]}))
+        "input_slot_new": snap["input_slot_new"],
+        # the batches whose rows the map's pool wrote (two are being filled
+        # at once then): all but the stream's first, or the check ran the
+        # pull path
+        "input_filled_by_map": snap["input_filled_by_map"]}))
     return 1 if wrong else 0
 
 
