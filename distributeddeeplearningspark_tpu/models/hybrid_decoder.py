@@ -47,6 +47,28 @@ over all S rows (S - 1 divides by no kernel's block): the last row's "next
 token" is id 0, and the loss leaves out the last TWO rows' targets; causal
 attention keeps every other row what S - 1 rows would give.
 
+Blocks of ONE sublayer (Nemotron-H's, arXiv:2504.03624: a pattern of Mamba-2
+layers, expert layers and attention layers, each a block of its own), the
+kinds ``"mamba2"``, ``"experts"`` and ``"bare_attention"`` of ``layer_types``::
+
+    x' = x + SUB(RMSNorm(x))                       (one norm a block)
+    SUB mamba2:  [z | xBC | dt] = x Win  (H P, H P + 2 G N and H wide)
+                 xBC = silu(conv(xBC) + b): depthwise, causal, K taps,
+                 inside t's document;  [x | B | C] = split(xBC)
+                 dt = softplus(dt + dt_bias);  A = -exp(A_log)  (a head)
+                 h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T,  y_t = h_t C_t +
+                 D x_t, a head of P with a state of P x N, head h reading
+                 group h // (H / G) of B and C, h = 0 before a document's
+                 first position (:func:`..ops.ssd.ssd_scan`, chunked)
+                 y = RMSNorm_groups(y * silu(z)) * w over G groups (the gate
+                 BEFORE the norm);  SUB = y Wout
+    SUB experts: the routed experts (and shared expert) below, here
+                 ``down(relu(up x)^2)`` of two matrices where ``expert_form``
+                 is ``"relu2"``
+    SUB bare_attention: the attention operator above WITHOUT rotary
+                 embedding and without per-head norm (no positional
+                 embedding at all: the state-space layers carry position)
+
 ``b`` is no parameter: it lives in the mutable collection
 ``moe.BIAS_COLLECTION`` and a training step moves it by its own load counts
 (auxiliary-loss-free balancing), so there is no router loss term.
@@ -86,16 +108,30 @@ from distributeddeeplearningspark_tpu.ops.flash_attention import (
     attn_blocks_masked_share,
     attn_blocks_walked_share,
 )
-from distributeddeeplearningspark_tpu.ops.short_conv import gated_short_conv
+from distributeddeeplearningspark_tpu.ops.short_conv import (
+    gated_short_conv,
+    silu_short_conv,
+)
+from distributeddeeplearningspark_tpu.ops.ssd import (
+    chunks_reset_share,
+    ssd_scan,
+)
 from distributeddeeplearningspark_tpu.parallel.sharding import ShardingRules
 
 CONV, ATTENTION, LATENT = "conv", "full_attention", "latent_attention"
+#: blocks of ONE sublayer, ``x + SUB(RMSNorm(x))`` (module docstring)
+MAMBA, EXPERTS, BARE_ATTENTION = "mamba2", "experts", "bare_attention"
+SOLO = (MAMBA, EXPERTS, BARE_ATTENTION)
 #: the step's counters (docs/OBSERVABILITY.md): the first two are means over
 #: the expert layers, the third the largest over them, the last three the
 #: batch's; ``losses.hybrid_moe_lm`` carries them into the step's metrics
 COUNTERS = ("moe_load_max_over_mean", "moe_rows_held_share",
             "router_bias_abs_max", "attn_pairs_share",
             "attn_blocks_walked_share", "attn_blocks_masked_share")
+#: two more, only of a model with state-space layers: the share of the
+#: batch's scan chunks that hold a document's first position, and the largest
+#: magnitude of a state the LAST such layer's scan handed between chunks
+SSM_COUNTERS = ("ssm_chunks_reset_share", "ssm_state_abs_max")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -125,6 +161,17 @@ class HybridDecoderConfig:
     train_router: bool = True
     routed_scaling_factor: float = 1.0
     shared_expert_size: int = 0      # 0: no shared expert
+    expert_form: str = "swiglu"      # or "relu2": down(relu(up x)^2)
+    # state-space layers (kind MAMBA): heads x head_dim is the inner width
+    ssm_heads: int = 64
+    ssm_head_dim: int = 64
+    ssm_groups: int = 8              # groups of B and C, and of the gated norm
+    ssm_state_size: int = 128
+    ssm_conv_taps: int = 4
+    ssm_chunk: int = 128             # of the chunked scan: changes no value
+    time_step_min: float = 0.001     # dt_bias is drawn so that softplus of it
+    time_step_max: float = 0.1       # is log-uniform between these two,
+    time_step_floor: float = 1e-4    # floored
     # latent attention (layers of kind LATENT; its rotary embedding is over
     # adjacent pairs, DeepSeek's ``rope_interleave``)
     q_lora_rank: int = 1536
@@ -142,7 +189,15 @@ class HybridDecoderConfig:
         if self.mtp_layers not in (0, 1):
             raise ValueError(f"mtp_layers {self.mtp_layers}: a module of "
                              f"depth 1 is built, or none")
-        unknown = set(self.layer_types) - {CONV, ATTENTION, LATENT}
+        unknown = set(self.layer_types) - {CONV, ATTENTION, LATENT, *SOLO}
+        if set(self.layer_types[:self.num_dense_layers]) & set(SOLO):
+            raise ValueError("a block of one sublayer has no feed-forward "
+                             "to be dense: num_dense_layers counts none")
+        if self.mtp_layers and self.layer_types[-1:] and (
+                self.layer_types[-1] in SOLO):
+            raise ValueError("the multi-token-prediction module is one more "
+                             "block of the last layer's kind, which must be "
+                             "an operator with its feed-forward")
         if unknown or not 0 <= self.num_dense_layers <= len(self.layer_types):
             raise ValueError(f"layer_types {sorted(unknown)} unknown, or "
                              f"{self.num_dense_layers} dense layers of "
@@ -190,6 +245,23 @@ class HybridDecoderConfig:
         base.update(kw)
         return HybridDecoderConfig.tiny(**base)
 
+    @staticmethod
+    def tiny_ssm(**kw) -> "HybridDecoderConfig":
+        """Nemotron-H's shape at a CPU test's size: the period ``M E M E M *
+        E`` of one-sublayer blocks; 4 state-space heads of 8 with a state of
+        16 in 2 groups (so head ``h`` reads group ``h // 2``, not ``h % 2``),
+        4 taps, chunks of 16; attention without positions; 8 relu² experts
+        of which 2 a token beside a wider shared one, scaled 2.5; an untied
+        head."""
+        base = dict(layer_types=(MAMBA, EXPERTS, MAMBA, EXPERTS, MAMBA,
+                                 BARE_ATTENTION, EXPERTS),
+                    num_dense_layers=0, ssm_heads=4, ssm_head_dim=8,
+                    ssm_groups=2, ssm_state_size=16, ssm_chunk=16,
+                    expert_form="relu2", shared_expert_size=128,
+                    routed_scaling_factor=2.5, tie_embeddings=False)
+        base.update(kw)
+        return HybridDecoderConfig.tiny(**base)
+
 
 def _dense(cfg, feats, name, axis=-1):
     return nn.DenseGeneral(feats, axis=axis, use_bias=False, dtype=cfg.dtype,
@@ -214,11 +286,83 @@ class ShortConv(nn.Module):
             gated_short_conv(bcx, taps, seg))
 
 
-class CausalAttention(nn.Module):
-    """Grouped-query attention inside a document, per-head q/k RMSNorm
-    before the rotary embedding, positions restarting with the document."""
+def gated_group_norm(y, z, scale, groups: int, eps: float):
+    """``RMSNorm_groups(y * silu(z)) * scale`` in float32: the gate BEFORE
+    the norm, the norm over each of ``groups`` equal slices of the last
+    axis."""
+    f32 = jnp.float32
+    gated = (y.astype(f32) * nn.silu(z.astype(f32))).reshape(
+        *y.shape[:-1], groups, -1)
+    gated = gated * jax.lax.rsqrt(
+        jnp.mean(jnp.square(gated), axis=-1, keepdims=True) + eps)
+    return gated.reshape(y.shape) * scale
+
+
+class Mamba2Mixer(nn.Module):
+    """The state-space sublayer (module docstring): ``W_in``, the ungated
+    short convolution with its bias and SiLU, the chunked scan, the grouped
+    gated RMSNorm (gate first), ``W_out``; ``seg`` reaches the convolution
+    and the scan. ``(x, seg) -> (y, the largest magnitude of a state the
+    scan handed between chunks)``."""
 
     cfg: HybridDecoderConfig
+
+    @nn.compact
+    def __call__(self, x, seg):
+        cfg = self.cfg
+        heads, p, g, n = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                          cfg.ssm_state_size)
+        inner, f32 = heads * p, jnp.float32
+        conv_width = inner + 2 * g * n                   # [x | B | C]
+        z, xbc, dt = jnp.split(
+            _dense(cfg, inner + conv_width + heads, "in_proj")(x),
+            [inner, inner + conv_width], axis=-1)
+        taps = self.param(
+            "conv_taps", nn.initializers.variance_scaling(
+                1.0, "fan_in", "uniform", in_axis=1, out_axis=0),
+            (conv_width, cfg.ssm_conv_taps), f32)
+        conv_bias = self.param("conv_bias", nn.initializers.zeros,
+                               (conv_width,), f32)
+        xs, bm, cm = jnp.split(silu_short_conv(xbc, taps, conv_bias, seg),
+                               [inner, inner + g * n], axis=-1)
+
+        def dt_bias_init(key, shape):
+            # softplus(dt_bias) log-uniform in [time_step_min, time_step_max]
+            lo, hi = jnp.log(cfg.time_step_min), jnp.log(cfg.time_step_max)
+            step = jnp.maximum(jnp.exp(jax.random.uniform(
+                key, shape, f32) * (hi - lo) + lo), cfg.time_step_floor)
+            return step + jnp.log(-jnp.expm1(-step))
+
+        a_log = self.param("A_log", lambda key, shape: jnp.log(
+            jax.random.uniform(key, shape, f32, 1.0, 16.0)), (heads,))
+        d_skip = self.param("D", nn.initializers.ones, (heads,), f32)
+        dt_bias = self.param("dt_bias", dt_bias_init, (heads,))
+        b, s = x.shape[:2]
+        y, state_abs_max = ssd_scan(
+            xs.reshape(b, s, heads, p), nn.softplus(dt.astype(f32) + dt_bias),
+            -jnp.exp(a_log), bm.reshape(b, s, g, n), cm.reshape(b, s, g, n),
+            d_skip, seg, chunk=cfg.ssm_chunk)
+        # (written only where a caller asks for "intermediates" as mutable)
+        self.sow("intermediates", "scan", y)
+        scale = self.param("norm", nn.initializers.ones, (inner,), f32)
+        y = gated_group_norm(y.reshape(b, s, inner), z, scale, g,
+                             cfg.rms_eps).astype(cfg.dtype)
+        # rescale_prenorm_residual: the output kernel over sqrt(layers)
+        out = nn.DenseGeneral(
+            cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, name="out_proj",
+            kernel_init=nn.initializers.variance_scaling(
+                1.0 / len(cfg.layer_types), "fan_in", "truncated_normal"))
+        return out(y), state_abs_max
+
+
+class CausalAttention(nn.Module):
+    """Grouped-query attention inside a document, per-head q/k RMSNorm
+    before the rotary embedding, positions restarting with the document;
+    ``bare``: neither the norm nor the rotary embedding."""
+
+    cfg: HybridDecoderConfig
+    bare: bool = False
 
     @nn.compact
     def __call__(self, x, seg, pos):
@@ -226,6 +370,9 @@ class CausalAttention(nn.Module):
         q = _dense(cfg, (cfg.num_heads, cfg.head_dim), "wq")(x)
         k = _dense(cfg, (cfg.num_kv_heads, cfg.head_dim), "wk")(x)
         v = _dense(cfg, (cfg.num_kv_heads, cfg.head_dim), "wv")(x)
+        if self.bare:
+            o = dot_product_attention(q, k, v, causal=True, segment_ids=seg)
+            return _dense(cfg, cfg.hidden_size, "wo", axis=(-2, -1))(o)
         q = rotary_embedding(RMSNorm(cfg.rms_eps, cfg.dtype, name="q_norm")(q),
                              pos, cfg.rope_theta)
         k = rotary_embedding(RMSNorm(cfg.rms_eps, cfg.dtype, name="k_norm")(k),
@@ -284,16 +431,47 @@ class SwiGLU(nn.Module):
 
 class HybridLayer(nn.Module):
     """``(x, seg, pos) -> (x, stats)``; ``stats`` is empty for a dense layer
-    and holds the expert layer's counters otherwise."""
+    and holds the expert layer's counters otherwise (a block of one
+    sublayer: its experts' counters, its scan's ``ssm_state_abs_max``, or
+    nothing)."""
 
     cfg: HybridDecoderConfig
     kind: str
     dense: bool
     packed: bool = True   # the batch carries segment ids (LATENT reads it)
 
+    def _experts(self, h):
+        cfg = self.cfg
+        y, moe = RoutedExperts(
+            cfg.hidden_size, cfg.expert_size, cfg.num_experts,
+            cfg.experts_per_token, held=cfg.experts_held,
+            norm_topk=cfg.norm_topk_prob, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, score="sigmoid",
+            select_bias=cfg.use_expert_bias,
+            bias_update_rate=cfg.bias_update_rate,
+            train_router=cfg.train_router,
+            routed_scale=cfg.routed_scaling_factor,
+            shared_size=cfg.shared_expert_size,
+            expert_form=cfg.expert_form, name="moe")(h)
+        return y, {"moe_load_max_over_mean": moe["load_max_over_mean"],
+                   "moe_rows_held_share": moe["rows_held_share"],
+                   "router_bias_abs_max": moe.get("bias_abs_max",
+                                                  jnp.float32(0.0))}
+
     @nn.compact
     def __call__(self, x, seg, pos):
         cfg = self.cfg
+        if self.kind in SOLO:
+            h = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm")(x)
+            if self.kind == MAMBA:
+                y, peak = Mamba2Mixer(cfg, name="mixer")(
+                    h, seg if self.packed else None)
+                return x + y, {"ssm_state_abs_max": peak}
+            if self.kind == EXPERTS:
+                y, stats = self._experts(h)
+                return x + y, stats
+            return x + CausalAttention(cfg, bare=True, name="self_attn")(
+                h, seg, pos), {}
         h = RMSNorm(cfg.rms_eps, cfg.dtype, name="operator_norm")(x)
         if self.kind == CONV:
             x = x + ShortConv(cfg, name="conv")(h, seg)
@@ -305,20 +483,7 @@ class HybridLayer(nn.Module):
         h = RMSNorm(cfg.rms_eps, cfg.dtype, name="ffn_norm")(x)
         if self.dense:
             return x + SwiGLU(cfg, name="mlp")(h), {}
-        y, moe = RoutedExperts(
-            cfg.hidden_size, cfg.expert_size, cfg.num_experts,
-            cfg.experts_per_token, held=cfg.experts_held,
-            norm_topk=cfg.norm_topk_prob, dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype, score="sigmoid",
-            select_bias=cfg.use_expert_bias,
-            bias_update_rate=cfg.bias_update_rate,
-            train_router=cfg.train_router,
-            routed_scale=cfg.routed_scaling_factor,
-            shared_size=cfg.shared_expert_size, name="moe")(h)
-        stats = {"moe_load_max_over_mean": moe["load_max_over_mean"],
-                 "moe_rows_held_share": moe["rows_held_share"],
-                 "router_bias_abs_max": moe.get("bias_abs_max",
-                                                jnp.float32(0.0))}
+        y, stats = self._experts(h)
         return x + y, stats
 
 
@@ -349,7 +514,10 @@ class _Period(nn.Module):
             x, stats = _layer_cls()(self.cfg, kind, False, self.packed,
                                     name=f"layer_{j}")(x, seg, pos)
             per_layer.append(stats)
-        return x, jax.tree.map(lambda *a: jnp.stack(a), *per_layer)
+        # a counter over the layers that have it (blocks of one sublayer
+        # differ in theirs), in layer order
+        return x, {name: jnp.stack([s[name] for s in per_layer if name in s])
+                   for name in sorted(set().union(*per_layer))}
 
 
 class MTPModule(nn.Module):
@@ -448,9 +616,15 @@ class HybridDecoderLM(nn.Module):
             (cfg.hidden_size, cfg.vocab_size), cfg.param_dtype))
         out.update(hidden=x, lm_head=head)
         for name, over_layers in zip(COUNTERS, (jnp.mean, jnp.mean, jnp.max)):
-            out[name] = (over_layers(jnp.concatenate(
-                [c[name] for c in collected]))
-                if collected else jnp.float32(0.0))
+            found = [c[name] for c in collected if name in c]
+            out[name] = (over_layers(jnp.concatenate(found))
+                         if found else jnp.float32(0.0))
+        if MAMBA in cfg.layer_types:
+            out["ssm_chunks_reset_share"] = chunks_reset_share(
+                seg, cfg.ssm_chunk)
+            out["ssm_state_abs_max"] = jnp.concatenate(
+                [c["ssm_state_abs_max"] for c in collected
+                 if "ssm_state_abs_max" in c])[-1]
         s = ids.shape[1]
         out["attn_pairs_share"] = jnp.mean(
             jnp.sum(pos.astype(jnp.float32) + 1.0, axis=1)) / (s * (s + 1) / 2)

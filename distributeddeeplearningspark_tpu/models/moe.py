@@ -1,6 +1,6 @@
-"""Routed experts: SwiGLU experts behind a top-k router, with no capacity and
-nothing dropped, computed for the experts a rank HOLDS. Two routers, one
-layer: ``score="softmax"`` (softmax over all experts, then top-k) and
+"""Routed experts: SwiGLU (or two-matrix relu²) experts behind a top-k router,
+with no capacity and nothing dropped, computed for the experts a rank HOLDS.
+Two routers, one layer: ``score="softmax"`` (softmax over all experts, then top-k) and
 ``score="sigmoid"`` (a sigmoid an expert, the top-k SELECTED on score plus a
 per-expert bias and WEIGHTED by the score without it; the bias is no
 parameter: it lives in the mutable collection :data:`BIAS_COLLECTION` and
@@ -17,8 +17,9 @@ rank see only ITS tokens' share is not built (ROADMAP queue 2, A.1).
 
 The ``T * k`` assignments are sorted by expert (those of experts held
 elsewhere last), the tokens' rows are gathered in that order, and three
-grouped matrix products (``jax.lax.ragged_dot``: on the TPU a grouped-matmul
-kernel of XLA's own that walks only the tiles of rows the group sizes cover)
+(relu² experts: two) grouped matrix products (``jax.lax.ragged_dot``: on the
+TPU a grouped-matmul kernel of XLA's own that walks only the tiles of rows
+the group sizes cover)
 run over a buffer of ``T * k`` rows, the worst case, of which
 ``T * k * held / num_experts`` are used on average. However unbalanced the
 routing, every assignment to a held expert is computed. Router math is
@@ -50,6 +51,11 @@ TOKEN_AXES = (*BATCH_AXES, AXIS_SEQ)
 BIAS_COLLECTION = "router_bias"
 #: added to the sum of a token's top-k sigmoid scores before they divide
 SIGMOID_NORM_EPS = 1e-6
+
+
+def relu2(x):
+    """``relu(x)^2``: the activation of a two-matrix expert."""
+    return jnp.square(nn.relu(x))
 
 
 def _zero_past(a, used):
@@ -110,7 +116,9 @@ def _held_experts(xf, router, w_gate, w_up, w_down, first, bias=None, *,
                   train_router: bool = True, routed_scale: float = 1.0,
                   per_rank=lambda a: a):
     """The part of the layer that the ``n`` experts ``first .. first + n``
-    add (``w_*`` are their kernels; ``first`` may be traced): ``xf [T, H] ->
+    add (``w_*`` are their kernels: SwiGLU experts, ``down(silu(gate x) * up
+    x)``, or, with ``w_gate`` ``None``, relu² experts, ``down(relu(up
+    x)^2)``; ``first`` may be traced): ``xf [T, H] ->
     (y [T, H] float32, assignments of every expert [E] int32, the router's
     probabilities (or sigmoid scores) summed over the tokens [E])``.
     ``bias [E]`` (sigmoid only) is added to the scores for the SELECTION
@@ -121,7 +129,7 @@ def _held_experts(xf, router, w_gate, w_up, w_down, first, bias=None, *,
     where the ranks' work on them begins (inside a ``shard_map``; the routing
     before it is every rank's alike)."""
     tokens, h = xf.shape
-    e, n = router.shape[1], w_gate.shape[0]
+    e, n = router.shape[1], w_up.shape[0]
     # float32 in earnest: on the TPU a float32 product is one bf16 pass
     # unless asked otherwise, and a router rounded to 8 bits picks other
     # experts than the model's
@@ -160,7 +168,10 @@ def _held_experts(xf, router, w_gate, w_up, w_down, first, bias=None, *,
     xs = _rows_to_experts(per_rank(xf.astype(dtype)), order, inverse, used)
     cast = lambda w: w.astype(dtype)
     up = jax.lax.ragged_dot(xs, cast(w_up), rows)
-    act = nn.silu(jax.lax.ragged_dot(xs, cast(w_gate), rows)) * up
+    if w_gate is None:
+        act = relu2(up)
+    else:
+        act = nn.silu(jax.lax.ragged_dot(xs, cast(w_gate), rows)) * up
     ys = jax.lax.ragged_dot(act, cast(w_down), rows)               # [T*k, H]
     yt = _rows_to_tokens(ys, order, inverse, used)
     y = jnp.einsum("tkh,tk->th", yt.reshape(tokens, k, h),
@@ -169,16 +180,18 @@ def _held_experts(xf, router, w_gate, w_up, w_down, first, bias=None, *,
     return y, counts, jnp.sum(probs, axis=0)
 
 
-def _split_over_the_mesh(fn, mesh, first: int, with_bias: bool = False):
+def _split_over_the_mesh(fn, mesh, first: int, with_bias: bool = False,
+                         gated: bool = True):
     """``fn`` (:func:`_held_experts` but for ``first``) for ``x [B, S, H]``
     on a mesh: batch rows over (data, fsdp), positions over ``seq``, the
-    experts' kernels over ``expert`` and their hidden width over ``tensor``.
+    experts' kernels over ``expert`` and their hidden width over ``tensor``
+    (relu² experts, not ``gated``, come with ``None`` for ``w_gate``).
     A rank computes the part of ITS experts and columns for ITS tokens; the
     parts add up over (expert, tensor), the statistics over the tokens'
     axes."""
     def local(x, router, w_gate, w_up, w_down, *bias):
         b, s, h = x.shape
-        mine = first + jax.lax.axis_index(AXIS_EXPERT) * w_gate.shape[0]
+        mine = first + jax.lax.axis_index(AXIS_EXPERT) * w_up.shape[0]
         # (the transpose of "differs from rank to rank" is the sum of the
         # ranks' cotangents, which is what a token's gradient is)
         y, counts, probs = fn(
@@ -193,7 +206,8 @@ def _split_over_the_mesh(fn, mesh, first: int, with_bias: bool = False):
     wide = P(AXIS_EXPERT, None, AXIS_TENSOR)
     return jax.shard_map(
         local, mesh=mesh,
-        in_specs=(tokens, P(), wide, wide, P(AXIS_EXPERT, AXIS_TENSOR, None),
+        in_specs=(tokens, P(), wide if gated else None, wide,
+                  P(AXIS_EXPERT, AXIS_TENSOR, None),
                   *([P()] if with_bias else [])),
         out_specs=(tokens, P(), P()))
 
@@ -211,9 +225,11 @@ class RoutedExperts(nn.Module):
     mutable, the layer moves it by ``bias_update_rate * sign(mean_e(c_e) -
     c_e)``, ``c`` being this call's assignment counts over ALL experts, and
     otherwise only reads it). Either way ``y = sum over the token's experts
-    that are held of g_e * down_e(silu(gate_e x) * up_e x)``, with ``g``
+    that are held of g_e * down_e(silu(gate_e x) * up_e x)`` (``expert_form
+    = "relu2"``: ``g_e * down_e(relu(up_e x)^2)``, two matrices an expert and
+    no ``w_gate``), with ``g``
     times ``routed_scale`` where that is not 1, plus, with ``shared_size >
-    0``, a SHARED expert ``down_s(silu(gate_s x) * up_s x)`` of that width
+    0``, a SHARED expert of the same form and of that width
     that every token passes and every rank computes whole (it belongs to no
     rank's share: the shares of all ranks sum to the whole layer with the
     shared expert counted ONCE; applied with ``"intermediates"`` mutable the
@@ -258,6 +274,7 @@ class RoutedExperts(nn.Module):
     train_router: bool = True
     routed_scale: float = 1.0
     shared_size: int = 0
+    expert_form: str = "swiglu"
 
     @nn.compact
     def __call__(self, x: jax.Array) -> tuple[jax.Array, dict]:
@@ -274,6 +291,10 @@ class RoutedExperts(nn.Module):
                 self.select_bias and self.score != "sigmoid"):
             raise ValueError(f"score {self.score!r} (softmax or sigmoid), "
                              f"select_bias {self.select_bias} (sigmoid only)")
+        if self.expert_form not in ("swiglu", "relu2"):
+            raise ValueError(f"expert_form {self.expert_form!r}: swiglu "
+                             f"(three matrices) or relu2 (two)")
+        gated = self.expert_form == "swiglu"
         router = self.param("router", nn.initializers.lecun_normal(), (h, e),
                             jnp.float32)
         # lecun-normal by each expert's OWN fan-in: the leading axis counts
@@ -282,7 +303,8 @@ class RoutedExperts(nn.Module):
         # sqrt(held) and the layer's output by its third power)
         init = nn.initializers.variance_scaling(
             1.0, "fan_in", "truncated_normal", batch_axis=(0,))
-        w_gate = self.param("w_gate", init, (n, h, i), self.param_dtype)
+        w_gate = self.param("w_gate", init, (n, h, i),
+                            self.param_dtype) if gated else None
         w_up = self.param("w_up", init, (n, h, i), self.param_dtype)
         w_down = self.param("w_down", init, (n, i, h), self.param_dtype)
 
@@ -310,7 +332,7 @@ class RoutedExperts(nn.Module):
                     f"the {n} experts held by expert, their width {i} by "
                     f"tensor")
             y, counts, probs = _split_over_the_mesh(
-                fn, mesh, first, with_bias=bias is not None)(
+                fn, mesh, first, with_bias=bias is not None, gated=gated)(
                 x, *kernels, *chosen_with)
 
         assignments = jnp.float32(x.size // h * k)
@@ -336,7 +358,10 @@ class RoutedExperts(nn.Module):
             dense = lambda feats, name: nn.Dense(
                 feats, use_bias=False, dtype=self.dtype,
                 param_dtype=self.param_dtype, name=name)
-            y = y + dense(h, "shared_down")(
-                nn.silu(dense(self.shared_size, "shared_gate")(x))
-                * dense(self.shared_size, "shared_up")(x))
+            if gated:
+                act = (nn.silu(dense(self.shared_size, "shared_gate")(x))
+                       * dense(self.shared_size, "shared_up")(x))
+            else:
+                act = relu2(dense(self.shared_size, "shared_up")(x))
+            y = y + dense(h, "shared_down")(act)
         return y, stats

@@ -1,6 +1,8 @@
 """The gated short convolution: a depthwise causal convolution of a few taps
 between two gates, the operator of a hybrid decoder's convolution layers
-(:mod:`..models.hybrid_decoder`), with its taps cut at document boundaries.
+(:mod:`..models.hybrid_decoder`), with its taps cut at document boundaries;
+and, beside it, the UNGATED form with a bias and a SiLU that a state-space
+layer runs before its scan (:func:`silu_short_conv`, plain XLA).
 
 ``bcx [B, S, 3C]`` is the block's input projection, ``(b, c, u) =
 split3(bcx)`` along the last axis, ``w [C, K]`` the taps (depthwise, no
@@ -72,13 +74,11 @@ def _segment_lanes(seg, b: int, s: int, taps: int):
     return jnp.stack(lanes, axis=-1)
 
 
-def gated_short_conv_xla(bcx, w, segment_ids=None):
-    """The operator in plain ``jax.numpy`` (float32 inside, the input's dtype
-    out): the path off the TPU and for shapes the kernels do not take."""
-    b, s, c3 = bcx.shape
-    taps = w.shape[1]
-    gate_b, gate_c, u = jnp.split(bcx.astype(jnp.float32), 3, axis=-1)
-    v = gate_b * u
+def _taps_xla(v, w, segment_ids):
+    """``z[t] = sum_j w[:, j] v[t - (K-1) + j]`` over the terms inside ``t``'s
+    document: ``v [B, S, C]`` float32, ``w [C, K]`` -> float32 ``[B, S, C]``
+    (the tap loop of both operators' XLA paths)."""
+    s, taps = v.shape[1], w.shape[1]
     pos = jnp.arange(s)
     z = w[:, taps - 1].astype(jnp.float32) * v
     for d in range(1, taps):
@@ -89,7 +89,33 @@ def gated_short_conv_xla(bcx, w, segment_ids=None):
         shifted = jnp.pad(v, ((0, 0), (d, 0), (0, 0)))[:, :s]
         z = z + w[:, taps - 1 - d].astype(jnp.float32) * jnp.where(
             ok[..., None], shifted, 0.0)
-    return (gate_c * z).astype(bcx.dtype)
+    return z
+
+
+def gated_short_conv_xla(bcx, w, segment_ids=None):
+    """The operator in plain ``jax.numpy`` (float32 inside, the input's dtype
+    out): the path off the TPU and for shapes the kernels do not take."""
+    gate_b, gate_c, u = jnp.split(bcx.astype(jnp.float32), 3, axis=-1)
+    return (gate_c * _taps_xla(gate_b * u, w, segment_ids)).astype(bcx.dtype)
+
+
+def silu_short_conv(v, w, bias, segment_ids=None):
+    """The UNGATED form, a state-space layer's (:mod:`..models.
+    hybrid_decoder`): ``v [B, S, C], w [C, K], bias [C], segment_ids [B, S]
+    | None -> y [B, S, C]``, ``y[t] = silu(bias + sum_j w[:, j] v[t - (K-1) +
+    j])`` over the terms inside ``t``'s document; float32 inside, ``v``'s
+    dtype out, differentiable in ``v``, ``w`` and ``bias``. Plain
+    ``jax.numpy`` on the TPU too, and it costs what that costs: at 16,384 x
+    6,144 the chip's trace shows seven passes over float32 arrays of 0.4 GB
+    a layer and step, forward, replay and backward (47 ms a step where one
+    read of ``v`` and one write of ``y`` and of each cotangent need 3:
+    PERF.md sections 5 and 6, PR 37). A kernel beside the gated form's is
+    the next step; it was left out of the PR that brought the layer."""
+    if v.shape[-1] != w.shape[0] or bias.shape != w.shape[:1]:
+        raise ValueError(f"v {v.shape} against taps {w.shape} and bias "
+                         f"{bias.shape}: want [B, S, C], [C, K] and [C]")
+    z = _taps_xla(v.astype(jnp.float32), w, segment_ids)
+    return jax.nn.silu(z + bias.astype(jnp.float32)).astype(v.dtype)
 
 
 def _roll(x, shift: int, interpret: bool):

@@ -172,9 +172,13 @@ def hybrid_moe_lm(outputs: dict[str, jax.Array], batch: dict[str, Any]
     the (tied) head, at every position but the window's last; a target across
     a document boundary is kept. No router term: the sigmoid router is
     balanced by its bias, which the step moves outside the gradient. The
-    model's counters (``hybrid_decoder.COUNTERS``) ride along into the step's
+    model's counters (``hybrid_decoder.COUNTERS`` and, of a model with
+    state-space layers, ``SSM_COUNTERS``) ride along into the step's
     metrics."""
-    from distributeddeeplearningspark_tpu.models.hybrid_decoder import COUNTERS
+    from distributeddeeplearningspark_tpu.models.hybrid_decoder import (
+        COUNTERS,
+        SSM_COUNTERS,
+    )
     from distributeddeeplearningspark_tpu.train.fused_ce import (
         chunked_softmax_xent,
     )
@@ -183,7 +187,8 @@ def hybrid_moe_lm(outputs: dict[str, jax.Array], batch: dict[str, Any]
                                    outputs["lm_head"],
                                    batch["input_ids"][:, 1:])
     loss, metrics = _reduce_next_token(per_tok, batch)
-    return loss, {**metrics, **{k: outputs[k] for k in COUNTERS}}
+    return loss, {**metrics, **{k: outputs[k] for k in COUNTERS},
+                  **{k: outputs[k] for k in SSM_COUNTERS if k in outputs}}
 
 
 def latent_moe_lm(outputs: dict[str, jax.Array], batch: dict[str, Any]

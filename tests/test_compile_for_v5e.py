@@ -153,8 +153,9 @@ def test_routed_experts_split_over_four_chips_compile(four_chips,
 #: name -> (batch, seq, q heads, kv heads, head size (or that of q and k, and
 #: v's), causal, key mask, segment ids): Llama's causal d=128, BERT-base's
 #: key-padding mask d=64, grouped KV, packed documents (mask + segment ids),
-#: the hybrid decoder's causal + grouped 32/8 + segment ids at d=64, and
-#: latent attention's 192 / 128
+#: the hybrid decoder's causal + grouped 32/8 + segment ids at d=64, latent
+#: attention's 192 / 128, and causal + segment ids at d=128 with 16 query
+#: heads a key-value head
 FLASH_REGIMES = {
     "causal_d128": (2, 1024, 4, 4, 128, True, False, False),
     "masked_d64_bert": (2, 512, 12, 12, 64, False, True, False),
@@ -166,6 +167,9 @@ FLASH_REGIMES = {
     # one window of the benchmark's fifth configuration (fit_s16k): q and k
     # 192 wide (no multiple of the 128 lanes), v 128
     "cell_mla_s16k": (1, 16384, 32, 32, (192, 128), True, False, False),
+    # one window of the benchmark's sixth configuration (fit_seg16k): 32
+    # query heads on 2 key-value heads of 128, packed documents
+    "cell_seg16k_gqa16": (1, 16384, 32, 2, 128, True, False, True),
 }
 
 
@@ -518,3 +522,26 @@ def test_the_joyai_train_step_compiles_for_one_chip_with_room_to_spare(
     live = (max(ma.argument_size_in_bytes, ma.output_size_in_bytes)
             + ma.temp_size_in_bytes)
     assert 0.25 * 16 * 2 ** 30 < live < 15 * 2 ** 30, live / 2 ** 30
+
+
+def test_the_nemotron_train_step_compiles_for_one_chip_with_room_for_the_check(
+        four_chips, no_compile_cache, routed_as_on_the_chip):
+    """``nemotron3_nano_30b_a3b.fit_seg16k``'s whole step for ONE described
+    chip: the flash kernels causal with segment ids at 32 / 2 heads of 128
+    in it, the chunked scan as three ``while`` loops a layer whose carried
+    tuple begins with the state (what ``ssd_ms_per_step`` finds), no array
+    of all the chunks' masks, and 528.1M parameters' state and the step's
+    temporaries inside what the check after the window leaves of 16 GiB (it
+    holds 24 bytes a parameter where the step's arguments are 12)."""
+    compiled = _lowered_step_of("nemotron3_nano_30b_a3b", "fit_seg16k",
+                                four_chips, True).compile()
+    text = compiled.as_text()
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert name in text, name
+    assert "[1,32,16384,16384]" not in text      # no [B, H, S, S] array
+    assert "128,8,8,128,128]" not in text        # nor every chunk's masks
+    assert "f32[1,8,8,64,128]" in text           # the state a chunk hands on
+    ma = compiled.memory_analysis()
+    live = (max(ma.argument_size_in_bytes, ma.output_size_in_bytes)
+            + ma.temp_size_in_bytes)
+    assert 0.25 * 16 * 2 ** 30 < live < 13.5 * 2 ** 30, live / 2 ** 30

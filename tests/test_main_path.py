@@ -47,6 +47,11 @@ FAMILIES = {
     "latent_moe_lm": ("train_latent_moe_lm.py", [
         "--master", "local[8]", "--variant", "tiny", "--seq-len", "128",
         "--batch-size", "8"]),
+    # blocks of one sublayer: the chunked state-space scan and its
+    # convolution over packed documents, relu² experts in their ``shard_map``
+    "ssm_moe_lm": ("train_ssm_moe_lm.py", [
+        "--master", "local[8]", "--variant", "tiny", "--seq-len", "128",
+        "--batch-size", "8"]),
 }
 
 #: counters of ``spans.COUNTERS`` that only some feeds write: the map's

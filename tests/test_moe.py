@@ -327,3 +327,124 @@ def test_moe_with_pipeline_rejected(eight_devices):
     cfg = LlamaConfig.tiny(moe_experts=2, intermediate_size=64)
     with pytest.raises(NotImplementedError, match="MoE"):
         make_pp_apply(cfg, mesh, 2)
+
+
+# -- two-matrix relu² experts (expert_form="relu2") ---------------------------
+
+def _relu2_layer(held=None, shared=24, **kw):
+    return RoutedExperts(16, 12, num_experts=16, top_k=3, held=held,
+                         dtype=jnp.float32, score="sigmoid", select_bias=True,
+                         routed_scale=2.5, shared_size=shared,
+                         expert_form="relu2", **kw)
+
+
+def _relu2_loop(x, p, bias, held, k=3, scale=2.5):
+    """A loop over the experts held: sigmoid scores, the top-k of score +
+    bias, weights ``scale * s_e / sum of the chosen s``, every expert
+    ``down(relu(up x)^2)``; the shared expert beside them. In numpy."""
+    x = np.asarray(x, np.float64).reshape(-1, x.shape[-1])
+    s = 1 / (1 + np.exp(-(x @ np.asarray(p["router"], np.float64))))
+    top = np.argsort(-(s + np.asarray(bias, np.float64)), axis=-1,
+                     kind="stable")[:, :k]
+    first, count = held
+    y = np.zeros_like(x)
+    for t in range(x.shape[0]):
+        for e in top[t]:
+            if first <= e < first + count:
+                up = x[t] @ np.asarray(p["w_up"][e - first], np.float64)
+                y[t] += (scale * s[t, e] / (s[t, top[t]].sum() + 1e-6)
+                         * (np.maximum(up, 0) ** 2)
+                         @ np.asarray(p["w_down"][e - first], np.float64))
+    shared = np.maximum(
+        x @ np.asarray(p["shared_up"]["kernel"], np.float64), 0) ** 2 \
+        @ np.asarray(p["shared_down"]["kernel"], np.float64)
+    return y, shared
+
+
+def test_relu2_experts_are_a_loop_over_experts_of_two_matrices():
+    from distributeddeeplearningspark_tpu.models.moe import BIAS_COLLECTION
+
+    x = _x(2, 24, 16, seed=3)
+    layer = _relu2_layer()
+    variables = layer.init(jax.random.PRNGKey(0), x)
+    params = variables["params"]
+    assert set(params) == {"router", "w_up", "w_down", "shared_up",
+                           "shared_down"}                # no gate anywhere
+    assert params["w_up"].shape == (16, 16, 12)
+    assert params["shared_up"]["kernel"].shape == (16, 24)
+    bias = jnp.asarray(np.random.default_rng(1).normal(size=16) * 0.2,
+                       jnp.float32)
+    variables = {"params": params, BIAS_COLLECTION: {"bias": bias}}
+    y, _ = layer.apply(variables, x)
+    routed, shared = _relu2_loop(x, params, bias, (0, 16))
+    np.testing.assert_allclose(np.asarray(y).reshape(-1, 16),
+                               routed + shared, rtol=2e-4, atol=2e-5)
+    # every leaf gets a gradient, and it is finite
+    grads = jax.grad(lambda p: jnp.sum(layer.apply(
+        {**variables, "params": p}, x)[0] ** 2))(params)
+    for path, g in jax.tree_util.tree_leaves_with_path(grads):
+        assert float(jnp.linalg.norm(g)) > 0, jax.tree_util.keystr(path)
+        assert bool(jnp.all(jnp.isfinite(g))), jax.tree_util.keystr(path)
+    with pytest.raises(ValueError, match="expert_form"):
+        RoutedExperts(16, 12, num_experts=4, top_k=2,
+                      expert_form="gelu").init(jax.random.PRNGKey(0), x)
+
+
+def test_the_sixteen_shares_add_up_to_the_uncut_relu2_layer():
+    """Every rank holds 1 of 16 experts, routes over all 16 with the same
+    bias and computes its expert's part and, alike on every rank, the shared
+    expert: the routed parts of the 16 ranks and the shared expert counted
+    ONCE are the uncut layer."""
+    from distributeddeeplearningspark_tpu.models.moe import BIAS_COLLECTION
+
+    x = _x(1, 40, 16, seed=5)
+    whole = _relu2_layer().init(jax.random.PRNGKey(2), x)["params"]
+    bias = jnp.asarray(np.random.default_rng(6).normal(size=16) * 0.2,
+                       jnp.float32)
+    variables = lambda p: {"params": p, BIAS_COLLECTION: {"bias": bias}}
+    want, stats = _relu2_layer().apply(variables(whole), x)
+    assert float(stats["rows_held_share"]) == 1.0
+    total, shares = jnp.zeros_like(want), 0.0
+    for rank in range(16):
+        mine = {**whole, "w_up": whole["w_up"][rank:rank + 1],
+                "w_down": whole["w_down"][rank:rank + 1]}
+        (part, stats), seen = _relu2_layer(held=(rank, 1)).apply(
+            variables(mine), x, mutable=["intermediates"])
+        shares += float(stats["rows_held_share"])
+        (routed,) = seen["intermediates"]["routed"]
+        total = total + routed
+        if rank == 0:
+            shared = part - routed          # what every rank computes alike
+            np.testing.assert_allclose(
+                np.asarray(routed).reshape(-1, 16),
+                _relu2_loop(x, mine, bias, (0, 1))[0], rtol=2e-4, atol=2e-5)
+    assert shares == pytest.approx(1.0)
+    np.testing.assert_allclose(np.asarray(total + shared), np.asarray(want),
+                               rtol=1e-4, atol=2e-5)
+    # counted on every rank it would be there sixteen times
+    assert float(jnp.max(jnp.abs(shared))) > 1e-3
+
+
+def test_relu2_experts_split_over_an_expert_mesh_give_the_one_device_layer(
+        eight_devices):
+    """The ``shard_map`` path without a gate kernel: data=2 x expert=4."""
+    x = _x(4, 8, 16, seed=7)
+    layer = RoutedExperts(16, 12, num_experts=8, top_k=2, dtype=jnp.float32,
+                          expert_form="relu2")
+    variables = layer.init(jax.random.PRNGKey(0), x)
+    want, _ = layer.apply(variables, x)
+    mesh = MeshSpec(data=2, expert=4).build(eight_devices)
+    ring_attention.set_default_mesh(mesh)
+    try:
+        got, _ = jax.jit(lambda v, x: layer.apply(v, x))(variables, x)
+        grads = jax.jit(jax.grad(lambda v, x: jnp.sum(
+            layer.apply(v, x)[0] ** 2)))(variables, x)
+    finally:
+        ring_attention.set_default_mesh(None)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
+                               atol=1e-5)
+    wants = jax.grad(lambda v, x: jnp.sum(layer.apply(v, x)[0] ** 2))(
+        variables, x)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(wants)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=1e-5)

@@ -135,3 +135,83 @@ def test_on_a_mesh_the_kernels_run_in_a_shard_map_over_the_batch_rows():
     for a, b in zip(got[1], want[1]):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
                                    rtol=1e-5)
+
+
+# -- the ungated form of a state-space layer: four taps, a bias, a SiLU -------
+
+def _ungated_inputs(dtype=jnp.float32):
+    rng = np.random.default_rng(3)
+    v = jnp.asarray(rng.normal(size=(B, S, C)), dtype)
+    w = jnp.asarray(rng.normal(size=(C, 4)), jnp.float32)
+    bias = jnp.asarray(rng.normal(size=(C,)), jnp.float32)
+    return v, w, bias, _inputs()[2]
+
+
+def _ungated_by_hand(v, w, bias, seg):
+    """``silu(b + sum_j w[:, j] v[t - 3 + j])`` position by position."""
+    v, w = np.asarray(v, np.float64), np.asarray(w, np.float64)
+    z = np.zeros_like(v) + np.asarray(bias, np.float64)
+    for row in range(v.shape[0]):
+        for t in range(v.shape[1]):
+            for j in range(w.shape[1]):
+                s = t - (w.shape[1] - 1) + j
+                if s >= 0 and (seg is None or seg[row, s] == seg[row, t]):
+                    z[row, t] += w[:, j] * v[row, s]
+    return z / (1 + np.exp(-z))
+
+
+@pytest.mark.parametrize("segmented", [True, False],
+                         ids=["documents", "one_document"])
+def test_the_ungated_form_is_its_shifted_sums_forward_and_backward(segmented):
+    """Four taps, a bias and a SiLU, the terms of a tap counted inside the
+    position's document (boundaries within the four taps, a document of one
+    position, one at the window's end); the gradient of the input, the taps
+    and the bias against the same written with ``jnp`` position shifts."""
+    v, w, bias, seg = _ungated_inputs()
+    seg = seg if segmented else None
+    got = sc.silu_short_conv(v, w, bias, seg)
+    assert got.shape == v.shape and got.dtype == v.dtype
+    np.testing.assert_allclose(
+        got, _ungated_by_hand(v, w, bias,
+                              None if seg is None else np.asarray(seg)),
+        rtol=1e-5, atol=1e-5)
+
+    def shifted_sums(v, w, bias):
+        z = jnp.broadcast_to(bias, v.shape)
+        for j in range(4):
+            d = 3 - j
+            moved = jnp.roll(v, d, axis=1)
+            ok = (jnp.arange(S) >= d)[None, :]
+            if seg is not None:
+                ok = ok & (jnp.roll(seg, d, axis=1) == seg)
+            z = z + w[:, j] * jnp.where(ok[..., None], moved, 0.0)
+        return z * jax.nn.sigmoid(z)
+
+    weights = jnp.asarray(np.random.default_rng(4).normal(size=v.shape),
+                          jnp.float32)
+    grads = jax.grad(lambda *a: jnp.sum(weights * sc.silu_short_conv(
+        *a, seg)), argnums=(0, 1, 2))(v, w, bias)
+    wants = jax.grad(lambda *a: jnp.sum(weights * shifted_sums(*a)),
+                     argnums=(0, 1, 2))(v, w, bias)
+    for name, a, b in zip(("v", "taps", "bias"), grads, wants):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4, err_msg=name)
+
+
+def test_the_ungated_form_in_bf16_and_its_refusals():
+    v, w, bias, seg = _ungated_inputs(jnp.bfloat16)
+    got = sc.silu_short_conv(v, w, bias, seg)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32),
+        _ungated_by_hand(v.astype(jnp.float32), w, bias, np.asarray(seg)),
+        rtol=2e-2, atol=2e-2)       # one bf16 rounding of the output
+    with pytest.raises(ValueError, match="taps"):
+        sc.silu_short_conv(v, w[:8], bias, seg)
+    with pytest.raises(ValueError, match="bias"):
+        sc.silu_short_conv(v, w, bias[:8], seg)
+    # the gated form's XLA path shares the tap loop and gives what it gave
+    bcx, w3, seg = _inputs()
+    np.testing.assert_allclose(
+        sc.gated_short_conv_xla(bcx, w3, seg),
+        _by_hand(bcx, w3, np.asarray(seg)), rtol=1e-5, atol=1e-5)
