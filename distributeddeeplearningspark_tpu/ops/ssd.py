@@ -26,6 +26,17 @@ of states, and the 0 is a MASK (no ``-inf`` goes under a running sum).
 Decays, running sums and states are float32 whatever the activations' dtype;
 the four products take the activations' dtype and accumulate in float32.
 
+``cs`` is made with a chunk's positions MINOR, ``[B, c, G, R, l]``, the
+layout the decay masks read, as the product with the ``[l, l]`` upper
+triangle of ones on the matrix unit (:func:`running_sum`) at
+``Precision.HIGHEST``: the default of a float32 product on the TPU is one
+bf16 pass, which is not float32. Its backward is the product with the
+transpose. ``jnp.cumsum`` over the positions is a reduce-window on the TPU;
+with the 8 x 8 heads minor it took 34 ms of the scan's 61 a step in
+``fit_seg16k`` (PERF.md, PR 37), and along the minor axis a layer's
+forward and backward alone take 13.4 ms where the product takes 9.0 (PR
+38).
+
 The chunks go through in GROUPS of :data:`GROUP` (one ``lax.scan`` step a
 group: the products of a group's chunks are batched, its states passed by a
 short inner scan), so what lives at once is a group's ``[group, heads,
@@ -80,6 +91,15 @@ def chunks_reset_share(segment_ids, chunk: int = DEFAULT_CHUNK):
                     .astype(jnp.float32))
 
 
+def running_sum(v):
+    """``v [..., l] -> `` its running sum along the last axis, float32: the
+    product with the ``[l, l]`` upper triangle of ones, on the matrix unit
+    at ``Precision.HIGHEST`` (module docstring)."""
+    l = v.shape[-1]
+    upper = jnp.triu(jnp.ones((l, l), jnp.float32))      # [j, i]: j <= i
+    return jnp.matmul(v, upper, precision=jax.lax.Precision.HIGHEST)
+
+
 def _group(state, x, dt, a, d, bm, cm, start):
     """One group of ``c`` chunks of ``l`` positions: ``(state [B, G, R, P,
     N] float32 handed to the group's first chunk, x [B, c, l, G, R, P], dt
@@ -89,15 +109,16 @@ def _group(state, x, dt, a, d, bm, cm, start):
     ``R`` the heads of a group."""
     f32, dtype = jnp.float32, x.dtype
     l = x.shape[2]
-    cs = jnp.cumsum(dt * a, axis=2)                       # [B, c, l, G, R]
+    # cs and what is made of it are [B, c, G, R, l]: the positions minor
+    cs = running_sum(jnp.moveaxis(dt * a, 2, -1))
+    by_pos = lambda t: jnp.moveaxis(t, -1, 2)             # [B, c, l, G, R]
     # the document of a position, counted inside its chunk: 0 until the
     # chunk's first boundary
     k = jnp.cumsum(start.astype(jnp.int32), axis=2)       # [B, c, l]
     reads = (k[..., :, None] == k[..., None, :]) & jnp.tril(
         jnp.ones((l, l), jnp.bool_))                      # [B, c, l, l]
     reads = reads[:, :, None, None]
-    by_head = jnp.moveaxis(cs, 2, -1)                     # [B, c, G, R, l]
-    diff = by_head[..., :, None] - by_head[..., None, :]
+    diff = cs[..., :, None] - cs[..., None, :]
     decay = jnp.where(reads, jnp.exp(jnp.where(reads, diff, 0.0)), 0.0)
     cb = jnp.einsum("bcign,bcjgn->bcgij", cm, bm, preferred_element_type=f32)
     xdt = x.astype(f32) * dt[..., None]                   # [B, c, l, G, R, P]
@@ -105,25 +126,25 @@ def _group(state, x, dt, a, d, bm, cm, start):
                    (cb[:, :, :, None] * decay).astype(dtype),
                    xdt.astype(dtype), preferred_element_type=f32)
     # a chunk's own part of its closing state: its last document's positions
-    last = (k == k[..., -1:])[..., None, None]            # [B, c, l, 1, 1]
+    last = (k == k[..., -1:])[:, :, None, None]           # [B, c, 1, 1, l]
     to_end = jnp.where(last, jnp.exp(jnp.where(
-        last, cs[:, :, -1:] - cs, 0.0)), 0.0)
+        last, cs[..., -1:] - cs, 0.0)), 0.0)
     own = jnp.einsum("bcjgrp,bcjgn->bcgrpn",
-                     (xdt * to_end[..., None]).astype(dtype), bm,
+                     (xdt * by_pos(to_end)[..., None]).astype(dtype), bm,
                      preferred_element_type=f32)
     # what a position keeps of the state its chunk was handed (cs <= 0)
-    first = (k == 0)[..., None, None]
-    from_start = jnp.where(first, jnp.exp(cs), 0.0)       # [B, c, l, G, R]
+    first = (k == 0)[:, :, None, None]
+    from_start = jnp.where(first, jnp.exp(cs), 0.0)       # [B, c, G, R, l]
 
     def hand(s, chunk):
         keeps, adds = chunk
         return keeps[..., None, None] * s + adds, s
 
     state, handed = jax.lax.scan(
-        hand, state, (jnp.moveaxis(from_start[:, :, -1], 1, 0),
+        hand, state, (jnp.moveaxis(from_start[..., -1], 1, 0),
                       jnp.moveaxis(own, 1, 0)))
     handed = jnp.moveaxis(handed, 0, 1)                   # [B, c, G, R, P, N]
-    y = y + from_start[..., None] * jnp.einsum(
+    y = y + by_pos(from_start)[..., None] * jnp.einsum(
         "bcign,bcgrpn->bcigrp", cm, handed.astype(dtype),
         preferred_element_type=f32)
     y = y + d[..., None] * x.astype(f32)

@@ -9,10 +9,14 @@ suite with several workers, and a module that touched it at import time
 would fail in every worker but one (on-chip-measurement guide, section 2).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
+
+from _jaxpr import eqns
 
 
 @pytest.fixture(scope="module")
@@ -230,18 +234,12 @@ def test_flash_bounds_operands_follow_the_segment_ids(regime):
     scalar, args = _flash_regime(regime)
 
     calls = {}
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                grid_mapping = eqn.params["grid_mapping"]
-                calls[eqn.params["name"]] = (
-                    len(eqn.invars), grid_mapping.num_index_operands,
-                    grid_mapping.grid)
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
-
-    walk(jax.make_jaxpr(jax.grad(scalar, argnums=(0, 1, 2)))(*args).jaxpr)
+    for eqn in eqns(jax.make_jaxpr(jax.grad(scalar, argnums=(0, 1, 2)))(
+            *args).jaxpr, "pallas_call"):
+        grid_mapping = eqn.params["grid_mapping"]
+        calls[eqn.params["name"]] = (
+            len(eqn.invars), grid_mapping.num_index_operands,
+            grid_mapping.grid)
     tables = 2 * segmented
     extra = masked + 2 * segmented + tables
     n = s // min(DEFAULT_BLOCK, s)
@@ -541,6 +539,9 @@ def test_the_nemotron_train_step_compiles_for_one_chip_with_room_for_the_check(
     assert "[1,32,16384,16384]" not in text      # no [B, H, S, S] array
     assert "128,8,8,128,128]" not in text        # nor every chunk's masks
     assert "f32[1,8,8,64,128]" in text           # the state a chunk hands on
+    # the running sum of dt A is a product, not a reduce-window over the
+    # chunk's positions with the 8 x 8 heads minor
+    assert not re.search(r"f32\[1,8,128,8,8\]\S* reduce-window\(", text)
     ma = compiled.memory_analysis()
     live = (max(ma.argument_size_in_bytes, ma.output_size_in_bytes)
             + ma.temp_size_in_bytes)

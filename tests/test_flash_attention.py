@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _jaxpr import eqns
 from distributeddeeplearningspark_tpu.ops.attention import (
     _pick_impl,
     _xla_attention,
@@ -704,16 +705,8 @@ def test_the_three_kernels_carry_stable_names():
         return jnp.sum(dot_product_attention(
             q, k, v, mask=mask, impl="flash") ** 2)
 
-    names = []
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                names.append(eqn.params["name"])
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
-
-    walk(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v).jaxpr)
+    names = [eqn.params["name"] for eqn in eqns(jax.make_jaxpr(
+        jax.grad(loss, argnums=(0, 1, 2)))(q, k, v).jaxpr, "pallas_call")]
     assert sorted(names) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
 
 

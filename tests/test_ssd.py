@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _jaxpr import eqns
 from distributeddeeplearningspark_tpu.ops import ssd
 
 NAMES = ("x", "dt", "A", "B", "C", "D")
@@ -203,3 +204,37 @@ def test_the_two_counters():
     g = jax.grad(
         lambda x: ssd.ssd_scan(x, dt, a, bm, cm, d, None, chunk=8)[1])(x)
     assert float(jnp.max(jnp.abs(g))) == 0.0
+
+
+@pytest.mark.parametrize("chunk", [8, 32, 128])
+def test_the_running_sum_is_a_float32_triangular_product(chunk):
+    """``cs`` is the product with a triangle of ones (``ops/ssd.py``'s
+    docstring): the running sum within float32 rounding at the tests'
+    harshest magnitudes (``dt`` 1 and ``A`` -16 in one row: ``cs`` to -16 x
+    ``chunk``), and in ``_group`` that product is at ``Precision.HIGHEST``,
+    which is what keeps it float32 on the TPU (one bf16 pass by default,
+    which no CPU run shows)."""
+    rng = np.random.default_rng(12)
+    dt = np.exp(rng.uniform(np.log(0.01), 0.0, (3, 5, chunk)))
+    a = -rng.uniform(1.0, 16.0, (3, 5, 1))
+    dt[0], a[0] = 1.0, -16.0
+    v = jnp.asarray(dt * a, jnp.float32)
+    got, want = ssd.running_sum(v), jnp.cumsum(v, axis=-1)
+    assert got.dtype == jnp.float32 and float(got[0, 0, -1]) == -16.0 * chunk
+    # one sign throughout: |cs| is the sum of the terms' magnitudes
+    err = float(jnp.max(jnp.abs(got - want) / jnp.abs(want)))
+    assert err <= chunk * 2.0 ** -23, err
+
+    b, c, g, r, p, n = 1, 2, 2, 2, 4, 8
+    f32 = jnp.float32
+    args = (jnp.zeros((b, g, r, p, n), f32), jnp.zeros((b, c, chunk, g, r, p)),
+            jnp.ones((b, c, chunk, g, r), f32), -jnp.ones((g, r), f32),
+            jnp.ones((g, r), f32), jnp.zeros((b, c, chunk, g, n)),
+            jnp.zeros((b, c, chunk, g, n)), jnp.zeros((b, c, chunk), bool))
+    jaxpr = jax.make_jaxpr(ssd._group)(*args).jaxpr
+    sums = [e for e in eqns(jaxpr, "dot_general")
+            if e.invars[1].aval.shape == (chunk, chunk)]
+    assert len(sums) == 1
+    assert sums[0].outvars[0].aval.shape == (b, c, g, r, chunk)
+    assert sums[0].outvars[0].aval.dtype == f32
+    assert sums[0].params["precision"] == (jax.lax.Precision.HIGHEST,) * 2

@@ -6,11 +6,19 @@ with each fault planted (the cell's other tests, and the toy size, are in
 side by side)."""
 
 import os
+import time
 
 import pytest
 
 from test_ssm_moe_benchmark import (  # noqa: F401 - the toy size
     NAME, ROOT, _copy_of_the_benchmark, _load, _toy)
+
+#: the toy run is counted in steps, not seconds: the clock its window reads
+#: moves STEP_S a step from the first, so the 5 s of warm-up and the 1-s
+#: window are 242 steps on any machine. A fault's term follows how far the
+#: toy model has trained: ``head_reads_group_h_mod_8``'s ``scan_energy``
+#: reads 0.041, 0.037, 0.027 and 0.0059 at 202, 242, 302 and 482 steps
+STEP_S = 0.025
 
 
 @pytest.fixture(scope="module")
@@ -18,13 +26,25 @@ def controls(tmp_path_factory):
     """``benchmark/controls/nemotron3_nano_30b_a3b.py`` at the toy size on
     ``local[1]``, in float32 but under the cell's OWN limits: one run of the
     cell, the harness's comparison repeated with each fault planted."""
-    from benchmark.harness import seedcache
+    from benchmark.harness import runner, seedcache, window
+
+    class StepClock(window.LapWindow):
+        def __init__(self, **kw):
+            super().__init__(clock=self.steps_s, **kw)
+            self.first = None
+
+        def steps_s(self):
+            if self.first is None:
+                self.first = (time.perf_counter(), self.last_step)
+            t, step = self.first
+            return t + (self.last_step - step) * STEP_S
 
     root = _copy_of_the_benchmark(
         tmp_path_factory.mktemp("controls") / "checkout")
     _toy(root, keep_check=True)
     mod = _load(str(root / "benchmark" / "controls" / f"{NAME}.py"))
     with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(runner, "LapWindow", StepClock)
         mp.setattr(seedcache, "ROOT", str(root / "benchmark" / ".cache"))
         mp.setenv("DLS_TELEMETRY_DIR", str(root / "telemetry"))  # restored
         return mod, mod.run(2 ** 31 + 11, 1.0, mod.ALL, master="local[1]",
