@@ -11,8 +11,8 @@ a kernel of its own). Norms and the rotary embedding are the
 Llama path's (:mod:`.llama`), the fused head loss :mod:`..train.fused_ce`'s.
 ``mtp_layers = 1`` adds DeepSeek-V3's multi-token-prediction module (below).
 
-Built from ``layer_types`` (``"conv"``, ``"full_attention"`` or
-``"latent_attention"`` a layer) and
+Built from ``layer_types`` (``"conv"``, ``"full_attention"``,
+``"sliding_attention"`` or ``"latent_attention"`` a layer) and
 ``num_dense_layers``. One block, ``x [B, S, hidden]``, ``seg[t]`` the
 document of position ``t``, ``pos[t] = t -`` the first position of that
 document in the window::
@@ -24,6 +24,14 @@ document in the window::
     OP attention:  q, k = rotary(RMSNorm_head(x Wq), pos), rotary(RMSNorm_head(
                    x Wk), pos);  softmax over {s <= t, seg[s] = seg[t]} of
                    q.k / sqrt(head_dim);  OP = (p v) Wo
+    OP sliding:    the same inside the band ``0 <= t - s < sliding_window``
+                   (the flash kernels' band regime walks only its blocks),
+                   with heads and rotary of its own; either kind may rotate
+                   only the head's leading share (``partial_rotary_factor``),
+                   at YaRN's frequencies (``yarn_factor``) with a factor on
+                   cos and sin, skip the per-head norm (``qk_norm``) and gate
+                   each head's output, ``o_h sigmoid(x Wg)_h``, before ``Wo``
+                   (``attention_gate``)
     OP latent:     c_q = RMSNorm(x Wdq);  [q_nope | q_rot] = c_q Wuq  (a head)
                    [c | k_r] = x Wdkv;  [k_nope | v] = RMSNorm(c) Wukv (a head)
                    q_rot, k_r = rotary(., pos) over adjacent pairs; k_r is ONE
@@ -33,7 +41,10 @@ document in the window::
     FFN experts:   s = sigmoid(x Wg); top-k of s + b; weights s_e / (sum of
                    the chosen s + 1e-6) times ``routed_scaling_factor``;
                    plus shared(x), a SwiGLU every token passes, where
-                   ``shared_expert_size`` is not 0
+                   ``shared_expert_size`` is not 0. With ``router_score``
+                   ``"softmax"``: softmax over all experts, the top k
+                   renormalised, no bias, and Switch's balance loss times
+                   ``router_aux_loss_coef`` as ``moe_aux``
 
 Multi-token prediction, depth 1 (arXiv:2412.19437 eq. 21-25), with ``h`` the
 last block's output BEFORE the final norm::
@@ -71,7 +82,7 @@ kinds ``"mamba2"``, ``"experts"`` and ``"bare_attention"`` of ``layer_types``::
 
 ``b`` is no parameter: it lives in the mutable collection
 ``moe.BIAS_COLLECTION`` and a training step moves it by its own load counts
-(auxiliary-loss-free balancing), so there is no router loss term.
+(auxiliary-loss-free balancing), so the sigmoid router has no loss term.
 
 Layout of the depth: the leading dense layers and whatever does not fill a
 period at the end are unrolled; the whole periods between them are ONE
@@ -97,6 +108,7 @@ from jax.sharding import PartitionSpec as P
 from distributeddeeplearningspark_tpu.models.llama import (
     RMSNorm,
     rotary_embedding,
+    yarn_inv_freq,
 )
 from distributeddeeplearningspark_tpu.models.moe import (
     BIAS_COLLECTION,
@@ -107,6 +119,7 @@ from distributeddeeplearningspark_tpu.ops.flash_attention import (
     FLASH_OUT_NAME,
     attn_blocks_masked_share,
     attn_blocks_walked_share,
+    window_blocks_walked_share,
 )
 from distributeddeeplearningspark_tpu.ops.short_conv import (
     gated_short_conv,
@@ -119,6 +132,9 @@ from distributeddeeplearningspark_tpu.ops.ssd import (
 from distributeddeeplearningspark_tpu.parallel.sharding import ShardingRules
 
 CONV, ATTENTION, LATENT = "conv", "full_attention", "latent_attention"
+#: causal attention inside a band of ``sliding_window`` positions, with heads
+#: and rotary of its own (``sliding_num_heads``, ``sliding_rope_theta``)
+SLIDING = "sliding_attention"
 #: blocks of ONE sublayer, ``x + SUB(RMSNorm(x))`` (module docstring)
 MAMBA, EXPERTS, BARE_ATTENTION = "mamba2", "experts", "bare_attention"
 SOLO = (MAMBA, EXPERTS, BARE_ATTENTION)
@@ -132,6 +148,11 @@ COUNTERS = ("moe_load_max_over_mean", "moe_rows_held_share",
 #: batch's scan chunks that hold a document's first position, and the largest
 #: magnitude of a state the LAST such layer's scan handed between chunks
 SSM_COUNTERS = ("ssm_chunks_reset_share", "ssm_state_abs_max")
+#: two more, only of a model with sliding-window layers: of the blocks on or
+#: under the diagonal, the share the band kernels walk (from their own
+#: predicate), and the in-document pairs inside the window over ``S x
+#: window`` (what the band requires)
+WINDOW_COUNTERS = ("window_blocks_walked_share", "window_pairs_share")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -145,6 +166,23 @@ class HybridDecoderConfig:
     num_kv_heads: int = 8
     head_dim: int = 64
     rope_theta: float = 1e6
+    # the full-attention layers' rotary beyond theta: the leading share of
+    # the head that turns, YaRN's frequencies (factor 1: none) and the
+    # factor on cos and sin
+    partial_rotary_factor: float = 1.0
+    yarn_factor: float = 1.0
+    yarn_original_max_position: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    rope_attention_factor: float = 1.0
+    qk_norm: bool = True             # per-head RMSNorm on q and k
+    # a per-head output gate on both attention kinds: o_h * sigmoid(x Wg)_h
+    attention_gate: bool = False
+    # layers of kind SLIDING: the band, their query heads (None: num_heads)
+    # and their rotary (the whole head at a theta of its own)
+    sliding_window: int | None = None
+    sliding_num_heads: int | None = None
+    sliding_rope_theta: float = 1e4
     rms_eps: float = 1e-5
     max_position: int = 128000
     conv_taps: int = 3               # conv_L_cache
@@ -152,6 +190,10 @@ class HybridDecoderConfig:
     # routed experts (models/moe.py RoutedExperts, sigmoid router)
     num_experts: int = 64            # the router's width
     experts_per_token: int = 4
+    # "sigmoid" (bias-balanced, no loss term) or "softmax" (Switch's balance
+    # loss times router_aux_loss_coef, summed over the expert layers)
+    router_score: str = "sigmoid"
+    router_aux_loss_coef: float = 0.0
     expert_size: int = 1536
     experts_held: tuple[int, int] | None = None   # (first, count); None: all
     norm_topk_prob: bool = True
@@ -189,7 +231,14 @@ class HybridDecoderConfig:
         if self.mtp_layers not in (0, 1):
             raise ValueError(f"mtp_layers {self.mtp_layers}: a module of "
                              f"depth 1 is built, or none")
-        unknown = set(self.layer_types) - {CONV, ATTENTION, LATENT, *SOLO}
+        unknown = set(self.layer_types) - {CONV, ATTENTION, LATENT, SLIDING,
+                                           *SOLO}
+        if SLIDING in self.layer_types and not (self.sliding_window or 0) > 0:
+            raise ValueError("sliding_attention layers need a positive "
+                             "sliding_window")
+        if self.router_score == "softmax" and self.use_expert_bias:
+            raise ValueError("the softmax router has no selection bias: "
+                             "use_expert_bias must be False")
         if set(self.layer_types[:self.num_dense_layers]) & set(SOLO):
             raise ValueError("a block of one sublayer has no feed-forward "
                              "to be dense: num_dense_layers counts none")
@@ -242,6 +291,30 @@ class HybridDecoderConfig:
                     qk_rope_head_dim=8, v_head_dim=16, rms_eps=1e-6,
                     shared_expert_size=64, routed_scaling_factor=2.5,
                     tie_embeddings=False, mtp_layers=1)
+        base.update(kw)
+        return HybridDecoderConfig.tiny(**base)
+
+    @staticmethod
+    def tiny_window(**kw) -> "HybridDecoderConfig":
+        """Laguna-XS.2's shape at a CPU test's size: a dense full-attention
+        layer, then the period (sliding, sliding, sliding, full) of expert
+        layers; window 16; 6 query heads on 2 in full layers (YaRN on the
+        leading half of the head, with its attention factor), 8 on 2 in
+        sliding ones (whole head at theta 1e4); per-head output gates, no q/k
+        norm; a softmax router over 8 experts, 2 a token, with Switch's loss,
+        beside an ungated shared expert, scaled 2.5; an untied head."""
+        base = dict(layer_types=(ATTENTION, SLIDING, SLIDING, SLIDING,
+                                 ATTENTION),
+                    num_dense_layers=1, num_heads=6, num_kv_heads=2,
+                    head_dim=16, rms_eps=1e-6, rope_theta=5e5,
+                    partial_rotary_factor=0.5, yarn_factor=64.0,
+                    yarn_original_max_position=4096, yarn_beta_fast=64.0,
+                    yarn_beta_slow=1.0, rope_attention_factor=1.4158883,
+                    qk_norm=False, attention_gate=True, sliding_window=16,
+                    sliding_num_heads=8, sliding_rope_theta=1e4,
+                    router_score="softmax", router_aux_loss_coef=0.001,
+                    use_expert_bias=False, shared_expert_size=64,
+                    routed_scaling_factor=2.5, tie_embeddings=False)
         base.update(kw)
         return HybridDecoderConfig.tiny(**base)
 
@@ -356,28 +429,68 @@ class Mamba2Mixer(nn.Module):
         return out(y), state_abs_max
 
 
+def rotary_of(cfg: HybridDecoderConfig, sliding: bool) -> dict:
+    """``rotary_embedding``'s keyword arguments for a layer kind: a sliding
+    layer's theta and share of the head, or a full layer's, with YaRN's
+    frequencies over the share that turns where ``yarn_factor`` is not 1 and
+    its factor on cos and sin. A sibling that sets none of these gets the
+    whole head at ``rope_theta``, as before they were."""
+    if sliding:
+        return {"theta": cfg.sliding_rope_theta}
+    rot = int(cfg.head_dim * cfg.partial_rotary_factor)
+    out = {"theta": cfg.rope_theta, "rotary_dim": rot,
+           "attention_factor": cfg.rope_attention_factor}
+    if cfg.yarn_factor != 1.0:
+        out["inv_freq"] = yarn_inv_freq(
+            rot, cfg.rope_theta, factor=cfg.yarn_factor,
+            original_max_position=cfg.yarn_original_max_position,
+            beta_fast=cfg.yarn_beta_fast, beta_slow=cfg.yarn_beta_slow)
+    return out
+
+
+def head_gate(o, g):
+    """``o [B, S, heads, D]`` times ``sigmoid(g)`` of its head (``g [B, S,
+    heads]``), the sigmoid in float32: the per-head output gate."""
+    return o * nn.sigmoid(g.astype(jnp.float32)).astype(o.dtype)[..., None]
+
+
 class CausalAttention(nn.Module):
     """Grouped-query attention inside a document, per-head q/k RMSNorm
-    before the rotary embedding, positions restarting with the document;
-    ``bare``: neither the norm nor the rotary embedding."""
+    (where ``qk_norm``) before the rotary embedding, positions restarting
+    with the document; ``bare``: neither the norm nor the rotary embedding;
+    ``sliding``: inside the band of ``sliding_window``, with the sliding
+    layers' heads and rotary (:func:`rotary_of`). With ``attention_gate``
+    each head's output is scaled by ``sigmoid(x Wg)`` of its own before
+    ``wo`` (:func:`head_gate`)."""
 
     cfg: HybridDecoderConfig
     bare: bool = False
+    sliding: bool = False
 
     @nn.compact
     def __call__(self, x, seg, pos):
         cfg = self.cfg
-        q = _dense(cfg, (cfg.num_heads, cfg.head_dim), "wq")(x)
+        heads = ((cfg.sliding_num_heads or cfg.num_heads) if self.sliding
+                 else cfg.num_heads)
+        q = _dense(cfg, (heads, cfg.head_dim), "wq")(x)
         k = _dense(cfg, (cfg.num_kv_heads, cfg.head_dim), "wk")(x)
         v = _dense(cfg, (cfg.num_kv_heads, cfg.head_dim), "wv")(x)
         if self.bare:
             o = dot_product_attention(q, k, v, causal=True, segment_ids=seg)
             return _dense(cfg, cfg.hidden_size, "wo", axis=(-2, -1))(o)
-        q = rotary_embedding(RMSNorm(cfg.rms_eps, cfg.dtype, name="q_norm")(q),
-                             pos, cfg.rope_theta)
-        k = rotary_embedding(RMSNorm(cfg.rms_eps, cfg.dtype, name="k_norm")(k),
-                             pos, cfg.rope_theta)
-        o = dot_product_attention(q, k, v, causal=True, segment_ids=seg)
+        rope = rotary_of(cfg, self.sliding)
+
+        def positioned(t, norm):   # (q's norm and rotary, then k's)
+            if cfg.qk_norm:
+                t = RMSNorm(cfg.rms_eps, cfg.dtype, name=norm)(t)
+            return rotary_embedding(t, pos, **rope)
+
+        q, k = positioned(q, "q_norm"), positioned(k, "k_norm")
+        o = dot_product_attention(
+            q, k, v, causal=True, segment_ids=seg,
+            window=cfg.sliding_window if self.sliding else None)
+        if cfg.attention_gate:
+            o = head_gate(o, _dense(cfg, heads, "gate")(x))
         return _dense(cfg, cfg.hidden_size, "wo", axis=(-2, -1))(o)
 
 
@@ -446,17 +559,20 @@ class HybridLayer(nn.Module):
             cfg.hidden_size, cfg.expert_size, cfg.num_experts,
             cfg.experts_per_token, held=cfg.experts_held,
             norm_topk=cfg.norm_topk_prob, dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype, score="sigmoid",
+            param_dtype=cfg.param_dtype, score=cfg.router_score,
             select_bias=cfg.use_expert_bias,
             bias_update_rate=cfg.bias_update_rate,
             train_router=cfg.train_router,
             routed_scale=cfg.routed_scaling_factor,
             shared_size=cfg.shared_expert_size,
             expert_form=cfg.expert_form, name="moe")(h)
-        return y, {"moe_load_max_over_mean": moe["load_max_over_mean"],
-                   "moe_rows_held_share": moe["rows_held_share"],
-                   "router_bias_abs_max": moe.get("bias_abs_max",
-                                                  jnp.float32(0.0))}
+        stats = {"moe_load_max_over_mean": moe["load_max_over_mean"],
+                 "moe_rows_held_share": moe["rows_held_share"],
+                 "router_bias_abs_max": moe.get("bias_abs_max",
+                                                jnp.float32(0.0))}
+        if cfg.router_aux_loss_coef:
+            stats["moe_aux"] = moe["aux"]
+        return y, stats
 
     @nn.compact
     def __call__(self, x, seg, pos):
@@ -477,6 +593,9 @@ class HybridLayer(nn.Module):
             x = x + ShortConv(cfg, name="conv")(h, seg)
         elif self.kind == LATENT:
             x = x + LatentAttention(cfg, self.packed, name="self_attn")(
+                h, seg, pos)
+        elif self.kind == SLIDING:
+            x = x + CausalAttention(cfg, sliding=True, name="self_attn")(
                 h, seg, pos)
         else:
             x = x + CausalAttention(cfg, name="self_attn")(h, seg, pos)
@@ -619,6 +738,10 @@ class HybridDecoderLM(nn.Module):
             found = [c[name] for c in collected if name in c]
             out[name] = (over_layers(jnp.concatenate(found))
                          if found else jnp.float32(0.0))
+        if cfg.router_aux_loss_coef:
+            out["moe_aux"] = cfg.router_aux_loss_coef * jnp.sum(
+                jnp.concatenate([c["moe_aux"] for c in collected
+                                 if "moe_aux" in c]))
         if MAMBA in cfg.layer_types:
             out["ssm_chunks_reset_share"] = chunks_reset_share(
                 seg, cfg.ssm_chunk)
@@ -632,6 +755,12 @@ class HybridDecoderLM(nn.Module):
         out["attn_blocks_walked_share"] = attn_blocks_walked_share(seg)
         # and, of those, what needs its mask (dQ and dK/dV leave it out elsewhere)
         out["attn_blocks_masked_share"] = attn_blocks_masked_share(seg)
+        if SLIDING in cfg.layer_types:
+            w = cfg.sliding_window
+            out["window_pairs_share"] = jnp.mean(jnp.sum(jnp.minimum(
+                pos + 1, w).astype(jnp.float32), axis=1)) / (s * w)
+            out["window_blocks_walked_share"] = window_blocks_walked_share(
+                seg, w)
         return out
 
 

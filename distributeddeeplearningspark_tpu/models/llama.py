@@ -35,6 +35,7 @@ from typing import Any, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 from jax.sharding import PartitionSpec as P
 
@@ -184,24 +185,65 @@ def _remat_policy(name: str | None):
     return policies[name]
 
 
+def yarn_inv_freq(dim: int, theta: float, *, factor: float,
+                  original_max_position: int, beta_fast: float,
+                  beta_slow: float) -> np.ndarray:
+    """YaRN's rotary frequencies (Peng et al., arXiv:2309.00071, as
+    ``transformers`` computes ``rope_type`` ``yarn``), float32 [dim / 2]:
+    ``f_i = theta^(-2i/dim)``; the pairs that turn more than ``beta_fast``
+    times over ``original_max_position`` keep ``f_i``, those that turn fewer
+    than ``beta_slow`` times take ``f_i / factor``, and a linear ramp joins
+    them between ``lo = floor(c(beta_fast))`` and ``hi = ceil(c(beta_slow))``,
+    ``c(r) = dim ln(original_max_position / (2 pi r)) / (2 ln theta)``, both
+    clamped to ``[0, dim - 1]``. The attention factor is the caller's."""
+    def correction(rotations):
+        return (dim * math.log(original_max_position / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(correction(beta_fast)), 0)
+    hi = min(math.ceil(correction(beta_slow)), dim - 1)
+    hi = hi + 0.001 if hi == lo else hi
+    f = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ramp = np.clip((np.arange(dim // 2) - lo) / (hi - lo), 0.0, 1.0)
+    return (f / factor * ramp + f * (1.0 - ramp)).astype(np.float32)
+
+
 def rotary_embedding(x: jax.Array, positions: jax.Array, theta: float, *,
-                     interleaved: bool = False) -> jax.Array:
+                     interleaved: bool = False, rotary_dim: int | None = None,
+                     inv_freq=None, attention_factor: float = 1.0
+                     ) -> jax.Array:
     """Apply RoPE to [B,S,H,D] in f32: half-split (rotate-half) convention,
     or with ``interleaved`` over ADJACENT pairs ``(2i, 2i+1)`` (DeepSeek's
-    ``rope_interleave``), the result in the same adjacent layout."""
+    ``rope_interleave``), the result in the same adjacent layout.
+
+    ``rotary_dim`` (a partial rotary factor): only the LEADING ``rotary_dim``
+    dimensions turn (rotate-half within them), the rest pass through as they
+    are. ``inv_freq`` [rotary_dim / 2] replaces ``theta``'s frequencies
+    (:func:`yarn_inv_freq`); ``attention_factor`` multiplies cos and sin
+    (YaRN's ``mscale``). The defaults give the whole head at ``theta``."""
     d = x.shape[-1]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angles = positions.astype(jnp.float32)[..., None] * inv_freq      # [B,S,D/2]
-    cos = jnp.cos(angles)[:, :, None, :]                              # [B,S,1,D/2]
+    rot = d if rotary_dim is None else rotary_dim
+    if interleaved and rot != d:
+        raise NotImplementedError("a partial rotary is rotate-half only")
+    inv_freq = (1.0 / (theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32)
+                                 / rot)) if inv_freq is None
+                else jnp.asarray(inv_freq, jnp.float32))
+    angles = positions.astype(jnp.float32)[..., None] * inv_freq    # [B,S,R/2]
+    cos = jnp.cos(angles)[:, :, None, :]                            # [B,S,1,R/2]
     sin = jnp.sin(angles)[:, :, None, :]
+    if attention_factor != 1.0:
+        cos, sin = cos * attention_factor, sin * attention_factor
+    xf = x.astype(jnp.float32)
     if interleaved:
-        pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], d // 2, 2)
+        pairs = xf.reshape(*x.shape[:-1], d // 2, 2)
         x1, x2 = pairs[..., 0], pairs[..., 1]
         out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
         return out.reshape(x.shape).astype(x.dtype)
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return out.astype(x.dtype)
+    x1, x2 = jnp.split(xf if rot == d else xf[..., :rot], 2, axis=-1)
+    parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if rot != d:
+        parts.append(xf[..., rot:])
+    return jnp.concatenate(parts, axis=-1).astype(x.dtype)
 
 
 class RMSNorm(nn.Module):

@@ -55,6 +55,7 @@ def dot_product_attention(
     causal: bool = False,
     scale: float | None = None,
     segment_ids: jax.Array | None = None,
+    window: int | None = None,
     impl: str = "auto",
 ) -> jax.Array:
     """Softmax attention over BSHD tensors.
@@ -64,12 +65,22 @@ def dot_product_attention(
     ``segment_ids``: [B, S] int32 packed-sequence ids — attention is blocked
     across different ids (VERDICT r2 #4 sequence packing); the flash kernel
     streams them blockwise, the XLA path expands them into the mask.
+    ``window`` (with ``causal``): query ``i`` sees key ``j`` only where ``i -
+    j < window``; the flash kernels walk only the band, the XLA path masks.
+    The ring and ulysses paths have no band and refuse one.
     """
+    if window is not None and not causal:
+        raise ValueError(f"window {window} without causal: the band is the "
+                         f"causal one, 0 <= i - j < window")
     if impl == "auto":
         impl = _pick_impl(q, k, bias, mask, v)
+    if impl in ("ring", "ulysses") and window is not None:
+        raise NotImplementedError(
+            f"impl={impl!r} has no sliding window (ROADMAP queue 2, A.5)")
     if impl == "flash":
         return _flash_on_mesh(q, k, v, bias=bias, mask=mask, causal=causal,
-                              scale=scale, segment_ids=segment_ids)
+                              scale=scale, segment_ids=segment_ids,
+                              window=window)
     if impl == "ring":
         from distributeddeeplearningspark_tpu.ops.ring_attention import ring_attention
 
@@ -88,10 +99,12 @@ def dot_product_attention(
     if segment_ids is not None:
         seg_mask = segment_ids[:, None, :, None] == segment_ids[:, None, None, :]
         mask = seg_mask if mask is None else jnp.logical_and(mask, seg_mask)
-    return _xla_attention(q, k, v, bias=bias, mask=mask, causal=causal, scale=scale)
+    return _xla_attention(q, k, v, bias=bias, mask=mask, causal=causal,
+                          scale=scale, window=window)
 
 
-def _flash_on_mesh(q, k, v, *, bias, mask, causal, scale, segment_ids):
+def _flash_on_mesh(q, k, v, *, bias, mask, causal, scale, segment_ids,
+                   window=None):
     """The flash kernel, laid out on the mesh the step is compiled for.
 
     GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
@@ -111,7 +124,8 @@ def _flash_on_mesh(q, k, v, *, bias, mask, causal, scale, segment_ids):
     mesh = resolve_mesh()
     if mesh is None or mesh.size == 1 or bias is not None:
         return flash_attention(q, k, v, bias=bias, mask=mask, causal=causal,
-                               scale=scale, segment_ids=segment_ids)
+                               scale=scale, segment_ids=segment_ids,
+                               window=window)
     if not _shards_evenly(q, k, mesh):
         raise ValueError(
             f"flash attention on mesh {dict(mesh.shape)}: batch {q.shape[0]} "
@@ -129,7 +143,8 @@ def _flash_on_mesh(q, k, v, *, bias, mask, causal, scale, segment_ids):
     def local(qq, kk, vv, *ex):
         return flash_attention(
             qq, kk, vv, mask=ex[0] if has_mask else None, causal=causal,
-            scale=scale, segment_ids=ex[-1] if segment_ids is not None else None)
+            scale=scale, segment_ids=ex[-1] if segment_ids is not None else None,
+            window=window)
 
     return jax.shard_map(
         local, mesh=mesh,
@@ -257,7 +272,8 @@ def _pick_impl(q: jax.Array, k: jax.Array, bias, mask, v=None) -> str:
     return "flash"
 
 
-def _xla_attention(q, k, v, *, bias, mask, causal, scale) -> jax.Array:
+def _xla_attention(q, k, v, *, bias, mask, causal, scale,
+                   window=None) -> jax.Array:
     depth = q.shape[-1]
     scale = scale if scale is not None else depth**-0.5
     # accumulate logits/softmax in f32 regardless of input dtype (bf16-safe)
@@ -268,6 +284,9 @@ def _xla_attention(q, k, v, *, bias, mask, causal, scale) -> jax.Array:
     if causal:
         sq, sk = logits.shape[-2], logits.shape[-1]
         cmask = jnp.tril(jnp.ones((sq, sk), jnp.bool_), k=sk - sq)
+        if window is not None:   # and no farther back than the window
+            cmask = jnp.logical_and(cmask, jnp.triu(
+                jnp.ones((sq, sk), jnp.bool_), k=sk - sq - window + 1))
         logits = jnp.where(cmask, logits, jnp.float32(-1e30))
     if mask is not None:
         logits = jnp.where(mask, logits, jnp.float32(-1e30))

@@ -40,6 +40,18 @@ under a key-padding mask):
   any order (pads at -1). :func:`attn_blocks_walked_share` counts, from the
   same predicate, what is walked of the triangle. Without segment ids the
   three calls take no table.
+- ``window`` (with ``causal``) — the band ``0 <= i - j < window`` of a
+  sliding-window layer: the element mask gains ``i - j < window``, and the
+  two predicates of the walk and of the *whole* class gain the band's bound
+  (:func:`_in_band`). The grid walks only the band: the key axis of the
+  forward and dQ grids spans the band's blocks of a query block (from its
+  first, moved back at the row's end so that every step names a block of the
+  array), the query axis of dK/dV those of a key block (:func:`band_blocks`),
+  so no step is spent above the diagonal or before the band. The calls are
+  then ``flash_window_fwd``, ``flash_window_bwd_dq`` and
+  ``flash_window_bwd_dkv``; they compose with segment ids (the tables are
+  computed with the band) and GQA. :func:`window_blocks_walked_share` counts
+  what they walk of the triangle.
 
 **Three classes of grid step.** A step does only what its block needs, by
 what the kernel can observe (the static regime, the step's ``(qb, kb)`` and,
@@ -155,36 +167,62 @@ def _block_id_range(segs, block: int):
     return jnp.min(blocks, axis=-1), jnp.max(blocks, axis=-1)
 
 
-def _blocks_meet(q_lo, q_hi, k_lo, k_hi, qb, kb, *, causal, block_q, block_k):
+def _in_band(qb, kb, *, causal, block_q, block_k, window=None):
+    """Whether score block ``(qb, kb)`` holds a pair the positions allow:
+    with ``causal`` it does not lie strictly above the diagonal and, with a
+    ``window``, its nearest pair (first query, last key) lies less than
+    ``window`` apart. ``True`` without either."""
+    ok = True
+    if causal:
+        ok = kb * block_k < (qb + 1) * block_q
+    if window is not None:
+        ok = jnp.logical_and(ok, qb * block_q - (kb + 1) * block_k + 1
+                             < window)
+    return ok
+
+
+def _blocks_meet(q_lo, q_hi, k_lo, k_hi, qb, kb, *, causal, block_q, block_k,
+                 window=None):
     """Whether score block ``(qb, kb)`` is walked: the id ranges of its
     queries and of its keys intersect (a superset of "holds an allowed pair"
-    for ids in any order, exact for running ids) and, with ``causal``, it does
-    not lie strictly above the diagonal. On scalars inside the kernels, on
-    arrays in :func:`segment_block_walk`: one predicate for both."""
+    for ids in any order, exact for running ids) and the positions allow a
+    pair in it (:func:`_in_band`: not strictly above the diagonal with
+    ``causal``, inside the band with a ``window``). On scalars inside the
+    kernels, on arrays in :func:`segment_block_walk`: one predicate for
+    both."""
     meet = jnp.logical_and(k_lo <= q_hi, k_hi >= q_lo)
-    if causal:
-        meet = jnp.logical_and(meet, kb * block_k < (qb + 1) * block_q)
+    if causal or window is not None:
+        meet = jnp.logical_and(meet, _in_band(
+            qb, kb, causal=causal, block_q=block_q, block_k=block_k,
+            window=window))
     return meet
 
 
-def _block_whole(q_lo, q_hi, k_lo, k_hi, qb, kb, *, causal, block_q, block_k):
+def _block_whole(q_lo, q_hi, k_lo, k_hi, qb, kb, *, causal, block_q, block_k,
+                 window=None):
     """Whether a WALKED score block ``(qb, kb)`` allows every pair in it, so
     that masking it is the identity: its queries and its keys are all of one
     document (the four bounds equal; ``None`` without segment ids; exact for
-    ids in any order and on a ring hop) and, with ``causal``, its last key
-    does not come after its first query. A key mask is not seen here: a
-    regime with one has no such class. On scalars inside the kernels, on
-    arrays in :func:`attn_blocks_masked_share`: one predicate for both."""
+    ids in any order and on a ring hop), with ``causal`` its last key does
+    not come after its first query and, with a ``window``, its farthest pair
+    (last query, first key) lies less than ``window`` apart. A key mask is
+    not seen here: a regime with one has no such class. On scalars inside
+    the kernels, on arrays in :func:`attn_blocks_masked_share`: one predicate
+    for both."""
     whole = True
     if q_lo is not None:
         whole = jnp.logical_and(jnp.logical_and(q_lo == q_hi, k_lo == k_hi),
                                 q_lo == k_lo)
     if causal:
         whole = jnp.logical_and(whole, (kb + 1) * block_k - 1 <= qb * block_q)
+    if window is not None:
+        whole = jnp.logical_and(
+            whole, (qb + 1) * block_q - 1 - kb * block_k < window)
     return whole
 
 
-def segment_block_walk(q_segs, kv_segs, *, causal, block_q, block_k):
+def segment_block_walk(q_segs, kv_segs, *, causal, block_q, block_k,
+                       window=None):
     """What the three kernels walk under segment ids, from ``q_segs`` and
     ``kv_segs`` [B, S] (they differ on a ring hop).
 
@@ -195,7 +233,8 @@ def segment_block_walk(q_segs, kv_segs, *, causal, block_q, block_k):
     blocks' side. The two tables are the kernels' scalar-prefetch operands:
     the id ranges decide (through :func:`_blocks_meet`) what is computed, the
     hull ``[first, last]`` what the index maps fetch. A row or column with no
-    walked block gets the hull [0, 0]."""
+    walked block gets the hull [0, 0]. With a ``window`` the band bounds the
+    walk too (:func:`_in_band`)."""
     q_lo, q_hi = _block_id_range(q_segs, block_q)
     k_lo, k_hi = _block_id_range(kv_segs, block_k)
     nq, nk = q_lo.shape[1], k_lo.shape[1]
@@ -203,7 +242,7 @@ def segment_block_walk(q_segs, kv_segs, *, causal, block_q, block_k):
     kb = jnp.arange(nk, dtype=jnp.int32)[None, None, :]
     walk = _blocks_meet(q_lo[:, :, None], q_hi[:, :, None], k_lo[:, None, :],
                         k_hi[:, None, :], qb, kb, causal=causal,
-                        block_q=block_q, block_k=block_k)
+                        block_q=block_q, block_k=block_k, window=window)
 
     def hull(ix, n, axis):   # integer min / max: no boolean reduction
         last = jnp.maximum(jnp.max(jnp.where(walk, ix, -1), axis=axis), 0)
@@ -217,18 +256,20 @@ def segment_block_walk(q_segs, kv_segs, *, causal, block_q, block_k):
             pack(k_lo, k_hi, q_first, q_last))
 
 
-def segment_block_classes(q_segs, kv_segs, *, causal, block_q, block_k):
+def segment_block_classes(q_segs, kv_segs, *, causal, block_q, block_k,
+                          window=None):
     """``(walk, whole)`` bool [B, S/block_q, S/block_k]: the blocks the
     kernels compute under these ids and, of them, those that need no mask,
     by the kernels' own two predicates on the tables they are handed."""
     walk, q_side, k_side = segment_block_walk(
-        q_segs, kv_segs, causal=causal, block_q=block_q, block_k=block_k)
+        q_segs, kv_segs, causal=causal, block_q=block_q, block_k=block_k,
+        window=window)
     b, nq, nk = walk.shape
     whole = _block_whole(
         *q_side[:2].reshape(2, b, nq, 1), *k_side[:2].reshape(2, b, 1, nk),
         jnp.arange(nq, dtype=jnp.int32)[None, :, None],
         jnp.arange(nk, dtype=jnp.int32)[None, None, :],
-        causal=causal, block_q=block_q, block_k=block_k)
+        causal=causal, block_q=block_q, block_k=block_k, window=window)
     return walk, jnp.logical_and(walk, whole)
 
 
@@ -267,6 +308,57 @@ def attn_blocks_masked_share(segment_ids, *, block: int = DEFAULT_BLOCK):
     return (walked - jnp.sum(whole)) / jnp.maximum(walked, 1.0)
 
 
+def window_blocks_walked_share(segment_ids, window: int, *,
+                               block: int = DEFAULT_BLOCK):
+    """Blocks the band kernels (:func:`flash_attention` with ``window``)
+    walk under ``segment_ids`` [B, S] over the ``n (n + 1) / 2`` on or under
+    the diagonal, mean over the rows: ``2 n - 1`` blocks, about ``4 / (n +
+    1)``, for a window of one block in a row that is one document, 1.0 for a
+    ``window`` of the whole row. From :func:`segment_block_walk`, the
+    predicate that also hands the kernels their bounds. A length the kernels
+    do not take goes to the XLA path, which computes every pair: 1.0."""
+    s = segment_ids.shape[1]
+    block = min(block, s)
+    if s % block:
+        return jnp.float32(1.0)
+    walk, _, _ = segment_block_walk(segment_ids, segment_ids, causal=True,
+                                    block_q=block, block_k=block,
+                                    window=window)
+    n = s // block
+    return (jnp.mean(jnp.sum(walk, axis=(1, 2)).astype(jnp.float32))
+            / (n * (n + 1) / 2))
+
+
+def band_blocks(window: int, *, block_q: int, block_k: int, num_qb: int,
+                num_kb: int) -> tuple[int, int]:
+    """``(key blocks, query blocks)``: the most key blocks the band of one
+    query block meets, and the most query blocks that of one key block
+    meets; the extents of the band kernels' inner grid axes (forward and dQ
+    walk a query block's keys, dK/dV a key block's queries)."""
+    keys = max((((i + 1) * block_q - 1) // block_k
+                - max(i * block_q - window + 1, 0) // block_k + 1)
+               for i in range(num_qb))
+    queries = max((min(num_qb - 1, ((j + 1) * block_k + window - 2)
+                       // block_q) - j * block_k // block_q + 1)
+                  for j in range(num_kb))
+    return min(keys, num_kb), min(queries, num_qb)
+
+
+def _band_key_block(i, t, *, window, block_q, block_k, num_kb, span):
+    """The key block at step ``t`` of query block ``i``'s band: the band's
+    first, so moved back near the end of the row that every step names a
+    block of the array (a step before the band or past the diagonal is
+    walked by no predicate)."""
+    first = jax.lax.div(jnp.maximum(i * block_q - (window - 1), 0), block_k)
+    return jnp.minimum(first, num_kb - span) + t
+
+
+def _band_query_block(i, t, *, block_q, block_k, num_qb, span):
+    """The query block at step ``t`` of key block ``i``'s band, as
+    :func:`_band_key_block`."""
+    return jnp.minimum(jax.lax.div(i * block_k, block_q), num_qb - span) + t
+
+
 def _within_hull(side, at, j):
     """Index-map helper: block ``j`` clamped to the hull of the walked blocks
     of table entry ``at``, so that a run of skipped steps names the block
@@ -275,19 +367,45 @@ def _within_hull(side, at, j):
 
 
 def _streamed_key_block(heads: int, num_qb: int, *, causal: bool,
-                        block_q: int, block_k: int):
+                        block_q: int, block_k: int, band=None):
     """The key block the forward and dQ grids ``(b, i, j)`` fetch at a step.
     Index maps take the tables last (``*t``: none without segment ids). A step
     that computes nothing names the block already resident: under segment ids
     the hull of the walked ones, else with ``causal`` the row's last block on
-    the diagonal, else (every block is walked) ``j``."""
+    the diagonal (with a window: clamped to the band too), else (every block
+    is walked) ``j``. ``band`` (a window's: ``j`` is a step of the band, not
+    a key block) maps ``(i, j)`` to the key block."""
     def kv_blk(b, i, j, *t):
+        if band is not None:
+            j = band["key_block"](i, j)
         if t:
             return _within_hull(t[0], b // heads * num_qb + i, j)
+        if band is not None:
+            first = jax.lax.div(
+                jnp.maximum(i * block_q - (band["window"] - 1), 0), block_k)
+            return jnp.clip(j, first,
+                            jax.lax.div((i + 1) * block_q - 1, block_k))
         if causal:
             return jnp.minimum(j, jax.lax.div((i + 1) * block_q - 1, block_k))
         return j
     return kv_blk
+
+
+def _band_of(window, *, block_q, block_k, num_qb, num_kb):
+    """What the grids, index maps and kernels of the band regime need of the
+    ``window`` (``None`` without one): the extents of the inner grid axes
+    and the maps from a step of the band to the block it names."""
+    if window is None:
+        return None
+    keys, queries = band_blocks(window, block_q=block_q, block_k=block_k,
+                                num_qb=num_qb, num_kb=num_kb)
+    return {"window": window, "keys": keys, "queries": queries,
+            "key_block": functools.partial(
+                _band_key_block, window=window, block_q=block_q,
+                block_k=block_k, num_kb=num_kb, span=keys),
+            "query_block": functools.partial(
+                _band_query_block, block_q=block_q, block_k=block_k,
+                num_qb=num_qb, span=queries)}
 
 
 def _pallas(kernel, *, name, grid, in_specs, out_specs, out_shape,
@@ -312,7 +430,7 @@ def _pallas(kernel, *, name, grid, in_specs, out_specs, out_shape,
 
 
 def _block_mask(qb, kb, s_blk, *, causal, mask_blk, block_q, block_k,
-                q_seg_blk=None, k_seg_blk=None):
+                q_seg_blk=None, k_seg_blk=None, window=None):
     """(masked logits, allowed bool | None) for one [Bq, Bk] score block.
 
     ``q_seg_blk`` [Bq] / ``k_seg_blk`` [Bk]: packed-sequence segment ids
@@ -320,7 +438,8 @@ def _block_mask(qb, kb, s_blk, *, causal, mask_blk, block_q, block_k,
     documents packed into one row never attend across their boundaries.
     ``q_seg_blk`` arrives sublane-oriented (broadcasts over lanes),
     ``k_seg_blk`` lane-oriented (broadcasts over sublanes) — both broadcast
-    directions are free on the VPU.
+    directions are free on the VPU. A ``window`` (with ``causal``) allows
+    ``0 <= q - k < window``.
     """
     allowed = None
     if causal:
@@ -329,6 +448,8 @@ def _block_mask(qb, kb, s_blk, *, causal, mask_blk, block_q, block_k,
         k_pos = kb * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
         allowed = q_pos >= k_pos
+        if window is not None:
+            allowed = jnp.logical_and(allowed, q_pos - k_pos < window)
     if mask_blk is not None:
         kv_ok = jnp.broadcast_to(mask_blk[None, :] != 0, (block_q, block_k))
         allowed = kv_ok if allowed is None else jnp.logical_and(allowed, kv_ok)
@@ -362,17 +483,20 @@ def _split_refs(refs, fixed: int, has_mask: bool, has_segs: bool):
 
 
 def _by_class(compute, tables, b, qb, kb, *, heads, causal, classed, num_qb,
-              num_kb, block_q, block_k):
+              num_kb, block_q, block_k, window=None):
     """Run ``compute(masked)`` as the class of block ``(qb, kb)`` needs
     (module docstring). *Nothing*: not at all; a skipped block would have
     left the accumulators as they are, to the bit. Walked are, under segment
     ids (``tables``; grid row ``b`` belongs to batch row ``b // heads``), the
     blocks :func:`_blocks_meet` names, else those on or under the diagonal
-    (``causal``), else all. With ``classed`` a walked block that is *whole*
-    by :func:`_block_whole` runs ``compute(False)``, the body without the
-    mask; every other one, and without ``classed`` every walked one,
+    (``causal``; inside the band with a ``window``, :func:`_in_band`), else
+    all. With ``classed`` a walked block that is *whole* by
+    :func:`_block_whole` runs ``compute(False)``, the body without the mask;
+    every other one, and without ``classed`` every walked one,
     ``compute(True)``."""
     bounds = dict(causal=causal, block_q=block_q, block_k=block_k)
+    if window is not None:
+        bounds["window"] = window
     ids = (None,) * 4
     walked = True
     if tables:
@@ -381,8 +505,9 @@ def _by_class(compute, tables, b, qb, kb, *, heads, causal, classed, num_qb,
         ids = (q_side[0, qi], q_side[1, qi], k_side[0, ki], k_side[1, ki])
         walked = _blocks_meet(*ids, qb, kb, **bounds)
     elif causal:
-        # blocks strictly above the diagonal contribute nothing
-        walked = kb * block_k < (qb + 1) * block_q
+        # blocks strictly above the diagonal (or outside the band) contribute
+        # nothing
+        walked = _in_band(qb, kb, **bounds)
     if not classed or not (tables or causal):   # (no mask at all: no edge)
         pl.when(walked)(lambda: compute(True))
         return
@@ -395,14 +520,18 @@ def _by_class(compute, tables, b, qb, kb, *, heads, causal, classed, num_qb,
 
 def _fwd_kernel(*refs, scale: float, causal: bool, has_mask: bool,
                 has_segs: bool, heads: int, num_qb: int, num_kb: int,
-                block_q: int, block_k: int):
+                block_q: int, block_k: int, band=None):
     tables, (q_ref, k_ref, v_ref), mask_ref, qseg_ref, kseg_ref, rest = (
         _split_refs(refs, 3, has_mask, has_segs))  # [1, Bq, D], [1, Bk, D]
     o_ref, lse_ref = rest[:2]                 # [1, Bq, D], [1, Bq, STAT]
     acc_ref, m_ref, l_ref = rest[2:]          # VMEM scratch
-    b, qb, kb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    b, qb, step = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    # (the grid's last axis walks a window's band, or every key block)
+    kb, steps, window = ((step, num_kb, None) if band is None else
+                         (band["key_block"](qb, step), band["keys"],
+                          band["window"]))
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _MASK_VALUE)
@@ -420,7 +549,8 @@ def _fwd_kernel(*refs, scale: float, causal: bool, has_mask: bool,
                 mask_blk=mask_ref[0, 0] if has_mask else None,
                 block_q=block_q, block_k=block_k,
                 q_seg_blk=qseg_ref[0, :, 0] if has_segs else None,
-                k_seg_blk=kseg_ref[0, 0] if has_segs else None)
+                k_seg_blk=kseg_ref[0, 0] if has_segs else None,
+                window=window)
         m_prev = m_ref[:, 0]                              # [Bq]
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
         p = jnp.exp(s - m_cur[:, None])
@@ -439,9 +569,9 @@ def _fwd_kernel(*refs, scale: float, causal: bool, has_mask: bool,
     # PERF.md section 5), and a second body only lengthened its steps
     _by_class(compute, tables, b, qb, kb, heads=heads, causal=causal,
               classed=False, num_qb=num_qb, num_kb=num_kb, block_q=block_q,
-              block_k=block_k)
+              block_k=block_k, window=window)
 
-    @pl.when(kb == num_kb - 1)
+    @pl.when(step == steps - 1)
     def _finalize():
         l = l_ref[:, 0]
         # fully-masked rows (all keys padded): emit 0 output, and an LSE of
@@ -453,7 +583,7 @@ def _fwd_kernel(*refs, scale: float, causal: bool, has_mask: bool,
 
 
 def _flash_fwd(q, k, v, kv_mask, *, scale, causal, group, block_q, block_k,
-               interpret, q_segs=None, kv_segs=None):
+               interpret, q_segs=None, kv_segs=None, window=None):
     bh, s, d = q.shape
     dv = v.shape[-1]   # the value heads' width: o's too, d (q's and k's) or not
     num_qb, num_kb = s // block_q, s // block_k
@@ -464,16 +594,19 @@ def _flash_fwd(q, k, v, kv_mask, *, scale, causal, group, block_q, block_k,
     heads = (bh // kv_mask.shape[0] if has_mask
              else bh // q_segs.shape[0] if has_segs else 0)
     tables = (segment_block_walk(q_segs, kv_segs, causal=causal,
-                                 block_q=block_q, block_k=block_k)[1:]
+                                 block_q=block_q, block_k=block_k,
+                                 window=window)[1:]
               if has_segs else ())
+    band = _band_of(window, block_q=block_q, block_k=block_k, num_qb=num_qb,
+                    num_kb=num_kb)
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, has_mask=has_mask,
         has_segs=has_segs, heads=heads, num_qb=num_qb, num_kb=num_kb,
-        block_q=block_q, block_k=block_k,
+        block_q=block_q, block_k=block_k, band=band,
     )
 
     kv_blk = _streamed_key_block(heads, num_qb, causal=causal,
-                                 block_q=block_q, block_k=block_k)
+                                 block_q=block_q, block_k=block_k, band=band)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j, *t: (b, i, 0)),
         pl.BlockSpec((1, block_k, d),
@@ -498,8 +631,8 @@ def _flash_fwd(q, k, v, kv_mask, *, scale, causal, group, block_q, block_k,
     vmem = _vmem()
     o, lse = _pallas(
         kernel,
-        name="flash_fwd",
-        grid=(bh, num_qb, num_kb),
+        name="flash_fwd" if band is None else "flash_window_fwd",
+        grid=(bh, num_qb, num_kb if band is None else band["keys"]),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, dv), lambda b, i, j, *t: (b, i, 0)),
@@ -527,13 +660,16 @@ def _flash_fwd(q, k, v, kv_mask, *, scale, causal, group, block_q, block_k,
 
 def _bwd_dq_kernel(*refs, scale: float, causal: bool, has_mask: bool,
                    has_segs: bool, heads: int, num_qb: int, num_kb: int,
-                   block_q: int, block_k: int):
+                   block_q: int, block_k: int, band=None):
     tables, fixed, mask_ref, qseg_ref, kseg_ref, (dq_ref, acc_ref) = (
         _split_refs(refs, 6, has_mask, has_segs))
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = fixed
-    b, qb, kb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    b, qb, step = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    kb, steps, window = ((step, num_kb, None) if band is None else
+                         (band["key_block"](qb, step), band["keys"],
+                          band["window"]))
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
@@ -549,7 +685,8 @@ def _bwd_dq_kernel(*refs, scale: float, causal: bool, has_mask: bool,
                 mask_blk=mask_ref[0, 0] if has_mask else None,
                 block_q=block_q, block_k=block_k,
                 q_seg_blk=qseg_ref[0, :, 0] if has_segs else None,
-                k_seg_blk=kseg_ref[0, 0] if has_segs else None)
+                k_seg_blk=kseg_ref[0, 0] if has_segs else None,
+                window=window)
         p = jnp.exp(s - lse_ref[0, :, 0][:, None])                 # [Bq, Bk]
         if allowed is not None:
             p = jnp.where(allowed, p, 0.0)
@@ -563,28 +700,35 @@ def _bwd_dq_kernel(*refs, scale: float, causal: bool, has_mask: bool,
     # (what a key block's mask holds is not known here: no *whole* class)
     _by_class(compute, tables, b, qb, kb, heads=heads, causal=causal,
               classed=not has_mask, num_qb=num_qb, num_kb=num_kb,
-              block_q=block_q, block_k=block_k)
+              block_q=block_q, block_k=block_k, window=window)
 
-    @pl.when(kb == num_kb - 1)
+    @pl.when(step == steps - 1)
     def _finalize():
         dq_ref[0] = (acc_ref[:] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(*refs, scale: float, causal: bool, has_mask: bool,
                     has_segs: bool, heads: int, num_qb: int, num_kb: int,
-                    group: int, block_q: int, block_k: int):
+                    group: int, block_q: int, block_k: int, band=None):
     """dK/dV for ONE kv head, accumulating over its `group` q heads × q blocks.
 
     Grid: (B·Hkv, num_kb, group·num_qb) — the innermost index j interleaves
     (q head in group, q block); the index maps select q row b·group + j//num_qb.
-    ``heads`` here counts the KV heads of a batch row.
+    With a window's ``band`` the q blocks are the band's: ``num_qb`` there is
+    ``band["queries"]``, the steps of one head. ``heads`` here counts the KV
+    heads of a batch row.
     """
     tables, fixed, mask_ref, qseg_ref, kseg_ref, rest = (
         _split_refs(refs, 6, has_mask, has_segs))
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = fixed
     dk_ref, dv_ref, dk_acc, dv_acc = rest
     b, kb, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    qb = j % num_qb
+    if band is None:
+        steps, window = num_qb, None
+        qb = j % num_qb
+    else:
+        steps, window = band["queries"], band["window"]
+        qb = band["query_block"](kb, j % steps)
 
     @pl.when(j == 0)
     def _init():
@@ -603,7 +747,8 @@ def _bwd_dkv_kernel(*refs, scale: float, causal: bool, has_mask: bool,
                 mask_blk=mask_ref[0, 0] if has_mask else None,
                 block_q=block_q, block_k=block_k,
                 q_seg_blk=qseg_ref[0, :, 0] if has_segs else None,
-                k_seg_blk=kseg_ref[0, 0] if has_segs else None)
+                k_seg_blk=kseg_ref[0, 0] if has_segs else None,
+                window=window)
         p = jnp.exp(s - lse_ref[0, :, 0][:, None])                 # [Bq, Bk]
         if allowed is not None:
             p = jnp.where(allowed, p, 0.0)
@@ -623,15 +768,16 @@ def _bwd_dkv_kernel(*refs, scale: float, causal: bool, has_mask: bool,
     # (what a key block's mask holds is not known here: no *whole* class)
     _by_class(compute, tables, b, qb, kb, heads=heads, causal=causal,
               classed=not has_mask, num_qb=num_qb, num_kb=num_kb,
-              block_q=block_q, block_k=block_k)
+              block_q=block_q, block_k=block_k, window=window)
 
-    @pl.when(j == group * num_qb - 1)
+    @pl.when(j == group * steps - 1)
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _flash_bwd(res, g, *, scale, causal, group, block_q, block_k, interpret):
+def _flash_bwd(res, g, *, scale, causal, group, block_q, block_k, interpret,
+               window=None):
     q, k, v, kv_mask, o, lse = res[:6]
     q_segs = res[6] if len(res) > 6 else None
     kv_segs = res[7] if len(res) > 7 else None
@@ -644,8 +790,11 @@ def _flash_bwd(res, g, *, scale, causal, group, block_q, block_k, interpret):
     heads = (bh // kv_mask.shape[0] if has_mask
              else bh // q_segs.shape[0] if has_segs else 0)
     tables = (segment_block_walk(q_segs, kv_segs, causal=causal,
-                                 block_q=block_q, block_k=block_k)[1:]
+                                 block_q=block_q, block_k=block_k,
+                                 window=window)[1:]
               if has_segs else ())
+    band = _band_of(window, block_q=block_q, block_k=block_k, num_qb=num_qb,
+                    num_kb=num_kb)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     # row stats travel as [bh, s, STAT_LANES] (Mosaic block rule — see module
     # docstring); the replication is a cheap transient, the residual is 2-D
@@ -655,11 +804,11 @@ def _flash_bwd(res, g, *, scale, causal, group, block_q, block_k, interpret):
     mask3 = kv_mask[:, None, :] if has_mask else None
     shared = dict(scale=scale, causal=causal, has_mask=has_mask,
                   has_segs=has_segs, num_qb=num_qb, num_kb=num_kb,
-                  block_q=block_q, block_k=block_k)
+                  block_q=block_q, block_k=block_k, band=band)
     vmem = _vmem()
 
     kv_blk = _streamed_key_block(heads, num_qb, causal=causal,
-                                 block_q=block_q, block_k=block_k)
+                                 block_q=block_q, block_k=block_k, band=band)
     q_row = lambda b, i, j, *t: (b, i, 0)
     kv_row = lambda b, i, j, *t: (b // group, kv_blk(b, i, j, *t), 0)
     lane_spec = pl.BlockSpec(
@@ -684,8 +833,8 @@ def _flash_bwd(res, g, *, scale, causal, group, block_q, block_k, interpret):
         operands.append(kv_segs[:, None, :])
     dq = _pallas(
         functools.partial(_bwd_dq_kernel, heads=heads, **shared),
-        name="flash_bwd_dq",
-        grid=(bh, num_qb, num_kb),
+        name="flash_bwd_dq" if band is None else "flash_window_bwd_dq",
+        grid=(bh, num_qb, num_kb if band is None else band["keys"]),
         in_specs=in_specs_q,
         out_specs=[pl.BlockSpec((1, block_q, d), q_row)],
         out_shape=[jax.ShapeDtypeStruct((bh, s, d), q.dtype)],
@@ -699,15 +848,23 @@ def _flash_bwd(res, g, *, scale, causal, group, block_q, block_k, interpret):
     kvheads = (bhkv // kv_mask.shape[0] if has_mask
                else bhkv // kv_segs.shape[0] if has_segs else 0)
 
+    # the query blocks one head's steps walk: every one, or a window's band
+    steps_q = num_qb if band is None else band["queries"]
+
     def q_blk(b, i, j, *t):  # the query block streamed at this step
-        qb = j % num_qb
+        qb = (j % num_qb if band is None
+              else band["query_block"](i, j % steps_q))
         if t:
             return _within_hull(t[1], b // kvheads * num_kb + i, qb)
+        if band is not None:   # clamped to the band of key block i
+            return jnp.clip(qb, jax.lax.div(i * block_k, block_q), jnp.minimum(
+                num_qb - 1, jax.lax.div((i + 1) * block_k + window - 2,
+                                        block_q)))
         if causal:  # each head's first query block at or under the key block
             return jnp.maximum(qb, jax.lax.div(i * block_k, block_q))
         return qb
 
-    q_head = lambda b, j: b * group + j // num_qb
+    q_head = lambda b, j: b * group + j // steps_q
     q_stream = lambda b, i, j, *t: (q_head(b, j), q_blk(b, i, j, *t), 0)
     kv_own = lambda b, i, j, *t: (b, i, 0)
     in_specs_kv = [
@@ -734,8 +891,8 @@ def _flash_bwd(res, g, *, scale, causal, group, block_q, block_k, interpret):
     dk, dv = _pallas(
         functools.partial(_bwd_dkv_kernel, heads=kvheads, group=group,
                           **shared),
-        name="flash_bwd_dkv",
-        grid=(bhkv, num_kb, group * num_qb),
+        name="flash_bwd_dkv" if band is None else "flash_window_bwd_dkv",
+        grid=(bhkv, num_kb, group * steps_q),
         in_specs=in_specs_kv,
         out_specs=[pl.BlockSpec((1, block_k, d), kv_own),
                    pl.BlockSpec((1, block_k, dv), kv_own)],
@@ -757,29 +914,32 @@ def _flash_bwd(res, g, *, scale, causal, group, block_q, block_k, interpret):
 # public API
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11, 12))
 def _flash(q, k, v, kv_mask, q_segs, kv_segs, scale, causal, group, block_q,
-           block_k, interpret):
+           block_k, interpret, window):
     o, _ = _flash_fwd(q, k, v, kv_mask, scale=scale, causal=causal,
                       group=group, block_q=block_q, block_k=block_k,
-                      interpret=interpret, q_segs=q_segs, kv_segs=kv_segs)
+                      interpret=interpret, q_segs=q_segs, kv_segs=kv_segs,
+                      window=window)
     return o
 
 
 def _flash_vjp_fwd(q, k, v, kv_mask, q_segs, kv_segs, scale, causal, group,
-                   block_q, block_k, interpret):
+                   block_q, block_k, interpret, window):
     o, lse = _flash_fwd(q, k, v, kv_mask, scale=scale, causal=causal,
                         group=group, block_q=block_q, block_k=block_k,
-                        interpret=interpret, q_segs=q_segs, kv_segs=kv_segs)
+                        interpret=interpret, q_segs=q_segs, kv_segs=kv_segs,
+                        window=window)
     o, lse = (checkpoint_name(o, FLASH_OUT_NAME),
               checkpoint_name(lse, FLASH_OUT_NAME))
     return o, (q, k, v, kv_mask, o, lse, q_segs, kv_segs)
 
 
-def _flash_vjp_bwd(scale, causal, group, block_q, block_k, interpret, res, g):
+def _flash_vjp_bwd(scale, causal, group, block_q, block_k, interpret, window,
+                   res, g):
     dq, dk, dv = _flash_bwd(res, g, scale=scale, causal=causal, group=group,
                             block_q=block_q, block_k=block_k,
-                            interpret=interpret)
+                            interpret=interpret, window=window)
     return dq, dk, dv, None, None, None
 
 
@@ -821,6 +981,7 @@ def flash_attention(
     causal: bool = False,
     scale: float | None = None,
     segment_ids: jax.Array | None = None,
+    window: int | None = None,
     block_q: int = DEFAULT_BLOCK,
     block_k: int = DEFAULT_BLOCK,
     interpret: bool | None = None,
@@ -836,8 +997,13 @@ def flash_attention(
     segment_ids[b, j]``, so multiple short documents packed into one row
     never attend across boundaries; streamed blockwise (q side sublane-
     oriented, k side lane-oriented), composes with ``mask`` and ``causal``.
-    ``interpret=None`` auto-selects interpreter mode off-TPU so tests run on
-    CPU; on TPU the kernel compiles via Mosaic.
+    ``window`` (with ``causal``; a static positive int): query ``i`` sees key
+    ``j`` only where ``i - j < window`` (``window`` keys, itself among them);
+    the three calls are then ``flash_window_fwd``, ``flash_window_bwd_dq``
+    and ``flash_window_bwd_dkv``, whose grids span the band's blocks
+    (:func:`band_blocks`) and not the row's, and compose with segment ids,
+    GQA and a key mask. ``interpret=None`` auto-selects interpreter mode
+    off-TPU so tests run on CPU; on TPU the kernel compiles via Mosaic.
     """
     if bias is not None:
         raise NotImplementedError(
@@ -852,6 +1018,10 @@ def flash_attention(
     if h % hkv:
         raise ValueError(f"q heads {h} must be a multiple of kv heads {hkv}")
     group = h // hkv
+    if window is not None and (not causal or isinstance(window, bool)
+                               or not isinstance(window, int) or window < 1):
+        raise ValueError(f"window {window!r}: a positive int, with causal "
+                         f"(got causal={causal})")
     kv_mask = as_kv_mask(mask, b, sk) if mask is not None else None
     segs = None
     if segment_ids is not None:
@@ -887,5 +1057,5 @@ def flash_attention(
         return x.transpose(0, 2, 1, 3).reshape(bb * hh, ss, dd)
 
     o = _flash(flat(q), flat(k), flat(v), kv_mask, segs, segs,
-               scale, causal, group, block_q, block_k, interpret)
+               scale, causal, group, block_q, block_k, interpret, window)
     return o.reshape(b, h, sq, v.shape[-1]).transpose(0, 2, 1, 3)

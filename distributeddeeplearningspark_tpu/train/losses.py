@@ -170,14 +170,17 @@ def hybrid_moe_lm(outputs: dict[str, jax.Array], batch: dict[str, Any]
     """The loss of :class:`~..models.hybrid_decoder.HybridDecoderLM`:
     next-token cross-entropy over the vocabulary the model holds, fused with
     the (tied) head, at every position but the window's last; a target across
-    a document boundary is kept. No router term: the sigmoid router is
-    balanced by its bias, which the step moves outside the gradient. The
-    model's counters (``hybrid_decoder.COUNTERS`` and, of a model with
-    state-space layers, ``SSM_COUNTERS``) ride along into the step's
-    metrics."""
+    a document boundary is kept. The sigmoid router has no loss term: it is
+    balanced by its bias, which the step moves outside the gradient. A
+    softmax router's Switch term (``moe_aux``, already weighted and summed
+    over the expert layers by the model) is added: ``loss = lm_loss +
+    moe_aux``. The model's counters (``hybrid_decoder.COUNTERS`` and, of a
+    model with state-space or sliding-window layers, ``SSM_COUNTERS`` or
+    ``WINDOW_COUNTERS``) ride along into the step's metrics."""
     from distributeddeeplearningspark_tpu.models.hybrid_decoder import (
         COUNTERS,
         SSM_COUNTERS,
+        WINDOW_COUNTERS,
     )
     from distributeddeeplearningspark_tpu.train.fused_ce import (
         chunked_softmax_xent,
@@ -187,8 +190,13 @@ def hybrid_moe_lm(outputs: dict[str, jax.Array], batch: dict[str, Any]
                                    outputs["lm_head"],
                                    batch["input_ids"][:, 1:])
     loss, metrics = _reduce_next_token(per_tok, batch)
+    if "moe_aux" in outputs:
+        metrics = {**metrics, "lm_loss": loss, "moe_aux": outputs["moe_aux"]}
+        loss = loss + outputs["moe_aux"]
+        metrics["loss"] = loss
     return loss, {**metrics, **{k: outputs[k] for k in COUNTERS},
-                  **{k: outputs[k] for k in SSM_COUNTERS if k in outputs}}
+                  **{k: outputs[k] for k in SSM_COUNTERS + WINDOW_COUNTERS
+                     if k in outputs}}
 
 
 def latent_moe_lm(outputs: dict[str, jax.Array], batch: dict[str, Any]

@@ -174,6 +174,11 @@ FLASH_REGIMES = {
     # one window of the benchmark's sixth configuration (fit_seg16k): 32
     # query heads on 2 key-value heads of 128, packed documents
     "cell_seg16k_gqa16": (1, 16384, 32, 2, 128, True, False, True),
+    # one window of the benchmark's seventh configuration (fit_swa16k): its
+    # sliding layers (64 query heads on 8, the band of 512: a ninth field is
+    # the window) and its full layers (48 on 8)
+    "cell_swa16k": (1, 16384, 64, 8, 128, True, False, True, 512),
+    "cell_swa16k_full": (1, 16384, 48, 8, 128, True, False, True),
 }
 
 
@@ -182,7 +187,8 @@ def _flash_regime(regime, sharding=None):
     from distributeddeeplearningspark_tpu.ops.flash_attention import (
         flash_attention)
 
-    b, s, h, hkv, d, causal, masked, segmented = FLASH_REGIMES[regime]
+    b, s, h, hkv, d, causal, masked, segmented, *window = FLASH_REGIMES[
+        regime]
     d_qk, d_v = d if isinstance(d, tuple) else (d, d)
 
     def sds(shape, dtype=jnp.bfloat16):
@@ -195,7 +201,8 @@ def _flash_regime(regime, sharding=None):
         o = flash_attention(
             q, k, v, causal=causal, interpret=False,
             mask=extra[0] if masked else None,
-            segment_ids=extra[-1] if segmented else None)
+            segment_ids=extra[-1] if segmented else None,
+            window=window[0] if window else None)
         return jnp.sum(o.astype(jnp.float32) ** 2)
 
     return scalar, args
@@ -214,6 +221,8 @@ def test_flash_kernel_compiles_in_every_regime(one_chip, no_compile_cache,
     text = jax.jit(fn).lower(*args).compile().as_text()
     kernels = (("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv") if grad
                else ("flash_fwd",))
+    if len(FLASH_REGIMES[regime]) > 8:      # the band's calls
+        kernels = tuple(k.replace("flash_", "flash_window_") for k in kernels)
     for name in kernels:
         assert name in text, name
     assert text.count("tpu_custom_call") >= len(kernels)
@@ -230,7 +239,7 @@ def test_flash_bounds_operands_follow_the_segment_ids(regime):
     from distributeddeeplearningspark_tpu.ops.flash_attention import (
         DEFAULT_BLOCK)
 
-    b, s, h, hkv, _, _, masked, segmented = FLASH_REGIMES[regime]
+    b, s, h, hkv, _, _, masked, segmented, *window = FLASH_REGIMES[regime]
     scalar, args = _flash_regime(regime)
 
     calls = {}
@@ -242,11 +251,16 @@ def test_flash_bounds_operands_follow_the_segment_ids(regime):
             grid_mapping.grid)
     tables = 2 * segmented
     extra = masked + 2 * segmented + tables
-    n = s // min(DEFAULT_BLOCK, s)
+    block = min(DEFAULT_BLOCK, s)
+    n = s // block
+    # a band's inner axis spans the blocks it can meet, ceil((w - 1) / block)
+    # + 1 of them, and the calls carry the band's names
+    span = -(-(window[0] - 1) // block) + 1 if window else n
+    name = "flash_window_" if window else "flash_"
     assert calls == {
-        "flash_fwd": (3 + extra, tables, (b * h, n, n)),
-        "flash_bwd_dq": (6 + extra, tables, (b * h, n, n)),
-        "flash_bwd_dkv": (6 + extra, tables, (b * hkv, n, h // hkv * n)),
+        name + "fwd": (3 + extra, tables, (b * h, n, span)),
+        name + "bwd_dq": (6 + extra, tables, (b * h, n, span)),
+        name + "bwd_dkv": (6 + extra, tables, (b * hkv, n, h // hkv * span)),
     }
 
 
@@ -425,6 +439,10 @@ PARENT_FLASH_LOWERINGS = {
     "gqa_causal_segments_d64": "252ea21bfb3e9ecc",
     "cell_seg32k": "088695e3219ba773",
     "cell_mla_s16k": "c68d4331ee8a8f3a",
+    # taken in the tree of PR 38, the parent of the band (window=None):
+    # fit_seg16k's regime, and the regime fit_swa16k's full layers run
+    "cell_seg16k_gqa16": "ceadac247c19d353",
+    "cell_swa16k_full": "285d38884e87dfd2",
 }
 #: the same of ``lfm2_24b_a2b.fit_seg32k``'s whole train step (the
 #: configuration's model, loss and optimizer at the cell's window), in the
@@ -546,3 +564,25 @@ def test_the_nemotron_train_step_compiles_for_one_chip_with_room_for_the_check(
     live = (max(ma.argument_size_in_bytes, ma.output_size_in_bytes)
             + ma.temp_size_in_bytes)
     assert 0.25 * 16 * 2 ** 30 < live < 13.5 * 2 ** 30, live / 2 ** 30
+
+
+def test_the_laguna_train_step_compiles_for_one_chip_with_room_for_the_check(
+        four_chips, no_compile_cache, routed_as_on_the_chip):
+    """``laguna_xs2.fit_swa16k``'s whole step for ONE described chip: the
+    band kernels (64 / 8 heads of 128, the window of 512) beside the causal
+    ones (48 / 8) with segment ids, no array of a layer's scores, and 490.3M
+    parameters' state and the step's temporaries inside what the check after
+    the window leaves of 16 GiB."""
+    compiled = _lowered_step_of("laguna_xs2", "fit_swa16k", four_chips,
+                                True).compile()
+    text = compiled.as_text()
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                 "flash_window_fwd", "flash_window_bwd_dq",
+                 "flash_window_bwd_dkv"):
+        assert name in text, name
+    assert ",16384,16384]" not in text           # no [.., S, S] array
+    ma = compiled.memory_analysis()
+    live = (max(ma.argument_size_in_bytes, ma.output_size_in_bytes)
+            + ma.temp_size_in_bytes)
+    # (10.5 GiB here: 5.5 of state, 5.0 of temporaries)
+    assert 0.25 * 16 * 2 ** 30 < live < 12 * 2 ** 30, live / 2 ** 30

@@ -94,10 +94,12 @@ def test_configuration_file_states_the_catalog_and_its_cuts():
     for name in NEW_METRICS:
         metric = next(m for m in bench["per_layer"] if m["name"] == name)
         # (the gated convolution is this model's alone; the six others
-        # serve the state-space decoder's cell too, appended by PR 37)
+        # serve the state-space decoder's cell too, appended by PR 37, and
+        # the sliding-window decoder's, appended by PR 39)
         assert metric["workloads"] == [CELL] + (
             [] if name.startswith("shortconv_")
-            else ["nemotron3_nano_30b_a3b.fit_seg16k"])
+            else ["nemotron3_nano_30b_a3b.fit_seg16k",
+                  "laguna_xs2.fit_swa16k"])
         assert metric["moves"] == "throughput"
         assert os.path.exists(os.path.join(
             ROOT, "benchmark", "layer_metrics", name + ".py"))

@@ -52,6 +52,12 @@ FAMILIES = {
     "ssm_moe_lm": ("train_ssm_moe_lm.py", [
         "--master", "local[8]", "--variant", "tiny", "--seq-len", "128",
         "--batch-size", "8"]),
+    # sliding-window layers among full ones (the band on the XLA path off
+    # the TPU), per-head gates, softmax experts with Switch's term beside a
+    # shared one
+    "window_moe_lm": ("train_window_moe_lm.py", [
+        "--master", "local[8]", "--variant", "tiny", "--seq-len", "128",
+        "--batch-size", "8"]),
 }
 
 #: counters of ``spans.COUNTERS`` that only some feeds write: the map's

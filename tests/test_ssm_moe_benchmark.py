@@ -23,7 +23,7 @@ CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 TRACE_METRICS = ("ssd_ms_per_step", "ssd_roofline", "mamba_conv_ms_per_step")
 COUNTER_METRICS = ("ssm_chunks_reset_share", "ssm_state_abs_max")
 #: readers the benchmark had, whose layers this cell runs: its name is the
-#: last of their ``workloads`` (an accepted list may be appended to)
+#: among their ``workloads`` (an accepted list may be appended to)
 ACCEPTED_COUNTERS = ("experts_load_max_over_mean", "attn_pairs_share",
                      "attn_blocks_walked_share", "attn_blocks_masked_share")
 ACCEPTED_TRACE = ("experts_ms_per_step", "flash_causal_ms_per_step",
@@ -139,7 +139,7 @@ def test_configuration_file_states_the_catalog_and_its_cuts():
     assert better["ssm_state_abs_max"] == "lower"
     for name in ACCEPTED_COUNTERS + ACCEPTED_TRACE + ACCEPTED_START:
         metric = next(m for m in bench["per_layer"] if m["name"] == name)
-        assert metric["workloads"][-1] == CELL, name
+        # (appended once; a later cell's name may follow it)
         assert metric["workloads"].count(CELL) == 1, name
     if not os.path.exists(CATALOG):
         return
