@@ -2,7 +2,8 @@
 between two gates, the operator of a hybrid decoder's convolution layers
 (:mod:`..models.hybrid_decoder`), with its taps cut at document boundaries;
 and, beside it, the UNGATED form with a bias and a SiLU that a state-space
-layer runs before its scan (:func:`silu_short_conv`, plain XLA).
+layer runs before its scan (:func:`silu_short_conv`, two kernels of its own:
+the end of this docstring).
 
 ``bcx [B, S, 3C]`` is the block's input projection, ``(b, c, u) =
 split3(bcx)`` along the last axis, ``w [C, K]`` the taps (depthwise, no
@@ -31,6 +32,31 @@ the row-statistics layout of the flash kernel (``[B, S, 8]`` int32): lane 0
 the position's id, lanes ``1 .. K-1`` the ids of the positions before it,
 lanes ``K .. 2K-2`` of those after it, with a sentinel past the row's ends,
 so a tap is read where two lanes are equal.
+
+The ungated form, ``y = silu(bias + z)`` with ``z`` over ``v`` itself, has
+two kernels of its own, ``mamba_conv_fwd`` and ``mamba_conv_bwd``, that share
+only the segment-id lanes and the rotation with the gated ones (two gates and
+no bias there, a bias and a SiLU and no gate here). They run on ``v`` with
+its POSITIONS MINOR, ``[B, C, S]``, blocks of ``block_c`` channels (sublanes)
+by ``block_t`` positions (lanes): XLA lays out the state-space layer's input
+projection, and every array of the layer around the convolution, with the
+positions minor, so the transposes either side of the kernels are changes of
+layout that cost nothing, where kernels on ``[B, S, C]`` made XLA lay the
+whole layer out the other way and add relayouts of the scan's inputs and
+outputs that cost what the kernels saved (PERF.md section 6). A shift
+along the positions is a lane rotation, and a block's halos are the 128
+positions before it and after it. Forward reads ``v`` and writes ``y`` in
+``v``'s dtype, float32 inside. Backward reads ``v`` and ``dy``, recomputes
+``z``, forms ``dz = dy silu'(z + bias)`` and writes ``dv``. Since ``dv[t]``
+needs ``dz`` at ``t + 1 .. t + K-1``, its after-halo carries ``v`` AND ``dy``
+of the 128 positions after the block, with those positions' segment ids:
+``z`` there comes from them and the block's own last positions, under the
+same mask. The segment ids travel as ``[B, 8, S]``, the gated form's lanes
+turned to rows. The taps and the bias travel as one ``[C, 8]`` float32 array,
+tap ``j`` in column ``j`` and the bias in column ``K`` (the bias lane, free
+while ``K <= MAX_TAPS``); the taps' and the bias's gradients come back in the
+same columns of a ``[B, C, 8]`` side output, summed over the position blocks
+in place and over the batch outside.
 """
 
 from __future__ import annotations
@@ -53,6 +79,11 @@ from distributeddeeplearningspark_tpu.utils.env import on_tpu, pallas_interpret
 DEFAULT_BLOCK_T = 512
 DEFAULT_BLOCK_C = 512
 HALO = 8          # rows of the neighbouring block a kernel is handed
+LANES = 128       # positions of it, where the positions are the lanes
+#: the ungated kernels' blocks, positions by channels: long and narrow, so a
+#: block's halos of LANES positions are a small share of what it reads
+#: (tools/mamba_conv_cost.py times others)
+MAMBA_BLOCK_T, MAMBA_BLOCK_C = 4096, 64
 MAX_TAPS = (STAT_LANES + 1) // 2
 #: ids of no document: before a row's first position, after its last
 _BEFORE, _AFTER = -(2 ** 31), -(2 ** 31) + 1
@@ -99,32 +130,20 @@ def gated_short_conv_xla(bcx, w, segment_ids=None):
     return (gate_c * _taps_xla(gate_b * u, w, segment_ids)).astype(bcx.dtype)
 
 
-def silu_short_conv(v, w, bias, segment_ids=None):
-    """The UNGATED form, a state-space layer's (:mod:`..models.
-    hybrid_decoder`): ``v [B, S, C], w [C, K], bias [C], segment_ids [B, S]
-    | None -> y [B, S, C]``, ``y[t] = silu(bias + sum_j w[:, j] v[t - (K-1) +
-    j])`` over the terms inside ``t``'s document; float32 inside, ``v``'s
-    dtype out, differentiable in ``v``, ``w`` and ``bias``. Plain
-    ``jax.numpy`` on the TPU too, and it costs what that costs: at 16,384 x
-    6,144 the chip's trace shows seven passes over float32 arrays of 0.4 GB
-    a layer and step, forward, replay and backward (47 ms a step where one
-    read of ``v`` and one write of ``y`` and of each cotangent need 3:
-    PERF.md sections 5 and 6, PR 37). A kernel beside the gated form's is
-    the next step; it was left out of the PR that brought the layer."""
-    if v.shape[-1] != w.shape[0] or bias.shape != w.shape[:1]:
-        raise ValueError(f"v {v.shape} against taps {w.shape} and bias "
-                         f"{bias.shape}: want [B, S, C], [C, K] and [C]")
+def silu_short_conv_xla(v, w, bias, segment_ids=None):
+    """The ungated form in plain ``jax.numpy`` (float32 inside, ``v``'s dtype
+    out): the path off the TPU and for shapes the kernels do not take."""
     z = _taps_xla(v.astype(jnp.float32), w, segment_ids)
     return jax.nn.silu(z + bias.astype(jnp.float32)).astype(v.dtype)
 
 
-def _roll(x, shift: int, interpret: bool):
-    """``x`` rotated ``shift`` rows towards higher indices."""
+def _roll(x, shift: int, interpret: bool, axis: int = 0):
+    """``x`` rotated ``shift`` rows (or columns) towards higher indices."""
     if interpret:
-        return jnp.roll(x, shift, axis=0)
+        return jnp.roll(x, shift, axis=axis)
     from jax.experimental.pallas import tpu as pltpu
 
-    return pltpu.roll(x, shift % x.shape[0], 0)
+    return pltpu.roll(x, shift % x.shape[axis], axis)
 
 
 def _before(v, halo, d: int, row, interpret: bool):
@@ -344,30 +363,28 @@ def gated_short_conv_pallas(bcx, w, segment_ids=None, *,
                       block_t, block_c, pallas_interpret(interpret))
 
 
-def gated_short_conv(bcx, w, segment_ids=None, *, impl: str = "auto"):
-    """``bcx [B, S, 3C], w [C, K], segment_ids [B, S] | None -> y [B, S, C]``
-    (module docstring). ``impl``: ``"pallas"``, ``"xla"``, or ``"auto"``: the
-    kernels on a TPU when the shape divides by their blocks, else XLA. On a
-    mesh of more than one device the kernels run in a ``shard_map`` over the
-    batch rows (GSPMD cannot partition a Mosaic call, as for the flash
-    kernel); the taps' gradient is summed over the rows' ranks by the
-    ``shard_map``'s transpose."""
+def _route(impl: str, fits: bool) -> str:
+    """``impl`` resolved: ``"auto"`` is the kernels on a TPU where the shape
+    ``fits`` their blocks, else XLA."""
+    if impl == "auto":
+        impl = "pallas" if on_tpu() and fits else "xla"
+    if impl not in ("pallas", "xla"):
+        raise ValueError(f"unknown short-convolution impl {impl!r}")
+    return impl
+
+
+def _over_batch_rows(kernels, x, params, segment_ids):
+    """``kernels(x, *params, segment_ids)``; on a mesh of more than one device
+    in a ``shard_map`` over the batch rows (GSPMD cannot partition a Mosaic
+    call, as for the flash kernel), ``params`` whole on every rank, their
+    gradients summed over the rows' ranks by the ``shard_map``'s
+    transpose."""
     from distributeddeeplearningspark_tpu.ops.ring_attention import resolve_mesh
 
-    b, s, _ = bcx.shape
-    c, taps = w.shape
-    if impl == "auto":
-        block_t, block_c = _blocks(s, c, DEFAULT_BLOCK_T, DEFAULT_BLOCK_C)
-        fits = not (s % block_t or c % block_c or block_t % 16
-                    or block_c % 128 or taps > MAX_TAPS)
-        impl = "pallas" if on_tpu() and fits else "xla"
-    if impl == "xla":
-        return gated_short_conv_xla(bcx, w, segment_ids)
-    if impl != "pallas":
-        raise ValueError(f"unknown short-convolution impl {impl!r}")
     mesh = resolve_mesh()
     if mesh is None or mesh.size == 1:
-        return gated_short_conv_pallas(bcx, w, segment_ids)
+        return kernels(x, *params, segment_ids)
+    b, s = x.shape[:2]
     rows = mesh.shape[BATCH_AXES[0]] * mesh.shape[BATCH_AXES[1]]
     if b % rows:
         raise ValueError(f"short convolution on mesh {dict(mesh.shape)}: "
@@ -375,6 +392,232 @@ def gated_short_conv(bcx, w, segment_ids=None, *, impl: str = "auto"):
     seg = (jnp.zeros((b, s), jnp.int32) if segment_ids is None
            else jnp.asarray(segment_ids, jnp.int32))
     return jax.shard_map(
-        gated_short_conv_pallas, mesh=mesh,
-        in_specs=(P(BATCH_AXES, None, None), P(), P(BATCH_AXES, None)),
-        out_specs=P(BATCH_AXES, None, None), check_vma=False)(bcx, w, seg)
+        kernels, mesh=mesh,
+        in_specs=(P(BATCH_AXES, None, None), *[P()] * len(params),
+                  P(BATCH_AXES, None)),
+        out_specs=P(BATCH_AXES, None, None), check_vma=False)(x, *params, seg)
+
+
+def gated_short_conv(bcx, w, segment_ids=None, *, impl: str = "auto"):
+    """``bcx [B, S, 3C], w [C, K], segment_ids [B, S] | None -> y [B, S, C]``
+    (module docstring). ``impl``: ``"pallas"``, ``"xla"``, or ``"auto"``: the
+    kernels on a TPU when the shape divides by their blocks, else XLA. On a
+    mesh of more than one device the kernels run in a ``shard_map`` over the
+    batch rows."""
+    s, (c, taps) = bcx.shape[1], w.shape
+    block_t, block_c = _blocks(s, c, DEFAULT_BLOCK_T, DEFAULT_BLOCK_C)
+    fits = not (s % block_t or c % block_c or block_t % 16
+                or block_c % 128 or taps > MAX_TAPS)
+    if _route(impl, fits) == "xla":
+        return gated_short_conv_xla(bcx, w, segment_ids)
+    return _over_batch_rows(gated_short_conv_pallas, bcx, (w,), segment_ids)
+
+
+# -- the ungated form: a bias and a SiLU, no gate -----------------------------
+
+def _silu_grad(u):
+    """``d silu(u) / du``, float32."""
+    sig = jax.nn.sigmoid(u)
+    return sig * (1.0 + u * (1.0 - sig))
+
+
+def _conv_lanes(ext, seg, wb, *, taps: int, interpret: bool):
+    """``(z, [v, v[t-1] masked, v[t-2] masked, ...])`` of one block ``v [C,
+    T]``: ``ext [C, LANES + T]`` is the ``LANES`` positions before the block
+    and then the block, so ``v[t - d]`` is ``ext`` rotated ``d`` lanes;
+    ``seg [8, T]`` the block's segment-id rows, ``wb [C, 8]`` taps and
+    bias."""
+    v = ext[:, LANES:]
+    terms = [v]
+    z = wb[:, taps - 1:taps] * v
+    for d in range(1, taps):
+        ok = seg[0:1, :] == seg[d:d + 1, :]
+        vd = jnp.where(ok, _roll(ext, d, interpret, axis=1)[:, LANES:], 0.0)
+        terms.append(vd)
+        z = z + wb[:, taps - 1 - d:taps - d] * vd
+    return z, terms
+
+
+def _silu_fwd_kernel(v_ref, hv_ref, seg_ref, wb_ref, y_ref, *, taps: int,
+                     interpret: bool):
+    f32 = jnp.float32
+    wb = wb_ref[...]
+    ext = jnp.concatenate([hv_ref[0], v_ref[0]], axis=1).astype(f32)
+    z, _ = _conv_lanes(ext, seg_ref[0], wb, taps=taps, interpret=interpret)
+    u = z + wb[:, taps:taps + 1]
+    y_ref[0] = (u * jax.nn.sigmoid(u)).astype(y_ref.dtype)
+
+
+def _silu_bwd_kernel(v_ref, dy_ref, hv_ref, nv_ref, ndy_ref, seg_ref,
+                     nseg_ref, wb_ref, dv_ref, dwb_ref, *, taps: int,
+                     interpret: bool):
+    """Grid ``(batch, channel block, position block)``. ``dwb`` (the taps'
+    and the bias's gradients, module docstring) stays in place over the
+    position blocks of a (batch, channel block) and is summed over the batch
+    outside."""
+    f32 = jnp.float32
+    i = pl.program_id(2)
+    seg, wb = seg_ref[0], wb_ref[...]
+    bias = wb[:, taps:taps + 1]
+    ext = jnp.concatenate([hv_ref[0], v_ref[0]], axis=1).astype(f32)
+    z, terms = _conv_lanes(ext, seg, wb, taps=taps, interpret=interpret)
+    dz = dy_ref[0].astype(f32) * _silu_grad(z + bias)
+    # dz of the LANES positions after the block: their z from them and the
+    # block's own last positions
+    n = dz.shape[1]
+    z_after, _ = _conv_lanes(
+        jnp.concatenate([ext[:, n:], nv_ref[0].astype(f32)], axis=1),
+        nseg_ref[0], wb, taps=taps, interpret=interpret)
+    dz_ext = jnp.concatenate(
+        [dz, ndy_ref[0].astype(f32) * _silu_grad(z_after + bias)], axis=1)
+    dv = wb[:, taps - 1:taps] * dz
+    for d in range(1, taps):                  # dz[t + d]: dz_ext rotated back
+        ok = seg[0:1, :] == seg[taps - 1 + d:taps + d, :]
+        dv = dv + wb[:, taps - 1 - d:taps - d] * jnp.where(
+            ok, _roll(dz_ext, n + LANES - d, interpret, axis=1)[:, :n], 0.0)
+    dv_ref[0] = dv.astype(dv_ref.dtype)
+    lane = jax.lax.broadcasted_iota(jnp.int32, dwb_ref.shape[1:], 1)
+    dwb = jnp.where(lane == taps, jnp.sum(dz, axis=1, keepdims=True), 0.0)
+    for d, term in enumerate(terms):
+        dwb = dwb + jnp.where(lane == taps - 1 - d,
+                              jnp.sum(dz * term, axis=1, keepdims=True), 0.0)
+
+    @pl.when(i == 0)
+    def _first():
+        dwb_ref[0] = dwb
+
+    @pl.when(i != 0)
+    def _later():
+        dwb_ref[0] += dwb
+
+
+def _silu_misfit(s: int, c: int, taps: int, block_t: int,
+                 block_c: int) -> str | None:
+    """Why the kernels cannot take the shape, or None."""
+    if taps > MAX_TAPS:
+        return f"taps: want K <= {MAX_TAPS}, got {taps}"
+    if s % block_t or c % block_c or block_t % LANES or block_c % 16:
+        return (f"positions {s} and channels {c} must divide by the blocks "
+                f"({block_t}, {block_c}), and those by ({LANES}, 16)")
+    return None
+
+
+def _mamba_conv_fwd(vt, wb, lanes, *, taps, block_t, block_c, interpret):
+    b, c, s = vt.shape
+    per = block_t // LANES
+    return pl.pallas_call(
+        functools.partial(_silu_fwd_kernel, taps=taps, interpret=interpret),
+        grid=(b, s // block_t, c // block_c),
+        in_specs=[pl.BlockSpec((1, block_c, block_t),
+                               lambda bb, i, j: (bb, j, i)),
+                  pl.BlockSpec((1, block_c, LANES),
+                               lambda bb, i, j: (
+                                   bb, j, jnp.maximum(i * per - 1, 0))),
+                  pl.BlockSpec((1, STAT_LANES, block_t),
+                               lambda bb, i, j: (bb, 0, i)),
+                  pl.BlockSpec((block_c, STAT_LANES),
+                               lambda bb, i, j: (j, 0))],
+        out_specs=pl.BlockSpec((1, block_c, block_t),
+                               lambda bb, i, j: (bb, j, i)),
+        out_shape=jax.ShapeDtypeStruct((b, c, s), vt.dtype),
+        compiler_params=_grid_params("parallel", "parallel", "parallel"),
+        interpret=interpret, name="mamba_conv_fwd",
+    )(vt, vt, lanes, wb)
+
+
+def _mamba_conv_bwd(vt, wb, lanes, dyt, *, taps, block_t, block_c,
+                    interpret):
+    b, c, s = vt.shape
+    per, last = block_t // LANES, s // LANES - 1
+    nxt = lambda i: jnp.minimum((i + 1) * per, last)
+    blk = pl.BlockSpec((1, block_c, block_t), lambda bb, j, i: (bb, j, i))
+    before = pl.BlockSpec(
+        (1, block_c, LANES),
+        lambda bb, j, i: (bb, j, jnp.maximum(i * per - 1, 0)))
+    after = pl.BlockSpec((1, block_c, LANES),
+                         lambda bb, j, i: (bb, j, nxt(i)))
+    dvt, dwb = pl.pallas_call(
+        functools.partial(_silu_bwd_kernel, taps=taps, interpret=interpret),
+        grid=(b, c // block_c, s // block_t),
+        in_specs=[blk, blk, before, after, after,             # v, dy, halos
+                  pl.BlockSpec((1, STAT_LANES, block_t),
+                               lambda bb, j, i: (bb, 0, i)),
+                  pl.BlockSpec((1, STAT_LANES, LANES),        # ids after
+                               lambda bb, j, i: (bb, 0, nxt(i))),
+                  pl.BlockSpec((block_c, STAT_LANES),
+                               lambda bb, j, i: (j, 0))],
+        out_specs=[blk, pl.BlockSpec((1, block_c, STAT_LANES),
+                                     lambda bb, j, i: (bb, j, 0))],
+        out_shape=[jax.ShapeDtypeStruct((b, c, s), vt.dtype),
+                   jax.ShapeDtypeStruct((b, c, STAT_LANES), jnp.float32)],
+        compiler_params=_grid_params("parallel", "parallel", "arbitrary"),
+        interpret=interpret, name="mamba_conv_bwd",
+    )(vt, dyt, vt, vt, dyt, lanes, lanes, wb)
+    return dvt, jnp.sum(dwb, axis=0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _mamba_conv(vt, wb, lanes, taps, block_t, block_c, interpret):
+    return _mamba_conv_fwd(vt, wb, lanes, taps=taps, block_t=block_t,
+                           block_c=block_c, interpret=interpret)
+
+
+def _mamba_conv_vjp_fwd(vt, wb, lanes, taps, block_t, block_c, interpret):
+    return _mamba_conv(vt, wb, lanes, taps, block_t, block_c, interpret), (
+        vt, wb, lanes)
+
+
+def _mamba_conv_vjp_bwd(taps, block_t, block_c, interpret, res, dyt):
+    vt, wb, lanes = res
+    dvt, dwb = _mamba_conv_bwd(vt, wb, lanes, dyt.astype(vt.dtype),
+                               taps=taps, block_t=block_t, block_c=block_c,
+                               interpret=interpret)
+    return dvt, dwb, None
+
+
+_mamba_conv.defvjp(_mamba_conv_vjp_fwd, _mamba_conv_vjp_bwd)
+
+
+def silu_short_conv_pallas(v, w, bias, segment_ids=None, *,
+                           block_t: int = MAMBA_BLOCK_T,
+                           block_c: int = MAMBA_BLOCK_C,
+                           interpret: bool | None = None):
+    """The two kernels; differentiable in ``v``, ``w`` and ``bias`` (custom
+    VJP of the kernels in ``v`` and the taps-and-bias array, whose own
+    transpose hands each its columns). They run on ``v`` with its positions
+    minor, the layout XLA gives the layer's input projection, so each
+    ``swapaxes`` here is free (module docstring)."""
+    b, s, c = v.shape
+    taps = w.shape[1]
+    block_t, block_c = _blocks(s, c, block_t, block_c)
+    misfit = _silu_misfit(s, c, taps, block_t, block_c)
+    if misfit:
+        raise ValueError(misfit)
+    wb = _padded_taps(w).at[taps].set(bias.astype(jnp.float32)).T
+    lanes = jnp.swapaxes(_segment_lanes(segment_ids, b, s, taps), 1, 2)
+    yt = _mamba_conv(jnp.swapaxes(v, 1, 2), wb, lanes, taps, block_t,
+                     block_c, pallas_interpret(interpret))
+    return jnp.swapaxes(yt, 1, 2)
+
+
+def silu_short_conv(v, w, bias, segment_ids=None, *, impl: str = "auto"):
+    """The UNGATED form, a state-space layer's (:mod:`..models.
+    hybrid_decoder`): ``v [B, S, C], w [C, K], bias [C], segment_ids [B, S]
+    | None -> y [B, S, C]``, ``y[t] = silu(bias + sum_j w[:, j] v[t - (K-1) +
+    j])`` over the terms inside ``t``'s document; float32 inside, ``v``'s
+    dtype out, differentiable in ``v``, ``w`` and ``bias``. ``impl`` as
+    :func:`gated_short_conv`'s: ``"auto"`` is the kernels ``mamba_conv_fwd``
+    and ``mamba_conv_bwd`` (module docstring) on a TPU when the shape divides
+    by their blocks, else :func:`silu_short_conv_xla`; on a mesh of more
+    than one device the kernels run in a ``shard_map`` over the batch
+    rows."""
+    if v.shape[-1] != w.shape[0] or bias.shape != w.shape[:1]:
+        raise ValueError(f"v {v.shape} against taps {w.shape} and bias "
+                         f"{bias.shape}: want [B, S, C], [C, K] and [C]")
+    s, (c, taps) = v.shape[1], w.shape
+    fits = _silu_misfit(s, c, taps, *_blocks(
+        s, c, MAMBA_BLOCK_T, MAMBA_BLOCK_C)) is None
+    if _route(impl, fits) == "xla":
+        return silu_short_conv_xla(v, w, bias, segment_ids)
+    return _over_batch_rows(silu_short_conv_pallas, v, (w, bias),
+                            segment_ids)

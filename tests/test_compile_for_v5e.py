@@ -292,6 +292,35 @@ def test_short_convolution_kernels_compile_at_published_widths(
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
+def test_mamba_convolution_kernels_compile_at_published_widths(
+        one_chip, no_compile_cache):
+    """``mamba_conv_fwd`` and ``mamba_conv_bwd`` of ``ops/short_conv.py`` at
+    one window of the benchmark's sixth configuration: 16,384 positions,
+    6,144 channels (x, B and C of a Mamba layer), four taps with the bias
+    beside them, segment ids; the lane rotations, the halos of 128
+    positions and the after-halo's segment ids are what interpret mode
+    cannot judge."""
+    from distributeddeeplearningspark_tpu.ops import short_conv
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def scalar(v, taps, bias, seg):
+        y = short_conv.silu_short_conv_pallas(v, taps, bias, seg,
+                                              interpret=False)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.value_and_grad(scalar, argnums=(0, 1, 2))).lower(
+        sds((1, 16384, 6144), jnp.bfloat16), sds((6144, 4)), sds((6144,)),
+        sds((1, 16384), jnp.int32)).compile()
+    text = compiled.as_text()
+    for name in ("mamba_conv_fwd", "mamba_conv_bwd"):
+        assert name in text, name
+    assert text.count("tpu_custom_call") >= 2
+    # one pass each: no float32 [T, 6144] temporary
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
 def test_flash_on_mesh_compiles_for_four_chips(four_chips, no_compile_cache,
                                                routed_as_on_the_chip):
     """``ops/attention._flash_on_mesh`` at BERT-base's widths over ``data=4``
@@ -541,19 +570,30 @@ def test_the_joyai_train_step_compiles_for_one_chip_with_room_to_spare(
 
 
 def test_the_nemotron_train_step_compiles_for_one_chip_with_room_for_the_check(
-        four_chips, no_compile_cache, routed_as_on_the_chip):
+        four_chips, no_compile_cache, routed_as_on_the_chip, monkeypatch):
     """``nemotron3_nano_30b_a3b.fit_seg16k``'s whole step for ONE described
     chip: the flash kernels causal with segment ids at 32 / 2 heads of 128
-    in it, the chunked scan as three ``while`` loops a layer whose carried
-    tuple begins with the state (what ``ssd_ms_per_step`` finds), no array
-    of all the chunks' masks, and 528.1M parameters' state and the step's
-    temporaries inside what the check after the window leaves of 16 GiB (it
-    holds 24 bytes a parameter where the step's arguments are 12)."""
+    and the Mamba layers' convolution kernels in it, the chunked scan as
+    three ``while`` loops a layer whose carried tuple begins with the state
+    (what ``ssd_ms_per_step`` finds), no array of all the chunks' masks, and
+    528.1M parameters' state and the step's temporaries inside what the
+    check after the window leaves of 16 GiB (it holds 24 bytes a parameter
+    where the step's arguments are 12)."""
+    from distributeddeeplearningspark_tpu.ops import short_conv
+
+    monkeypatch.setattr(short_conv, "on_tpu", lambda: True)
     compiled = _lowered_step_of("nemotron3_nano_30b_a3b", "fit_seg16k",
                                 four_chips, True).compile()
     text = compiled.as_text()
-    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                 "mamba_conv_fwd", "mamba_conv_bwd"):
         assert name in text, name
+    # the convolution's kernels take the layer's positions-minor layout, so
+    # XLA relays out neither the scan's output nor its chunked inputs (with
+    # kernels on [B, S, C] it added six float32 copies of the one and nine
+    # of the others: PERF.md section 6)
+    assert not re.search(r"f32\[1,16384,4096\]\S* copy\(", text)
+    assert len(re.findall(r"bf16\[16,1,8,128,8,8,64\]\S* copy\(", text)) <= 3
     assert "[1,32,16384,16384]" not in text      # no [B, H, S, S] array
     assert "128,8,8,128,128]" not in text        # nor every chunk's masks
     assert "f32[1,8,8,64,128]" in text           # the state a chunk hands on

@@ -1,8 +1,9 @@
-"""The gated short convolution (``ops/short_conv.py``): the two Pallas kernels
-in interpret mode and the XLA path against a position-by-position loop, with
-document boundaries inside the three taps and at a kernel block's edge,
-forward and backward; the ``shard_map`` over the batch rows on the CPU mesh.
-Mosaic's verdict on the kernels is in ``tests/test_compile_for_v5e.py``."""
+"""The short convolutions (``ops/short_conv.py``), gated and ungated: each
+form's two Pallas kernels in interpret mode and its XLA path against a
+position-by-position loop, with document boundaries inside the taps and at a
+kernel block's edge, forward and backward; the routers; the ``shard_map``
+over the batch rows on the CPU mesh. Mosaic's verdict on the kernels is in
+``tests/test_compile_for_v5e.py``."""
 
 import jax
 import jax.numpy as jnp
@@ -139,12 +140,26 @@ def test_on_a_mesh_the_kernels_run_in_a_shard_map_over_the_batch_rows():
 
 # -- the ungated form of a state-space layer: four taps, a bias, a SiLU -------
 
+#: the ungated kernels run with the positions in the lanes: blocks of 128
+#: positions (four) and 128 channels (two)
+S_U, BLOCK_T_U, BLOCK_C_U = 512, 128, 128
+
+
 def _ungated_inputs(dtype=jnp.float32):
+    """Four taps; documents of one position at a block's start (128), that
+    end a position before a block's end (255) and start one after it (257),
+    one at the window's last position, and boundaries three positions apart
+    across a block's start (381, 384)."""
     rng = np.random.default_rng(3)
-    v = jnp.asarray(rng.normal(size=(B, S, C)), dtype)
+    v = jnp.asarray(rng.normal(size=(B, S_U, C)), dtype)
     w = jnp.asarray(rng.normal(size=(C, 4)), jnp.float32)
     bias = jnp.asarray(rng.normal(size=(C,)), jnp.float32)
-    return v, w, bias, _inputs()[2]
+    seg = np.zeros((B, S_U), np.int32)
+    for at in (128, 129, 255, 257, 511):
+        seg[0, at:] += 1
+    for at in (1, 127, 381, 384):
+        seg[1, at:] += 1
+    return v, w, bias, jnp.asarray(seg)
 
 
 def _ungated_by_hand(v, w, bias, seg):
@@ -160,16 +175,29 @@ def _ungated_by_hand(v, w, bias, seg):
     return z / (1 + np.exp(-z))
 
 
+def _silu_pallas(v, w, bias, seg):
+    """The kernels at four position blocks and two channel blocks."""
+    return sc.silu_short_conv_pallas(v, w, bias, seg, block_t=BLOCK_T_U,
+                                     block_c=BLOCK_C_U)
+
+
+UNGATED = {"xla": sc.silu_short_conv_xla, "pallas_interpreted": _silu_pallas}
+
+
 @pytest.mark.parametrize("segmented", [True, False],
                          ids=["documents", "one_document"])
-def test_the_ungated_form_is_its_shifted_sums_forward_and_backward(segmented):
+@pytest.mark.parametrize("impl", list(UNGATED))
+def test_the_ungated_form_is_its_shifted_sums_forward_and_backward(
+        impl, segmented):
     """Four taps, a bias and a SiLU, the terms of a tap counted inside the
-    position's document (boundaries within the four taps, a document of one
-    position, one at the window's end); the gradient of the input, the taps
-    and the bias against the same written with ``jnp`` position shifts."""
+    position's document (boundaries within the four taps, at, after and
+    before a block's edge, a document of one position, one at the window's
+    end); the gradient of the input, the taps and the bias against the same
+    written with ``jnp`` position shifts."""
     v, w, bias, seg = _ungated_inputs()
     seg = seg if segmented else None
-    got = sc.silu_short_conv(v, w, bias, seg)
+    fn = UNGATED[impl]
+    got = fn(v, w, bias, seg)
     assert got.shape == v.shape and got.dtype == v.dtype
     np.testing.assert_allclose(
         got, _ungated_by_hand(v, w, bias,
@@ -181,7 +209,7 @@ def test_the_ungated_form_is_its_shifted_sums_forward_and_backward(segmented):
         for j in range(4):
             d = 3 - j
             moved = jnp.roll(v, d, axis=1)
-            ok = (jnp.arange(S) >= d)[None, :]
+            ok = (jnp.arange(S_U) >= d)[None, :]
             if seg is not None:
                 ok = ok & (jnp.roll(seg, d, axis=1) == seg)
             z = z + w[:, j] * jnp.where(ok[..., None], moved, 0.0)
@@ -189,8 +217,8 @@ def test_the_ungated_form_is_its_shifted_sums_forward_and_backward(segmented):
 
     weights = jnp.asarray(np.random.default_rng(4).normal(size=v.shape),
                           jnp.float32)
-    grads = jax.grad(lambda *a: jnp.sum(weights * sc.silu_short_conv(
-        *a, seg)), argnums=(0, 1, 2))(v, w, bias)
+    grads = jax.grad(lambda *a: jnp.sum(weights * fn(*a, seg)),
+                     argnums=(0, 1, 2))(v, w, bias)
     wants = jax.grad(lambda *a: jnp.sum(weights * shifted_sums(*a)),
                      argnums=(0, 1, 2))(v, w, bias)
     for name, a, b in zip(("v", "taps", "bias"), grads, wants):
@@ -198,9 +226,32 @@ def test_the_ungated_form_is_its_shifted_sums_forward_and_backward(segmented):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4, err_msg=name)
 
 
-def test_the_ungated_form_in_bf16_and_its_refusals():
+@pytest.mark.parametrize("segmented", [True, False],
+                         ids=["documents", "one_document"])
+def test_the_ungated_kernels_give_what_the_plain_path_gives(segmented):
+    """``mamba_conv_fwd`` and ``mamba_conv_bwd`` against
+    ``silu_short_conv_xla``: the same float32 arithmetic, so the output and
+    the three gradients within float32 rounding; the taps' and the bias's
+    gradients summed over both rows and all four position blocks."""
+    v, w, bias, seg = _ungated_inputs()
+    seg = seg if segmented else None
+    weights = jnp.asarray(np.random.default_rng(5).normal(size=v.shape),
+                          jnp.float32)
+    seen = {name: jax.value_and_grad(
+        lambda *a, fn=fn: jnp.sum(weights * fn(*a, seg)), (0, 1, 2))(
+            v, w, bias) for name, fn in UNGATED.items()}
+    got, want = seen["pallas_interpreted"], seen["xla"]
+    np.testing.assert_allclose(float(got[0]), float(want[0]), rtol=1e-6)
+    for name, a, b in zip(("v", "taps", "bias"), got[1], want[1]):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=2e-5, err_msg=name)
+    assert float(jnp.abs(want[1][2]).max()) > 1.0   # the bias's gradient
+
+
+@pytest.mark.parametrize("impl", list(UNGATED))
+def test_the_ungated_form_in_bf16_and_its_refusals(impl):
     v, w, bias, seg = _ungated_inputs(jnp.bfloat16)
-    got = sc.silu_short_conv(v, w, bias, seg)
+    got = UNGATED[impl](v, w, bias, seg)
     assert got.dtype == jnp.bfloat16
     np.testing.assert_allclose(
         np.asarray(got, np.float32),
@@ -215,3 +266,63 @@ def test_the_ungated_form_in_bf16_and_its_refusals():
     np.testing.assert_allclose(
         sc.gated_short_conv_xla(bcx, w3, seg),
         _by_hand(bcx, w3, np.asarray(seg)), rtol=1e-5, atol=1e-5)
+
+
+def test_the_ungated_router_takes_the_kernels_where_the_shape_fits(
+        monkeypatch):
+    """Answered "on a TPU": ``"auto"`` runs the kernels where the window
+    divides by their blocks and falls back to XLA where it does not;
+    ``"pallas"`` refuses such a shape, and more taps than the lanes hold."""
+    from _jaxpr import eqns
+
+    monkeypatch.setattr(sc, "on_tpu", lambda: True)
+    v, w, bias, seg = _ungated_inputs()
+
+    def kernels(v, seg):
+        jaxpr = jax.make_jaxpr(jax.grad(lambda v: jnp.sum(
+            sc.silu_short_conv(v, w, bias, seg))))(v)
+        return sorted(e.params["name"] for e in eqns(jaxpr.jaxpr,
+                                                     "pallas_call"))
+
+    assert kernels(v, seg) == ["mamba_conv_bwd", "mamba_conv_fwd"]
+    odd = v[:, :500], seg[:, :500]       # no multiple of 128 positions
+    assert kernels(*odd) == []
+    np.testing.assert_array_equal(
+        sc.silu_short_conv(odd[0], w, bias, odd[1]),
+        sc.silu_short_conv_xla(odd[0], w, bias, odd[1]))
+    with pytest.raises(ValueError, match="divide"):
+        sc.silu_short_conv(odd[0], w, bias, odd[1], impl="pallas")
+    with pytest.raises(ValueError, match="K <="):
+        sc.silu_short_conv(v, jnp.zeros((C, 5)), bias, seg, impl="pallas")
+    with pytest.raises(ValueError, match="unknown"):
+        sc.silu_short_conv(v, w, bias, seg, impl="cudnn")
+
+
+def test_on_a_mesh_the_ungated_kernels_run_in_a_shard_map_over_the_rows():
+    """Two devices, a row each: the same numbers, and the taps' and the
+    bias's gradients summed over the rows' ranks."""
+    from distributeddeeplearningspark_tpu.ops import ring_attention
+    from distributeddeeplearningspark_tpu.parallel.mesh import MeshSpec
+
+    v, w, bias, seg = _ungated_inputs()
+    v, w, bias = v[..., :128], w[:128], bias[:128]  # one block wide
+    mesh = MeshSpec(data=2).build(jax.devices()[:2])
+    g = jnp.asarray(np.random.default_rng(6).normal(size=v.shape),
+                    jnp.float32)
+
+    def scalar(fn):
+        return lambda *a: jnp.sum(fn(*a, seg) * g)
+
+    ring_attention.set_default_mesh(mesh)
+    try:
+        got = jax.jit(jax.value_and_grad(scalar(
+            lambda *a: sc.silu_short_conv(*a, impl="pallas")),
+            (0, 1, 2)))(v, w, bias)
+    finally:
+        ring_attention.set_default_mesh(None)
+    want = jax.value_and_grad(scalar(sc.silu_short_conv_xla), (0, 1, 2))(
+        v, w, bias)
+    np.testing.assert_allclose(float(got[0]), float(want[0]), rtol=1e-5)
+    for a, b in zip(got[1], want[1]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
+                                   rtol=1e-5)

@@ -125,6 +125,39 @@ def test_a_planted_fault_leaves_the_program_as_it_was():
         assert getattr(module, attribute) is sound, name
 
 
+def test_the_planted_convolution_reaches_the_kernels_through_auto(
+        monkeypatch):
+    """``conv_crosses_documents`` swaps ``hybrid_decoder.silu_short_conv`` by
+    name and calls the sound one with the default ``impl="auto"``: answered
+    "on a TPU", that is the kernels, run here interpreted, with the segment
+    ids dropped."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from _jaxpr import eqns
+    from distributeddeeplearningspark_tpu.models import hybrid_decoder
+    from distributeddeeplearningspark_tpu.ops import short_conv
+
+    mod = _load(os.path.join(ROOT, "benchmark", "controls", NAME + ".py"))
+    monkeypatch.setattr(short_conv, "on_tpu", lambda: True)
+    rng = np.random.default_rng(0)
+    v = jnp.asarray(rng.normal(size=(1, 256, 128)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(128, 4)), jnp.float32)
+    bias = jnp.asarray(rng.normal(size=(128,)), jnp.float32)
+    seg = jnp.asarray(np.repeat([0, 1], 128)[None], jnp.int32)
+    with mod.faults(None, None, {})["conv_crosses_documents"][1]():
+        jaxpr = jax.make_jaxpr(hybrid_decoder.silu_short_conv)(v, w, bias,
+                                                              seg)
+        got = hybrid_decoder.silu_short_conv(v, w, bias, seg)
+    assert [e.params["name"] for e in eqns(jaxpr.jaxpr, "pallas_call")] == [
+        "mamba_conv_fwd"]
+    np.testing.assert_allclose(
+        got, short_conv.silu_short_conv_xla(v, w, bias, None), atol=1e-5)
+    sound = short_conv.silu_short_conv_xla(v, w, bias, seg)
+    assert float(jnp.abs(got - sound).max()) > 0.1
+
+
 def test_the_permuted_scan_is_the_scan_with_groups_read_h_mod_g():
     """The control ``head_reads_group_h_mod_8`` plants what its name says:
     against the sequential recurrence with the other mapping written out."""
