@@ -76,22 +76,30 @@ class StarvationProbe:
       ``map_parallel`` pool that made them (``feed._Assembly``), so that
       the producer did nothing once an example; 0 where no stream offers
       ``fill``.
+    - ``input_worker_items`` / ``input_worker_reads``
+      (``dls.feed/worker_example``, ``dls.feed/worker_read``) — numbers:
+      the examples this feed's thread took from worker processes
+      (``data/workers.WorkerPool``), and the files those processes read for
+      them, credited with each example (0 on the threads' path).
     - ``input_blocked_s`` (``dls.feed/ring_full``) — the producer holding a
       finished batch with no room in the ring: the feed's headroom.
     - ``input_map_s`` (``dls.feed/map``) — thread-seconds inside
-      ``map_parallel``'s function, summed over the pool; absent until a
-      parallel map has run under this probe.
+      ``map_parallel``'s function, summed over the pool, or the worker
+      processes' seconds inside theirs, credited with each example; absent
+      until a parallel map has run under this probe.
     - ``input_decode_s`` (``dls.feed/decode``) — seconds inside
-      ``vision.decode_jpeg`` on this feed's threads (thread-seconds where
-      the pool decodes, and then a part of ``input_map_s``); absent until a
-      JPEG has been decoded under this probe.
+      ``vision.decode_jpeg`` on this feed's threads or worker processes
+      (summed over them where a pool decodes, and then a part of
+      ``input_map_s``); absent until a JPEG has been decoded under this
+      probe.
 
     ``clock`` is injectable so tests measure deterministic fake seconds.
     ``snapshot(reset=True)`` returns-and-clears, giving per-lap gauges.
     """
 
     #: the counters that are numbers of sections, not their seconds
-    _COUNTED = ("input_slot_reused", "input_slot_new", "input_filled_by_map")
+    _COUNTED = ("input_slot_reused", "input_slot_new", "input_filled_by_map",
+                "input_worker_items", "input_worker_reads")
     #: the counters every snapshot carries (``input_map_s`` and
     #: ``input_decode_s`` apart)
     _ALWAYS = ("input_wait_s", "input_put_s", "input_assembly_s",
@@ -122,6 +130,14 @@ class StarvationProbe:
             if key == "input_wait_s":
                 self._waits += 1
                 self._wait_max = max(self._wait_max, dt)
+
+    def credit(self, name: str, amount: float) -> None:
+        """Add ``amount`` to ``name``'s counter: what a worker process did
+        for an example this feed's thread took from it, which none of this
+        process's sections saw (seconds, or a number of files)."""
+        key = spans.COUNTERS[name]
+        with self._lock:
+            self._sums[key] = self._sums.get(key, 0.0) + amount
 
     def record_depth(self, depth: int) -> None:
         with self._lock:

@@ -19,11 +19,72 @@ from __future__ import annotations
 import gzip
 import os
 import struct
+import threading
 from typing import Iterator
 
 import numpy as np
 
 from distributeddeeplearningspark_tpu.rdd import PartitionedDataset
+
+#: files this process has read through :func:`read_file`, and its lock: a
+#: worker process of ``data/workers.py`` reports the difference with each
+#: example it maps (``input_worker_reads``)
+_files_read = 0
+_files_read_lock = threading.Lock()
+
+
+def _new_lock_in_child() -> None:
+    # a process forked while another thread held the lock would wait for
+    # it forever: the child gets a lock of its own
+    global _files_read_lock
+    _files_read_lock = threading.Lock()
+
+
+if hasattr(os, "register_at_fork"):  # POSIX
+    os.register_at_fork(after_in_child=_new_lock_in_child)
+
+
+def read_file(path) -> bytes:
+    """The whole of the file at ``path``, counted in :func:`files_read`."""
+    global _files_read
+    with open(path, "rb") as f:
+        data = f.read()
+    with _files_read_lock:
+        _files_read += 1
+    return data
+
+
+def files_read() -> int:
+    """Files this process has read through :func:`read_file` so far (a
+    forked process starts from its parent's count)."""
+    return _files_read
+
+
+class EncodedFile(os.PathLike):
+    """An encoded image file that a source has listed and not read.
+
+    ``imagenet_folder(decode=False)`` yields one under ``"jpeg"``, so that a
+    shuffle holds a listing and not an epoch's bytes, and the file is read by
+    whoever maps the example (``vision.decode_jpeg``): a worker process of
+    ``data/workers.py`` reads its own examples' files and no other's.
+    ``bytes(f)`` and ``f.read()`` read it; ``open(f)`` opens it."""
+
+    __slots__ = ("path",)
+
+    def __init__(self, path: str):
+        self.path = path
+
+    def __fspath__(self) -> str:
+        return self.path
+
+    def read(self) -> bytes:
+        return read_file(self.path)
+
+    def __bytes__(self) -> bytes:
+        return self.read()
+
+    def __repr__(self) -> str:
+        return f"EncodedFile({self.path!r})"
 
 
 def synthetic_mnist(
@@ -171,8 +232,10 @@ def imagenet_folder(
     overlapping device compute the way the reference's executors decode
     inside Spark tasks. Labels follow sorted
     class-directory order (torchvision's convention) unless an explicit
-    ``class_to_index`` is given; ``decode=False`` yields raw bytes under
-    ``"jpeg"`` for pipelines that want decode inside a later ``.map``.
+    ``class_to_index`` is given; ``decode=False`` yields the file unread, an
+    :class:`EncodedFile` under ``"jpeg"``, for pipelines that read and
+    decode it inside a later ``.map`` (``vision.imagenet_train``): the
+    partition's walk then opens no file at all.
     """
     root = os.path.abspath(root)
     classes = class_to_index if class_to_index is not None else folder_classes(root)
@@ -201,8 +264,7 @@ def imagenet_folder(
                         img = np.repeat(img, 3, axis=-1)
                     yield {"image": img, "label": np.int32(label)}
                 else:
-                    with open(path, "rb") as f:
-                        yield {"jpeg": f.read(), "label": np.int32(label)}
+                    yield {"jpeg": EncodedFile(path), "label": np.int32(label)}
 
         return gen
 

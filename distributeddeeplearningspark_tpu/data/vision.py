@@ -17,10 +17,13 @@ see :func:`decode_jpeg`.
 from __future__ import annotations
 
 import io
+import os
 from typing import Callable, Iterable
 
 import numpy as np
 
+from distributeddeeplearningspark_tpu.data.sources import (
+    EncodedFile, read_file)
 from distributeddeeplearningspark_tpu.rdd import PartitionedDataset
 from distributeddeeplearningspark_tpu.telemetry import spans
 
@@ -142,16 +145,16 @@ def decode_jpeg(path_or_bytes) -> np.ndarray:
        4:2:0 file's pixels differ by a level or so on average, and with
        them the content-seeded crop and flip (:func:`_content_seed`).
 
-    A malformed stream raises ``ValueError`` on both routes. The time spent
-    here is the span ``dls.feed/decode`` (counter ``input_decode_s`` where
-    the calling thread has a feed's sink bound).
+    A malformed stream raises ``ValueError`` on both routes. A path (or a
+    ``sources.EncodedFile``) is read here, through ``sources.read_file``.
+    The time spent decoding is the span ``dls.feed/decode`` (counter
+    ``input_decode_s`` where the calling thread has a feed's sink bound).
     """
     if isinstance(path_or_bytes, (bytes, bytearray)):
         data, where = bytes(path_or_bytes), ""
     else:
-        with open(path_or_bytes, "rb") as f:
-            data = f.read()
-        where = f" in {path_or_bytes}"
+        data = read_file(path_or_bytes)
+        where = f" in {os.fspath(path_or_bytes)}"
     with spans.span("dls.feed/decode", spans.bound_sink()):
         try:
             from PIL import Image
@@ -279,10 +282,12 @@ def train_transform(size: int = 224, seed: int = 0) -> Callable[[dict], dict]:
 
 
 def _decode_if_bytes(example: dict) -> dict:
-    """``{"jpeg": bytes}`` (imagenet_folder(decode=False)) → decoded
-    ``{"image": ...}``. Decoding INSIDE the transform is what lets
-    ``map_parallel`` spread it over cores — decode in the source iterator
-    runs on the single consumer thread no matter the pool size."""
+    """``{"jpeg": file or bytes}`` (``imagenet_folder(decode=False)`` gives
+    an unread ``sources.EncodedFile``) → decoded ``{"image": ...}``. Reading
+    and decoding INSIDE the transform is what lets ``map_parallel`` or the
+    worker processes spread it over cores — in the source iterator it would
+    run on the single consumer thread no matter the pool size, and each
+    worker process would read every file of the epoch."""
     if "jpeg" not in example:
         return example
     out = {k: v for k, v in example.items() if k != "jpeg"}
@@ -335,33 +340,35 @@ def imagenet_train(dataset: PartitionedDataset, *, size: int = 224, seed: int = 
                    num_threads: int | None = None,
                    repeat: bool = False,
                    num_workers: int | None = None) -> PartitionedDataset:
-    """RDD-shaped pipeline: shuffle → (repeat) → decode+augment.
+    """RDD-shaped pipeline: shuffle → (repeat) → read+decode+augment.
 
-    Feed it ``imagenet_folder(root, decode=False)`` so JPEG decode happens
-    INSIDE the (optionally parallel) transform — decode in the source
-    iterator would stay on the single consumer thread and cap a host at one
-    core's rate while a chip consumes thousands (the cell
+    Feed it ``imagenet_folder(root, decode=False)`` so the file's read and
+    its JPEG decode happen INSIDE the (parallel) transform — in the source
+    iterator they would stay on the single consumer thread and cap a host at
+    one core's rate while a chip consumes thousands (the cell
     ``resnet50_imagenet.fit_jpeg`` of ``BENCHMARK.json`` runs this path).
-    ``num_threads``: thread-pool decode/augment (the
-    Spark task-slots-per-executor analog; 0/1 = serial; augmentation is
-    content-seeded per example, so thread scheduling cannot change WHICH
-    augmentation an example gets — but concurrent native-kernel calls have
-    been observed to race at the byte level on oversubscribed shared hosts
-    (tests/test_input_workers.py quarantine note), so pipelines that need
-    bit-determinism should use ``num_threads=0`` or worker processes,
-    which reproduce exactly at any width).
     ``repeat=True`` makes the stream infinite HERE — shuffle must precede
-    repeat, and repeating before the parallel map keeps one thread pool
-    alive across epochs instead of respawning per pass.
+    repeat, and repeating before the parallel map keeps one pool alive
+    across epochs instead of respawning it per pass.
 
-    ``num_workers`` (default ``DLS_DATA_WORKERS``, 0 = off): run the
-    decode/augment map across worker *processes* instead of threads —
-    :class:`~.workers.WorkerMappedDataset`, real cores with no GIL and
-    shared-memory delivery. The batch stream is byte-identical for any
-    worker count (content-seeded augmentation + ordered delivery), so
-    checkpoint fast-forward resume is unaffected. When enabled it replaces
-    the thread pool (``num_threads`` is ignored) — process×thread pools
-    would oversubscribe the host.
+    **Where the map runs.** ``num_workers`` (else ``DLS_DATA_WORKERS``),
+    where either is given, decides: a count above 0 runs the map across
+    that many worker *processes* (:class:`~.workers.WorkerMappedDataset`:
+    real cores with no interpreter lock to share, shared-memory delivery;
+    ``num_threads`` is then ignored, as process x thread pools would
+    oversubscribe the host), 0 keeps ``map_parallel``'s thread pool
+    (``num_threads``: the Spark task-slots-per-executor analog; 0/1 =
+    serial). Where neither is given, the input decides (:func:`_map_workers`):
+    a source of files that no walk has read (``decode=False``) is decoded
+    in the worker processes the host's cores allow, decoded or in-memory
+    examples (record shards) keep the threads, and so does a caller that
+    names ``num_threads``. Augmentation is content-seeded per example and
+    the pool delivers in order, so the batch stream is byte-identical for
+    any worker count, and checkpoint fast-forward resume is unaffected. The
+    thread pool's concurrent native-kernel calls have been observed to race
+    at the byte level on oversubscribed shared hosts
+    (tests/test_input_workers.py quarantine note): pipelines that need
+    bit-determinism on threads use ``num_threads=0``.
     """
     from distributeddeeplearningspark_tpu.data import workers as workers_lib
 
@@ -369,21 +376,84 @@ def imagenet_train(dataset: PartitionedDataset, *, size: int = 224, seed: int = 
     if repeat:
         ds = ds.repeat()
     tf = train_transform(size, seed)
+    num_workers = _map_workers(dataset, tf, num_workers, num_threads)
     if workers_lib.resolve_num_workers(num_workers) > 0:
-        return workers_lib.WorkerMappedDataset(ds, tf, num_workers,
-                                               label="imagenet_train")
+        return workers_lib.WorkerMappedDataset(
+            ds, tf, num_workers, label="imagenet_train", **_ring(size))
     return ds.map_parallel(tf, num_threads=num_threads)
 
 
 def imagenet_eval(dataset: PartitionedDataset, *, size: int = 224,
                   num_threads: int | None = None,
                   num_workers: int | None = None) -> PartitionedDataset:
+    """Center crop + normalize; the map runs where :func:`imagenet_train`'s
+    would."""
     from distributeddeeplearningspark_tpu.data import workers as workers_lib
 
+    tf = eval_transform(size)
+    num_workers = _map_workers(dataset, tf, num_workers, num_threads)
     if workers_lib.resolve_num_workers(num_workers) > 0:
         return workers_lib.WorkerMappedDataset(
-            dataset, eval_transform(size), num_workers, label="imagenet_eval")
-    return dataset.map_parallel(eval_transform(size), num_threads=num_threads)
+            dataset, tf, num_workers, label="imagenet_eval", **_ring(size))
+    return dataset.map_parallel(tf, num_threads=num_threads)
+
+
+#: examples a decode worker may map ahead of the feed: at about 3 ms each,
+#: 8 are 25 ms of lookahead a worker, a third of a batch of 256 over 11
+_DECODE_AHEAD = 8
+
+
+def _ring(size: int) -> dict:
+    """A decode worker's lookahead and its ring, sized by the examples the
+    map gives (a ``size`` x ``size`` x 3 float32 image): the ``ahead`` it
+    may have queued and six more it is writing or the feed holds, or that
+    the ring's holes waste. A worker pool maps every page of its rings into
+    the feed's process before it forks (``workers._new_ring``), so the ring
+    costs its size at the start: 32 MiB a worker where 8.4 will do."""
+    return {"max_ahead": _DECODE_AHEAD,
+            "ring_bytes": (_DECODE_AHEAD + 6) * size * size * 3 * 4}
+
+
+def _map_workers(dataset: PartitionedDataset, fn: Callable[[dict], dict],
+                 num_workers: int | None,
+                 num_threads: int | None) -> int | None:
+    """``num_workers`` as the caller or ``DLS_DATA_WORKERS`` gave it, else
+    the worker processes for the decode map ``fn`` of ``dataset`` by what
+    the dataset holds.
+
+    Only a source whose examples hold a file no walk has read
+    (:class:`~.sources.EncodedFile`, looked at in partition 0's first
+    example) gets processes, as many as :func:`~.workers.
+    default_map_workers` allows (0 where fork or the cores are missing):
+    there the map reads and decodes, milliseconds an image of Python and
+    libjpeg that threads serialize on the interpreter lock, and each
+    process reads only its own examples' files. Decoded or in-memory
+    examples keep the threads, and so does a caller that names
+    ``num_threads``. Where processes are chosen, ``fn`` maps that first
+    example once here: what it imports and loads the first time (PIL's
+    plugins, compiled anew where the installation keeps no bytecode; the
+    native kernels, built where none is cached) is then done once, before
+    any worker forks, and not again in each of them."""
+    from distributeddeeplearningspark_tpu.data import workers as workers_lib
+
+    if (num_workers is not None or num_threads is not None
+            or workers_lib.WORKERS_ENV in os.environ
+            or not dataset.num_partitions):
+        return num_workers
+    it = iter(dataset.iter_partition(0))
+    try:
+        first = next(it, None)
+    finally:
+        close = getattr(it, "close", None)
+        if close is not None:
+            close()
+    if not (isinstance(first, dict)
+            and isinstance(first.get("jpeg"), EncodedFile)):
+        return num_workers
+    width = workers_lib.default_map_workers()
+    if width:
+        fn(first)
+    return width
 
 
 def _fused_example_transform(size: int, seed: int) -> Callable[[dict], dict]:

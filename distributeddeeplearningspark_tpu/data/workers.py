@@ -62,15 +62,26 @@ pool degrades to the serial in-process map with a one-time warning: same
 bytes, no speedup.
 
 Each worker re-iterates its partition's *source* and maps only its residue
-class — no input pickling, no dispatcher thread. That duplicates the cheap
-source walk (file reads / record seeks, page-cached) ``k``× per partition
-and is the right trade while the map (JPEG decode ~20 ms) dominates it by
-orders of magnitude; materialize records (data/records.py) first if your
-source walk is the expensive part.
+class — no input pickling, no dispatcher thread. So the source walk runs
+``k``× per partition, and it has to cost next to nothing: a walk that READS
+each element puts every worker through the whole epoch's bytes before its
+first example, at every epoch and all at once (1,024 JPEG files of 100 KB
+read 0.12 s a walk; the first batch of 256 took 0.35 s at 7 workers, 0.79
+at 16 and 1.48 at 32 on an 8-core host). A source of files therefore lists
+them unread (``imagenet_folder(decode=False)`` yields
+``sources.EncodedFile``) and the map reads each where it decodes it: a
+worker opens its own residue class's files and no other's, and no process
+holds an epoch's bytes. Each example carries the number of files its worker
+read since the one before (``input_worker_reads``), so a walk that reads
+shows as ``k`` reads an example. Materialize records (data/records.py)
+first where the elements themselves must be read to be walked.
 
 Sizing: one decoded 500px JPEG is ~750 KB, a 224px float32 plane ~600 KB;
 the default 32 MB ring per worker (``DLS_DATA_WORKER_RING_MB``) holds
-~40–50 in-flight examples, and tmpfs allocates pages only when touched.
+~40–50 in-flight examples, and the consumer's process writes every page of
+it before the workers fork (:func:`_new_ring`), so tmpfs holds the whole
+ring from the start (``vision.imagenet_train`` sizes its rings to its
+lookahead, 8.4 MB at 224 px).
 An example that cannot get ring space within a bounded wait (consumer
 holding too many views, or bigger than the whole ring) falls back to queue
 transport — pickled, slower, counted in the ``overflow`` gauge — so
@@ -80,6 +91,7 @@ liveness never depends on ring capacity.
 from __future__ import annotations
 
 import bisect
+import mmap
 import multiprocessing as mp
 import os
 import queue as queue_lib
@@ -94,7 +106,9 @@ from typing import Any, Callable, Iterable, Iterator
 import numpy as np
 
 from distributeddeeplearningspark_tpu import telemetry
+from distributeddeeplearningspark_tpu.data.sources import files_read
 from distributeddeeplearningspark_tpu.rdd import PartitionedDataset
+from distributeddeeplearningspark_tpu.telemetry import spans
 
 #: env knob: default worker count when ``num_workers=None`` (0 = in-process).
 WORKERS_ENV = "DLS_DATA_WORKERS"
@@ -122,6 +136,8 @@ _ALIGN = 64
 _ALLOC_WAIT_S = 0.25
 #: consumer liveness-poll interval while waiting on a worker queue.
 _POLL_S = 0.2
+#: where Linux keeps ``multiprocessing.shared_memory`` segments
+_SHM_DIR = "/dev/shm"
 
 # stats array layout (one float64 stride per worker, single-writer cells:
 # the worker owns all four, the consumer only reads)
@@ -146,6 +162,29 @@ def resolve_num_workers(num_workers: int | None) -> int:
         warnings.warn(f"ignoring non-integer {WORKERS_ENV}="
                       f"{os.environ.get(WORKERS_ENV)!r}")
         return 0
+
+
+#: the most worker processes :func:`default_map_workers` gives a map
+_MAP_WORKERS_MAX = 12
+#: threads of the training process beside the pool: the loop and the
+#: prefetch thread that assembles the batches
+_PROCESS_THREADS = 2
+
+
+def default_map_workers() -> int:
+    """Worker processes for a map that decodes files, where nobody chose a
+    number (``vision.imagenet_train`` over ``decode=False``): the cores this
+    process may run on less the loop's and the prefetch thread's, at most
+    ``_MAP_WORKERS_MAX``. 0 — the thread pool — where fork is missing or
+    fewer than two would be left."""
+    if not fork_available():
+        return 0
+    try:
+        cores = len(os.sched_getaffinity(0))
+    except AttributeError:  # pragma: no cover - not Linux
+        cores = os.cpu_count() or 1
+    width = min(cores - _PROCESS_THREADS, _MAP_WORKERS_MAX)
+    return width if width >= 2 else 0
 
 
 def _ring_bytes(override: int | None) -> int:
@@ -316,6 +355,53 @@ def _align(n: int) -> int:
     return (n + _ALIGN - 1) // _ALIGN * _ALIGN
 
 
+class _Sections:
+    """A worker process's feed sink: the seconds of the sections its map
+    opens (``decode_jpeg``'s ``dls.feed/decode``), which each example hands
+    to the consumer's probe. Fork leaves the parent's probe bound to the
+    thread it copied; a copy is read by nobody, and its lock may have been
+    held by another thread when the process forked."""
+
+    clock = staticmethod(time.perf_counter)
+
+    def __init__(self) -> None:
+        self.seconds: dict[str, float] = {}
+
+    def add(self, name: str, dt: float, inner_s: float = 0.0) -> None:
+        self.seconds[name] = self.seconds.get(name, 0.0) + dt
+
+    def take(self) -> dict[str, float]:
+        out, self.seconds = self.seconds, {}
+        return out
+
+
+def _new_ring(size: int, w: int) -> shared_memory.SharedMemory:
+    """Worker ``w``'s arena, every page of it mapped into this process now.
+
+    The consumer reads each example out of the ring, and a page it maps
+    for the first time after a worker wrote it cost this process about
+    80 us on a TPU host (measured: 12 ms for each of a pool's first
+    examples, until every slot of every ring had been read once, 4 s to
+    the first batch of 256 at 11 workers), where writing the page here,
+    before any worker exists, costs 9-10 us. tmpfs then holds the whole
+    ring from the start; where it has no room for it (a small
+    ``/dev/shm``), the pages are left to be mapped as they are used, since
+    a write past tmpfs's end would kill this process."""
+    shm = shared_memory.SharedMemory(
+        create=True, size=size,
+        name=f"dlsw-{os.getpid()}-{uuid.uuid4().hex[:8]}-{w}")
+    try:
+        room = os.statvfs(_SHM_DIR)
+    except OSError:  # not Linux's tmpfs: leave the pages be
+        return shm
+    if room.f_bavail * room.f_frsize < size:
+        return shm
+    pages = np.frombuffer(shm.buf, np.uint8)
+    pages[::mmap.PAGESIZE] = 0
+    del pages  # no view may outlive the ring's close
+    return shm
+
+
 def _worker_loop(wid: int, num_workers: int, source_factory, fn,
                  shm, out_q, free_q, stats, stop_evt, skip: int = 0) -> None:
     """Child body (fork-inherited state): iterate the source, map this
@@ -331,6 +417,9 @@ def _worker_loop(wid: int, num_workers: int, source_factory, fn,
     # PARENT's serial path and must not leak N× fan-out into the children
     # (the child env is private; fork copied it, the parent keeps its own)
     os.environ["DLS_NATIVE_THREADS"] = "1"
+    sections = _Sections()
+    spans.bind_sink(sections)
+    read_before = files_read()
     ring = _Arena(shm.size)
     buf = shm.buf
     base = wid * _ST_STRIDE
@@ -387,9 +476,17 @@ def _worker_loop(wid: int, num_workers: int, source_factory, fn,
             ci += 1
             if ci < skip:
                 continue
+            sections.take()  # what the walk to this element opened
             t0 = time.perf_counter()
             ex = fn(item) if fn is not None else item
-            stats[base + _ST_BUSY] += time.perf_counter() - t0
+            map_s = time.perf_counter() - t0
+            stats[base + _ST_BUSY] += map_s
+            # what this example cost the process, for the consumer's probe:
+            # the map's seconds, its sections', and the files read since
+            # the example before (the walk's reads included)
+            read_now = files_read()
+            cost = (map_s, sections.take(), read_now - read_before)
+            read_before = read_now
             if not isinstance(ex, dict):
                 # non-dict results (token arrays, scalars) ride the same
                 # transport under a wrapper key the consumer unwraps
@@ -407,7 +504,7 @@ def _worker_loop(wid: int, num_workers: int, source_factory, fn,
                 # transport keeps liveness; the overflow gauge tells you
                 # to raise DLS_DATA_WORKER_RING_MB
                 stats[base + _ST_OVERFLOW] += 1
-                if not put(("pkl", j, ex)):
+                if not put(("pkl", j, ex, cost)):
                     return
             elif planes:
                 metas = []
@@ -418,11 +515,11 @@ def _worker_loop(wid: int, num_workers: int, source_factory, fn,
                     np.copyto(dst, a)
                     metas.append((k, a.dtype.str, a.shape, off + rel))
                     rel += _align(a.nbytes)
-                if not put(("shm", j, alloc_id, metas, inline)):
+                if not put(("shm", j, alloc_id, metas, inline, cost)):
                     return
                 alloc_id += 1
             else:
-                if not put(("pkl", j, ex)):
+                if not put(("pkl", j, ex, cost)):
                     return
             stats[base + _ST_PRODUCED] += 1
             stats[base + _ST_RING_USED] = ring.used
@@ -476,10 +573,7 @@ class WorkerPool:
                        else _DEFAULT_MAX_AHEAD)
         self._stats = ctx.RawArray("d", num_workers * _ST_STRIDE)
         self._stop = ctx.Event()
-        self._shms = [shared_memory.SharedMemory(
-            create=True, size=rb,
-            name=f"dlsw-{os.getpid()}-{uuid.uuid4().hex[:8]}-{w}")
-            for w in range(num_workers)]
+        self._shms = [_new_ring(rb, w) for w in range(num_workers)]
         self._out_qs = [ctx.Queue(maxsize=max(2, self._ahead))
                         for _ in range(num_workers)]
         self._free_qs = [ctx.Queue() for _ in range(num_workers)]
@@ -516,7 +610,13 @@ class WorkerPool:
 
     def stream(self) -> Iterator[Any]:
         """The mapped stream, in exact source order. Closes the pool on
-        exhaustion, on error, and on generator close."""
+        exhaustion, on error, and on generator close.
+
+        The taking of each example out of its worker's ring is one
+        ``dls.feed/worker_example`` section of the pulling thread's feed
+        sink, which is credited what the example cost its worker
+        (:func:`_credit`)."""
+        sink = spans.bound_sink()
         try:
             j = 0
             while True:
@@ -531,7 +631,11 @@ class WorkerPool:
                     raise WorkerCrashed(
                         f"input worker {rec[1]} raised:\n{rec[2]}",
                         worker=rec[1])
-                yield self._materialize(w, rec)
+                with spans.span("dls.feed/worker_example", sink):
+                    ex = self._materialize(w, rec)
+                _credit(sink, rec[-1])
+                yield ex
+                del ex
                 self._consumed[w] += 1
                 j += 1
         finally:
@@ -585,9 +689,7 @@ class WorkerPool:
                        respawns_left=self._respawns_left,
                        label=self.label or None)
         ctx = mp.get_context("fork")
-        shm = shared_memory.SharedMemory(
-            create=True, size=self._ring_bytes,
-            name=f"dlsw-{os.getpid()}-{uuid.uuid4().hex[:8]}-{w}")
+        shm = _new_ring(self._ring_bytes, w)
         out_q = ctx.Queue(maxsize=max(2, self._ahead))
         free_q = ctx.Queue()
         # rebase the (single-writer, but its writer is dead) produced cell
@@ -619,7 +721,7 @@ class WorkerPool:
             ex = rec[2]
             return ex[_VALUE_KEY] if (isinstance(ex, dict)
                                       and _VALUE_KEY in ex) else ex
-        _, _j, alloc_id, metas, inline = rec
+        _, _j, alloc_id, metas, inline, _cost = rec
         ex = dict(inline)
         buf = self._shms[w].buf
         ex_bytes = sum(
@@ -715,6 +817,20 @@ class WorkerPool:
                 pass
 
 
+def _credit(sink, cost) -> None:
+    """Hand what one example cost its worker process to the consumer's feed
+    sink (``StarvationProbe.credit``): the map's seconds, the seconds of its
+    sections, the files the process read for it."""
+    credit = getattr(sink, "credit", None)
+    if credit is None:
+        return
+    map_s, sections, reads = cost
+    credit("dls.feed/map", map_s)
+    for name, seconds in sections.items():
+        credit(name, seconds)
+    credit("dls.feed/worker_read", reads)
+
+
 def pool_gauges() -> dict:
     """Aggregate gauges over every live pool, keyed for the telemetry
     step_metrics record (merged by ``StarvationProbe.snapshot``). Empty dict
@@ -802,6 +918,13 @@ class WorkerMappedDataset(PartitionedDataset):
 
         super().__init__([make(i) for i in range(P)],
                          infinite=base.is_infinite)
+
+    def take(self, n: int) -> list:
+        """The first ``n`` mapped elements, mapped in this process: the
+        elements a pool would deliver (the map is per element and the pool
+        keeps the order), without forking one for a handful of examples
+        (a trainer's sample of the first batch's shapes)."""
+        return [self.fn(x) for x in self.base.take(n)]
 
     def with_num_workers(self, num_workers: int | None
                          ) -> "WorkerMappedDataset":

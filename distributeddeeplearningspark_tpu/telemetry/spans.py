@@ -56,6 +56,14 @@ COUNTERS = {
     "dls.feed/slot_reused": "input_slot_reused",
     "dls.feed/slot_new": "input_slot_new",
     "dls.feed/filled_by_map": "input_filled_by_map",
+    # numbers too, of what the worker processes of ``data/workers.py`` did
+    # for the feed: the examples its thread took from them (one section an
+    # example), and the files the processes read for those, which no
+    # section of this process sees: each example brings its number along
+    # and ``StarvationProbe.credit`` adds it, as it adds the processes'
+    # seconds in ``dls.feed/map`` and ``dls.feed/decode``
+    "dls.feed/worker_example": "input_worker_items",
+    "dls.feed/worker_read": "input_worker_reads",
     # the start (``anatomy.StartupLedger``); ``dls.start/fit`` holds every
     # section below it, so its own time is the start that no section covers
     "dls.start/import": "import_s",

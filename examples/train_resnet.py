@@ -58,10 +58,11 @@ def main() -> None:
                         "crops never upscale degraded frames)")
     p.add_argument("--data-workers", type=int, default=None,
                    help="decode/augment worker processes (default: "
-                        "DLS_DATA_WORKERS env; 0 = in-process). Byte-"
-                        "identical batch stream at any count — see "
-                        "docs/PERFORMANCE.md 'Scaling the host input "
-                        "pipeline'")
+                        "DLS_DATA_WORKERS env, else as many as the cores "
+                        "allow for --data-dir and the thread pool for "
+                        "records; 0 = in-process). Byte-identical batch "
+                        "stream at any count — see docs/PERFORMANCE.md "
+                        "'Scaling the host input pipeline'")
     p.add_argument("--eval-dir", default=None,
                    help="validation root (same layout); reports top-1/top-5 "
                         "after training via the exact tail-inclusive evaluator")

@@ -233,10 +233,16 @@ def test_imagenet_folder_trains_through_pipeline(tmp_path):
 
 
 def test_imagenet_folder_raw_bytes_mode(tmp_path):
+    """``decode=False`` hands the file over unread; its bytes are read where
+    the map needs them."""
+    from distributeddeeplearningspark_tpu.data.sources import EncodedFile
+
     root = _make_imagenet(tmp_path)
     ds = imagenet_folder(str(root), num_partitions=1, decode=False)
     e = ds.take(1)[0]
-    assert isinstance(e["jpeg"], bytes) and e["jpeg"][:2] == b"\xff\xd8"
+    assert isinstance(e["jpeg"], EncodedFile)
+    assert bytes(e["jpeg"])[:2] == b"\xff\xd8"
+    assert vision.decode_jpeg(e["jpeg"]).shape[-1] == 3
 
 
 @pytest.mark.parametrize("how", ["thread_pool", "two_worker_processes"])

@@ -416,10 +416,12 @@ def test_a_traced_fit_holds_every_span_on_named_lines(tmp_path, monkeypatch):
     # benchmark's extract keys host lines by name and keeps the last)
     assert len(names) == len(set(names)), names
     by_line = dict(lines)
-    # this run saves no checkpoint, evaluates nothing and decodes no JPEG
+    # this run saves no checkpoint, evaluates nothing, decodes no JPEG and
+    # starts no worker process
     of_start = {n for n in spans.COUNTERS if n.startswith(spans.START_PREFIX)}
     expected = (set(spans.COUNTERS) - of_start
-                - {"dls.fit/checkpoint", "dls.fit/eval", "dls.feed/decode"}
+                - {"dls.fit/checkpoint", "dls.fit/eval", "dls.feed/decode",
+                   "dls.feed/worker_example", "dls.feed/worker_read"}
                 | {"train", spans.PHASE_PREFIX + "compile"})
     # (whether eight steps get to fill a kept slot again is the threads' race)
     reused = {"dls.feed/slot_reused"}
