@@ -6,7 +6,11 @@ virtual devices so real Mesh/pjit/GSPMD code paths — including collectives —
 execute in unit tests exactly as they would on an 8-chip slice.
 """
 
+import contextlib
+import math
 import os
+import signal
+import time
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 flags = os.environ.get("XLA_FLAGS", "")
@@ -45,6 +49,19 @@ _SLOW_PATTERNS = (
     # (restore-fallback, fault parsing) stay in the fast tier
     "test_chaos.py::test_rollback_without_checkpointer",
     "test_chaos.py::test_on_nonfinite_validation",
+    # chaos drills through real supervised gangs and whole fits
+    "test_chaos.py::test_kill_mid_finalize_recovers_from_verified_step",
+    "test_chaos.py::test_hang_is_killed_classified_and_relaunched",
+    "test_chaos.py::test_crash_drill_dlstatus_reports_attempts_and_goodput",
+    "test_chaos.py::test_die_host_shrinks_gang_and_training_continues",
+    "test_chaos.py::test_sigterm_drains_and_continues_from_current_step",
+    "test_chaos.py::test_die_host_real_gang_reshards_onto_survivor",
+    "test_chaos.py::test_nan_spike_skip_policy_finishes_finite",
+    "test_chaos.py::test_nan_every_step_exhausts_skip_budget",
+    "test_chaos.py::test_nan_spike_rollback_policy",
+    "test_chaos.py::test_rollback_walks_past_nan_checkpoints",
+    "test_serve_fleet.py::test_fleet_processes_end_to_end",  # real processes
+    "test_telemetry.py::test_fit_emits_telemetry_and_dlstatus_reads_it",
     "test_profiling.py::test_fit", # Trainer runs writing real trace files
     "test_profiling.py::test_profile_cli",
     "test_profiling.py::test_op_breakdown",
@@ -125,6 +142,113 @@ def pytest_collection_modifyitems(config, items):
     for item in items:
         if any(pat in item.nodeid for pat in _SLOW_PATTERNS):
             item.add_marker(pytest.mark.slow)
+    if hasattr(config, "workerinput"):      # an xdist worker's collection
+        items.sort(key=_start_rank)
+
+
+# ---------------------------------------------------------------------------
+# The order the files start in. Under xdist's ``--dist loadfile`` a worker
+# takes a whole file, and xdist by default hands out the files with the MOST
+# tests first: a file of a few slow tests then starts last and the run waits
+# for it alone. Under xdist the files slowest in a run of six workers on an
+# 8-core host (seconds, setup included) start first, slowest first, and the
+# rest keep their order; a run without xdist keeps pytest's order.
+# ---------------------------------------------------------------------------
+
+_SLOWEST_FIRST = (
+    "test_compile_for_v5e.py",          # 700
+    "test_window_moe_controls.py",      # 600
+    "test_hybrid_decoder.py",           # 557
+    "test_latent_moe_controls.py",      # 399
+    "test_latent_attention.py",         # 397
+    "test_window_moe_benchmark.py",     # 372
+    "test_latent_moe_benchmark.py",     # 284
+    "test_sparse_decoder.py",           # 279
+    "test_ssm_moe_controls.py",         # 274
+    "test_hybrid_decoder_ssm.py",       # 254
+    "test_flash_attention.py",          # 243
+    "test_ssm_moe_benchmark.py",        # 241
+    "test_hybrid_moe_controls.py",      # 223
+    "test_hybrid_decoder_window.py",    # 201
+    "test_main_path.py",                # 198
+    "test_sparse_moe_controls.py",      # 191
+    "test_llama.py",                    # 181
+    "test_flash_window.py",             # 175
+    "test_ssd.py",                      # 174
+    "test_moe.py",                      # 164
+)
+
+
+def _start_rank(item):
+    name = item.nodeid.split("::")[0].rsplit("/", 1)[-1]
+    return (_SLOWEST_FIRST.index(name) if name in _SLOWEST_FIRST
+            else len(_SLOWEST_FIRST))
+
+
+def pytest_configure(config):
+    # the order above, not the count of tests, decides which file starts
+    # first (xdist reads this on the controller; without xdist it is unused)
+    config.option.loadscopereorder = False
+
+
+# ---------------------------------------------------------------------------
+# One time limit for every test. The whole run has a limit of its own, so a
+# test that hangs would take the run's; this one fails the test instead. It
+# counts from the start of the test's setup, so the fixtures it is the first
+# to use are in it, and it is armed in each phase alone, so that it never
+# fires while pytest writes a report. A test that sets its own SIGALRM
+# handler puts this one back when it ends.
+# ---------------------------------------------------------------------------
+
+#: seconds a test may take, its setup and teardown included
+TEST_LIMIT_S = 300
+#: the tests that may take longer, and how long (seconds measured under six
+#: xdist workers on an 8-core host). The controls of the model cells: the
+#: first test's setup is one run of the cell, then the harness's comparison
+#: compiled again for each planted fault (``benchmark/harness/checks.py``),
+#: up to 640 s. A rehearsal of a cell: two runs, each compiling the step and
+#: the reference check, up to 209 s. The train steps compiled for a
+#: described v5e at published widths, up to 222 s
+_LONGER_LIMITS_S = {"_moe_controls": 900,
+                    "::test_the_cell_rehearses_at_a_toy_size": 600,
+                    "test_compile_for_v5e.py": 600}
+
+
+@contextlib.contextmanager
+def _time_limit(item):
+    limit = next((s for pat, s in _LONGER_LIMITS_S.items()
+                  if pat in item.nodeid), TEST_LIMIT_S)
+    if not hasattr(item, "_limit_deadline"):
+        item._limit_deadline = time.monotonic() + limit
+
+    def expired(signum, frame):
+        raise TimeoutError(f"{item.nodeid} ran over its {limit}-s limit")
+
+    before = signal.signal(signal.SIGALRM, expired)
+    signal.alarm(max(1, math.ceil(item._limit_deadline - time.monotonic())))
+    try:
+        yield
+    finally:
+        signal.alarm(0)
+        signal.signal(signal.SIGALRM, before)
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_setup(item):
+    with _time_limit(item):
+        yield
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_call(item):
+    with _time_limit(item):
+        yield
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_teardown(item):
+    with _time_limit(item):
+        yield
 
 
 @pytest.fixture(autouse=True)

@@ -182,7 +182,6 @@ def test_drain_evidence_roundtrip_and_classification(tmp_path):
 # -- drill 1: SIGKILL mid-checkpoint-finalize --------------------------------
 
 
-@pytest.mark.slow
 def test_kill_mid_finalize_recovers_from_verified_step(tmp_path):
     """THE acceptance drill: a worker dies mid-checkpoint-finalize leaving a
     partial latest step (torn bytes, manifest already committed); the
@@ -279,7 +278,6 @@ def test_restore_failure_without_fallback_burns_restarts(tmp_path):
 # -- drill 3: hang -----------------------------------------------------------
 
 
-@pytest.mark.slow
 def test_hang_is_killed_classified_and_relaunched(tmp_path):
     """DLS_FAULT=hang@8: attempt 0 stops progressing mid-run; the watchdog
     kills it, the attempt is classified 'hang', and the relaunch (fault
@@ -306,7 +304,6 @@ def test_hang_is_killed_classified_and_relaunched(tmp_path):
 # -- drill 3b: crash + dlstatus — the run is explainable from its dir alone --
 
 
-@pytest.mark.slow
 def test_crash_drill_dlstatus_reports_attempts_and_goodput(tmp_path):
     """ISSUE 2 acceptance: after a supervised DLS_FAULT=crash run,
     ``dlstatus <workdir>`` reports the attempt timeline, the recovery
@@ -370,7 +367,6 @@ def _losses_by_step(workdir, *, after_ts=None):
     return out
 
 
-@pytest.mark.slow
 def test_die_host_shrinks_gang_and_training_continues(tmp_path):
     """THE elastic acceptance drill: DLS_FAULT=die_host@12 kills host 1 of a
     2-host gang mid-run and keeps it dead across attempts. After 2
@@ -462,7 +458,6 @@ def test_die_host_shrinks_gang_and_training_continues(tmp_path):
             s, drill_losses[s], clean_losses[s])
 
 
-@pytest.mark.slow
 def test_sigterm_drains_and_continues_from_current_step(tmp_path):
     """THE graceful-preemption drill (ISSUE 16): DLS_FAULT=sigterm@9 is a
     preemption NOTICE for host 1 of a 2-host gang. The doomed rank drains
@@ -558,7 +553,6 @@ def test_sigterm_drains_and_continues_from_current_step(tmp_path):
             s, drill_losses[s], clean_losses[s])
 
 
-@pytest.mark.slow
 def test_die_host_real_gang_reshards_onto_survivor(tmp_path):
     """The same drill over a REAL jax.distributed gang (2 processes sharing
     one DP mesh): host 1's rank dies at step 12 and stays dead; the shrunk
@@ -616,7 +610,6 @@ def _mnist_trainer(checkpointer=None, seed=1):
     return t, ds
 
 
-@pytest.mark.slow
 def test_nan_spike_skip_policy_finishes_finite(tmp_path, monkeypatch):
     """Acceptance: fit(on_nonfinite='skip') + DLS_FAULT=nan@N finishes with
     finite final metrics and reports the skipped-step count in its summary;
@@ -640,7 +633,6 @@ def test_nan_spike_skip_policy_finishes_finite(tmp_path, monkeypatch):
         _recovery_events(tmp_path)
 
 
-@pytest.mark.slow
 def test_nan_every_step_exhausts_skip_budget(monkeypatch):
     """Persistent divergence must not masquerade as progress: a loss that is
     non-finite from init (lr=inf blows up step 1 and never recovers) has to
@@ -667,7 +659,6 @@ def test_nan_every_step_exhausts_skip_budget(monkeypatch):
               on_nonfinite="skip", nonfinite_budget=3)
 
 
-@pytest.mark.slow
 def test_nan_spike_rollback_policy(tmp_path, monkeypatch):
     """fit(on_nonfinite='rollback'): the model rewinds to the last verified
     checkpoint while the data stream keeps moving, so the poisoned window is
@@ -699,7 +690,6 @@ def test_nan_spike_rollback_policy(tmp_path, monkeypatch):
                for e in recov), recov
 
 
-@pytest.mark.slow
 def test_rollback_walks_past_nan_checkpoints(tmp_path, monkeypatch):
     """Checkpoint cadence finer than the detection window: the newest
     byte-verified checkpoints hold NaN params (divergence was saved before a

@@ -752,8 +752,10 @@ def test_flash_takes_a_value_head_narrower_than_the_query_and_key(
     assert float(jnp.abs(other - want).max()) > 1e-3
     w = jnp.asarray(np.random.default_rng(1).normal(size=got.shape),
                     jnp.float32)
-    gf = jax.grad(lambda *a: jnp.sum(flash(*a) * w), argnums=(0, 1, 2))(q, k, v)
-    gd = jax.grad(lambda *a: jnp.sum(dense(*a) * w), argnums=(0, 1, 2))(q, k, v)
+    gf = jax.jit(jax.grad(lambda *a: jnp.sum(flash(*a) * w),
+                          argnums=(0, 1, 2)))(q, k, v)
+    gd = jax.jit(jax.grad(lambda *a: jnp.sum(dense(*a) * w),
+                          argnums=(0, 1, 2)))(q, k, v)
     for a, b_, like in zip(gf, gd, (q, k, v)):
         assert a.shape == like.shape
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),

@@ -1,11 +1,13 @@
-"""The hybrid decoder (``models/hybrid_decoder.py``) at a toy size on the CPU:
-the program against the benchmark's plain reference
+"""The hybrid decoder (``models/hybrid_decoder.py``) at a toy size on the CPU,
+its LFM2 kinds: the program against the benchmark's plain reference
 (``benchmark/reference/lfm2_24b_a2b.py``) for the loss and every gradient
 leaf, both operators across document boundaries (XLA paths, and the Pallas
 kernels interpreted), both feed-forwards, the sigmoid router's selection on
 score + bias, the bias through ``make_train_step`` under scan, remat and
 gradient accumulation, the eight ranks' shares against the uncut layer, and
-``packed_token_windows(segment_ids=True)``."""
+``packed_token_windows(segment_ids=True)``. Each other layer kind has a file
+of its own that takes this file's helpers (``test_hybrid_decoder_ssm.py``,
+``test_hybrid_decoder_window.py``), so that the kinds run side by side."""
 
 import os
 import sys
@@ -71,7 +73,7 @@ def _batch(seq=128, rows=2, documents=5, seed=0):
 
 def _init(cfg, batch, bias_scale=0.05, seed=0):
     model = HybridDecoderLM(cfg)
-    variables = model.init(jax.random.PRNGKey(seed), batch)
+    variables = jax.jit(model.init)(jax.random.PRNGKey(seed), batch)
     rng = np.random.default_rng(seed + 1)
     bias = jax.tree.map(
         lambda a: jnp.asarray(rng.normal(size=a.shape) * bias_scale,
@@ -136,11 +138,11 @@ def test_program_equals_reference_for_loss_and_every_gradient_leaf(
     assert bool(cfg.layout()[3]) == (layout == "with_a_tail")
     batch = _batch()
     model, params, mutable = _init(cfg, batch)
-    got, got_grad = jax.value_and_grad(
-        lambda p: _program_loss(model, p, mutable, batch))(params)
-    want, want_grad = jax.value_and_grad(
+    got, got_grad = jax.jit(jax.value_and_grad(
+        lambda p: _program_loss(model, p, mutable, batch)))(params)
+    want, want_grad = jax.jit(jax.value_and_grad(
         lambda p: reference.training_loss(p, mutable, batch,
-                                          _reference_cfg(cfg)))(params)
+                                          _reference_cfg(cfg))))(params)
     assert abs(float(got) - float(want)) < 2e-5
     errors = _leaf_errors(got_grad, want_grad)
     assert len(errors) > 30
@@ -167,19 +169,20 @@ def test_positions_that_do_not_restart_give_the_same_attention(monkeypatch):
     cfg = HybridDecoderConfig.tiny(experts_held=(2, 4))
     batch = _batch()
     model, params, mutable = _init(cfg, batch)
-    sound = _program_loss(model, params, mutable, batch)
+    loss = lambda b: jax.jit(  # traced again at each call, after the patch
+        lambda p: _program_loss(model, p, mutable, b))(params)
+    sound = loss(batch)
     seg = jnp.asarray([[0, 0, 0, 1, 1, 2, 2, 2]])
     assert document_positions(seg).tolist() == [[0, 1, 2, 0, 1, 0, 1, 2]]
     monkeypatch.setattr(
         hybrid_decoder, "document_positions",
         lambda seg: jnp.broadcast_to(jnp.arange(seg.shape[1]), seg.shape))
-    other = _program_loss(model, params, mutable, batch)
+    other = loss(batch)
     assert abs(float(other) - float(sound)) < 1e-5
     # ... while a boundary that is not there is another function
     monkeypatch.undo()
     one = {**batch, "segment_ids": jnp.zeros_like(batch["segment_ids"])}
-    assert abs(float(_program_loss(model, params, mutable, one))
-               - float(sound)) > 1e-4
+    assert abs(float(loss(one)) - float(sound)) > 1e-4
 
 
 def test_the_kernels_interpreted_give_the_models_numbers(monkeypatch):
@@ -285,7 +288,7 @@ def test_the_bias_moves_only_when_its_collection_is_mutable():
 
 def _train_state(cfg, batch, tx):
     model = HybridDecoderLM(cfg)
-    variables = dict(model.init(jax.random.PRNGKey(0), batch))
+    variables = dict(jax.jit(model.init)(jax.random.PRNGKey(0), batch))
     params = variables.pop("params")
     return model, step_lib.TrainState.create(
         params=params, opt_state=tx.init(params), mutable=variables)
@@ -472,286 +475,3 @@ def test_token_windows_carry_the_document_of_every_position(tmp_path,
                                     segment_ids=True).collect()
     assert all(a["segment_ids"].tobytes() == b["segment_ids"].tobytes()
                for a, b in zip(got, one))
-
-
-# -- blocks of ONE sublayer: Mamba-2, experts, attention without positions ----
-
-@pytest.fixture(scope="module")
-def ssm_reference():
-    ref = _load("reference/nemotron3_nano_30b_a3b.py")
-    # blocks small enough that a toy window has several of each kind
-    ref.ROWS, ref.QUERIES, ref.STATES = 48, 32, 12
-    return ref
-
-
-def _ssm_reference_cfg(cfg: HybridDecoderConfig) -> dict:
-    """What the reference reads of a configuration file, for ``cfg``."""
-    from distributeddeeplearningspark_tpu.models import hybrid_decoder as hd
-
-    letters = {hd.MAMBA: "M", hd.EXPERTS: "E", hd.BARE_ATTENTION: "*"}
-    first, count = cfg.experts_held or (0, cfg.num_experts)
-    return {"hybrid_override_pattern": "".join(
-                letters[k] for k in cfg.layer_types),
-            "layer_norm_epsilon": cfg.rms_eps,
-            "mamba_num_heads": cfg.ssm_heads,
-            "mamba_head_dim": cfg.ssm_head_dim, "n_groups": cfg.ssm_groups,
-            "ssm_state_size": cfg.ssm_state_size,
-            "num_attention_heads": cfg.num_heads,
-            "num_key_value_heads": cfg.num_kv_heads,
-            "head_dim": cfg.head_dim, "experts_held": [first, count],
-            "num_experts_per_tok": cfg.experts_per_token,
-            "norm_topk_prob": cfg.norm_topk_prob,
-            "routed_scaling_factor": cfg.routed_scaling_factor,
-            "train_router": cfg.train_router,
-            "router_width": cfg.num_experts, "check": {}}
-
-
-@pytest.mark.parametrize("layout, train_router", [
-    ("one_period", True), ("periods_and_a_tail", False)])
-def test_the_ssm_preset_equals_its_reference_for_loss_and_every_leaf(
-        ssm_reference, layout, train_router):
-    """``tiny_ssm`` (the pattern ``M E M E M * E``: the chunked scan with its
-    resets, the ungated convolution, the gated group norm, attention without
-    positions, relu² experts beside a wider shared one, experts 2-5 of 8
-    held) against the sequential recurrence of
-    ``benchmark/reference/nemotron3_nano_30b_a3b.py``; and two scanned
-    periods with a trailing layer. 100 positions fill no whole chunk of
-    16."""
-    from distributeddeeplearningspark_tpu.models import hybrid_decoder as hd
-
-    kinds = {} if layout == "one_period" else {"layer_types": (
-        hd.MAMBA, hd.EXPERTS, hd.BARE_ATTENTION) * 2 + (hd.MAMBA,)}
-    cfg = HybridDecoderConfig.tiny_ssm(experts_held=(2, 4),
-                                       train_router=train_router, **kinds)
-    lead, period, whole, trail = cfg.layout()
-    assert lead == () and (len(period), whole, len(trail)) == (
-        (7, 1, 0) if layout == "one_period" else (3, 2, 1))
-    batch = _batch(seq=100)
-    model, params, mutable = _init(cfg, batch)
-    rng = np.random.default_rng(5)     # off a zero bias and a unit D
-    params = jax.tree.map(lambda a: a + jnp.asarray(
-        0.05 * rng.normal(size=a.shape), a.dtype), params)
-    got, got_grad = jax.jit(jax.value_and_grad(
-        lambda p: _program_loss(model, p, mutable, batch)))(params)
-    want, want_grad = jax.jit(jax.value_and_grad(
-        lambda p: ssm_reference.training_loss(
-            p, mutable, batch, _ssm_reference_cfg(cfg))))(params)
-    assert abs(float(got) - float(want)) < 2e-5
-    errors = _leaf_errors(got_grad, want_grad)
-    assert len(errors) > 30
-    assert max(errors.values()) < 2e-3, max(errors.items(), key=lambda kv: kv[1])
-    leaves = {k: float(jnp.abs(v).max()) for k, v in zip(
-        errors, jax.tree.leaves(want_grad))}
-    for name in ("A_log", "'D'", "dt_bias", "conv_taps", "conv_bias",
-                 "in_proj", "out_proj", "mixer']['norm", "wq", "w_up",
-                 "shared_down", "lm_head", "embedding"):
-        assert any(name in k and v > 0 for k, v in leaves.items()), name
-    routers = [v for k, v in leaves.items() if "router" in k]
-    assert routers and all((v > 0) == train_router for v in routers)
-
-
-def test_a_block_of_one_sublayer_has_one_norm_and_the_siblings_keep_theirs():
-    from distributeddeeplearningspark_tpu.models import hybrid_decoder as hd
-    import hashlib
-
-    cfg = HybridDecoderConfig.tiny_ssm()
-    batch = _batch(seq=64)
-    shapes = jax.eval_shape(HybridDecoderLM(cfg).init, jax.random.PRNGKey(0),
-                            batch)
-    period = shapes["params"]["periods"]
-    inside = {hd.MAMBA: "mixer", hd.EXPERTS: "moe",
-              hd.BARE_ATTENTION: "self_attn"}
-    for j, kind in enumerate(cfg.layer_types):
-        assert set(period[f"layer_{j}"]) == {"norm", inside[kind]}, j
-    assert set(period["layer_0"]["mixer"]) == {
-        "in_proj", "conv_taps", "conv_bias", "A_log", "D", "dt_bias", "norm",
-        "out_proj"}
-    # inner 32 = 4 heads of 8; xBC 32 + 2 groups x 16 x 2 = 96; dt 4
-    assert period["layer_0"]["mixer"]["in_proj"]["kernel"].shape == (
-        1, 128, 32 + 96 + 4)
-    assert period["layer_0"]["mixer"]["conv_taps"].shape == (1, 96, 4)
-    assert set(period["layer_5"]["self_attn"]) == {"wq", "wk", "wv", "wo"}
-    assert "w_gate" not in period["layer_1"]["moe"]
-    # only expert blocks keep a bias
-    assert set(shapes[BIAS_COLLECTION]["periods"]) == {
-        "layer_1", "layer_3", "layer_6"}
-    with pytest.raises(ValueError, match="one sublayer"):
-        HybridDecoderConfig.tiny_ssm(num_dense_layers=1)
-
-    # the two sibling presets and the published depth: every leaf's path,
-    # shape and dtype as in the tree before this model came (digests taken
-    # on the parent commit)
-    def digest(cfg):
-        shapes = jax.eval_shape(
-            HybridDecoderLM(cfg).init, jax.random.PRNGKey(0),
-            {"input_ids": jnp.zeros((1, 64), jnp.int32)})
-        lines = sorted(
-            f"{jax.tree_util.keystr(p)} {a.shape} {a.dtype}"
-            for p, a in jax.tree_util.tree_leaves_with_path(shapes))
-        return hashlib.sha256("\n".join(lines).encode()).hexdigest()[:16]
-
-    assert digest(HybridDecoderConfig.tiny()) == "dd76e23b050a24f8"
-    assert digest(HybridDecoderConfig.tiny_latent()) == "8e2a2fa29f88ec8c"
-    assert digest(HybridDecoderConfig()) == "43047667391444dc"
-    # (taken on the parent of the sliding-window layers, PR 38)
-    assert digest(HybridDecoderConfig.tiny_ssm()) == "0845a5a91455910b"
-
-
-def test_a_packed_row_equals_its_documents_through_scan_conv_and_attention():
-    """The whole ``tiny_ssm`` model: the hidden states of a packed row are
-    those of its documents run alone (the experts route a token by itself,
-    so they mix nothing either); and the two state-space counters reach the
-    step's metrics through the loss."""
-    cfg = HybridDecoderConfig.tiny_ssm()
-    rng = np.random.default_rng(2)
-    ids = jnp.asarray(rng.integers(1, 256, (1, 64)), jnp.int32)
-    cuts = (0, 21, 32, 64)               # inside a chunk of 16, on an edge
-    seg = jnp.asarray(np.searchsorted(np.asarray(cuts[1:-1]), np.arange(64),
-                                      side="right"), jnp.int32)[None]
-    batch = {"input_ids": ids, "segment_ids": seg}
-    model = HybridDecoderLM(cfg)
-    variables = model.init(jax.random.PRNGKey(1), batch)
-    out = model.apply(variables, batch)
-    for lo, hi in zip(cuts, cuts[1:]):
-        alone = model.apply(variables, {"input_ids": ids[:, lo:hi]})
-        np.testing.assert_allclose(out["hidden"][:, lo:hi], alone["hidden"],
-                                   rtol=2e-4, atol=2e-4)
-    # 2 boundaries in 4 chunks of 16: positions 21 and 32
-    assert float(out["ssm_chunks_reset_share"]) == pytest.approx(2 / 4)
-    assert float(out["ssm_state_abs_max"]) > 0
-    _, metrics = losses.hybrid_moe_lm(out, batch)
-    assert {"ssm_chunks_reset_share", "ssm_state_abs_max"} <= set(metrics)
-    # a model without state-space layers has neither
-    plain = HybridDecoderLM(HybridDecoderConfig.tiny())
-    plain_out = plain.apply(plain.init(jax.random.PRNGKey(0), batch), batch)
-    assert "ssm_state_abs_max" not in plain_out
-    assert set(COUNTERS) <= set(plain_out)
-
-
-# -- sliding-window layers among full ones (Laguna-XS.2's shape) -------------
-
-@pytest.fixture(scope="module")
-def window_reference():
-    ref = _load("reference/laguna_xs2.py")
-    ref.ROWS, ref.QUERIES = 40, 24     # blocks that do not divide the row
-    return ref
-
-
-def _window_reference_cfg(cfg: HybridDecoderConfig) -> dict:
-    """What ``benchmark/reference/laguna_xs2.py`` reads of a configuration
-    file, for ``cfg``."""
-    from distributeddeeplearningspark_tpu.models import hybrid_decoder as hd
-
-    first, count = cfg.experts_held or (0, cfg.num_experts)
-    kinds = {hd.ATTENTION: "full_attention", hd.SLIDING: "sliding_attention"}
-    return {
-        "layer_types": [kinds[k] for k in cfg.layer_types],
-        "mlp_layer_types": ["dense" if i < cfg.num_dense_layers else "sparse"
-                            for i in range(len(cfg.layer_types))],
-        "num_attention_heads_per_layer": [
-            cfg.sliding_num_heads if k == hd.SLIDING else cfg.num_heads
-            for k in cfg.layer_types],
-        "num_key_value_heads": cfg.num_kv_heads, "head_dim": cfg.head_dim,
-        "rms_norm_eps": cfg.rms_eps, "sliding_window": cfg.sliding_window,
-        "rope_parameters": {
-            "full_attention": {
-                "rope_type": "yarn", "rope_theta": cfg.rope_theta,
-                "factor": cfg.yarn_factor,
-                "original_max_position_embeddings":
-                    cfg.yarn_original_max_position,
-                "beta_fast": cfg.yarn_beta_fast,
-                "beta_slow": cfg.yarn_beta_slow,
-                "attention_factor": cfg.rope_attention_factor,
-                "partial_rotary_factor": cfg.partial_rotary_factor},
-            "sliding_attention": {
-                "rope_type": "default", "rope_theta": cfg.sliding_rope_theta,
-                "partial_rotary_factor": 1.0}},
-        "experts_held": [first, count], "router_width": cfg.num_experts,
-        "num_experts_per_tok": cfg.experts_per_token,
-        "moe_routed_scaling_factor": cfg.routed_scaling_factor,
-        "train_router": cfg.train_router,
-        "assumed_values": {"norm_topk_prob": cfg.norm_topk_prob,
-                           "router_aux_loss_coef": cfg.router_aux_loss_coef},
-        "check": {}}
-
-
-@pytest.mark.parametrize("experts_held, train_router", [
-    ((2, 4), True), (None, False)])
-def test_the_window_preset_equals_its_reference_for_loss_and_every_leaf(
-        window_reference, experts_held, train_router):
-    """``tiny_window`` (a dense full-attention layer, then sliding, sliding,
-    sliding, full with experts: the band of 16 at 8 query heads on 2 with
-    theta 1e4, the full layers at 6 on 2 with YaRN on half the head and its
-    factor, per-head gates, no q/k norm, a softmax router with Switch's
-    term beside an ungated shared expert, x2.5) against
-    ``benchmark/reference/laguna_xs2.py``: the loss and every leaf's
-    gradient, documents inside the band's reach."""
-    cfg = HybridDecoderConfig.tiny_window(experts_held=experts_held,
-                                          train_router=train_router)
-    assert cfg.layout()[1:3] == (cfg.layer_types[1:], 1)
-    batch = _batch(seq=100)
-    model = HybridDecoderLM(cfg)
-    variables = model.init(jax.random.PRNGKey(0), batch)
-    assert set(variables) == {"params"}              # no bias collection
-    rng = np.random.default_rng(5)
-    params = jax.tree.map(lambda a: a + jnp.asarray(
-        0.05 * rng.normal(size=a.shape), a.dtype), variables["params"])
-    got, got_grad = jax.jit(jax.value_and_grad(
-        lambda p: _program_loss(model, p, {}, batch)))(params)
-    want, want_grad = jax.jit(jax.value_and_grad(
-        lambda p: window_reference.training_loss(
-            p, batch, _window_reference_cfg(cfg))))(params)
-    assert abs(float(got) - float(want)) < 2e-5
-    errors = _leaf_errors(got_grad, want_grad)
-    assert len(errors) > 50
-    assert max(errors.values()) < 2e-3, max(errors.items(), key=lambda kv: kv[1])
-    leaves = {k: float(jnp.abs(v).max()) for k, v in zip(
-        errors, jax.tree.leaves(want_grad))}
-    for name in ("gate", "wq", "wk", "wo", "w_gate", "shared_gate", "mlp",
-                 "lm_head", "embedding"):
-        assert any(name in k and v > 0 for k, v in leaves.items()), name
-    # Switch's term reaches the router whether its weights carry the
-    # routing's gradient (train_router) or not
-    routers = [v for k, v in leaves.items() if "router" in k]
-    assert len(routers) == 4 and all(v > 0 for v in routers)
-
-
-def test_a_packed_row_equals_its_documents_through_the_band_and_full_layers():
-    """The whole ``tiny_window`` model: the hidden states of a packed row
-    are those of its documents run alone; the band's two counters reach the
-    step's metrics through the loss with the Switch term; a model without
-    sliding layers has neither."""
-    from distributeddeeplearningspark_tpu.models import hybrid_decoder as hd
-
-    cfg = HybridDecoderConfig.tiny_window()
-    rng = np.random.default_rng(2)
-    ids = jnp.asarray(rng.integers(1, 256, (1, 64)), jnp.int32)
-    cuts = (0, 21, 32, 64)               # inside the band of 16, on an edge
-    seg = jnp.asarray(np.searchsorted(np.asarray(cuts[1:-1]), np.arange(64),
-                                      side="right"), jnp.int32)[None]
-    batch = {"input_ids": ids, "segment_ids": seg}
-    model = HybridDecoderLM(cfg)
-    variables = model.init(jax.random.PRNGKey(1), batch)
-    out = model.apply(variables, batch)
-    for lo, hi in zip(cuts, cuts[1:]):
-        alone = model.apply(variables, {"input_ids": ids[:, lo:hi]})
-        np.testing.assert_allclose(out["hidden"][:, lo:hi], alone["hidden"],
-                                   rtol=2e-4, atol=2e-4)
-    # in-document keys inside the band, over S x 16: documents of 21, 11, 32
-    want = sum(sum(min(t + 1, 16) for t in range(n)) for n in (21, 11, 32))
-    assert float(out["window_pairs_share"]) == pytest.approx(want / (64 * 16))
-    assert float(out["window_blocks_walked_share"]) == 1.0  # one block of 64
-    loss, metrics = losses.hybrid_moe_lm(out, batch)
-    assert set(hd.WINDOW_COUNTERS) <= set(metrics)
-    assert float(metrics["loss"]) == pytest.approx(
-        float(metrics["lm_loss"]) + float(metrics["moe_aux"]))
-    # 4 expert layers' Switch terms, each near 1, at 0.001
-    assert 0.003 < float(metrics["moe_aux"]) < 0.008
-    plain = HybridDecoderLM(HybridDecoderConfig.tiny())
-    plain_out = plain.apply(plain.init(jax.random.PRNGKey(0), batch), batch)
-    assert not set(hd.WINDOW_COUNTERS) & set(plain_out)
-    assert "moe_aux" not in plain_out
-    with pytest.raises(ValueError, match="sliding_window"):
-        HybridDecoderConfig.tiny_window(sliding_window=None)
-    with pytest.raises(ValueError, match="bias"):
-        HybridDecoderConfig.tiny_window(use_expert_bias=True)

@@ -1,20 +1,17 @@
-"""The analytic counts, the configuration file, the per-layer readers and a
-CPU rehearsal of the cell ``lfm2_24b_a2b.fit_seg32k`` at a toy size, its
-planted faults among them (as ``tests/test_sparse_moe_benchmark.py`` does for
-the cell before it). A CPU run checks control flow, counts and agreement with
-the reference; it yields no time, rate or utilisation."""
+"""The analytic counts, the configuration file, the per-layer readers and a CPU
+rehearsal of the cell ``lfm2_24b_a2b.fit_seg32k`` at a toy size; its planted
+faults are in ``tests/test_hybrid_moe_controls.py``, which takes ``TOY`` from
+here. A CPU run checks control flow, counts and agreement with the reference;
+it yields no time, rate or utilisation."""
 
 import json
 import os
-import shutil
-import sys
 import time
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+import _cells as cells
+from _cells import ROOT, ev, json_of, lap, load, reader, tree  # noqa: F401
 
 CELL = "lfm2_24b_a2b.fit_seg32k"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
@@ -24,22 +21,12 @@ NEW_METRICS = ("shortconv_ms_per_step", "shortconv_roofline",
                "attn_pairs_share", "attn_blocks_walked_share")
 
 
-def _load(path):
-    from benchmark.harness import runner
-    return runner.load_module(path)
-
-
-def _json(*rel):
-    with open(os.path.join(ROOT, *rel)) as f:
-        return json.load(f)
-
-
 def test_operations_per_token_count_required_work():
     from benchmark.harness import flops_hybrid
 
-    cfg = _json("benchmark", "configs", "lfm2_24b_a2b.json")
-    traffic = _json("benchmark", "traffic", "fit_seg32k.json")
-    mod = _load(os.path.join(ROOT, "benchmark", "configs", "lfm2_24b_a2b.py"))
+    cfg = json_of("benchmark", "configs", "lfm2_24b_a2b.json")
+    traffic = json_of("benchmark", "traffic", "fit_seg32k.json")
+    mod = load(os.path.join(ROOT, "benchmark", "configs", "lfm2_24b_a2b.py"))
     share = flops_hybrid.in_document_pairs_share(traffic)
     assert 0.42 < share < 0.45           # the issue's own count: 0.43
     assert share == flops_hybrid.in_document_pairs_share(dict(traffic))
@@ -74,9 +61,9 @@ def test_operations_per_token_count_required_work():
 
 
 def test_configuration_file_states_the_catalog_and_its_cuts():
-    bench = _json("BENCHMARK.json")
+    bench = json_of("BENCHMARK.json")
     conf = next(c for c in bench["configs"] if c["name"] == "lfm2_24b_a2b")
-    cfg = _json(conf["file"])
+    cfg = json_of(conf["file"])
     assert sorted(conf["reduced"]) == [
         "layer_types", "num_dense_layers", "num_experts", "num_hidden_layers",
         "vocab_size"]
@@ -115,15 +102,6 @@ def test_configuration_file_states_the_catalog_and_its_cuts():
     assert cfg["layer_types"] == row["config"]["layer_types"][1:6]
 
 
-def _copy_of_the_benchmark(root):
-    root.mkdir()
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
-    shutil.copytree(os.path.join(ROOT, "benchmark"), root / "benchmark",
-                    ignore=shutil.ignore_patterns(".cache", "out",
-                                                  "__pycache__", "tests"))
-    return root
-
-
 TOY = {
     "configs/lfm2_24b_a2b.json": dict(
         vocab_size=256, hidden_size=128, intermediate_size=192,
@@ -132,13 +110,12 @@ TOY = {
         router_width=8, num_experts=4, experts_held=[2, 4],
         num_experts_per_tok=2, moe_intermediate_size=64,
         compute_dtype="float32",
-        # ten times the cell's rate. The harness warms up for five seconds
-        # and measures one, so the number of steps that move the bias is the
-        # machine's speed: 170 here alone (|b| 0.17, ``selection_without_bias``
-        # moves ``experts_energy`` by 0.19), about 8 on the driver's machine
-        # under six test workers (0.0089 against the 0.01 asked of a fault).
-        # The term grows by 1.1 a unit of |b|: at 0.01 a step, 8 steps give
-        # 0.09, and a fast machine stops near |b| 0.3 (the load is even)
+        # ten times the cell's rate. The controls' run is 242 steps
+        # (``_cells.STEP_S``), each of which moves the bias; on the wall
+        # clock it was the machine's speed, 8 steps on a loaded one (0.0089
+        # against the 0.01 asked of a fault). The term grows by 1.1 a unit of
+        # |b|: at 0.01 a step, 8 steps give 0.09, and the bias stops near
+        # |b| 0.3 (the load is even)
         assumed_values={"router_bias_update_rate": 0.01},
         check={"examples": 1, "loss_abs_tol": 1e-4, "grad_rel_tol": 1e-3,
                "term_weights": {"experts_energy": 1.0, "boundary_energy": 1.0,
@@ -151,34 +128,17 @@ TOY = {
 }
 
 
-def _toy(root, keep_check=False):
-    for rel, patch in TOY.items():
-        path = root / "benchmark" / rel
-        real = json.loads(path.read_text())
-        if keep_check:
-            patch = {k: v for k, v in patch.items() if k != "check"}
-        path.write_text(json.dumps({**real, **patch}))
-
-
-@pytest.fixture
-def tree(tmp_path, monkeypatch):
-    from benchmark.harness import seedcache
-
-    root = _copy_of_the_benchmark(tmp_path / "checkout")
-    monkeypatch.setattr(seedcache, "ROOT", str(root / "benchmark" / ".cache"))
-    # ``runner.measure`` sets it for its process and never takes it back:
-    # set here first, it is restored when the test ends
-    monkeypatch.setenv("DLS_TELEMETRY_DIR", str(tmp_path / "telemetry"))
-    return root
-
-
 def test_the_cell_rehearses_at_a_toy_size(tree):
     from benchmark.harness import runner
 
-    _toy(tree)
-    r = runner.measure(str(tree), CELL, seed=2 ** 31 + 7, seconds=1.0,
-                       trace=True, t_process=time.perf_counter(),
-                       master="local[1]")
+    with cells.run_in(tree, step_s=cells.REHEARSAL_STEP_S):
+        r = runner.measure(str(tree), CELL, seed=2 ** 31 + 7, seconds=1.0,
+                           trace=True, t_process=time.perf_counter(),
+                           master="local[1]")
+        again = runner.measure(str(tree), CELL, seed=2 ** 31 + 7,
+                               seconds=0.5, trace=False,
+                               t_process=time.perf_counter(),
+                               master="local[1]")
     assert r["correct"], r["facts"]
     assert r["failed"] == 0 and r["attempted"] > 0
     ref = r["facts"]["reference"]
@@ -198,9 +158,6 @@ def test_the_cell_rehearses_at_a_toy_size(tree):
     # a CPU run has no device plane: the device-trace readers return nothing
     for name in NEW_METRICS[:5] + ("device_step_ms", "mfu"):
         assert name not in r["metrics"], name
-    again = runner.measure(str(tree), CELL, seed=2 ** 31 + 7, seconds=0.5,
-                           trace=False, t_process=time.perf_counter(),
-                           master="local[1]")
     assert again["facts"]["feed"]["seed_cache_hit"] is True
     assert set(again["metrics"]) == {"throughput", "setup_s"}
 
@@ -216,9 +173,8 @@ def test_the_timed_forward_gives_the_terms_the_reference_gives(tree):
 
     from distributeddeeplearningspark_tpu.models.moe import BIAS_COLLECTION
 
-    _toy(tree)
-    mod = _load(str(tree / "benchmark" / "configs" / "lfm2_24b_a2b.py"))
-    ref = _load(str(tree / "benchmark" / "reference" / "lfm2_24b_a2b.py"))
+    mod = load(str(tree / "benchmark" / "configs" / "lfm2_24b_a2b.py"))
+    ref = load(str(tree / "benchmark" / "reference" / "lfm2_24b_a2b.py"))
     cfg = json.loads((tree / "benchmark" / "configs"
                       / "lfm2_24b_a2b.json").read_text())
     traffic = json.loads((tree / "benchmark" / "traffic"
@@ -230,13 +186,16 @@ def test_the_timed_forward_gives_the_terms_the_reference_gives(tree):
                                       jnp.int32),
              "segment_ids": jnp.asarray(
                  np.sort(rng.integers(0, 4, (2, 96)), axis=1), jnp.int32)}
-    variables = dict(built["model"].init(jax.random.PRNGKey(0), batch))
+    init = jax.jit(built["model"].init)
+    variables = dict(init(jax.random.PRNGKey(0), batch))
     params = variables.pop("params")
     mutable = {BIAS_COLLECTION: jax.tree.map(
         lambda a: jnp.asarray(rng.normal(size=a.shape) * 0.05, jnp.float32),
         variables[BIAS_COLLECTION])}
-    got = mod.parts(built["model"], built["loss"], params, mutable, batch)
-    want = ref.parts(params, mutable, batch, cfg)
+    got = jax.jit(lambda p, m, b: mod.parts(built["model"], built["loss"],
+                                            p, m, b))(params, mutable, batch)
+    want = jax.jit(lambda p, m, b: ref.parts(p, m, b, cfg))(params, mutable,
+                                                            batch)
     assert set(got) - set(want) == {"rows_held_share"}
     assert set(cfg["check"]["term_weights"]) == set(want) - {"loss"}
     for name, value in want.items():
@@ -256,176 +215,59 @@ def test_a_program_without_segment_ids_is_refused_with_a_message(
     from benchmark.harness import runner
     from distributeddeeplearningspark_tpu.data import text
 
-    _toy(tree)
     old = text.packed_token_windows
     monkeypatch.setattr(
         text, "packed_token_windows",
         lambda docs, *, seq_len, eos_id=None, num_partitions=1, key="tokens":
         old(docs, seq_len=seq_len, eos_id=eos_id,
             num_partitions=num_partitions, key=key))
-    with pytest.raises(runner.Refused) as e:
+    with cells.run_in(tree), pytest.raises(runner.Refused) as e:
         runner.measure(str(tree), CELL, seed=3, seconds=0.5, trace=False,
                        t_process=time.perf_counter(), master="local[1]")
     assert "segment ids" in e.value.args[0][0]
 
 
-@pytest.fixture(scope="module")
-def controls(tmp_path_factory):
-    """``benchmark/controls/lfm2_24b_a2b.py`` at the toy size on
-    ``local[1]``, in float32 but under the cell's OWN limits: one run of the
-    cell, the harness's comparison repeated with each fault planted."""
-    from benchmark.harness import seedcache
-
-    root = _copy_of_the_benchmark(
-        tmp_path_factory.mktemp("controls") / "checkout")
-    _toy(root, keep_check=True)
-    mod = _load(str(root / "benchmark" / "controls" / "lfm2_24b_a2b.py"))
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(seedcache, "ROOT", str(root / "benchmark" / ".cache"))
-        mp.setenv("DLS_TELEMETRY_DIR", str(root / "telemetry"))  # restored
-        return mod, mod.run(2 ** 31 + 11, 1.0, mod.ALL, master="local[1]",
-                            root=str(root))
-
-
-def test_the_sound_program_is_correct_under_the_cells_own_limits(controls):
-    _, seen = controls
-    assert seen["result"]["correct"], seen["sound"]
-    assert seen["sound"]["failures"] == [] == seen["harness"]["failures"]
-    # the comparison made again is the harness's own, to the digit
-    for key in ("loss_program", "loss_reference", "grad_rel_err"):
-        assert seen["sound"][key] == pytest.approx(seen["harness"][key],
-                                                   rel=1e-6), key
-    assert seen["router_bias_abs_max"] > 0
-    # lap by lap, the share of the assignments on the experts held (4 of 8)
-    shares = [lap["moe_rows_held_share"] for lap in seen["laps"]]
-    assert len(shares) >= 2 and all(0.3 < s < 0.7 for s in shares), shares
-
-
-@pytest.mark.parametrize("fault", [
-    "taps_cross_documents", "attention_crosses_documents",
-    "selection_without_bias", "drop_assignments", "taps_gradient_lost",
-    "e4m3_weights"])
-def test_a_planted_fault_is_not_correct(controls, fault):
-    seen = controls[1][fault]
-    assert seen["correct"] is False and seen["failures"], seen
-    if fault == "taps_gradient_lost":
-        assert seen["loss_abs_err"] < 1e-5
-
-
-@pytest.mark.parametrize("fault, term", [
-    ("taps_cross_documents", "boundary_energy"),
-    ("taps_cross_documents", "boundary_probe"),
-    ("selection_without_bias", "experts_energy"),
-    ("selection_without_bias", "expert_probe"),
-    ("drop_assignments", "experts_energy"),
-    ("drop_assignments", "expert_probe")])
-def test_a_fault_moves_the_term_of_the_timed_forward_and_the_probe(
-        controls, fault, term):
-    """Each term is printed on both sides; the sound program's differ from
-    the reference's by rounding, a fault's by the fault."""
-    sound = controls[1]["sound"]["terms"][term]
-    assert abs(sound[2]) < 1e-4, sound
-    assert abs(controls[1][fault]["terms"][term][2]) > 0.01
-
-
-def test_positions_not_restarted_is_no_fault_and_the_comparison_says_so(
-        controls):
-    mod, seen = controls
-    assert "positions_not_restarted" in mod.ALL
-    assert seen["positions_not_restarted"]["correct"] is True
-
-
-def test_a_planted_fault_leaves_the_program_as_it_was():
-    from distributeddeeplearningspark_tpu.models import moe
-
-    mod = _load(os.path.join(ROOT, "benchmark", "controls",
-                             "lfm2_24b_a2b.py"))
-    from distributeddeeplearningspark_tpu.models import hybrid_decoder
-
-    planted = mod.faults(None, None, {})
-    for name, module, attribute in (
-            ("drop_assignments", moe, "_zero_past"),
-            ("selection_without_bias", moe, "_held_experts"),
-            ("taps_cross_documents", hybrid_decoder, "gated_short_conv"),
-            ("attention_crosses_documents", hybrid_decoder,
-             "dot_product_attention"),
-            ("positions_not_restarted", hybrid_decoder,
-             "document_positions")):
-        sound = getattr(module, attribute)
-        with planted[name][1]():
-            assert getattr(module, attribute) is not sound, name
-        assert getattr(module, attribute) is sound, name
-
-
-def _ctx(events, steps=2, laps=()):
-    """A traced run's context with hand-made device events (name, start ns,
-    duration ns, info)."""
-    peaks = _json("benchmark", "peaks.json")
-    mod = _load(os.path.join(ROOT, "benchmark", "configs", "lfm2_24b_a2b.py"))
-    return {"trace": {"devices": {"0": {"XLA Ops": events}}, "steps": steps,
-                      "host": {}},
-            "cfg": _json("benchmark", "configs", "lfm2_24b_a2b.json"),
-            "traffic": _json("benchmark", "traffic", "fit_seg32k.json"),
-            "peaks": peaks["TPU v5 lite"], "cell": {"config_mod": mod},
-            "facts": {}, "laps": list(laps)}
-
-
-def _ev(name, start_ms, dur_ms, op="fusion", result=""):
-    kind = name.rsplit(".", 1)[0]
-    return [name, start_ms * 1e6, dur_ms * 1e6,
-            {"kind": kind, "op": op, "result": result}]
-
-
-def _reader(name):
-    return _load(os.path.join(ROOT, "benchmark", "layer_metrics",
-                              name + ".py")).read
-
-
-def _lap(**metrics):
-    return {"step": 10, "metrics": metrics}
-
-
 def test_stage_readers_find_kernels_by_name_and_xla_stages_by_shape():
     events = [
-        _ev("while.1", 0, 100, op="while", result="(s32[], bf16[131072,2048])"),
-        _ev("shortconv_fwd.1", 0, 1, op="custom-call",
-            result="bf16[1,32768,2048]"),
-        _ev("shortconv_bwd.2", 1, 3, op="custom-call",
-            result="(bf16[1,32768,6144], f32[1,8,2048])"),
-        _ev("flash_fwd.1", 10, 20, op="custom-call"),
-        _ev("flash_bwd_dq.1", 30, 30, op="custom-call"),
-        _ev("flash_bwd_dkv.1", 60, 40, op="custom-call"),
-        _ev("ragged-dot-none.2", 100, 3, op="custom-call",
-            result="bf16[131072,1536]"),
-        _ev("fusion.9", 103, 4, result="bf16[131072,2048]"),
-        _ev("fusion.10", 107, 1, result="f32[32768,64]"),
-        _ev("fusion.12", 108, 2, result="(f32[32768,4], s32[32768,4])"),
-        _ev("fusion.11", 120, 9, result="bf16[1,32768,2048]"),  # nobody's
+        ev("while.1", 0, 100, op="while", result="(s32[], bf16[131072,2048])"),
+        ev("shortconv_fwd.1", 0, 1, op="custom-call",
+           result="bf16[1,32768,2048]"),
+        ev("shortconv_bwd.2", 1, 3, op="custom-call",
+           result="(bf16[1,32768,6144], f32[1,8,2048])"),
+        ev("flash_fwd.1", 10, 20, op="custom-call"),
+        ev("flash_bwd_dq.1", 30, 30, op="custom-call"),
+        ev("flash_bwd_dkv.1", 60, 40, op="custom-call"),
+        ev("ragged-dot-none.2", 100, 3, op="custom-call",
+           result="bf16[131072,1536]"),
+        ev("fusion.9", 103, 4, result="bf16[131072,2048]"),
+        ev("fusion.10", 107, 1, result="f32[32768,64]"),
+        ev("fusion.12", 108, 2, result="(f32[32768,4], s32[32768,4])"),
+        ev("fusion.11", 120, 9, result="bf16[1,32768,2048]"),  # nobody's
     ]
-    ctx = _ctx(events)
-    assert _reader("shortconv_ms_per_step")(ctx) == pytest.approx(4 / 2)
-    assert _reader("flash_causal_ms_per_step")(ctx) == pytest.approx(90 / 2)
-    assert _reader("experts_ms_per_step")(ctx) == pytest.approx(10 / 2)
+    ctx = cells.ctx(CELL, events)
+    assert reader("shortconv_ms_per_step")(ctx) == pytest.approx(4 / 2)
+    assert reader("flash_causal_ms_per_step")(ctx) == pytest.approx(90 / 2)
+    assert reader("experts_ms_per_step")(ctx) == pytest.approx(10 / 2)
     # a program without kernels or counters (the parent): nothing, no raise
-    bare = _ctx([_ev("fusion.1", 0, 5, result="bf16[32,512,768]")])
+    bare = cells.ctx(CELL, [ev("fusion.1", 0, 5, result="bf16[32,512,768]")])
     for name in NEW_METRICS:
         if name != "experts_ms_per_step":
-            assert _reader(name)(bare) is None, name
+            assert reader(name)(bare) is None, name
     for name in NEW_METRICS:
-        assert _reader(name)({**bare, "trace": None}) is None, name
+        assert reader(name)({**bare, "trace": None}) is None, name
     # ... and in a cell of another configuration
-    other = {**_ctx(events), "cfg": {"num_experts_per_tok": 8},
+    other = {**cells.ctx(CELL, events), "cfg": {"num_experts_per_tok": 8},
              "cell": {"config_mod": object()}}
     for name in ("flash_causal_ms_per_step", "flash_causal_roofline",
                  "shortconv_roofline", "experts_ms_per_step",
                  "experts_load_max_over_mean"):
-        assert _reader(name)(other) is None, name
+        assert reader(name)(other) is None, name
 
 
 def test_rooflines_count_executions_from_the_trace_and_stay_under_100():
     from benchmark.harness import flops, flops_hybrid
 
-    ctx = _ctx([])
+    ctx = cells.ctx(CELL, [])
     mod = ctx["cell"]["config_mod"]
     conv = flops_hybrid.shortconv_kernels(
         **mod.shortconv_shapes(ctx["cfg"], ctx["traffic"]))
@@ -443,38 +285,38 @@ def test_rooflines_count_executions_from_the_trace_and_stay_under_100():
                        ("flash_bwd_dkv", 1)):
         for _ in range(runs):
             ms = 4e3 * least[name][0]
-            events.append(_ev(f"{name}.1", t, ms, op="custom-call"))
+            events.append(ev(f"{name}.1", t, ms, op="custom-call"))
             t += ms
-    laps = [_lap(attn_pairs_share=0.3, moe_load_max_over_mean=2.0,
-                 router_bias_abs_max=0.01, attn_blocks_walked_share=0.35,
-                 attn_blocks_masked_share=0.25),
-            _lap(attn_pairs_share=0.5, moe_load_max_over_mean=4.0,
-                 router_bias_abs_max=0.02, attn_blocks_walked_share=0.55,
-                 attn_blocks_masked_share=0.45)]
-    ctx = _ctx(events, steps=1, laps=laps)
-    assert _reader("shortconv_roofline")(ctx) == pytest.approx(25.0)
-    assert _reader("flash_causal_roofline")(ctx) == pytest.approx(25.0)
+    laps = [lap(attn_pairs_share=0.3, moe_load_max_over_mean=2.0,
+                router_bias_abs_max=0.01, attn_blocks_walked_share=0.35,
+                attn_blocks_masked_share=0.25),
+            lap(attn_pairs_share=0.5, moe_load_max_over_mean=4.0,
+                router_bias_abs_max=0.02, attn_blocks_walked_share=0.55,
+                attn_blocks_masked_share=0.45)]
+    ctx = cells.ctx(CELL, events, steps=1, laps=laps)
+    assert reader("shortconv_roofline")(ctx) == pytest.approx(25.0)
+    assert reader("flash_causal_roofline")(ctx) == pytest.approx(25.0)
     assert ctx["facts"]["shortconv_roofline"]["shortconv_fwd"][
         "runs_per_step"] == 8
     assert ctx["facts"]["flash_causal_roofline"][
         "pairs_share_of_the_laps"] == pytest.approx(0.4)
-    assert _reader("attn_pairs_share")(ctx) == pytest.approx(40.0)
-    assert _reader("attn_blocks_walked_share")(ctx) == pytest.approx(45.0)
+    assert reader("attn_pairs_share")(ctx) == pytest.approx(40.0)
+    assert reader("attn_blocks_walked_share")(ctx) == pytest.approx(45.0)
     assert ctx["facts"]["attn_blocks_walked_share"] == {
         "laps": 2, "min": 0.35, "max": 0.55}
-    assert _reader("attn_blocks_masked_share")(ctx) == pytest.approx(35.0)
+    assert reader("attn_blocks_masked_share")(ctx) == pytest.approx(35.0)
     assert ctx["facts"]["attn_blocks_masked_share"] == {
         "laps": 2, "min": 0.25, "max": 0.45}
     # a program that lacks the counter (the parent) gives nothing
-    assert _reader("attn_blocks_masked_share")(
-        _ctx(events, steps=1, laps=[_lap(attn_pairs_share=0.3)])) is None
-    assert _reader("experts_load_max_over_mean")(ctx) == pytest.approx(3.0)
+    assert reader("attn_blocks_masked_share")(cells.ctx(
+        CELL, events, steps=1, laps=[lap(attn_pairs_share=0.3)])) is None
+    assert reader("experts_load_max_over_mean")(ctx) == pytest.approx(3.0)
     # a window packed with one document: the whole causal triangle is
     # required, and a kernel AT its roofline reads 100, not more
     full = flops_hybrid.flash_causal_kernels(
         **mod.flash_causal_shapes(ctx["cfg"], ctx["traffic"]), pairs_share=1.0)
-    events = [_ev(f"{k}.1", i, 1e3 * flops.least_seconds(
+    events = [ev(f"{k}.1", i, 1e3 * flops.least_seconds(
         v["ops"], v["bytes"], ctx["peaks"])[0], op="custom-call")
         for i, (k, v) in enumerate(full.items())]
-    ctx = _ctx(events, steps=1, laps=[_lap(attn_pairs_share=1.0)])
-    assert _reader("flash_causal_roofline")(ctx) == pytest.approx(100.0)
+    ctx = cells.ctx(CELL, events, steps=1, laps=[lap(attn_pairs_share=1.0)])
+    assert reader("flash_causal_roofline")(ctx) == pytest.approx(100.0)

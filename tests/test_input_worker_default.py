@@ -11,13 +11,12 @@ data/workers.py, data/sources.py).
 - Each worker reads its own examples' files and no other's, each epoch: the
   feed's probe counts one file read an example (``input_worker_reads``).
 
-Every test runs under a time limit of its own: a forked worker that hangs
-would otherwise hold the suite.
+A forked worker that hangs would hold the suite: the suite's time limit
+(``tests/conftest.py``) fails the test instead.
 """
 
 import itertools
 import os
-import signal
 import time
 
 import numpy as np
@@ -34,24 +33,8 @@ from distributeddeeplearningspark_tpu.telemetry import spans
 pytestmark = pytest.mark.skipif(
     not W.fork_available(), reason="worker pool needs the fork start method")
 
-#: seconds each test may take before it fails
-LIMIT_S = 120
 #: images in the folder: two classes of ``PER_CLASS``; batches of ``BATCH``
 PER_CLASS, BATCH = 6, 4
-
-
-@pytest.fixture(autouse=True)
-def time_limit():
-    def expired(signum, frame):
-        raise TimeoutError(f"test ran over its {LIMIT_S}-s limit")
-
-    before = signal.signal(signal.SIGALRM, expired)
-    signal.alarm(LIMIT_S)
-    try:
-        yield
-    finally:
-        signal.alarm(0)
-        signal.signal(signal.SIGALRM, before)
 
 
 @pytest.fixture(autouse=True)

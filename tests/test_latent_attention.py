@@ -126,7 +126,8 @@ def test_the_layer_is_the_references_on_both_paths(reference, monkeypatch,
         return jnp.sum(reference.latent_attention(
             x[0], p, rms, seg[0], pos[0], _reference_cfg(cfg))[0] * w[0])
 
-    want, want_grad = jax.value_and_grad(plain, argnums=(0, 1))(params, x)
+    want, want_grad = jax.jit(jax.value_and_grad(plain, argnums=(0, 1)))(
+        params, x)
     for on_tpu in (False, True):
         monkeypatch.setattr(attention, "on_tpu", lambda: on_tpu)
         got, got_grad = jax.jit(jax.value_and_grad(
@@ -260,11 +261,12 @@ def test_the_module_over_s_rows_is_the_exact_form_over_s_minus_1():
     ids = _ids(seq=96)
     batch = {"input_ids": ids}
     model = HybridDecoderLM(cfg)
-    variables = model.init(jax.random.PRNGKey(0), batch)
+    variables = jax.jit(model.init)(jax.random.PRNGKey(0), batch)
     params = variables["params"]
-    out, seen = model.apply(
-        variables, batch, mutable=["intermediates"],
-        capture_intermediates=lambda m, _: isinstance(m, MTPModule))
+    out, seen = jax.jit(lambda v: model.apply(
+        v, batch, mutable=["intermediates"],
+        capture_intermediates=lambda m, _: isinstance(m, MTPModule)))(
+            variables)
     loss, metrics = losses.latent_moe_lm(out, batch)
     assert float(loss) == pytest.approx(
         float(metrics["lm_loss"]) + 0.1 * float(metrics["mtp_nll"]), rel=1e-6)
@@ -275,7 +277,7 @@ def test_the_module_over_s_rows_is_the_exact_form_over_s_minus_1():
     embed = params["token_embed"]["embedding"]
     seg = jnp.zeros((2, 95), jnp.int32)
     pos = hybrid_decoder.document_positions(seg)
-    exact, _ = MTPModule(cfg, False).apply(
+    exact, _ = jax.jit(MTPModule(cfg, False).apply)(
         {"params": params["mtp"],
          BIAS_COLLECTION: variables[BIAS_COLLECTION]["mtp"]},
         h[:, :-1], embed[ids[:, 1:]], seg, pos)
@@ -285,10 +287,10 @@ def test_the_module_over_s_rows_is_the_exact_form_over_s_minus_1():
     want = -jnp.mean(jnp.take_along_axis(logp, ids[:, 2:, None], -1))
     assert float(metrics["mtp_nll"]) == pytest.approx(float(want), rel=1e-5)
     # head and embedding are the main model's own: both terms reach them
-    main_only = jax.grad(lambda p: losses.hybrid_moe_lm(
-        model.apply({**variables, "params": p}, batch), batch)[0])(params)
-    both = jax.grad(lambda p: losses.latent_moe_lm(
-        model.apply({**variables, "params": p}, batch), batch)[0])(params)
+    main_only = jax.jit(jax.grad(lambda p: losses.hybrid_moe_lm(
+        model.apply({**variables, "params": p}, batch), batch)[0]))(params)
+    both = jax.jit(jax.grad(lambda p: losses.latent_moe_lm(
+        model.apply({**variables, "params": p}, batch), batch)[0]))(params)
     for leaf in ("lm_head", "token_embed"):
         a, b = jax.tree.leaves(both[leaf])[0], jax.tree.leaves(
             main_only[leaf])[0]
@@ -302,9 +304,9 @@ def test_the_module_over_s_rows_is_the_exact_form_over_s_minus_1():
 def _hidden_before_the_final_norm(model, variables, batch):
     """The last main block's output: what the scan of periods carried out of
     its last iteration (captured stacked, an entry an iteration)."""
-    _, seen = model.apply(
-        variables, batch, mutable=["intermediates"],
-        capture_intermediates=lambda m, _: m.name == "periods")
+    _, seen = jax.jit(lambda v: model.apply(
+        v, batch, mutable=["intermediates"],
+        capture_intermediates=lambda m, _: m.name == "periods"))(variables)
     (x, _), = seen["intermediates"]["periods"]["__call__"]
     return x[-1]
 
@@ -317,11 +319,11 @@ def test_the_counters_cover_the_modules_block_and_reach_the_metrics():
                                           experts_held=(0, 4))
     batch = {"input_ids": _ids()}
     model = HybridDecoderLM(cfg)
-    variables = dict(model.init(jax.random.PRNGKey(0), batch))
+    variables = dict(jax.jit(model.init)(jax.random.PRNGKey(0), batch))
     assert set(variables) == {"params", BIAS_COLLECTION}
     bias = variables[BIAS_COLLECTION]
     bias["mtp"]["block"]["moe"]["bias"] = jnp.full((8,), -0.25)
-    out = model.apply(variables, batch)
+    out = jax.jit(model.apply)(variables, batch)
     # the largest |b| over the blocks is the module's
     assert float(out["router_bias_abs_max"]) == 0.25
     assert 0.2 < float(out["moe_rows_held_share"]) < 0.8
@@ -331,14 +333,16 @@ def test_the_counters_cover_the_modules_block_and_reach_the_metrics():
             "perplexity"} <= set(metrics)
     plain = HybridDecoderLM(HybridDecoderConfig.tiny_latent(
         dtype=jnp.float32, mtp_layers=0))
-    out = plain.apply(plain.init(jax.random.PRNGKey(0), batch), batch)
+    out = jax.jit(plain.apply)(
+        jax.jit(plain.init)(jax.random.PRNGKey(0), batch), batch)
     assert "mtp_hidden" not in out
     with pytest.raises(ValueError, match="mtp_layers"):
         HybridDecoderConfig.tiny_latent(mtp_layers=2)
     # tied, the head is the embedding and no leaf of its own
     tied = HybridDecoderLM(HybridDecoderConfig.tiny_latent(
         dtype=jnp.float32, tie_embeddings=True))
-    assert "lm_head" not in tied.init(jax.random.PRNGKey(0), batch)["params"]
+    assert "lm_head" not in jax.jit(tied.init)(jax.random.PRNGKey(0),
+                                               batch)["params"]
 
 
 def test_latent_layers_mix_with_the_other_kinds():
@@ -348,8 +352,9 @@ def test_latent_layers_mix_with_the_other_kinds():
     assert cfg.layout() == ((LATENT,), (ATTENTION, LATENT), 2, ())
     batch = {"input_ids": _ids(seq=64)}
     model = HybridDecoderLM(cfg)
-    variables = model.init(jax.random.PRNGKey(0), batch)
-    loss, _ = losses.latent_moe_lm(model.apply(variables, batch), batch)
+    variables = jax.jit(model.init)(jax.random.PRNGKey(0), batch)
+    loss, _ = losses.latent_moe_lm(jax.jit(model.apply)(variables, batch),
+                                   batch)
     assert np.isfinite(float(loss))
 
 

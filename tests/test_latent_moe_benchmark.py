@@ -1,20 +1,17 @@
-"""The analytic counts, the configuration file, the per-layer readers and a
-CPU rehearsal of the cell ``joyai_llm_flash.fit_s16k`` at a toy size, its
-planted faults among them (as ``tests/test_hybrid_moe_benchmark.py`` does for
-the cell before it). A CPU run checks control flow, counts and agreement with
-the reference; it yields no time, rate or utilisation."""
+"""The analytic counts, the configuration file, the per-layer readers and a CPU
+rehearsal of the cell ``joyai_llm_flash.fit_s16k`` at a toy size; its planted
+faults are in ``tests/test_latent_moe_controls.py``, which takes ``TOY`` from
+here. A CPU run checks control flow, counts and agreement with the reference;
+it yields no time, rate or utilisation."""
 
 import json
 import os
-import shutil
-import sys
 import time
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+import _cells as cells
+from _cells import ROOT, ev, json_of, load, reader, tree  # noqa: F401
 
 CELL = "joyai_llm_flash.fit_s16k"
 NAME = "joyai_llm_flash"
@@ -23,22 +20,12 @@ NEW_METRICS = ("mla_attn_ms_per_step", "mla_attn_roofline",
                "mla_proj_ms_per_step")
 
 
-def _load(path):
-    from benchmark.harness import runner
-    return runner.load_module(path)
-
-
-def _json(*rel):
-    with open(os.path.join(ROOT, *rel)) as f:
-        return json.load(f)
-
-
 def test_operations_per_token_count_required_work():
     from benchmark.harness import flops_mla
 
-    cfg = _json("benchmark", "configs", NAME + ".json")
-    traffic = _json("benchmark", "traffic", "fit_s16k.json")
-    mod = _load(os.path.join(ROOT, "benchmark", "configs", NAME + ".py"))
+    cfg = json_of("benchmark", "configs", NAME + ".json")
+    traffic = json_of("benchmark", "traffic", "fit_s16k.json")
+    mod = load(os.path.join(ROOT, "benchmark", "configs", NAME + ".py"))
     per_token = mod.flops_per_item(cfg, traffic)
     # the issue's hand count: 618M of matrix products outside attention,
     # 1,007M in attention (6 blocks x S/2 x 32 heads x 2 x (192 + 128)),
@@ -77,9 +64,9 @@ def test_operations_per_token_count_required_work():
 
 
 def test_configuration_file_states_the_catalog_and_its_cuts():
-    bench = _json("BENCHMARK.json")
+    bench = json_of("BENCHMARK.json")
     conf = next(c for c in bench["configs"] if c["name"] == NAME)
-    cfg = _json(conf["file"])
+    cfg = json_of(conf["file"])
     assert sorted(conf["reduced"]) == ["n_routed_experts", "num_hidden_layers",
                                        "vocab_size"]
     assert cfg["published"]["num_hidden_layers"] == 40
@@ -99,7 +86,7 @@ def test_configuration_file_states_the_catalog_and_its_cuts():
     assert "491.7M" in cfg["deployment"] and "32" in cfg["deployment"]
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert cell["chips"] == 1 and cell["traffic"] == "fit_s16k"
-    traffic = _json("benchmark", "traffic", "fit_s16k.json")
+    traffic = json_of("benchmark", "traffic", "fit_s16k.json")
     assert {k: traffic[k] for k in (
         "feed", "num_docs", "doc_len_median", "doc_len_sigma", "doc_len_min",
         "doc_len_max", "zipf_exponent", "vocab_size", "eos_id", "seq_len",
@@ -132,9 +119,9 @@ def test_the_built_model_has_the_parameters_the_file_counts():
     import jax.numpy as jnp
     import numpy as np
 
-    cfg = _json("benchmark", "configs", NAME + ".json")
-    traffic = _json("benchmark", "traffic", "fit_s16k.json")
-    mod = _load(os.path.join(ROOT, "benchmark", "configs", NAME + ".py"))
+    cfg = json_of("benchmark", "configs", NAME + ".json")
+    traffic = json_of("benchmark", "traffic", "fit_s16k.json")
+    mod = load(os.path.join(ROOT, "benchmark", "configs", NAME + ".py"))
     model = mod.build(cfg, traffic)["model"]
     shapes = jax.eval_shape(lambda: model.init(
         jax.random.PRNGKey(0), {"input_ids": jnp.zeros((1, 512), jnp.int32)}))
@@ -150,15 +137,6 @@ def test_the_built_model_has_the_parameters_the_file_counts():
         == 2048 * 16160
     # the bias keeps the router's width: 256 a block, five blocks
     assert count(shapes["router_bias"]) == 5 * 256
-
-
-def _copy_of_the_benchmark(root):
-    root.mkdir()
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
-    shutil.copytree(os.path.join(ROOT, "benchmark"), root / "benchmark",
-                    ignore=shutil.ignore_patterns(".cache", "out",
-                                                  "__pycache__", "tests"))
-    return root
 
 
 TOY = {
@@ -182,34 +160,17 @@ TOY = {
 }
 
 
-def _toy(root, keep_check=False):
-    for rel, patch in TOY.items():
-        path = root / "benchmark" / rel
-        real = json.loads(path.read_text())
-        if keep_check:
-            patch = {k: v for k, v in patch.items() if k != "check"}
-        path.write_text(json.dumps({**real, **patch}))
-
-
-@pytest.fixture
-def tree(tmp_path, monkeypatch):
-    from benchmark.harness import seedcache
-
-    root = _copy_of_the_benchmark(tmp_path / "checkout")
-    monkeypatch.setattr(seedcache, "ROOT", str(root / "benchmark" / ".cache"))
-    # ``runner.measure`` sets it for its process and never takes it back:
-    # set here first, it is restored when the test ends
-    monkeypatch.setenv("DLS_TELEMETRY_DIR", str(tmp_path / "telemetry"))
-    return root
-
-
 def test_the_cell_rehearses_at_a_toy_size(tree):
     from benchmark.harness import runner
 
-    _toy(tree)
-    r = runner.measure(str(tree), CELL, seed=2 ** 31 + 7, seconds=1.0,
-                       trace=True, t_process=time.perf_counter(),
-                       master="local[1]")
+    with cells.run_in(tree, step_s=cells.REHEARSAL_STEP_S):
+        r = runner.measure(str(tree), CELL, seed=2 ** 31 + 7, seconds=1.0,
+                           trace=True, t_process=time.perf_counter(),
+                           master="local[1]")
+        again = runner.measure(str(tree), CELL, seed=2 ** 31 + 7,
+                               seconds=0.5, trace=False,
+                               t_process=time.perf_counter(),
+                               master="local[1]")
     assert r["correct"], r["facts"]
     assert r["failed"] == 0 and r["attempted"] > 0
     ref = r["facts"]["reference"]
@@ -236,9 +197,6 @@ def test_the_cell_rehearses_at_a_toy_size(tree):
     # a CPU run has no device plane: the device-trace readers return nothing
     for name in NEW_METRICS + ("device_step_ms", "mfu"):
         assert name not in r["metrics"], name
-    again = runner.measure(str(tree), CELL, seed=2 ** 31 + 7, seconds=0.5,
-                           trace=False, t_process=time.perf_counter(),
-                           master="local[1]")
     assert again["facts"]["feed"]["seed_cache_hit"] is True
     assert set(again["metrics"]) == {"throughput", "setup_s"}
 
@@ -255,9 +213,8 @@ def test_the_timed_forward_gives_the_terms_the_reference_gives(tree):
 
     from distributeddeeplearningspark_tpu.models.moe import BIAS_COLLECTION
 
-    _toy(tree)
-    mod = _load(str(tree / "benchmark" / "configs" / f"{NAME}.py"))
-    ref = _load(str(tree / "benchmark" / "reference" / f"{NAME}.py"))
+    mod = load(str(tree / "benchmark" / "configs" / f"{NAME}.py"))
+    ref = load(str(tree / "benchmark" / "reference" / f"{NAME}.py"))
     ref.QUERIES, ref.ROWS = 32, 40
     cfg = json.loads((tree / "benchmark" / "configs"
                       / f"{NAME}.json").read_text())
@@ -269,15 +226,18 @@ def test_the_timed_forward_gives_the_terms_the_reference_gives(tree):
     rng = np.random.default_rng(0)
     ids = jnp.asarray(rng.integers(1, 256, (2, 96)), jnp.int32)
     segs = jnp.asarray(np.sort(rng.integers(0, 4, (2, 96)), axis=1), jnp.int32)
-    variables = dict(built["model"].init(jax.random.PRNGKey(0),
-                                         {"input_ids": ids}))
+    init = jax.jit(built["model"].init)
+    variables = dict(init(jax.random.PRNGKey(0), {"input_ids": ids}))
     params = variables.pop("params")
     mutable = {BIAS_COLLECTION: jax.tree.map(
         lambda a: jnp.asarray(rng.normal(size=a.shape) * 0.05, jnp.float32),
         variables[BIAS_COLLECTION])}
+    program = jax.jit(lambda p, m, b: mod.parts(built["model"],
+                                                built["loss"], p, m, b))
+    reference = jax.jit(lambda p, m, b: ref.parts(p, m, b, cfg))
     for batch in ({"input_ids": ids}, {"input_ids": ids, "segment_ids": segs}):
-        got = mod.parts(built["model"], built["loss"], params, mutable, batch)
-        want = ref.parts(params, mutable, batch, cfg)
+        got = program(params, mutable, batch)
+        want = reference(params, mutable, batch)
         assert set(got) - set(want) == {"rows_held_share"}
         assert set(cfg["check"]["term_weights"]) == set(want) - {
             "loss", "lm_loss", "mtp_nll"}
@@ -303,25 +263,25 @@ def test_every_leafs_gradient_is_the_references_in_float32(tree):
     import jax.numpy as jnp
     import numpy as np
 
-    _toy(tree)
-    mod = _load(str(tree / "benchmark" / "configs" / f"{NAME}.py"))
-    ref = _load(str(tree / "benchmark" / "reference" / f"{NAME}.py"))
+    mod = load(str(tree / "benchmark" / "configs" / f"{NAME}.py"))
+    ref = load(str(tree / "benchmark" / "reference" / f"{NAME}.py"))
     cfg = json.loads((tree / "benchmark" / "configs"
                       / f"{NAME}.json").read_text())
     cfg["train_router"] = True    # the routers' leaves get a gradient too
     built = mod.build(cfg, {"vocab_size": 256, "seq_len": 64})
     batch = {"input_ids": jnp.asarray(
         np.random.default_rng(1).integers(1, 256, (2, 64)), jnp.int32)}
-    variables = dict(built["model"].init(jax.random.PRNGKey(3), batch))
+    init = jax.jit(built["model"].init)
+    variables = dict(init(jax.random.PRNGKey(3), batch))
     params = variables.pop("params")
 
     def program(p):
         out = built["model"].apply({"params": p, **variables}, batch)
         return built["loss"](out, batch)[0]
 
-    got_loss, got = jax.value_and_grad(program)(params)
-    want_loss, want = jax.value_and_grad(
-        lambda p: ref.training_loss(p, variables, batch, cfg))(params)
+    got_loss, got = jax.jit(jax.value_and_grad(program))(params)
+    want_loss, want = jax.jit(jax.value_and_grad(
+        lambda p: ref.training_loss(p, variables, batch, cfg)))(params)
     assert float(got_loss) == pytest.approx(float(want_loss), rel=1e-6)
     leaves = jax.tree_util.tree_leaves_with_path(want)
     assert len(leaves) == len(jax.tree.leaves(got)) >= 45
@@ -339,174 +299,55 @@ def test_a_program_without_the_layer_is_refused_with_a_message(
     from benchmark.harness import runner
     from distributeddeeplearningspark_tpu.models import hybrid_decoder
 
-    _toy(tree)
     monkeypatch.delattr(hybrid_decoder, "LatentAttention")
-    with pytest.raises(runner.Refused) as e:
+    with cells.run_in(tree), pytest.raises(runner.Refused) as e:
         runner.measure(str(tree), CELL, seed=3, seconds=0.5, trace=False,
                        t_process=time.perf_counter(), master="local[1]")
     assert "latent-attention" in e.value.args[0][0]
     assert not (tree / "benchmark" / ".cache").exists()   # no feed was built
 
 
-@pytest.fixture(scope="module")
-def controls(tmp_path_factory):
-    """``benchmark/controls/joyai_llm_flash.py`` at the toy size on
-    ``local[1]``, in float32 but under the cell's OWN limits: one run of the
-    cell, the harness's comparison repeated with each fault planted."""
-    from benchmark.harness import seedcache
-
-    root = _copy_of_the_benchmark(
-        tmp_path_factory.mktemp("controls") / "checkout")
-    _toy(root, keep_check=True)
-    mod = _load(str(root / "benchmark" / "controls" / f"{NAME}.py"))
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(seedcache, "ROOT", str(root / "benchmark" / ".cache"))
-        mp.setenv("DLS_TELEMETRY_DIR", str(root / "telemetry"))  # restored
-        return mod, mod.run(2 ** 31 + 11, 1.0, mod.ALL, master="local[1]",
-                            root=str(root))
-
-
-def test_the_sound_program_is_correct_under_the_cells_own_limits(controls):
-    _, seen = controls
-    assert seen["result"]["correct"], seen["sound"]
-    assert seen["sound"]["failures"] == [] == seen["harness"]["failures"]
-    # the comparison made again is the harness's own, to the digit
-    for key in ("loss_program", "loss_reference", "grad_rel_err"):
-        assert seen["sound"][key] == pytest.approx(seen["harness"][key],
-                                                   rel=1e-6), key
-    assert seen["router_bias_abs_max"] > 0
-    # lap by lap, the share of the assignments on the experts held (4 of 8)
-    shares = [lap["moe_rows_held_share"] for lap in seen["laps"]]
-    assert len(shares) >= 2 and all(0.2 < s < 0.9 for s in shares), shares
-    assert all(lap["mtp_nll"] > 0 and lap["lm_loss"] > 0
-               for lap in seen["laps"])
-
-
-@pytest.mark.parametrize("fault", [
-    "scores_scaled_by_sqrt_128", "rotary_on_position_free",
-    "rotary_key_per_head", "q_latent_norm_left_out",
-    "kv_latent_norm_left_out", "shared_expert_left_out", "routed_scale_1",
-    "mtp_predicts_next_token", "mtp_weight_0", "e4m3_attention_path",
-    "e4m3_weights"])
-def test_a_planted_fault_is_not_correct(controls, fault):
-    mod, seen = controls
-    assert fault in mod.ALL
-    assert seen[fault]["correct"] is False and seen[fault]["failures"], \
-        seen[fault]
-
-
-@pytest.mark.parametrize("fault, term, least", [
-    ("scores_scaled_by_sqrt_128", "attention_energy", 0.01),
-    ("routed_scale_1", "experts_energy", 1.5),      # ln 6.25 = 1.83, and the
-    ("routed_scale_1", "expert_probe", 1.8),        # later blocks see other inputs
-    ("q_latent_norm_left_out", "latents_spread", 1.0),
-    ("kv_latent_norm_left_out", "latents_spread", 1.0),
-    ("mtp_weight_0", "loss", 0.05)])
-def test_a_fault_moves_the_term_that_is_there_for_it(controls, fault, term,
-                                                     least):
-    """Each term is printed on both sides; the sound program's differ from
-    the reference's by rounding, a fault's by the fault."""
-    sound = controls[1]["sound"]["terms"][term]
-    assert abs(sound[2]) < 1e-4, sound
-    assert abs(controls[1][fault]["terms"][term][2]) > least
-
-
-def test_the_weight_0_fault_leaves_the_main_loss_where_it_was(controls):
-    """Weighing the module's term by 0 moves the training loss (the compared
-    scalar sees it) and not the main model's term."""
-    seen = controls[1]
-    main = seen["mtp_weight_0"]["terms"]["lm_loss"]
-    assert main[0] == pytest.approx(main[1], rel=1e-5)
-    assert seen["mtp_weight_0"]["terms"]["loss"][0] == pytest.approx(
-        main[0], rel=1e-5)
-
-
-def test_a_planted_fault_leaves_the_program_as_it_was():
-    from distributeddeeplearningspark_tpu.models import hybrid_decoder, moe
-
-    mod = _load(os.path.join(ROOT, "benchmark", "controls", NAME + ".py"))
-    planted = mod.faults(None, None, {})
-    for name, module, attribute in (
-            ("routed_scale_1", moe, "_held_experts"),
-            ("shared_expert_left_out", hybrid_decoder, "RoutedExperts"),
-            ("scores_scaled_by_sqrt_128", hybrid_decoder,
-             "dot_product_attention"),
-            ("rotary_on_position_free", hybrid_decoder,
-             "dot_product_attention"),
-            ("rotary_key_per_head", hybrid_decoder, "dot_product_attention"),
-            ("q_latent_norm_left_out", hybrid_decoder, "RMSNorm"),
-            ("kv_latent_norm_left_out", hybrid_decoder, "RMSNorm")):
-        sound = getattr(module, attribute)
-        with planted[name][1]():
-            assert getattr(module, attribute) is not sound, name
-        assert getattr(module, attribute) is sound, name
-
-
-def _ctx(events, steps=2, laps=()):
-    """A traced run's context with hand-made device events (name, start ns,
-    duration ns, info)."""
-    peaks = _json("benchmark", "peaks.json")
-    mod = _load(os.path.join(ROOT, "benchmark", "configs", NAME + ".py"))
-    return {"trace": {"devices": {"0": {"XLA Ops": events}}, "steps": steps,
-                      "host": {}},
-            "cfg": _json("benchmark", "configs", NAME + ".json"),
-            "traffic": _json("benchmark", "traffic", "fit_s16k.json"),
-            "peaks": peaks["TPU v5 lite"], "cell": {"config_mod": mod},
-            "facts": {}, "laps": list(laps)}
-
-
-def _ev(name, start_ms, dur_ms, op="fusion", result=""):
-    kind = name.rsplit(".", 1)[0]
-    return [name, start_ms * 1e6, dur_ms * 1e6,
-            {"kind": kind, "op": op, "result": result}]
-
-
-def _reader(name):
-    return _load(os.path.join(ROOT, "benchmark", "layer_metrics",
-                              name + ".py")).read
-
-
 def test_stage_readers_find_kernels_by_name_and_xla_stages_by_shape():
     events = [
-        _ev("while.1", 0, 100, op="while",
-            result="(s32[], bf16[1,16384,32,192])"),
-        _ev("flash_fwd.1", 10, 20, op="custom-call"),
-        _ev("flash_bwd_dq.1", 30, 30, op="custom-call",
-            result="bf16[32,16384,192]"),
-        _ev("flash_bwd_dkv.1", 60, 40, op="custom-call",
-            result="(bf16[32,16384,192], bf16[32,16384,128])"),
-        _ev("fusion.1", 100, 1, result="bf16[16384,1536]"),
-        _ev("fusion.2", 101, 2, result="bf16[1,16384,576]"),
-        _ev("fusion.3", 103, 3, result="bf16[16384,512]"),
-        _ev("fusion.4", 106, 4, result="bf16[1,16384,32,192]"),
-        _ev("fusion.5", 110, 5, result="(bf16[1,16384,32,256], f32[16384])"),
-        _ev("copy.6", 115, 6, op="copy", result="bf16[32,16384,192]"),
+        ev("while.1", 0, 100, op="while",
+           result="(s32[], bf16[1,16384,32,192])"),
+        ev("flash_fwd.1", 10, 20, op="custom-call"),
+        ev("flash_bwd_dq.1", 30, 30, op="custom-call",
+           result="bf16[32,16384,192]"),
+        ev("flash_bwd_dkv.1", 60, 40, op="custom-call",
+           result="(bf16[32,16384,192], bf16[32,16384,128])"),
+        ev("fusion.1", 100, 1, result="bf16[16384,1536]"),
+        ev("fusion.2", 101, 2, result="bf16[1,16384,576]"),
+        ev("fusion.3", 103, 3, result="bf16[16384,512]"),
+        ev("fusion.4", 106, 4, result="bf16[1,16384,32,192]"),
+        ev("fusion.5", 110, 5, result="(bf16[1,16384,32,256], f32[16384])"),
+        ev("copy.6", 115, 6, op="copy", result="bf16[32,16384,192]"),
         # nobody's: the block's width, v and o at 128, weights, the experts
-        _ev("fusion.7", 130, 9, result="bf16[1,16384,2048]"),
-        _ev("fusion.8", 139, 9, result="bf16[1,16384,32,128]"),
-        _ev("fusion.9", 148, 9, result="f32[1536,32,192]"),
-        _ev("fusion.10", 157, 9, result="bf16[131072,2048]"),
-        _ev("fusion.11", 166, 9, result="f32[2048,1536]"),
+        ev("fusion.7", 130, 9, result="bf16[1,16384,2048]"),
+        ev("fusion.8", 139, 9, result="bf16[1,16384,32,128]"),
+        ev("fusion.9", 148, 9, result="f32[1536,32,192]"),
+        ev("fusion.10", 157, 9, result="bf16[131072,2048]"),
+        ev("fusion.11", 166, 9, result="f32[2048,1536]"),
     ]
-    ctx = _ctx(events)
-    assert _reader("mla_attn_ms_per_step")(ctx) == pytest.approx(90 / 2)
-    assert _reader("mla_proj_ms_per_step")(ctx) == pytest.approx(21 / 2)
+    ctx = cells.ctx(CELL, events)
+    assert reader("mla_attn_ms_per_step")(ctx) == pytest.approx(90 / 2)
+    assert reader("mla_proj_ms_per_step")(ctx) == pytest.approx(21 / 2)
     assert ctx["facts"]["mla_proj_ms_per_step"]["events_per_step"] == 3
     # a program without the kernels (the parent): nothing, no raise
-    bare = _ctx([_ev("fusion.1", 0, 5, result="bf16[32,512,768]")])
+    bare = cells.ctx(CELL, [ev("fusion.1", 0, 5, result="bf16[32,512,768]")])
     for name in NEW_METRICS:
-        assert _reader(name)(bare) is None, name
-        assert _reader(name)({**bare, "trace": None}) is None, name
+        assert reader(name)(bare) is None, name
+        assert reader(name)({**bare, "trace": None}) is None, name
     # ... and in a cell of another configuration
-    other = {**_ctx(events), "cell": {"config_mod": object()}}
+    other = {**cells.ctx(CELL, events), "cell": {"config_mod": object()}}
     for name in NEW_METRICS:
-        assert _reader(name)(other) is None, name
+        assert reader(name)(other) is None, name
 
 
 def test_the_roofline_counts_executions_from_the_trace_and_stays_under_100():
     from benchmark.harness import flops, flops_mla
 
-    ctx = _ctx([])
+    ctx = cells.ctx(CELL, [])
     mod = ctx["cell"]["config_mod"]
     kernels = flops_mla.mla_attn_kernels(
         **mod.mla_attn_shapes(ctx["cfg"], ctx["traffic"]))
@@ -518,17 +359,17 @@ def test_the_roofline_counts_executions_from_the_trace_and_stays_under_100():
     for name in kernels:
         for _ in range(6):
             ms = 4e3 * least[name][0]
-            events.append(_ev(f"{name}.1", t, ms, op="custom-call"))
+            events.append(ev(f"{name}.1", t, ms, op="custom-call"))
             t += ms
-    ctx = _ctx(events, steps=1)
-    assert _reader("mla_attn_roofline")(ctx) == pytest.approx(25.0)
+    ctx = cells.ctx(CELL, events, steps=1)
+    assert reader("mla_attn_roofline")(ctx) == pytest.approx(25.0)
     assert ctx["facts"]["mla_attn_roofline"]["flash_fwd"][
         "runs_per_step"] == 6
     # a kernel AT its roofline reads 100, not more: the count is of the
     # model's own widths, so a kernel that pads 192 to 256 in VMEM reads lower
-    events = [_ev(f"{k}.1", i, 1e3 * least[k][0], op="custom-call")
+    events = [ev(f"{k}.1", i, 1e3 * least[k][0], op="custom-call")
               for i, k in enumerate(kernels)]
-    assert _reader("mla_attn_roofline")(_ctx(events, steps=1)) == \
+    assert reader("mla_attn_roofline")(cells.ctx(CELL, events, steps=1)) == \
         pytest.approx(100.0)
     padded = flops_mla.mla_attn_kernels(**{
         **mod.mla_attn_shapes(ctx["cfg"], ctx["traffic"]),

@@ -509,7 +509,6 @@ def test_dlstatus_fleet_serve_rollup(tmp_path, capsys):
 # -- the real thing: replica processes (slow tier) ----------------------------
 
 
-@pytest.mark.slow
 def test_fleet_processes_end_to_end(tmp_path):
     """2 lenet replica PROCESSES (gang env contract): infer through the
     router, per-replica telemetry in ONE workdir, a rolling reload with

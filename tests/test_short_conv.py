@@ -217,10 +217,10 @@ def test_the_ungated_form_is_its_shifted_sums_forward_and_backward(
 
     weights = jnp.asarray(np.random.default_rng(4).normal(size=v.shape),
                           jnp.float32)
-    grads = jax.grad(lambda *a: jnp.sum(weights * fn(*a, seg)),
-                     argnums=(0, 1, 2))(v, w, bias)
-    wants = jax.grad(lambda *a: jnp.sum(weights * shifted_sums(*a)),
-                     argnums=(0, 1, 2))(v, w, bias)
+    grads = jax.jit(jax.grad(lambda *a: jnp.sum(weights * fn(*a, seg)),
+                             argnums=(0, 1, 2)))(v, w, bias)
+    wants = jax.jit(jax.grad(lambda *a: jnp.sum(weights * shifted_sums(*a)),
+                             argnums=(0, 1, 2)))(v, w, bias)
     for name, a, b in zip(("v", "taps", "bias"), grads, wants):
         assert a.shape == b.shape, name
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4, err_msg=name)

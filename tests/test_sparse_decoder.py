@@ -122,7 +122,8 @@ def test_kernels_match_the_dense_path_forward_and_backward(monkeypatch):
         def f(*a):
             o, kl, sel = fn(*a)
             return jnp.sum(o * cot) + 3.0 * jnp.mean(kl), (o, kl, sel)
-        return jax.value_and_grad(f, argnums=tuple(range(6)), has_aux=True)
+        return jax.jit(jax.value_and_grad(f, argnums=tuple(range(6)),
+                                          has_aux=True))
 
     kern = scalar(lambda *a: ia.indexed_attention(*a, topk=64))
     dense = scalar(lambda *a: ia.indexed_attention_xla(*a, topk=64))
@@ -230,9 +231,10 @@ def test_layer_matches_the_reference(tiny):
     ref = _reference()
     x = jax.random.normal(jax.random.key(7), (2, 96, cfg.hidden_size))
     lp = jax.tree.map(lambda a: a[0], params["layers"])
-    got, stats = SparseDecoderLayer(cfg).apply({"params": lp}, x)
+    got, stats = jax.jit(SparseDecoderLayer(cfg).apply)({"params": lp}, x)
     with jax.default_matmul_precision("highest"):
-        want, kl, aux = ref.layer(x, lp, _ref_cfg(cfg))
+        want, kl, aux = jax.jit(
+            lambda x, lp: ref.layer(x, lp, _ref_cfg(cfg)))(x, lp)
     np.testing.assert_allclose(got, want, atol=2e-5)
     np.testing.assert_allclose(stats["index_kl"], kl, rtol=1e-4)
     np.testing.assert_allclose(stats["moe_aux"], aux, rtol=1e-5)
@@ -246,10 +248,10 @@ def test_loss_and_gradients_of_a_two_layer_model_match_the_reference(tiny):
     def program(p):
         return losses.sparse_moe_lm(model.apply({"params": p}, batch), batch)[0]
 
-    got_loss, got_grad = jax.value_and_grad(program)(params)
+    got_loss, got_grad = jax.jit(jax.value_and_grad(program))(params)
     with jax.default_matmul_precision("highest"):
-        want_loss, want_grad = jax.value_and_grad(
-            lambda p: ref.training_loss(p, batch, _ref_cfg(cfg)))(params)
+        want_loss, want_grad = jax.jit(jax.value_and_grad(
+            lambda p: ref.training_loss(p, batch, _ref_cfg(cfg))))(params)
     assert abs(float(got_loss) - float(want_loss)) < 1e-5
     assert _rel(got_grad, want_grad) < 1e-4
 
@@ -265,10 +267,10 @@ def test_the_compared_scalar_adds_the_expert_probe_and_weighs_leaves(tiny):
     rcfg = {**_ref_cfg(cfg), "check": {"grad_leaf_weights": {
         "layers/wq/kernel": 32, "layers/wk/kernel": 32}}}
     with jax.default_matmul_precision("highest"):
-        plain, plain_grad = jax.value_and_grad(
-            lambda p: ref.training_loss(p, batch, rcfg))(params)
-        want, want_grad = jax.value_and_grad(
-            lambda p: ref.loss(p, {}, batch, rcfg))(params)
+        plain, plain_grad = jax.jit(jax.value_and_grad(
+            lambda p: ref.training_loss(p, batch, rcfg)))(params)
+        want, want_grad = jax.jit(jax.value_and_grad(
+            lambda p: ref.loss(p, {}, batch, rcfg)))(params)
         probe = ref.expert_probe(params, batch["input_ids"], rcfg)
     got_probe = config.expert_probe(model, params, batch)
     assert float(got_probe) == pytest.approx(float(probe), rel=1e-5)
@@ -281,8 +283,8 @@ def test_the_compared_scalar_adds_the_expert_probe_and_weighs_leaves(tiny):
             np.testing.assert_allclose(a, scale * np.asarray(b), rtol=1e-5,
                                        atol=1e-9)
     weights = rcfg["check"]["grad_leaf_weights"]
-    weighed = jax.grad(lambda p: losses.sparse_moe_lm(model.apply(
-        {"params": config._weigh(p, weights)}, batch), batch)[0])(params)
+    weighed = jax.jit(jax.grad(lambda p: losses.sparse_moe_lm(model.apply(
+        {"params": config._weigh(p, weights)}, batch), batch)[0]))(params)
     assert _rel(weighed, want_grad) < 1e-4
 
 
@@ -296,7 +298,7 @@ def test_indexer_trains_on_the_kl_term_alone(tiny):
                 return out["index_kl"]
             return losses.sparse_moe_lm(out, batch)[1]["lm_loss"] \
                 + out["moe_aux"]
-        return jax.grad(f)(params)["layers"]
+        return jax.jit(jax.grad(f))(params)["layers"]
 
     by_kl, by_lm = term("index_kl"), term("lm")
     indexer = ("index_wq", "index_wk", "index_w", "index_k_norm")
@@ -318,8 +320,8 @@ def test_kernel_path_of_the_model_matches_the_dense_path(monkeypatch):
     params = dense.init(jax.random.key(9), batch)["params"]
 
     def run():
-        return jax.value_and_grad(lambda p: losses.sparse_moe_lm(
-            dense.apply({"params": p}, batch), batch)[0])(params)
+        return jax.jit(jax.value_and_grad(lambda p: losses.sparse_moe_lm(
+            dense.apply({"params": p}, batch), batch)[0]))(params)
 
     la, ga = run()  # off the TPU "auto" is the dense path
     monkeypatch.setattr(sparse_decoder, "indexed_attention", functools.partial(

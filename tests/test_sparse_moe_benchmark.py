@@ -1,23 +1,20 @@
 """The token feed, the analytic counts and a CPU rehearsal of the cell
 ``keye_vl2_30b_a3b.fit_s8k`` at toy sizes (as
-``benchmark/tests/test_driven_by_data.py`` rehearses the older cells). A CPU
-run checks control flow, counts and agreement with the reference; it yields
-no time, rate or utilisation."""
+``benchmark/tests/test_driven_by_data.py`` rehearses the older cells); its
+planted faults are in ``tests/test_sparse_moe_controls.py``. A CPU run checks
+control flow, counts and agreement with the reference; it yields no time,
+rate or utilisation."""
 
 import json
 import os
-import shutil
-import sys
 import time
 
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-from distributeddeeplearningspark_tpu.data import records, text  # noqa: E402
+import _cells as cells
+from _cells import ROOT, ev, load, reader, tree  # noqa: F401
+from distributeddeeplearningspark_tpu.data import records, text
 
 CELL = "keye_vl2_30b_a3b.fit_s8k"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
@@ -73,11 +70,6 @@ def test_token_windows_through_the_worker_pool_are_the_same(token_records):
                for x, y in zip(a, b))
 
 
-def _load(path):
-    from benchmark.harness import runner
-    return runner.load_module(path)
-
-
 def test_operations_per_token_count_the_selection_not_s_squared():
     from benchmark.harness import flops_sparse
 
@@ -85,8 +77,8 @@ def test_operations_per_token_count_the_selection_not_s_squared():
         ROOT, "benchmark", "configs", "keye_vl2_30b_a3b.json")))
     traffic = json.load(open(os.path.join(
         ROOT, "benchmark", "traffic", "fit_s8k.json")))
-    mod = _load(os.path.join(ROOT, "benchmark", "configs",
-                             "keye_vl2_30b_a3b.py"))
+    mod = load(os.path.join(ROOT, "benchmark", "configs",
+                            "keye_vl2_30b_a3b.py"))
     per_token = mod.flops_per_item(cfg, traffic)
     # the issue's hand count: 437 MFLOP forward, 1.31 GFLOP a token
     assert abs(per_token / 3 - 437.7e6) < 0.5e6
@@ -127,23 +119,6 @@ def test_configuration_file_states_the_catalog_and_its_cuts():
             assert cfg[key] == value, key
 
 
-@pytest.fixture
-def tree(tmp_path, monkeypatch):
-    from benchmark.harness import seedcache
-
-    root = tmp_path / "checkout"
-    root.mkdir()
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
-    shutil.copytree(os.path.join(ROOT, "benchmark"), root / "benchmark",
-                    ignore=shutil.ignore_patterns(".cache", "out",
-                                                  "__pycache__", "tests"))
-    monkeypatch.setattr(seedcache, "ROOT", str(root / "benchmark" / ".cache"))
-    # ``runner.measure`` sets it for its process and never takes it back:
-    # set here first, it is restored when the test ends
-    monkeypatch.setenv("DLS_TELEMETRY_DIR", str(tmp_path / "telemetry"))
-    return root
-
-
 TOY = {
     "configs/keye_vl2_30b_a3b.json": dict(
         vocab_size=256, hidden_size=64, num_hidden_layers=2,
@@ -164,14 +139,19 @@ TOY = {
 
 
 def test_the_cell_rehearses_at_a_toy_size(tree):
+    """On the harness's own window and the wall clock: tier-1's one run of
+    the window's real clock, since ``benchmark/tests/`` is not in tier-1
+    (the other cells' rehearsals count steps: ``_cells.StepClock``)."""
     from benchmark.harness import runner
 
-    for rel, patch in TOY.items():
-        path = tree / "benchmark" / rel
-        path.write_text(json.dumps({**json.loads(path.read_text()), **patch}))
-    r = runner.measure(str(tree), CELL, seed=2 ** 31 + 7, seconds=1.0,
-                       trace=True, t_process=time.perf_counter(),
-                       master="local[1]")
+    with cells.run_in(tree, step_s=None):
+        r = runner.measure(str(tree), CELL, seed=2 ** 31 + 7, seconds=1.0,
+                           trace=True, t_process=time.perf_counter(),
+                           master="local[1]")
+        again = runner.measure(str(tree), CELL, seed=2 ** 31 + 7,
+                               seconds=0.5, trace=False,
+                               t_process=time.perf_counter(),
+                               master="local[1]")
     assert r["correct"], r["facts"]
     assert r["failed"] == 0 and r["attempted"] > 0
     ref = r["facts"]["reference"]
@@ -185,143 +165,50 @@ def test_the_cell_rehearses_at_a_toy_size(tree):
                  "dsa_attend_roofline", "device_step_ms", "mfu"):
         assert name not in r["metrics"], name
     assert r["facts"]["feed"]["seed_cache_hit"] is False
-    again = runner.measure(str(tree), CELL, seed=2 ** 31 + 7, seconds=0.5,
-                           trace=False, t_process=time.perf_counter(),
-                           master="local[1]")
     assert again["facts"]["feed"]["seed_cache_hit"] is True
     assert set(again["metrics"]) == {"throughput", "setup_s"}
-
-
-@pytest.fixture(scope="module")
-def controls(tmp_path_factory):
-    """``benchmark/controls/keye_vl2_30b_a3b.py`` at the toy size on
-    ``local[1]``, in float32 but under the cell's OWN limits and leaf
-    weights: one run of the cell, the harness's comparison repeated with
-    each fault planted."""
-    from benchmark.harness import seedcache
-
-    root = tmp_path_factory.mktemp("controls") / "checkout"
-    root.mkdir()
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
-    shutil.copytree(os.path.join(ROOT, "benchmark"), root / "benchmark",
-                    ignore=shutil.ignore_patterns(".cache", "out",
-                                                  "__pycache__", "tests"))
-    for rel, patch in TOY.items():
-        path = root / "benchmark" / rel
-        real = json.loads(path.read_text())
-        patch = {k: v for k, v in patch.items() if k != "check"}
-        path.write_text(json.dumps({**real, **patch}))
-    mod = _load(str(root / "benchmark" / "controls" / "keye_vl2_30b_a3b.py"))
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(seedcache, "ROOT", str(root / "benchmark" / ".cache"))
-        mp.setenv("DLS_TELEMETRY_DIR", str(root / "telemetry"))  # restored
-        return mod.run(2 ** 31 + 11, 1.0, mod.ALL, flips=True,
-                       master="local[1]")
-
-
-def test_the_sound_program_is_correct_under_the_cells_own_limits(controls):
-    assert controls["result"]["correct"], controls["sound"]
-    assert controls["sound"]["failures"] == []
-    # float32 on both sides: the selections agree but for ties in rounding
-    for layer in controls["selection_flips"]:
-        assert layer["share_of_selected"] < 0.01, layer
-
-
-@pytest.mark.parametrize("fault", ["drop_assignments", "e4m3_weights",
-                                   "dense_prefix", "wq_gradient_lost"])
-def test_a_planted_fault_is_not_correct(controls, fault):
-    """ISSUE 26: a dropped assignment, an 8-bit product and attention over
-    the dense prefix each fail a limit of the comparison that decides
-    ``correct`` (and a gradient that never reaches ``wq`` does)."""
-    seen = controls[fault]
-    assert seen["correct"] is False and seen["failures"], seen
-    if fault == "drop_assignments":
-        # it is the probe that sees it: the missing output is missing energy
-        assert seen["loss_abs_err"] > 10 * 0.002, seen
-    if fault == "wq_gradient_lost":
-        assert seen["loss_abs_err"] < 1e-5 and seen["grad_rel_err"] > 0.07
-
-
-def test_a_planted_fault_leaves_the_program_as_it_was():
-    from distributeddeeplearningspark_tpu.models import moe
-
-    mod = _load(os.path.join(ROOT, "benchmark", "controls",
-                             "keye_vl2_30b_a3b.py"))
-    sound = moe._zero_past
-    _, planted = mod.faults(None, None, {"sa_config": {"topk": 1}}, 8)[
-        "drop_assignments"]
-    with planted():
-        assert moe._zero_past is not sound
-    assert moe._zero_past is sound
-
-
-def _ctx(events, steps=2):
-    """A traced run's context with hand-made device events (name, start ns,
-    duration ns, info)."""
-    cfg = json.load(open(os.path.join(
-        ROOT, "benchmark", "configs", "keye_vl2_30b_a3b.json")))
-    traffic = json.load(open(os.path.join(
-        ROOT, "benchmark", "traffic", "fit_s8k.json")))
-    peaks = json.load(open(os.path.join(ROOT, "benchmark", "peaks.json")))
-    mod = _load(os.path.join(ROOT, "benchmark", "configs",
-                             "keye_vl2_30b_a3b.py"))
-    return {"trace": {"devices": {"0": {"XLA Ops": events}}, "steps": steps,
-                      "host": {}},
-            "cfg": cfg, "traffic": traffic, "peaks": peaks["TPU v5 lite"],
-            "cell": {"config_mod": mod}, "facts": {}, "laps": []}
-
-
-def _ev(name, start_ms, dur_ms, op="fusion", result=""):
-    kind = name.rsplit(".", 1)[0]
-    return [name, start_ms * 1e6, dur_ms * 1e6,
-            {"kind": kind, "op": op, "result": result}]
-
-
-def _reader(name):
-    return _load(os.path.join(ROOT, "benchmark", "layer_metrics",
-                              name + ".py")).read
 
 
 def test_stage_readers_take_unions_and_find_xla_stages_by_shape():
     events = [
         # a scan's while spans its body: it belongs to no stage
-        _ev("while.1", 0, 100, op="while", result="(s32[], f32[2,8192,8192])"),
-        _ev("dsa_index_fwd.1", 0, 4, op="custom-call",
-            result="f32[2,8192,8192]"),
-        _ev("dsa_index_select.1", 4, 6, op="custom-call",
-            result="(f32[2,8192,8], s32[2,8192,8])"),
+        ev("while.1", 0, 100, op="while", result="(s32[], f32[2,8192,8192])"),
+        ev("dsa_index_fwd.1", 0, 4, op="custom-call",
+           result="f32[2,8192,8192]"),
+        ev("dsa_index_select.1", 4, 6, op="custom-call",
+           result="(f32[2,8192,8], s32[2,8192,8])"),
         # the selection's XLA part, by shape; a conditional spans its branch
-        _ev("conditional.3", 10, 10, op="conditional",
-            result="pred[2,8192,8192]"),
-        _ev("fusion.7", 12, 5, result="pred[2,8192,8192]"),
-        _ev("dsa_attend_fwd.1", 20, 16, op="custom-call",
-            result="(bf16[64,8192,128], f32[64,8192,8])"),
-        _ev("dsa_kl_target.1", 36, 12, op="custom-call",
-            result="(f32[2,8192,8], f32[2,8192,8192])"),
-        _ev("ragged-dot-none.2", 50, 3, op="custom-call",
-            result="bf16[131072,768]"),
-        _ev("fusion.9", 53, 4, result="bf16[131072,2048]"),
-        _ev("sort.4", 57, 1, op="sort",
-            result="(f32[16384,128], s32[16384,128])"),
-        _ev("fusion.11", 60, 9, result="bf16[2,8192,2048]"),  # nobody's
+        ev("conditional.3", 10, 10, op="conditional",
+           result="pred[2,8192,8192]"),
+        ev("fusion.7", 12, 5, result="pred[2,8192,8192]"),
+        ev("dsa_attend_fwd.1", 20, 16, op="custom-call",
+           result="(bf16[64,8192,128], f32[64,8192,8])"),
+        ev("dsa_kl_target.1", 36, 12, op="custom-call",
+           result="(f32[2,8192,8], f32[2,8192,8192])"),
+        ev("ragged-dot-none.2", 50, 3, op="custom-call",
+           result="bf16[131072,768]"),
+        ev("fusion.9", 53, 4, result="bf16[131072,2048]"),
+        ev("sort.4", 57, 1, op="sort",
+           result="(f32[16384,128], s32[16384,128])"),
+        ev("fusion.11", 60, 9, result="bf16[2,8192,2048]"),  # nobody's
     ]
-    ctx = _ctx(events)
-    assert _reader("dsa_index_ms_per_step")(ctx) == pytest.approx(20 / 2)
-    assert _reader("dsa_attend_ms_per_step")(ctx) == pytest.approx(28 / 2)
-    assert _reader("moe_ms_per_step")(ctx) == pytest.approx(8 / 2)
+    ctx = cells.ctx(CELL, events)
+    assert reader("dsa_index_ms_per_step")(ctx) == pytest.approx(20 / 2)
+    assert reader("dsa_attend_ms_per_step")(ctx) == pytest.approx(28 / 2)
+    assert reader("moe_ms_per_step")(ctx) == pytest.approx(8 / 2)
     # a program without the kernels (the parent): nothing to read, no raise
-    bare = _ctx([_ev("fusion.1", 0, 5, result="bf16[32,512,768]")])
+    bare = cells.ctx(CELL, [ev("fusion.1", 0, 5, result="bf16[32,512,768]")])
     for name in ("dsa_index_ms_per_step", "dsa_attend_ms_per_step",
                  "dsa_index_roofline", "dsa_attend_roofline",
                  "moe_load_max_over_mean"):
-        assert _reader(name)(bare) is None, name
-    assert _reader("dsa_index_ms_per_step")({**bare, "trace": None}) is None
+        assert reader(name)(bare) is None, name
+    assert reader("dsa_index_ms_per_step")({**bare, "trace": None}) is None
 
 
 def test_rooflines_count_executions_from_the_trace_and_stay_under_100():
     from benchmark.harness import flops, flops_sparse
 
-    ctx = _ctx([])
+    ctx = cells.ctx(CELL, [])
     costs = flops_sparse.dsa_kernels(
         **ctx["cell"]["config_mod"].dsa_shapes(ctx["cfg"], ctx["traffic"]))
     least = {k: flops.least_seconds(v["ops"], v["bytes"], ctx["peaks"])[0]
@@ -334,11 +221,11 @@ def test_rooflines_count_executions_from_the_trace_and_stay_under_100():
                        ("dsa_index_select", 1)):
         for _ in range(runs):
             ms = 2e3 * least[name]
-            events.append(_ev(f"jvp_{name}_.1", t, ms, op="custom-call"))
+            events.append(ev(f"jvp_{name}_.1", t, ms, op="custom-call"))
             t += ms
-    ctx = _ctx(events, steps=1)
-    assert _reader("dsa_attend_roofline")(ctx) == pytest.approx(50.0)
-    assert _reader("dsa_index_roofline")(ctx) == pytest.approx(50.0)
+    ctx = cells.ctx(CELL, events, steps=1)
+    assert reader("dsa_attend_roofline")(ctx) == pytest.approx(50.0)
+    assert reader("dsa_index_roofline")(ctx) == pytest.approx(50.0)
     runs = ctx["facts"]["dsa_attend_roofline"]["dsa_attend_fwd"]
     assert runs["runs_per_step"] == 2 and runs["bound"] == "compute"
     assert ctx["facts"]["dsa_index_roofline"]["dsa_index_select"][

@@ -59,10 +59,11 @@ def test_chunked_scan_is_the_recurrence_values_and_every_gradient(chunk,
     weights = jnp.asarray(np.random.default_rng(2).normal(size=got.shape),
                           jnp.float32)
     scalar = lambda fn: lambda *a: jnp.sum(weights * fn(*a))
-    grads = jax.grad(scalar(lambda *a: _scan(*a, seg, chunk=chunk)),
-                     argnums=tuple(range(6)))(*args)
-    wants = jax.grad(scalar(lambda *a: ssd.ssd_scan_sequential(*a, seg)),
-                     argnums=tuple(range(6)))(*args)
+    grads = jax.jit(jax.grad(scalar(lambda *a: _scan(*a, seg, chunk=chunk)),
+                             argnums=tuple(range(6))))(*args)
+    wants = jax.jit(jax.grad(
+        scalar(lambda *a: ssd.ssd_scan_sequential(*a, seg)),
+        argnums=tuple(range(6))))(*args)
     for name, a, b in zip(NAMES, grads, wants):
         assert a.shape == b.shape, name
         assert float(jnp.linalg.norm(b)) > 0, name
@@ -97,9 +98,10 @@ def test_a_packed_row_equals_its_documents_run_one_by_one(cuts):
             total = total + jnp.sum(weights[:, lo:hi] * y)
         return total
 
-    got, grads = jax.value_and_grad(packed, argnums=(0, 1, 2, 3))(
+    got, grads = jax.jit(jax.value_and_grad(packed, argnums=(0, 1, 2, 3)))(
         x, dt, bm, cm)
-    want, wants = jax.value_and_grad(one_by_one, argnums=(0, 1, 2, 3))(
+    want, wants = jax.jit(jax.value_and_grad(one_by_one,
+                                             argnums=(0, 1, 2, 3)))(
         x, dt, bm, cm)
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     for g, w in zip(grads, wants):

@@ -1,20 +1,17 @@
-"""The analytic counts, the configuration file, the per-layer readers and a
-CPU rehearsal of the cell ``nemotron3_nano_30b_a3b.fit_seg16k`` at a toy
-size, its planted faults among them (as ``tests/test_latent_moe_benchmark.py``
-does for the cell before it). A CPU run checks control flow, counts and
-agreement with the reference; it yields no time, rate or utilisation."""
+"""The analytic counts, the configuration file, the per-layer readers and a CPU
+rehearsal of the cell ``nemotron3_nano_30b_a3b.fit_seg16k`` at a toy size; its
+planted faults are in ``tests/test_ssm_moe_controls.py``, which takes ``TOY``
+from here. A CPU run checks control flow, counts and agreement with the
+reference; it yields no time, rate or utilisation."""
 
 import json
 import os
-import shutil
-import sys
 import time
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+import _cells as cells
+from _cells import ROOT, ev, json_of, load, reader, tree  # noqa: F401
 
 CELL = "nemotron3_nano_30b_a3b.fit_seg16k"
 NAME = "nemotron3_nano_30b_a3b"
@@ -33,22 +30,12 @@ ACCEPTED_START = ("start_import_s", "start_session_s", "start_state_init_s",
                   "start_unaccounted_s")
 
 
-def _load(path):
-    from benchmark.harness import runner
-    return runner.load_module(path)
-
-
-def _json(*rel):
-    with open(os.path.join(ROOT, *rel)) as f:
-        return json.load(f)
-
-
 def test_operations_per_token_count_required_work():
     from benchmark.harness import flops_hybrid, flops_ssm
 
-    cfg = _json("benchmark", "configs", NAME + ".json")
-    traffic = _json("benchmark", "traffic", TRAFFIC + ".json")
-    mod = _load(os.path.join(ROOT, "benchmark", "configs", NAME + ".py"))
+    cfg = json_of("benchmark", "configs", NAME + ".json")
+    traffic = json_of("benchmark", "traffic", TRAFFIC + ".json")
+    mod = load(os.path.join(ROOT, "benchmark", "configs", NAME + ".py"))
     per_token = mod.flops_per_item(cfg, traffic)
     # the issue's hand count, forward: a Mamba layer 81M (55.4M in, 22.0M
     # out, 3.4M in the four products of the chunked scan), an expert layer
@@ -88,9 +75,9 @@ def test_operations_per_token_count_required_work():
 
 
 def test_configuration_file_states_the_catalog_and_its_cuts():
-    bench = _json("BENCHMARK.json")
+    bench = json_of("BENCHMARK.json")
     conf = next(c for c in bench["configs"] if c["name"] == NAME)
-    cfg = _json(conf["file"])
+    cfg = json_of(conf["file"])
     assert conf["reduced"] == ["num_hidden_layers", "hybrid_override_pattern",
                                "n_routed_experts", "vocab_size"]
     assert cfg["published"]["num_hidden_layers"] == 52
@@ -113,7 +100,7 @@ def test_configuration_file_states_the_catalog_and_its_cuts():
     assert "528,092,736" in cfg["deployment"] and "16" in cfg["deployment"]
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert cell["chips"] == 1 and cell["traffic"] == TRAFFIC
-    traffic = _json("benchmark", "traffic", TRAFFIC + ".json")
+    traffic = json_of("benchmark", "traffic", TRAFFIC + ".json")
     assert {k: traffic[k] for k in (
         "feed", "num_docs", "doc_len_median", "doc_len_sigma", "doc_len_min",
         "doc_len_max", "zipf_exponent", "vocab_size", "eos_id", "seq_len",
@@ -159,9 +146,9 @@ def test_the_built_model_has_the_parameters_the_file_counts():
     import jax.numpy as jnp
     import numpy as np
 
-    cfg = _json("benchmark", "configs", NAME + ".json")
-    traffic = _json("benchmark", "traffic", TRAFFIC + ".json")
-    mod = _load(os.path.join(ROOT, "benchmark", "configs", NAME + ".py"))
+    cfg = json_of("benchmark", "configs", NAME + ".json")
+    traffic = json_of("benchmark", "traffic", TRAFFIC + ".json")
+    mod = load(os.path.join(ROOT, "benchmark", "configs", NAME + ".py"))
     model = mod.build(cfg, traffic)["model"]
     assert model.cfg.layout()[1:3] == (model.cfg.layer_types, 1)
     shapes = jax.eval_shape(lambda: model.init(
@@ -187,15 +174,6 @@ def test_the_built_model_has_the_parameters_the_file_counts():
     assert count(shapes["router_bias"]) == 3 * 128
 
 
-def _copy_of_the_benchmark(root):
-    root.mkdir()
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
-    shutil.copytree(os.path.join(ROOT, "benchmark"), root / "benchmark",
-                    ignore=shutil.ignore_patterns(".cache", "out",
-                                                  "__pycache__", "tests"))
-    return root
-
-
 TOY = {
     f"configs/{NAME}.json": dict(
         vocab_size=256, hidden_size=128, num_attention_heads=4,
@@ -218,34 +196,17 @@ TOY = {
 }
 
 
-def _toy(root, keep_check=False):
-    for rel, patch in TOY.items():
-        path = root / "benchmark" / rel
-        real = json.loads(path.read_text())
-        if keep_check:
-            patch = {k: v for k, v in patch.items() if k != "check"}
-        path.write_text(json.dumps({**real, **patch}))
-
-
-@pytest.fixture
-def tree(tmp_path, monkeypatch):
-    from benchmark.harness import seedcache
-
-    root = _copy_of_the_benchmark(tmp_path / "checkout")
-    monkeypatch.setattr(seedcache, "ROOT", str(root / "benchmark" / ".cache"))
-    # ``runner.measure`` sets it for its process and never takes it back:
-    # set here first, it is restored when the test ends
-    monkeypatch.setenv("DLS_TELEMETRY_DIR", str(tmp_path / "telemetry"))
-    return root
-
-
 def test_the_cell_rehearses_at_a_toy_size(tree):
     from benchmark.harness import runner
 
-    _toy(tree)
-    r = runner.measure(str(tree), CELL, seed=2 ** 31 + 7, seconds=0.5,
-                       trace=True, t_process=time.perf_counter(),
-                       master="local[1]")
+    with cells.run_in(tree, step_s=cells.REHEARSAL_STEP_S):
+        r = runner.measure(str(tree), CELL, seed=2 ** 31 + 7, seconds=0.5,
+                           trace=True, t_process=time.perf_counter(),
+                           master="local[1]")
+        again = runner.measure(str(tree), CELL, seed=2 ** 31 + 7,
+                               seconds=0.5, trace=False,
+                               t_process=time.perf_counter(),
+                               master="local[1]")
     assert r["correct"], r["facts"]
     assert r["failed"] == 0 and r["attempted"] > 0
     ref = r["facts"]["reference"]
@@ -283,9 +244,6 @@ def test_the_cell_rehearses_at_a_toy_size(tree):
     # a CPU run has no device plane: the device-trace readers return nothing
     for name in TRACE_METRICS + ACCEPTED_TRACE + ("device_step_ms", "mfu"):
         assert name not in r["metrics"], name
-    again = runner.measure(str(tree), CELL, seed=2 ** 31 + 7, seconds=0.5,
-                           trace=False, t_process=time.perf_counter(),
-                           master="local[1]")
     assert again["facts"]["feed"]["seed_cache_hit"] is True
     assert set(again["metrics"]) == {"throughput", "setup_s"}
 
@@ -302,9 +260,8 @@ def test_the_timed_forward_gives_the_terms_the_reference_gives(tree):
 
     from distributeddeeplearningspark_tpu.models.moe import BIAS_COLLECTION
 
-    _toy(tree)
-    mod = _load(str(tree / "benchmark" / "configs" / f"{NAME}.py"))
-    ref = _load(str(tree / "benchmark" / "reference" / f"{NAME}.py"))
+    mod = load(str(tree / "benchmark" / "configs" / f"{NAME}.py"))
+    ref = load(str(tree / "benchmark" / "reference" / f"{NAME}.py"))
     ref.QUERIES, ref.ROWS, ref.STATES = 32, 40, 7
     cfg = json.loads((tree / "benchmark" / "configs"
                       / f"{NAME}.json").read_text())
@@ -316,7 +273,8 @@ def test_the_timed_forward_gives_the_terms_the_reference_gives(tree):
     ids = jnp.asarray(rng.integers(1, 256, (2, 96)), jnp.int32)
     segs = jnp.asarray(np.sort(rng.integers(0, 4, (2, 96)), axis=1), jnp.int32)
     batch = {"input_ids": ids, "segment_ids": segs}
-    variables = dict(built["model"].init(jax.random.PRNGKey(0), batch))
+    init = jax.jit(built["model"].init)
+    variables = dict(init(jax.random.PRNGKey(0), batch))
     # (off their initial values: a zero bias and a unit D hide nothing)
     params = jax.tree.map(
         lambda a: a + jnp.asarray(0.05 * rng.normal(size=a.shape), a.dtype),
@@ -324,8 +282,10 @@ def test_the_timed_forward_gives_the_terms_the_reference_gives(tree):
     mutable = {BIAS_COLLECTION: jax.tree.map(
         lambda a: jnp.asarray(rng.normal(size=a.shape) * 0.05, jnp.float32),
         variables[BIAS_COLLECTION])}
-    got = mod.parts(built["model"], built["loss"], params, mutable, batch)
-    want = ref.parts(params, mutable, batch, cfg)
+    got = jax.jit(lambda p, m, b: mod.parts(built["model"], built["loss"],
+                                            p, m, b))(params, mutable, batch)
+    want = jax.jit(lambda p, m, b: ref.parts(p, m, b, cfg))(params, mutable,
+                                                            batch)
     assert set(got) - set(want) == {"rows_held_share"}
     assert set(cfg["check"]["term_weights"]) == set(want) - {"loss"}
     for name, value in want.items():
@@ -349,9 +309,8 @@ def test_every_leafs_gradient_is_the_references_in_float32(tree):
     import jax.numpy as jnp
     import numpy as np
 
-    _toy(tree)
-    mod = _load(str(tree / "benchmark" / "configs" / f"{NAME}.py"))
-    ref = _load(str(tree / "benchmark" / "reference" / f"{NAME}.py"))
+    mod = load(str(tree / "benchmark" / "configs" / f"{NAME}.py"))
+    ref = load(str(tree / "benchmark" / "reference" / f"{NAME}.py"))
     ref.STATES = 16
     cfg = json.loads((tree / "benchmark" / "configs"
                       / f"{NAME}.json").read_text())
@@ -363,7 +322,8 @@ def test_every_leafs_gradient_is_the_references_in_float32(tree):
                                       jnp.int32),
              "segment_ids": jnp.asarray(
                  np.sort(rng.integers(0, 3, (2, 64)), axis=1), jnp.int32)}
-    variables = dict(built["model"].init(jax.random.PRNGKey(3), batch))
+    init = jax.jit(built["model"].init)
+    variables = dict(init(jax.random.PRNGKey(3), batch))
     params = variables.pop("params")
 
     def program(p):
@@ -391,39 +351,14 @@ def test_a_program_without_the_layer_is_refused_with_a_message(
 
     from benchmark.harness import runner
 
-    _toy(tree)
     # (what the import system says of a module that is not there)
     monkeypatch.setitem(sys.modules,
                         "distributeddeeplearningspark_tpu.ops.ssd", None)
-    with pytest.raises(runner.Refused) as e:
+    with cells.run_in(tree), pytest.raises(runner.Refused) as e:
         runner.measure(str(tree), CELL, seed=3, seconds=0.5, trace=False,
                        t_process=time.perf_counter(), master="local[1]")
     assert "state-space" in e.value.args[0][0]
     assert not (tree / "benchmark" / ".cache").exists()   # no feed was built
-
-
-def _ctx(events, steps=2, laps=()):
-    """A traced run's context with hand-made device events (name, start ns,
-    duration ns, info)."""
-    peaks = _json("benchmark", "peaks.json")
-    mod = _load(os.path.join(ROOT, "benchmark", "configs", NAME + ".py"))
-    return {"trace": {"devices": {"0": {"XLA Ops": events}}, "steps": steps,
-                      "host": {}},
-            "cfg": _json("benchmark", "configs", NAME + ".json"),
-            "traffic": _json("benchmark", "traffic", TRAFFIC + ".json"),
-            "peaks": peaks["TPU v5 lite"], "cell": {"config_mod": mod},
-            "facts": {}, "laps": list(laps)}
-
-
-def _ev(name, start_ms, dur_ms, op="fusion", result=""):
-    kind = name.rsplit(".", 1)[0]
-    return [name, start_ms * 1e6, dur_ms * 1e6,
-            {"kind": kind, "op": op, "result": result[:120]}]
-
-
-def _reader(name):
-    return _load(os.path.join(ROOT, "benchmark", "layer_metrics",
-                              name + ".py")).read
 
 
 #: the scan's loops as the chip's compiler names their results (cut from the
@@ -452,44 +387,44 @@ HEAD_LOSS = ("(s32[], f32[16383], f32[16383], bf16[16,2688,1024], "
 def _a_step(t0, scale=1.0):
     """One step's loops of three Mamba layers: forward, then replay and
     backward, each pass ``scale`` ms; the inner loops inside theirs."""
-    events, t = [_ev("while.483", t0, 0.5 * scale, "while", MASKS)], t0 + 1
+    events, t = [ev("while.483", t0, 0.5 * scale, "while", MASKS)], t0 + 1
     for kind in (FORWARD,) * 3 + (REPLAY, BACKWARD) * 3:
-        events.append(_ev("while.1", t, scale, "while", kind))
-        events.append(_ev("while.2", t + 0.1 * scale, 0.2 * scale, "while",
-                          INNER))
+        events.append(ev("while.1", t, scale, "while", kind))
+        events.append(ev("while.2", t + 0.1 * scale, 0.2 * scale, "while",
+                         INNER))
         t += 2 * scale
-    events.append(_ev("while.493", t, 5.0, "while", HEAD_LOSS))
+    events.append(ev("while.493", t, 5.0, "while", HEAD_LOSS))
     return events
 
 
 def test_stage_readers_find_the_scan_by_its_loops_and_the_conv_by_shape():
     events = _a_step(0.0) + _a_step(100.0) + [
-        _ev("slice_convert_fusion.1", 200, 1, result="f32[1,16384,6144]"),
-        _ev("multiply_convert_fusion.1", 201, 2,
-            result="(bf16[1,16384,6144], f32[1,16384,6144])"),
-        _ev("fusion.5", 203, 3,
-            result="(f32[6144,1], f32[6144,1], f32[6144,1], f32[6144,1])"),
-        _ev("fusion.6", 206, 4, result="bf16[16384,6144]"),
+        ev("slice_convert_fusion.1", 200, 1, result="f32[1,16384,6144]"),
+        ev("multiply_convert_fusion.1", 201, 2,
+           result="(bf16[1,16384,6144], f32[1,16384,6144])"),
+        ev("fusion.5", 203, 3,
+           result="(f32[6144,1], f32[6144,1], f32[6144,1], f32[6144,1])"),
+        ev("fusion.6", 206, 4, result="bf16[16384,6144]"),
         # nobody's: the input projection, the taps' optimizer, the block
-        _ev("convolution.1", 210, 9, result="bf16[16384,10304]"),
-        _ev("fusion.7", 219, 9, result="(f32[1,6144,4], f32[1,6144,4])"),
-        _ev("fusion.8", 228, 9, result="bf16[1,16384,2688]"),
-        _ev("fusion.9", 237, 9, result="bf16[1,16384,4096]"),
+        ev("convolution.1", 210, 9, result="bf16[16384,10304]"),
+        ev("fusion.7", 219, 9, result="(f32[1,6144,4], f32[1,6144,4])"),
+        ev("fusion.8", 228, 9, result="bf16[1,16384,2688]"),
+        ev("fusion.9", 237, 9, result="bf16[1,16384,4096]"),
     ]
-    ctx = _ctx(events)
+    ctx = cells.ctx(CELL, events)
     # nine passes of 1 ms and the masks' loop of 0.5 ms a step
-    assert _reader("ssd_ms_per_step")(ctx) == pytest.approx(9.5)
+    assert reader("ssd_ms_per_step")(ctx) == pytest.approx(9.5)
     facts = ctx["facts"]["ssd_ms_per_step"]
     assert facts["forward_loops_per_step"] == 6
     assert facts["backward_loops_per_step"] == 3
     assert facts["masks_loops_per_step"] == 1
-    assert _reader("mamba_conv_ms_per_step")(ctx) == pytest.approx(10 / 2)
+    assert reader("mamba_conv_ms_per_step")(ctx) == pytest.approx(10 / 2)
     assert ctx["facts"]["mamba_conv_ms_per_step"]["events_per_step"] == 2
     # however many chunks the program puts in a group: 32 groups of 4 here
-    regrouped = _ctx([_ev("while.1", 0, 3, "while", kind.replace(
+    regrouped = cells.ctx(CELL, [ev("while.1", 0, 3, "while", kind.replace(
         "[16,1,8,", "[32,1,4,")) for kind in (FORWARD, REPLAY, BACKWARD,
                                               MASKS, INNER)], steps=1)
-    assert _reader("ssd_ms_per_step")(regrouped) == pytest.approx(3.0)
+    assert reader("ssd_ms_per_step")(regrouped) == pytest.approx(3.0)
     assert regrouped["facts"]["ssd_ms_per_step"] == {
         "forward_loops_per_step": 2, "backward_loops_per_step": 1,
         "masks_loops_per_step": 1, "forward_ms": 3.0, "backward_ms": 3.0,
@@ -497,34 +432,34 @@ def test_stage_readers_find_the_scan_by_its_loops_and_the_conv_by_shape():
     # a kernel that runs the scan, or part of it, is found by its name,
     # which starts ``ssd_``: its time is the stage's; its executions the
     # share cannot count yet, and says so
-    kernels = [_ev("ssd_bwd_chunk.1", 50, 2, "custom-call", "bf16[1,16384]"),
-               _ev("ssd_fwd.2", 60, 1, "custom-call", "bf16[1,16384]")]
-    with_kernels = _ctx(_a_step(0.0) + kernels, steps=1)
-    assert _reader("ssd_ms_per_step")(with_kernels) == pytest.approx(12.5)
+    kernels = [ev("ssd_bwd_chunk.1", 50, 2, "custom-call", "bf16[1,16384]"),
+               ev("ssd_fwd.2", 60, 1, "custom-call", "bf16[1,16384]")]
+    with_kernels = cells.ctx(CELL, _a_step(0.0) + kernels, steps=1)
+    assert reader("ssd_ms_per_step")(with_kernels) == pytest.approx(12.5)
     assert with_kernels["facts"]["ssd_ms_per_step"]["kernels_ms"] == 3.0
-    assert _reader("ssd_roofline")(with_kernels) is None
+    assert reader("ssd_roofline")(with_kernels) is None
     assert with_kernels["facts"]["ssd_roofline"]["kernels"] == 2
-    assert _reader("ssd_ms_per_step")(_ctx(kernels, steps=1)) == \
+    assert reader("ssd_ms_per_step")(cells.ctx(CELL, kernels, steps=1)) == \
         pytest.approx(3.0)
     # a program without the scan (the parent): nothing, no raise
-    bare = _ctx([_ev("fusion.1", 0, 5, result="bf16[32,512,768]")])
+    bare = cells.ctx(CELL, [ev("fusion.1", 0, 5, result="bf16[32,512,768]")])
     for name in ("ssd_ms_per_step", "ssd_roofline"):
-        assert _reader(name)(bare) is None, name
+        assert reader(name)(bare) is None, name
     for name in TRACE_METRICS:
-        assert _reader(name)({**bare, "trace": None}) is None, name
+        assert reader(name)({**bare, "trace": None}) is None, name
     # ... and in a cell of another configuration
-    other = {**_ctx(events), "cell": {"config_mod": object()}}
+    other = {**cells.ctx(CELL, events), "cell": {"config_mod": object()}}
     for name in TRACE_METRICS:
-        assert _reader(name)(other) is None, name
+        assert reader(name)(other) is None, name
     # the counters: mean and largest over the window's laps, or nothing
     laps = [{"metrics": {"ssm_chunks_reset_share": s, "ssm_state_abs_max": m}}
             for s, m in ((0.0078125, 3.0), (0.015625, 5.0), (0.0234375, 4.0))]
-    ctx = _ctx([], laps=laps)
-    assert _reader("ssm_chunks_reset_share")(ctx) == pytest.approx(1.5625)
-    assert _reader("ssm_state_abs_max")(ctx) == 5.0
+    ctx = cells.ctx(CELL, [], laps=laps)
+    assert reader("ssm_chunks_reset_share")(ctx) == pytest.approx(1.5625)
+    assert reader("ssm_state_abs_max")(ctx) == 5.0
     for name in COUNTER_METRICS:
-        assert _reader(name)(_ctx([], laps=[{"metrics": {"loss": 1.0}}])) \
-            is None, name
+        assert reader(name)(cells.ctx(
+            CELL, [], laps=[{"metrics": {"loss": 1.0}}])) is None, name
 
 
 def test_the_accepted_readers_find_this_cells_experts_and_flash_kernels():
@@ -534,35 +469,36 @@ def test_the_accepted_readers_find_this_cells_experts_and_flash_kernels():
     6 rows, the three flash kernels by name, and their roofline on the
     in-document pairs the step counted."""
     events = [
-        _ev("ragged-dot.1", 0, 4, "ragged-dot", "bf16[98304,1856]"),
-        _ev("fusion.2", 4, 2, result="bf16[98304,2688]"),
-        _ev("fusion.3", 6, 1, result="f32[16384,128]"),
-        _ev("flash_fwd.1", 10, 30, "custom-call",
-            "(bf16[32,16384,128], f32[32,16384,8])"),
-        _ev("flash_bwd_dq.1", 40, 24, "custom-call", "bf16[32,16384,128]"),
-        _ev("flash_bwd_dkv.1", 64, 30, "custom-call",
-            "(bf16[2,16384,128], bf16[2,16384,128])"),
+        ev("ragged-dot.1", 0, 4, "ragged-dot", "bf16[98304,1856]"),
+        ev("fusion.2", 4, 2, result="bf16[98304,2688]"),
+        ev("fusion.3", 6, 1, result="f32[16384,128]"),
+        ev("flash_fwd.1", 10, 30, "custom-call",
+           "(bf16[32,16384,128], f32[32,16384,8])"),
+        ev("flash_bwd_dq.1", 40, 24, "custom-call", "bf16[32,16384,128]"),
+        ev("flash_bwd_dkv.1", 64, 30, "custom-call",
+           "(bf16[2,16384,128], bf16[2,16384,128])"),
         # nobody's: a Mamba layer's B and C, the scan's loop
-        _ev("fusion.4", 100, 9, result="bf16[1,16384,8,128]"),
-        _ev("while.1", 110, 9, "while", FORWARD),
+        ev("fusion.4", 100, 9, result="bf16[1,16384,8,128]"),
+        ev("while.1", 110, 9, "while", FORWARD),
     ]
     laps = [{"metrics": {"attn_pairs_share": 0.4}}]
-    ctx = _ctx(events, steps=1, laps=laps)
-    assert _reader("experts_ms_per_step")(ctx) == pytest.approx(7.0)
-    assert _reader("flash_causal_ms_per_step")(ctx) == pytest.approx(84.0)
-    share = _reader("flash_causal_roofline")(ctx)
+    ctx = cells.ctx(CELL, events, steps=1, laps=laps)
+    assert reader("experts_ms_per_step")(ctx) == pytest.approx(7.0)
+    assert reader("flash_causal_ms_per_step")(ctx) == pytest.approx(84.0)
+    share = reader("flash_causal_roofline")(ctx)
     assert 0 < share < 100
     # (at the chip's own times, which these are, the kernels stand near a
     # quarter of their roofline)
     assert share == pytest.approx(24.0, abs=3.0)
     # without the step's counter the share has nothing to stand on
-    assert _reader("flash_causal_roofline")(_ctx(events, steps=1)) is None
+    assert reader("flash_causal_roofline")(
+        cells.ctx(CELL, events, steps=1)) is None
 
 
 def test_the_roofline_stays_under_100_and_refuses_a_partial_stage():
     from benchmark.harness import flops, flops_ssm
 
-    ctx = _ctx([])
+    ctx = cells.ctx(CELL, [])
     mod = ctx["cell"]["config_mod"]
     shapes = mod.ssd_shapes(ctx["cfg"], ctx["traffic"])
     layers = shapes.pop("layers")
@@ -575,30 +511,31 @@ def test_the_roofline_stays_under_100_and_refuses_a_partial_stage():
         for kind in (FORWARD,) * layers + (REPLAY, BACKWARD) * layers:
             ms = slow * 1e3 * least[
                 "backward" if kind is BACKWARD else "forward"][0]
-            events.append(_ev("while.1", t, ms, "while", kind))
+            events.append(ev("while.1", t, ms, "while", kind))
             t += ms
         return events, t
 
     # every pass at four times its least time: 25%
     events, _ = a_step(0.0, 4.0)
-    ctx = _ctx(events, steps=1)
-    assert _reader("ssd_roofline")(ctx) == pytest.approx(25.0)
+    ctx = cells.ctx(CELL, events, steps=1)
+    assert reader("ssd_roofline")(ctx) == pytest.approx(25.0)
     # the masks' loop is time of the stage and no execution: it reads LOWER
-    ctx = _ctx(events + [_ev("while.483", 1e4, 1.0, "while", MASKS)], steps=1)
-    assert _reader("ssd_roofline")(ctx) < 25.0
+    ctx = cells.ctx(CELL, events + [ev("while.483", 1e4, 1.0, "while",
+                                       MASKS)], steps=1)
+    assert reader("ssd_roofline")(ctx) < 25.0
     # a scan AT its roofline reads 100, not more
     events, _ = a_step(0.0, 1.0)
-    assert _reader("ssd_roofline")(_ctx(events, steps=1)) == \
+    assert reader("ssd_roofline")(cells.ctx(CELL, events, steps=1)) == \
         pytest.approx(100.0)
     # a trace that lacks one layer's backward pass: a share of what was
     # found would read HIGH; it gives nothing, and says why
     partial = [e for e in events if e[3]["result"] != BACKWARD[:120]][:-1] + [
         e for e in events if e[3]["result"] == BACKWARD[:120]][:2]
-    ctx = _ctx(partial, steps=1)
-    assert _reader("ssd_roofline")(ctx) is None
+    ctx = cells.ctx(CELL, partial, steps=1)
+    assert reader("ssd_roofline")(ctx) is None
     assert "refused" in ctx["facts"]["ssd_roofline"]
     # two steps traced, the loops of one: nothing either
-    assert _reader("ssd_roofline")(_ctx(events, steps=2)) is None
+    assert reader("ssd_roofline")(cells.ctx(CELL, events, steps=2)) is None
 
 
 def test_the_readers_on_an_extract_cut_from_the_chips_trace():
@@ -613,20 +550,20 @@ def test_the_readers_on_an_extract_cut_from_the_chips_trace():
     with gzip.open(os.path.join(ROOT, "tests", "fixtures",
                                 "ssd_loops_extract.json.gz"), "rt") as f:
         extract = json.load(f)
-    ctx = {**_ctx([]), "trace": extract}
-    ms = _reader("ssd_ms_per_step")(ctx)
+    ctx = {**cells.ctx(CELL, []), "trace": extract}
+    ms = reader("ssd_ms_per_step")(ctx)
     facts = ctx["facts"]["ssd_ms_per_step"]
     assert (facts["forward_loops_per_step"], facts["backward_loops_per_step"],
             facts["masks_loops_per_step"]) == (6, 3, 1)
     assert 55 < ms < 70                                # 61.4 over five steps
     assert ms == pytest.approx(facts["forward_ms"] + facts["backward_ms"]
                                + facts["masks_ms"], rel=1e-6)
-    share = _reader("ssd_roofline")(ctx)
+    share = reader("ssd_roofline")(ctx)
     assert 10 < share < 18                             # 13.7 over five steps
     # 2 x 0.743 + 1.321 ms a layer, three layers, over the stage's time
     assert share == pytest.approx(100 * 3 * (2 * 0.74258 + 1.32128) / ms,
                                   rel=1e-3)
-    conv = _reader("mamba_conv_ms_per_step")(ctx)
+    conv = reader("mamba_conv_ms_per_step")(ctx)
     assert 40 < conv < 55                              # 47.0 over five steps
     assert ctx["facts"]["mamba_conv_ms_per_step"]["events_per_step"] == 99
     events = extract["devices"]["0"]["XLA Ops"]
@@ -634,7 +571,7 @@ def test_the_readers_on_an_extract_cut_from_the_chips_trace():
                     e[3].get("result", ""))
     partial = {**extract, "devices": {"0": {"XLA Ops": [
         e for e in events if e is not backward]}}}
-    ctx = {**_ctx([]), "trace": partial}
-    assert _reader("ssd_roofline")(ctx) is None
+    ctx = {**cells.ctx(CELL, []), "trace": partial}
+    assert reader("ssd_roofline")(ctx) is None
     assert ctx["facts"]["ssd_roofline"]["backward_loops"] == 5
-    assert _reader("ssd_ms_per_step")(ctx) is not None   # the time is read
+    assert reader("ssd_ms_per_step")(ctx) is not None   # the time is read

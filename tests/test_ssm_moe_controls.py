@@ -6,49 +6,17 @@ with each fault planted (the cell's other tests, and the toy size, are in
 side by side)."""
 
 import os
-import time
 
 import pytest
 
-from test_ssm_moe_benchmark import (  # noqa: F401 - the toy size
-    NAME, ROOT, _copy_of_the_benchmark, _load, _toy)
-
-#: the toy run is counted in steps, not seconds: the clock its window reads
-#: moves STEP_S a step from the first, so the 5 s of warm-up and the 1-s
-#: window are 242 steps on any machine. A fault's term follows how far the
-#: toy model has trained: ``head_reads_group_h_mod_8``'s ``scan_energy``
-#: reads 0.041, 0.037, 0.027 and 0.0059 at 202, 242, 302 and 482 steps
-STEP_S = 0.025
+import _cells as cells
+from _cells import ROOT, load
+from test_ssm_moe_benchmark import NAME, TOY
 
 
 @pytest.fixture(scope="module")
 def controls(tmp_path_factory):
-    """``benchmark/controls/nemotron3_nano_30b_a3b.py`` at the toy size on
-    ``local[1]``, in float32 but under the cell's OWN limits: one run of the
-    cell, the harness's comparison repeated with each fault planted."""
-    from benchmark.harness import runner, seedcache, window
-
-    class StepClock(window.LapWindow):
-        def __init__(self, **kw):
-            super().__init__(clock=self.steps_s, **kw)
-            self.first = None
-
-        def steps_s(self):
-            if self.first is None:
-                self.first = (time.perf_counter(), self.last_step)
-            t, step = self.first
-            return t + (self.last_step - step) * STEP_S
-
-    root = _copy_of_the_benchmark(
-        tmp_path_factory.mktemp("controls") / "checkout")
-    _toy(root, keep_check=True)
-    mod = _load(str(root / "benchmark" / "controls" / f"{NAME}.py"))
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(runner, "LapWindow", StepClock)
-        mp.setattr(seedcache, "ROOT", str(root / "benchmark" / ".cache"))
-        mp.setenv("DLS_TELEMETRY_DIR", str(root / "telemetry"))  # restored
-        return mod, mod.run(2 ** 31 + 11, 1.0, mod.ALL, master="local[1]",
-                            root=str(root))
+    return cells.run_controls(tmp_path_factory, NAME, TOY)
 
 
 def test_the_sound_program_is_correct_under_the_cells_own_limits(controls):
@@ -106,7 +74,7 @@ def test_a_fault_moves_the_term_that_is_there_for_it(controls, fault, term,
 def test_a_planted_fault_leaves_the_program_as_it_was():
     from distributeddeeplearningspark_tpu.models import hybrid_decoder, moe
 
-    mod = _load(os.path.join(ROOT, "benchmark", "controls", NAME + ".py"))
+    mod = load(os.path.join(ROOT, "benchmark", "controls", NAME + ".py"))
     planted = mod.faults(None, None, {})
     for name, module, attribute in (
             ("state_not_reset", hybrid_decoder, "ssd_scan"),
@@ -139,7 +107,7 @@ def test_the_planted_convolution_reaches_the_kernels_through_auto(
     from distributeddeeplearningspark_tpu.models import hybrid_decoder
     from distributeddeeplearningspark_tpu.ops import short_conv
 
-    mod = _load(os.path.join(ROOT, "benchmark", "controls", NAME + ".py"))
+    mod = load(os.path.join(ROOT, "benchmark", "controls", NAME + ".py"))
     monkeypatch.setattr(short_conv, "on_tpu", lambda: True)
     rng = np.random.default_rng(0)
     v = jnp.asarray(rng.normal(size=(1, 256, 128)), jnp.float32)
@@ -148,7 +116,7 @@ def test_the_planted_convolution_reaches_the_kernels_through_auto(
     seg = jnp.asarray(np.repeat([0, 1], 128)[None], jnp.int32)
     with mod.faults(None, None, {})["conv_crosses_documents"][1]():
         jaxpr = jax.make_jaxpr(hybrid_decoder.silu_short_conv)(v, w, bias,
-                                                              seg)
+                                                               seg)
         got = hybrid_decoder.silu_short_conv(v, w, bias, seg)
     assert [e.params["name"] for e in eqns(jaxpr.jaxpr, "pallas_call")] == [
         "mamba_conv_fwd"]
@@ -167,7 +135,7 @@ def test_the_permuted_scan_is_the_scan_with_groups_read_h_mod_g():
     from distributeddeeplearningspark_tpu.models import hybrid_decoder
     from distributeddeeplearningspark_tpu.ops import ssd
 
-    mod = _load(os.path.join(ROOT, "benchmark", "controls", NAME + ".py"))
+    mod = load(os.path.join(ROOT, "benchmark", "controls", NAME + ".py"))
     rng = np.random.default_rng(0)
     b, s, h, p, g, n = 1, 32, 6, 4, 3, 8
     arr = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)

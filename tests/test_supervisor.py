@@ -83,7 +83,6 @@ def gang():
         pytest.skip(reason)
 
 
-@pytest.mark.slow
 def test_gang_completes_without_faults(tmp_path, gang):
     sup = Supervisor(
         [sys.executable, WORKER, "train", "--ckpt-dir", str(tmp_path),
@@ -96,7 +95,6 @@ def test_gang_completes_without_faults(tmp_path, gang):
     assert int(step) == 10 and int(attempt) == 0
 
 
-@pytest.mark.slow
 def test_kill_one_worker_recovers_from_checkpoint(tmp_path, gang):
     """Process 1 SIGKILLs itself at step 15 of 30 on attempt 0; the supervisor
     tears down the gang and relaunches; workers resume from the step-10
@@ -116,7 +114,6 @@ def test_kill_one_worker_recovers_from_checkpoint(tmp_path, gang):
     assert int(step) == 30 and int(attempt) == 1
 
 
-@pytest.mark.slow
 def test_two_process_gang_matches_single_process_numerics(tmp_path, eight_devices, gang):
     """VERDICT r4 next-#8: the DCN control-plane analog of the dryrun's
     single-process fingerprint. A 2-process × 4-device jax.distributed
@@ -152,7 +149,6 @@ def test_two_process_gang_matches_single_process_numerics(tmp_path, eight_device
                                    err_msg=k)
 
 
-@pytest.mark.slow
 def test_desync_sanitizer_catches_split_brain(tmp_path, gang):
     sup = Supervisor(
         [sys.executable, WORKER, "desync"],

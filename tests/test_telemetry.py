@@ -622,7 +622,6 @@ def test_status_cli_no_telemetry_exits_nonzero(tmp_path, capsys):
 # -- end-to-end: Trainer.fit emits a readable run ---------------------------
 
 
-@pytest.mark.slow
 def test_fit_emits_telemetry_and_dlstatus_reads_it(tmp_path, monkeypatch):
     """The integration contract: a plain fit() with DLS_TELEMETRY_DIR set
     leaves a stream from which dlstatus reports compile/productive time,

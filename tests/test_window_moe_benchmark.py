@@ -10,15 +10,12 @@ and agreement with the reference; it yields no time, rate or utilisation."""
 import json
 import math
 import os
-import shutil
-import sys
 import time
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+import _cells as cells
+from _cells import ROOT, ev, json_of, load, reader, tree  # noqa: F401
 
 CELL = "laguna_xs2.fit_swa16k"
 NAME = "laguna_xs2"
@@ -39,25 +36,15 @@ ACCEPTED_START = ("start_import_s", "start_session_s", "start_state_init_s",
                   "start_unaccounted_s")
 
 
-def _load(path):
-    from benchmark.harness import runner
-    return runner.load_module(path)
-
-
-def _json(*rel):
-    with open(os.path.join(ROOT, *rel)) as f:
-        return json.load(f)
-
-
 def _mod():
-    return _load(os.path.join(ROOT, "benchmark", "configs", NAME + ".py"))
+    return load(os.path.join(ROOT, "benchmark", "configs", NAME + ".py"))
 
 
 def test_operations_per_token_count_required_work():
     from benchmark.harness import flops_hybrid, flops_window
 
-    cfg = _json("benchmark", "configs", NAME + ".json")
-    traffic = _json("benchmark", "traffic", TRAFFIC + ".json")
+    cfg = json_of("benchmark", "configs", NAME + ".json")
+    traffic = json_of("benchmark", "traffic", TRAFFIC + ".json")
     per_token = _mod().flops_per_item(cfg, traffic)
     h, d, kv = 2048, 128, 8 * 128
     pairs = flops_hybrid.in_document_pairs_share(traffic)
@@ -98,9 +85,9 @@ def test_operations_per_token_count_required_work():
 
 
 def test_configuration_file_states_the_catalog_and_its_cuts():
-    bench = _json("BENCHMARK.json")
+    bench = json_of("BENCHMARK.json")
     conf = next(c for c in bench["configs"] if c["name"] == NAME)
-    cfg = _json(conf["file"])
+    cfg = json_of(conf["file"])
     assert conf["reduced"] == REDUCED
     assert cfg["published"]["num_hidden_layers"] == 40
     assert cfg["num_hidden_layers"] == 5
@@ -123,7 +110,7 @@ def test_configuration_file_states_the_catalog_and_its_cuts():
     assert "490,297,344" in cfg["deployment"] and "16" in cfg["deployment"]
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert cell["chips"] == 1 and cell["traffic"] == TRAFFIC
-    traffic = _json("benchmark", "traffic", TRAFFIC + ".json")
+    traffic = json_of("benchmark", "traffic", TRAFFIC + ".json")
     assert {k: traffic[k] for k in (
         "feed", "num_docs", "doc_len_median", "doc_len_sigma", "doc_len_min",
         "doc_len_max", "zipf_exponent", "vocab_size", "eos_id", "seq_len",
@@ -164,8 +151,8 @@ def test_the_built_model_has_the_parameters_the_file_counts():
     import jax.numpy as jnp
     import numpy as np
 
-    cfg = _json("benchmark", "configs", NAME + ".json")
-    traffic = _json("benchmark", "traffic", TRAFFIC + ".json")
+    cfg = json_of("benchmark", "configs", NAME + ".json")
+    traffic = json_of("benchmark", "traffic", TRAFFIC + ".json")
     model = _mod().build(cfg, traffic)["model"]
     lead, period, whole, trail = model.cfg.layout()
     assert (len(lead), len(period), whole, trail) == (1, 4, 1, ())
@@ -214,7 +201,7 @@ def test_yarn_frequencies_against_a_table_at_the_published_values():
 
     from distributeddeeplearningspark_tpu.models.llama import yarn_inv_freq
 
-    ref = _load(os.path.join(ROOT, "benchmark", "reference", NAME + ".py"))
+    ref = load(os.path.join(ROOT, "benchmark", "reference", NAME + ".py"))
     d, base = 64, 5e5
     lo = math.floor(d * math.log(4096 / (64 * 2 * math.pi))
                     / (2 * math.log(base)))
@@ -287,7 +274,7 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer():
 
     from distributeddeeplearningspark_tpu.models.moe import RoutedExperts
 
-    ref = _load(os.path.join(ROOT, "benchmark", "reference", NAME + ".py"))
+    ref = load(os.path.join(ROOT, "benchmark", "reference", NAME + ".py"))
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(1, 64, 32)), jnp.float32)
     make = lambda held, shared: RoutedExperts(
@@ -325,15 +312,6 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer():
                                np.asarray(want[0]), atol=2e-5)
 
 
-def _copy_of_the_benchmark(root):
-    root.mkdir()
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
-    shutil.copytree(os.path.join(ROOT, "benchmark"), root / "benchmark",
-                    ignore=shutil.ignore_patterns(".cache", "out",
-                                                  "__pycache__", "tests"))
-    return root
-
-
 #: the configuration at a toy size: the same kinds, gate, rotary and router,
 #: a window of 16
 TOY = {
@@ -358,38 +336,22 @@ TOY = {
 }
 
 
-def _toy(root, keep_check=False):
-    for rel, patch in TOY.items():
-        path = root / "benchmark" / rel
-        if keep_check:
-            patch = {k: v for k, v in patch.items() if k != "check"}
-        path.write_text(json.dumps({**json.loads(path.read_text()), **patch}))
-
-
 def _toy_cfg(root):
     return json.loads((root / "benchmark" / "configs"
                        / f"{NAME}.json").read_text())
 
 
-@pytest.fixture
-def tree(tmp_path, monkeypatch):
-    from benchmark.harness import seedcache
-
-    root = _copy_of_the_benchmark(tmp_path / "checkout")
-    monkeypatch.setattr(seedcache, "ROOT", str(root / "benchmark" / ".cache"))
-    # ``runner.measure`` sets it for its process and never takes it back:
-    # set here first, it is restored when the test ends
-    monkeypatch.setenv("DLS_TELEMETRY_DIR", str(tmp_path / "telemetry"))
-    return root
-
-
 def test_the_cell_rehearses_at_a_toy_size(tree):
     from benchmark.harness import runner
 
-    _toy(tree)
-    r = runner.measure(str(tree), CELL, seed=2 ** 31 + 11, seconds=0.5,
-                       trace=True, t_process=time.perf_counter(),
-                       master="local[1]")
+    with cells.run_in(tree, step_s=cells.REHEARSAL_STEP_S):
+        r = runner.measure(str(tree), CELL, seed=2 ** 31 + 11, seconds=0.5,
+                           trace=True, t_process=time.perf_counter(),
+                           master="local[1]")
+        again = runner.measure(str(tree), CELL, seed=2 ** 31 + 11,
+                               seconds=0.5, trace=False,
+                               t_process=time.perf_counter(),
+                               master="local[1]")
     assert r["correct"], r["facts"]
     assert r["failed"] == 0 and r["attempted"] > 0
     ref = r["facts"]["reference"]
@@ -421,9 +383,6 @@ def test_the_cell_rehearses_at_a_toy_size(tree):
     # a CPU run has no device plane: the device-trace readers return nothing
     for name in TRACE_METRICS + ACCEPTED_TRACE + ("device_step_ms", "mfu"):
         assert name not in r["metrics"], name
-    again = runner.measure(str(tree), CELL, seed=2 ** 31 + 11, seconds=0.5,
-                           trace=False, t_process=time.perf_counter(),
-                           master="local[1]")
     assert again["facts"]["feed"]["seed_cache_hit"] is True
     assert set(again["metrics"]) == {"throughput", "setup_s"}
 
@@ -435,9 +394,8 @@ def _toy_model(tree, seq=96, seed=0):
     import jax.numpy as jnp
     import numpy as np
 
-    _toy(tree)
-    mod = _load(str(tree / "benchmark" / "configs" / f"{NAME}.py"))
-    ref = _load(str(tree / "benchmark" / "reference" / f"{NAME}.py"))
+    mod = load(str(tree / "benchmark" / "configs" / f"{NAME}.py"))
+    ref = load(str(tree / "benchmark" / "reference" / f"{NAME}.py"))
     ref.QUERIES, ref.ROWS = 32, 40       # blocks that do not divide the row
     cfg = _toy_cfg(tree)
     built = mod.build(cfg, {"vocab_size": 256, "seq_len": seq})
@@ -446,7 +404,8 @@ def _toy_model(tree, seq=96, seed=0):
                                       jnp.int32),
              "segment_ids": jnp.asarray(
                  np.sort(rng.integers(0, 4, (1, seq)), axis=1), jnp.int32)}
-    params = built["model"].init(jax.random.PRNGKey(seed), batch)["params"]
+    init = jax.jit(built["model"].init)
+    params = init(jax.random.PRNGKey(seed), batch)["params"]
     params = jax.tree.map(
         lambda a: a + jnp.asarray(0.05 * rng.normal(size=a.shape), a.dtype),
         params)
@@ -458,11 +417,13 @@ def test_the_timed_forward_gives_the_terms_the_reference_gives(tree):
     (``step_parts``: the pass that gives the loss) and the three probes are
     the reference's; a run whose held share is under the floor compares as
     infinite."""
+    import jax
     import numpy as np
 
     mod, ref, cfg, built, params, batch = _toy_model(tree)
-    got = mod.parts(built["model"], built["loss"], params, {}, batch)
-    want = ref.parts(params, {}, batch, cfg)
+    got = jax.jit(lambda p, b: mod.parts(built["model"], built["loss"], p,
+                                         {}, b))(params, batch)
+    want = jax.jit(lambda p, b: ref.parts(p, {}, b, cfg))(params, batch)
     assert set(got) - set(want) == {"rows_held_share"}
     assert set(cfg["check"]["term_weights"]) == set(want) - {"loss"}
     for name, value in want.items():
@@ -515,7 +476,6 @@ def test_a_program_without_the_band_is_refused_with_a_message(
 
     from benchmark.harness import runner
 
-    _toy(tree)
     sound = importlib.util.find_spec
 
     def without_the_band(name, *a, **kw):
@@ -525,34 +485,11 @@ def test_a_program_without_the_band_is_refused_with_a_message(
         return sound(name, *a, **kw)
 
     monkeypatch.setattr(importlib.util, "find_spec", without_the_band)
-    with pytest.raises(runner.Refused) as e:
+    with cells.run_in(tree), pytest.raises(runner.Refused) as e:
         runner.measure(str(tree), CELL, seed=3, seconds=0.5, trace=False,
                        t_process=time.perf_counter(), master="local[1]")
     assert "band" in e.value.args[0][0]
     assert not (tree / "benchmark" / ".cache").exists()   # no feed was built
-
-
-def _ctx(events, steps=2, laps=()):
-    """A traced run's context with hand-made device events (name, start ns,
-    duration ns, info)."""
-    peaks = _json("benchmark", "peaks.json")
-    return {"trace": {"devices": {"0": {"XLA Ops": events}}, "steps": steps,
-                      "host": {}},
-            "cfg": _json("benchmark", "configs", NAME + ".json"),
-            "traffic": _json("benchmark", "traffic", TRAFFIC + ".json"),
-            "peaks": peaks["TPU v5 lite"], "cell": {"config_mod": _mod()},
-            "facts": {}, "laps": list(laps)}
-
-
-def _ev(name, start_ms, dur_ms, op="custom-call", result=""):
-    kind = name.rsplit(".", 1)[0]
-    return [name, start_ms * 1e6, dur_ms * 1e6,
-            {"kind": kind, "op": op, "result": result[:120]}]
-
-
-def _reader(name):
-    return _load(os.path.join(ROOT, "benchmark", "layer_metrics",
-                              name + ".py")).read
 
 
 def _band_step(t0, scale):
@@ -561,7 +498,7 @@ def _band_step(t0, scale):
     layers, which are not the band's."""
     from benchmark.harness import flops, flops_window
 
-    ctx = _ctx([])
+    ctx = cells.ctx(CELL, [])
     work = flops_window.flash_window_kernels(
         **_mod().flash_window_shapes(ctx["cfg"], ctx["traffic"]),
         pairs_share=0.95)
@@ -571,11 +508,12 @@ def _band_step(t0, scale):
                      "flash_window_bwd_dkv"):
             ms = scale * 1e3 * flops.least_seconds(
                 work[name]["ops"], work[name]["bytes"], ctx["peaks"])[0]
-            events.append(_ev(f"{name}.1", t, ms))
+            events.append(ev(f"{name}.1", t, ms, op="custom-call"))
             t += ms
     for _ in range(2):
-        events.append(_ev("flash_fwd.1", t, 9.0))
-        events.append(_ev("flash_bwd_dkv.1", t + 9, 9.0))
+        events.append(ev("flash_fwd.1", t, 9.0, op="custom-call"))
+        events.append(ev("flash_bwd_dkv.1", t + 9, 9.0,
+                         op="custom-call"))
         t += 18
     return events
 
@@ -588,25 +526,26 @@ def test_the_band_readers_read_the_band_kernels_and_stay_under_100():
                          "window_blocks_walked_share": 0.12,
                          "attn_pairs_share": 0.4}}]
     events = _band_step(0.0, 4.0)
-    ctx = _ctx(events, steps=1, laps=laps)
+    ctx = cells.ctx(CELL, events, steps=1, laps=laps)
     band = sum(e[2] for e in events if "flash_window_" in e[0]) / 1e6
-    assert _reader("window_attn_ms_per_step")(ctx) == pytest.approx(band)
+    assert reader("window_attn_ms_per_step")(ctx) == pytest.approx(band)
     # every kernel at four times its least time: 25%
-    assert _reader("window_attn_roofline")(ctx) == pytest.approx(25.0)
+    assert reader("window_attn_roofline")(ctx) == pytest.approx(25.0)
     assert ctx["facts"]["window_attn_roofline"][
         "pairs_share_of_the_laps"] == pytest.approx(0.95)
-    at_roofline = _ctx(_band_step(0.0, 1.0), steps=1, laps=laps)
-    assert _reader("window_attn_roofline")(at_roofline) == pytest.approx(100.0)
-    assert _reader("window_blocks_walked_share")(ctx) == pytest.approx(11.5)
+    at_roofline = cells.ctx(CELL, _band_step(0.0, 1.0), steps=1, laps=laps)
+    assert reader("window_attn_roofline")(at_roofline) == pytest.approx(100.0)
+    assert reader("window_blocks_walked_share")(ctx) == pytest.approx(11.5)
     # the causal readers take the full layers' kernels and not the band's
-    causal = _reader("flash_causal_ms_per_step")(ctx)
+    causal = reader("flash_causal_ms_per_step")(ctx)
     assert causal == pytest.approx(36.0)
     # a program without the band (no kernels, no counters): nothing, no raise
-    bare = _ctx([_ev("flash_fwd.1", 0, 5)], laps=[{"metrics": {"loss": 1}}])
+    bare = cells.ctx(CELL, [ev("flash_fwd.1", 0, 5, op="custom-call")],
+                     laps=[{"metrics": {"loss": 1}}])
     for name in TRACE_METRICS + COUNTER_METRICS:
-        assert _reader(name)(bare) is None, name
-        assert _reader(name)({**bare, "trace": None}) is None, name
+        assert reader(name)(bare) is None, name
+        assert reader(name)({**bare, "trace": None}) is None, name
     # ... and in a cell of another configuration
     other = {**ctx, "cell": {"config_mod": object()}}
     for name in TRACE_METRICS:
-        assert _reader(name)(other) is None, name
+        assert reader(name)(other) is None, name

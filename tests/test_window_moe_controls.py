@@ -6,47 +6,17 @@ planted (the cell's other tests, and the toy size, are in
 run side by side)."""
 
 import os
-import time
 
 import pytest
 
-from test_window_moe_benchmark import (  # noqa: F401 - the toy size
-    NAME, ROOT, _copy_of_the_benchmark, _load, _toy)
-
-#: the toy run is counted in steps, not seconds: the clock its window reads
-#: moves STEP_S a step from the first, so the warm-up and the 1-s window are
-#: the same steps on any machine
-STEP_S = 0.025
+import _cells as cells
+from _cells import ROOT, load
+from test_window_moe_benchmark import NAME, TOY
 
 
 @pytest.fixture(scope="module")
 def controls(tmp_path_factory):
-    """``benchmark/controls/laguna_xs2.py`` at the toy size on ``local[1]``,
-    in float32 but under the cell's OWN limits: one run of the cell, the
-    harness's comparison repeated with each fault planted."""
-    from benchmark.harness import runner, seedcache, window
-
-    class StepClock(window.LapWindow):
-        def __init__(self, **kw):
-            super().__init__(clock=self.steps_s, **kw)
-            self.first = None
-
-        def steps_s(self):
-            if self.first is None:
-                self.first = (time.perf_counter(), self.last_step)
-            t, step = self.first
-            return t + (self.last_step - step) * STEP_S
-
-    root = _copy_of_the_benchmark(
-        tmp_path_factory.mktemp("controls") / "checkout")
-    _toy(root, keep_check=True)
-    mod = _load(str(root / "benchmark" / "controls" / f"{NAME}.py"))
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(runner, "LapWindow", StepClock)
-        mp.setattr(seedcache, "ROOT", str(root / "benchmark" / ".cache"))
-        mp.setenv("DLS_TELEMETRY_DIR", str(root / "telemetry"))  # restored
-        return mod, mod.run(2 ** 31 + 11, 1.0, mod.ALL, master="local[1]",
-                            root=str(root))
+    return cells.run_controls(tmp_path_factory, NAME, TOY)
 
 
 def test_the_sound_program_is_correct_under_the_cells_own_limits(controls):
@@ -106,7 +76,7 @@ def test_a_fault_moves_the_term_that_is_there_for_it(controls, fault, term,
 def test_a_planted_fault_leaves_the_program_as_it_was():
     from distributeddeeplearningspark_tpu.models import hybrid_decoder, moe
 
-    mod = _load(os.path.join(ROOT, "benchmark", "controls", NAME + ".py"))
+    mod = load(os.path.join(ROOT, "benchmark", "controls", NAME + ".py"))
     planted = mod.faults(None, None, {"sliding_window": 16, "rope_parameters": {
         "full_attention": {"rope_theta": 5e5}}})
     for name, module, attribute in (
